@@ -33,7 +33,7 @@ from .parallel.mesh import (
     sliced_site_mesh,
 )
 
-__version__ = "0.18.0"
+__version__ = "0.19.0"
 
 
 def __getattr__(name):

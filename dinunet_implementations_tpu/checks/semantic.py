@@ -187,10 +187,9 @@ def _sub_jaxprs(params: dict):
     """All jaxprs nested in one eqn's params (scan/while/pjit/shard_map/
     custom_* — any param that is a Jaxpr, a ClosedJaxpr, or a sequence of
     them)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr as closed
+    from jax.extend.core import Jaxpr as plain
 
-    closed = jax.core.ClosedJaxpr
-    plain = jax.core.Jaxpr
     for v in params.values():
         if isinstance(v, closed):
             yield v.jaxpr
@@ -1347,10 +1346,8 @@ IDENTITY_CASES = {
 
 #: the rankDAD corner's cases — the fused power-iteration kernel only
 #: exists in the compression engines' program. The BASE cell pins
-#: fused_poweriter=False (not the auto default, which resolves per backend
-#: — on a TPU host auto=ON would flip both pairs' expectations and fail the
-#: gate spuriously), so off == baseline and on must inject the pallas_call
-#: on EVERY backend.
+#: fused_poweriter=False (what the None default resolves to on every
+#: backend), so off == baseline and on must inject the pallas_call.
 IDENTITY_CASES_RANKDAD = {
     "poweriter-fused-off": (dict(engine=dict(fused_poweriter=False)), True),
     "poweriter-fused-on": (dict(engine=dict(fused_poweriter=True)), False),

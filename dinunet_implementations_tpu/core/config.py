@@ -279,9 +279,10 @@ class TrainConfig:
     sequence_microbatches: int = 0
     # rounds-leading scan xs for the epoch loop (trainer/steps.py): the
     # default trades ~1x the epoch-input size in peak HBM residency for
-    # +9.5-21% throughput (docs/bench_scanxs_ab_r5.jsonl). False switches to
-    # the per-round dynamic-slice arm — the escape hatch for multi-GB epoch
-    # inputs where that residency bump matters more than the speed.
+    # throughput (chosen by an r5 A/B; not re-measured since — ROADMAP D3).
+    # False switches to the per-round dynamic-slice arm — the escape hatch
+    # for multi-GB epoch inputs where that residency bump matters more than
+    # the speed.
     rounds_scan_xs: bool = True
     # input pipeline (trainer/loop.py): "device" (default) uploads each
     # site's inventory to the mesh once per fit and drives every epoch from a
@@ -350,10 +351,11 @@ class TrainConfig:
     wire_stochastic: bool = False
     # fused Pallas power-iteration kernel (r14, ops/poweriter_pallas.py):
     # one VMEM-resident kernel per rank class for the rankDAD subspace
-    # iteration — no HBM round trips between power refinements. None =
-    # auto (on for the TPU backend, off elsewhere); False = the exact
-    # legacy XLA loop (program-identical, S005-gated); True forces the
-    # kernel (interpret-mode on CPU — parity tests / A/B bench).
+    # iteration — no HBM round trips between power refinements. None and
+    # False = the XLA loop (program-identical, S005-gated); True = the
+    # kernel: interpret mode on a CPU (parity tests / A/B bench), and on a
+    # TPU the compiler's error — the kernel does not lower there yet
+    # (ROADMAP S2).
     fused_poweriter: bool | None = None
     # overlapped rounds (r14, trainer/steps.py): issue round t's
     # aggregation collective while round t+1's batch gather + compute run

@@ -1,136 +1,63 @@
-"""Version compatibility for the jax APIs this repo leans on.
+"""Where the persistent XLA compilation cache lives.
 
-The codebase targets the current jax surface (top-level ``jax.shard_map``
-with ``check_vma``; ``jax.experimental.layout.Format(Layout.AUTO)``), but the
-pinned container may carry an older 0.4.x jaxlib where those are spelled
-``jax.experimental.shard_map.shard_map(..., check_rep=...)`` and
-``Layout(DeviceLocalLayout.AUTO)``. One shim owns the difference so every
-trainer/test call site stays on the new spelling.
+The repo targets one jax (``pyproject.toml``: ``jax>=0.9.0``) and calls its
+API directly (``jax.shard_map``, ``jax.lax.axis_size``,
+``jax.experimental.layout``). What every entry point still has to agree on
+is the compile cache's directory: a later process only finds what an earlier
+one compiled if both look in the same place, and whoever launches the
+processes (a chip tool, CI) must be able to put that place on a disk that
+survives. One resolver owns the choice:
+
+1. ``JAX_COMPILATION_CACHE_DIR`` set in the environment — jax reads it
+   itself at import; no code path here sets another directory.
+2. otherwise the configured path (``TrainConfig.compile_cache_dir`` / CLI
+   ``--compile-cache``);
+3. otherwise no persistent cache.
 """
 
 from __future__ import annotations
 
+import os
+
 import jax
 
-try:  # new API (jax >= 0.6): top-level shard_map, check_vma kwarg
-    from jax import shard_map as _shard_map_new
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map_new(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-except ImportError:  # 0.4.x: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-        # 0.4.x's replication checker has no rule for `while` (the low-rank
-        # engines' tol loop) and aborts instead of skipping — so the old-jax
-        # shim always runs unchecked; the new-jax path keeps full checking.
-        del check_vma
-        return _shard_map_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def axis_size(axis_name):
-    """Static size of a bound mesh/vmap axis. ``jax.lax.axis_size`` on
-    current jax; older versions spell it ``psum(1, axis)`` (a compile-time
-    constant either way)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def resolve_compile_cache_dir(configured: str = "") -> str:
+    """The directory this process's compile cache uses: the environment's
+    ``JAX_COMPILATION_CACHE_DIR`` if set, else ``configured``, else ``""``
+    (no persistent cache)."""
+    return os.environ.get(CACHE_ENV) or configured or ""
 
 
-def auto_input_format():
-    """The AUTO input-layout marker accepted by ``jax.jit(in_shardings=...)``
-    (lets XLA choose the layout of a large resident input — see
-    ``trainer.steps.compile_epoch_aot``)."""
-    try:
-        from jax.experimental.layout import Format, Layout
+def enable_compile_cache(configured: str = "") -> str:
+    """Turn the persistent compilation cache on at the resolved directory
+    (:func:`resolve_compile_cache_dir`) and return it; ``""`` = nothing to
+    enable. Idempotent — every trainer / serving engine calls it.
 
-        return Format(Layout.AUTO)
-    except ImportError:
-        from jax.experimental.layout import DeviceLocalLayout, Layout
-
-        return Layout(DeviceLocalLayout.AUTO)
-
-
-def input_formats_of(compiled):
-    """The compiled executable's chosen input layouts (name changed from
-    ``input_layouts`` to ``input_formats`` across jax versions)."""
-    if hasattr(compiled, "input_formats"):
-        return compiled.input_formats
-    return compiled.input_layouts
-
-
-#: last jaxlib known to corrupt the heap when a cache-DESERIALIZED
-#: executable coexists with the donated-table streaming step (see
-#: serving/engine.py warmup and stream_cache_safe below)
-_STREAM_CACHE_BAD_THROUGH = (0, 4)
-
-
-def stream_cache_safe(version: str | None = None) -> bool:
-    """Whether the persistent compile cache may stay enabled while warming
-    the DONATED-table streaming executables.
-
-    On jaxlib 0.4.x (observed 0.4.36, CPU) any cache-deserialized executable
-    living in the process corrupts the heap once the streaming step — whose
-    session table is an input-output-aliased donated buffer — runs
-    (segfault; repro in serving/engine.py warmup docstring and the
-    ``test_stream_cache_gate`` probe). The workaround used to bypass the
-    cache for every streaming warmup unconditionally; this gate narrows it
-    to the known-bad jaxlib range so fixed runtimes get the cache-warm
-    startup back. The subprocess regression probe in tests/test_fleet.py
-    re-runs the repro whenever this gate opens — a jaxlib that still has
-    the bug fails the probe loudly instead of corrupting a server."""
-    if version is None:
-        import jaxlib
-
-        version = jaxlib.__version__
-    try:
-        parts = tuple(int(p) for p in version.split(".")[:2])
-    except ValueError:
-        return False  # unparseable version: keep the safe bypass
-    return parts > _STREAM_CACHE_BAD_THROUGH
-
-
-def enable_compile_cache(path: str) -> None:
-    """Point jax's persistent compilation cache at ``path`` (opt-in via
-    ``TrainConfig.compile_cache_dir`` / CLI ``--compile-cache``).
-
-    Re-runs and per-fold re-fits of the same (engine, topology) program then
-    deserialize the compiled epoch instead of re-running XLA. Idempotent —
-    safe to call once per trainer. The write thresholds are zeroed so even
-    fast-compiling programs (CPU tests, --small benches) populate the cache;
-    the knobs are best-effort across jax versions."""
-    import os
-
-    os.makedirs(path, exist_ok=True)
-    try:
+    With the environment variable set, jax's own reading of it stands and
+    no directory is set here. Either way the write thresholds are zeroed so
+    fast-compiling programs (CPU tests, the small serving buckets) are
+    cached too: a second run against the same directory then adds no
+    entries."""
+    path = resolve_compile_cache_dir(configured)
+    if not path:
+        return ""
+    if not os.environ.get(CACHE_ENV):
         from jax.experimental.compilation_cache import compilation_cache as cc
 
-        cc.set_cache_dir(path)
-        # jax latches its cache-used decision on the FIRST compilation of the
-        # process (is_cache_used's once-per-task check); enabling the cache
-        # mid-session (a trainer constructed after other jax work) needs the
-        # latch cleared or nothing is ever written
-        if hasattr(cc, "reset_cache"):
+        os.makedirs(path, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != path:
+            cc.set_cache_dir(path)
+            # jax latches its cache-used decision on the FIRST compilation
+            # of the process; enabling the cache mid-session (a trainer
+            # constructed after other jax work) needs the latch cleared or
+            # nothing is ever written
             cc.reset_cache()
-    except ImportError:
-        jax.config.update("jax_compilation_cache_dir", path)
-    for opt, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except (AttributeError, ValueError):
-            pass  # older jax without this knob: its default threshold applies
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
 
 
-__all__ = [
-    "shard_map", "auto_input_format", "input_formats_of",
-    "enable_compile_cache", "stream_cache_safe",
-]
+__all__ = ["CACHE_ENV", "enable_compile_cache", "resolve_compile_cache_dir"]

@@ -101,11 +101,15 @@ def make_ica_demo_tree(
     stride: int = 10,
     seed: int = 0,
     shift: float = 0.8,
+    input_size: int = 32,
+    hidden_size: int = 24,
 ) -> str:
     """Generate an ICA-Classification simulator tree under ``root``.
 
     Class signal: label-1 subjects get a ``+shift``·σ mean shift in the
-    first quarter of the components.
+    first quarter of the components. ``input_size`` / ``hidden_size`` are
+    the model widths written into the inputspec: demo-sized by default,
+    256 / 348 for the HCP-width flagship (``chip_smoke.py``).
     """
     rng = np.random.default_rng(seed)
     spec = []
@@ -127,8 +131,8 @@ def make_ica_demo_tree(
             window_size=window,
             window_stride=stride,
             num_components=comps,
-            input_size=32,
-            hidden_size=24,
+            input_size=input_size,
+            hidden_size=hidden_size,
             num_class=2,
         ).items()})
     with open(os.path.join(root, "inputspec.json"), "w") as fh:

@@ -131,17 +131,13 @@ def make_rankdad(
     )
     ddtype = np.dtype(dcn.dtype) if dcn is not None else None
 
-    def _use_fused() -> bool:
-        # fused Pallas power iteration (ops/poweriter_pallas.py): None =
-        # auto (on for the TPU backend, off elsewhere — the interpret-mode
-        # CPU kernel exists for parity tests and the A/B bench, not as the
-        # default CPU path). Resolved lazily at trace time so engine
-        # construction never forces jax backend initialization.
-        if fused_poweriter is None:
-            return jax.default_backend() == "tpu"
-        # factory kwarg, never a tracer: a static Python flag from
-        # TrainConfig.fused_poweriter
-        return bool(fused_poweriter)  # jaxlint: disable=R005
+    # fused Pallas power iteration (ops/poweriter_pallas.py): explicit opt-in
+    # on every backend. The kernel has only ever run in interpret mode and
+    # does not lower for a TPU (Mosaic has no `scatter`; ROADMAP S2), so
+    # None resolves to the XLA loop; True on a TPU fails with the compiler's
+    # own error rather than falling back. (A factory kwarg from
+    # TrainConfig.fused_poweriter, never a tracer.)
+    fused = fused_poweriter is True
 
     def _effective_rank(g) -> int:
         # shape arithmetic only (g may be a ShapeDtypeStruct row template on
@@ -333,7 +329,7 @@ def make_rankdad(
                 return subspace_iteration_grouped(
                     [(ms, r, oms) for r, (ms, oms) in zip(rs, groups_in)],
                     dad_num_pow_iters, dad_tol, matmul_dtype=mm_dtype,
-                    fused=_use_fused(),
+                    fused=fused,
                 )
 
             results = jax.vmap(factorize)(arg)
@@ -345,7 +341,7 @@ def make_rankdad(
                     for r, idxs in order
                 ],
                 dad_num_pow_iters, dad_tol, matmul_dtype=mm_dtype,
-                fused=_use_fused(),
+                fused=fused,
             )
         for (r, idxs), pqs in zip(order, results):
             # weight one factor so the gathered reconstruction sums to the

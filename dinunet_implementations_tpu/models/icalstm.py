@@ -31,7 +31,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..core.jaxcompat import axis_size
 from .layers import BatchNorm, TorchLinearInit, compute_dtype_of, dense
 
 
@@ -426,7 +425,7 @@ class ICALstm(nn.Module):
         if self.sequence_axis is not None:
             from ..parallel.sequence import shard_sequence
 
-            n = axis_size(self.sequence_axis)
+            n = jax.lax.axis_size(self.sequence_axis)
             if S % n:
                 raise ValueError(
                     f"sequence parallelism needs windows ({S}) divisible by "

@@ -21,7 +21,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..core.jaxcompat import axis_size
 from .layers import compute_dtype_of, dense
 
 
@@ -161,7 +160,7 @@ class MultimodalNet(nn.Module):
             # the only cross-chunk op, handled by ring_attention's K/V ring)
             from ..parallel.sequence import gather_sequence, shard_sequence
 
-            n = axis_size(self.axis_name)
+            n = jax.lax.axis_size(self.axis_name)
             if T % n:
                 raise ValueError(
                     f"ring attention needs tokens ({T}) divisible by the "

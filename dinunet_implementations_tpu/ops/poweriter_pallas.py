@@ -37,9 +37,11 @@ class stops instead of spinning until the slowest class finishes).
 contractions inside the kernel (the ``lp_matmul`` policy,
 ``engines/lowrank.py``); normalization/Cholesky/σ stay f32.
 
-CPU fallback: ``interpret=True`` whenever the backend is not TPU (the
-``_interpret()`` pattern from ``ops/lstm_pallas.py``) — tier-1, the parity
-tests, and the paired A/B bench run the same kernel everywhere. VMEM
+Status: opt-in only (``fused_poweriter=True``). The kernel has run in
+interpret mode alone (``interpret=True`` on a CPU backend — tier-1, the
+parity tests, the paired A/B bench); lowering it for a TPU fails in Mosaic
+on the ``scatter`` that ``_small_cholesky`` / ``_small_tril_inverse`` build
+with ``.at[...].set`` (ROADMAP S2), and nothing catches that error. VMEM
 budget: :func:`class_fits_vmem` estimates the kernel's resident bytes and
 callers (``lowrank.subspace_iteration_grouped``) fall back to the legacy
 XLA loop for any class that would not fit — a trace-time static decision.

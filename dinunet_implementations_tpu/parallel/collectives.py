@@ -65,7 +65,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..core.jaxcompat import axis_size
 from .mesh import SITE_AXIS
 
 
@@ -291,9 +290,9 @@ def site_mean(tree, axis_name=SITE_AXIS):
     if isinstance(axis_name, PackedAxis):
         n = axis_name.pack
         if axis_name.name is not None:
-            n = n * axis_size(axis_name.name)
+            n = n * jax.lax.axis_size(axis_name.name)
         if axis_name.slice_name is not None:
-            n = n * axis_size(axis_name.slice_name)
+            n = n * jax.lax.axis_size(axis_name.slice_name)
         return jax.tree.map(
             lambda g: two_level_psum(g, axis_name) / n, tree
         )
@@ -712,8 +711,8 @@ def site_index(axis_name=SITE_AXIS):
 
 def site_count(axis_name=SITE_AXIS):
     if isinstance(axis_name, PackedAxis):
-        n = 1 if axis_name.name is None else axis_size(axis_name.name)
+        n = 1 if axis_name.name is None else jax.lax.axis_size(axis_name.name)
         if axis_name.slice_name is not None:
-            n = n * axis_size(axis_name.slice_name)
+            n = n * jax.lax.axis_size(axis_name.slice_name)
         return n * axis_name.pack
-    return axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)

@@ -30,7 +30,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..core.jaxcompat import axis_size
 from .mesh import MODEL_AXIS
 
 
@@ -51,7 +50,7 @@ def ring_attention(q, k, v, axis_name: str | None = MODEL_AXIS):
 
         return dot_product_attention(q, k, v)
 
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale = q.shape[-1] ** -0.5
     B, T, N, Hd = q.shape
 
@@ -124,7 +123,7 @@ def ring_lstm(cell_fn, x_local, h0, c0, axis_name: str = MODEL_AXIS,
     Returns ``(hs_local [B, T_local, H], (hT, cT))`` where the terminal
     carry is valid on every device (broadcast from the last ring position).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B = x_local.shape[0]
     m = _auto_microbatches(B, n) if microbatches is None else microbatches
@@ -207,7 +206,7 @@ def reverse_sequence(x_local, axis_name: str = MODEL_AXIS, axis: int = 1):
     Self-inverse, and its AD transpose is itself (ppermute + flip are both
     linear and self-inverse here), so gradients route back to the owning chunk.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     swapped = jax.lax.ppermute(
         x_local, axis_name, [(i, n - 1 - i) for i in range(n)]
     )
@@ -216,7 +215,7 @@ def reverse_sequence(x_local, axis_name: str = MODEL_AXIS, axis: int = 1):
 
 def shard_sequence(x, axis_name: str = MODEL_AXIS, axis: int = 1):
     """Split a gathered [B, T, ...] array into this device's chunk."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     T = x.shape[axis]
     chunk = T // n

@@ -227,8 +227,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--fused-poweriter", default=None,
                    choices=["auto", "on", "off"],
                    help="fused Pallas power-iteration kernel for the "
-                        "rankDAD subspace iteration (default auto: on for "
-                        "the TPU backend; ops/poweriter_pallas.py)")
+                        "rankDAD subspace iteration (ops/poweriter_pallas"
+                        ".py). auto = off on every backend: the kernel "
+                        "does not lower for a TPU yet, and 'on' there "
+                        "fails with the compiler's error")
     p.add_argument("--dp-clip", type=float, default=None, metavar="C",
                    help="privacy plane (r20, privacy/dpsgd.py): clip each "
                         "site's round-gradient L2 norm to C inside the "

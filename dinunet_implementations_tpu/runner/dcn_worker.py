@@ -443,25 +443,14 @@ def main(argv=None) -> int:
     if args.supervise:
         return _supervise(args)
 
-    # Belt and braces across jax versions: the XLA_FLAGS env var is consumed
-    # at backend-client creation (lazy — still effective even when
-    # sitecustomize imported jax at interpreter start, as long as no device
-    # was queried), and newer jax prefers the jax_num_cpu_devices knob.
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + f" --xla_force_host_platform_device_count="
-            f"{args.devices_per_process}"
-        ).strip()
-
     import jax
 
+    # CPU emulation: one child per slice over virtual CPU devices, until each
+    # child can be given chips of its own (ROADMAP R4). The device-count knob
+    # only shapes the CPU backend, so it is set whatever the platform.
     if not os.environ.get("JAX_PLATFORMS"):
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.devices_per_process)
-        except AttributeError:
-            pass  # older jax: the XLA_FLAGS device-count flag applies
+    jax.config.update("jax_num_cpu_devices", args.devices_per_process)
 
     from dinunet_implementations_tpu.parallel import (
         distributed_init,

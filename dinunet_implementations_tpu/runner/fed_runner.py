@@ -72,7 +72,45 @@ def auto_site_mesh(cfg: TrainConfig, num_sites: int):
     over either form — processes map to slices on a multi-host runtime,
     virtual devices emulate them in one process. Shared by the batch
     :class:`FedRunner` and the daemon-mode :class:`FedDaemon`, so both
-    resolve churn-capacity and fold topologies identically."""
+    resolve churn-capacity and fold topologies identically.
+
+    Logs the devices the resolved topology uses, and warns — naming the idle
+    devices and ``--sites-per-device`` — when it folds onto one device while
+    more than one accelerator is visible."""
+    import jax
+
+    from ..trainer.logs import log_info, log_warning
+
+    mesh = _resolve_site_mesh(cfg, num_sites)
+    devs = jax.devices()
+    if mesh is not None:
+        used = list(mesh.devices.flat)
+        log_info(
+            f"[mesh] {num_sites} sites on {len(used)} of {len(devs)} "
+            f"{used[0].platform} device(s), axes {dict(mesh.shape)}: "
+            + ", ".join(str(d) for d in used)
+        )
+        return mesh
+    log_info(
+        f"[mesh] {num_sites} sites folded onto one device: {devs[0]} "
+        f"({devs[0].device_kind})"
+    )
+    idle = [d for d in devs[1:] if d.platform != "cpu"]
+    if idle:
+        k = max(cfg.sites_per_device, 1)
+        log_warning(
+            f"[warn] {len(idle)} of {len(devs)} {devs[0].platform} devices "
+            f"stay idle ({', '.join(str(d) for d in idle)}): {num_sites} "
+            f"sites at --sites-per-device {k} need {num_sites // k} devices, "
+            f"so every site folds onto {devs[0]}. Pass --sites-per-device "
+            f"N with {num_sites}/N <= {len(devs)} to spread the sites."
+        )
+    return None
+
+
+def _resolve_site_mesh(cfg: TrainConfig, num_sites: int):
+    """The topology decision behind :func:`auto_site_mesh`: a mesh, or
+    ``None`` for the one-device vmap fold."""
     import jax
 
     m = max(cfg.model_axis_size, 1)

@@ -10,7 +10,8 @@ pin:
 
 - **Compile-free request path.** Warmup ``.lower().compile()``s ONE
   executable per (lane, shape bucket) — against the persistent XLA compile
-  cache (PR 4) when ``TrainConfig.compile_cache_dir`` is set, so a restart
+  cache when one is placed (``JAX_COMPILATION_CACHE_DIR``, else
+  ``TrainConfig.compile_cache_dir``; core/jaxcompat.py), so a restart
   loads machine code from disk instead of recompiling (the cold/warm gap
   ``bench.py --serve`` measures). The request path only ever invokes those
   stored ``Compiled`` executables: a shape outside the bucket set is a loud
@@ -56,6 +57,12 @@ DEFAULT_STREAM_CHUNK = 8
 
 class ServingError(RuntimeError):
     """The serving engine cannot honor a request/configuration."""
+
+
+def _nonfinite_rows(probs) -> int:
+    """Rows of an already-fetched ``[n, C]`` probability block holding a
+    NaN or an infinity."""
+    return int((~np.isfinite(probs).all(axis=-1)).sum())
 
 
 class _Req:
@@ -132,10 +139,9 @@ class InferenceEngine:
             params, batch_stats, self.meta = load_inference_state(checkpoint)
         if params is None:
             raise ServingError("need a checkpoint path or explicit params")
-        if cfg.compile_cache_dir:
-            from ..core.jaxcompat import enable_compile_cache
+        from ..core.jaxcompat import enable_compile_cache
 
-            enable_compile_cache(cfg.compile_cache_dir)
+        enable_compile_cache(cfg.compile_cache_dir)
         self.model = self.spec.build_model(cfg)
         self.task = FederatedTask(
             self.model, has_batch_stats=bool(batch_stats)
@@ -162,8 +168,7 @@ class InferenceEngine:
         self.stream_chunk = int(stream_chunk)
         self.stream_buckets = tuple(sorted(set(int(b) for b in stream_buckets)))
         # streaming lane: auto (the task/config supports it) unless the
-        # caller opts out (streaming=False — e.g. a batched-only deployment
-        # that wants the persistent-compile-cache warm start; see warmup)
+        # caller opts out (streaming=False — a batched-only deployment)
         self.streaming = self.spec.serving.supports_streaming(cfg)
         if streaming is False:
             self.streaming = False
@@ -185,6 +190,8 @@ class InferenceEngine:
         self.warmup_seconds = 0.0
         self.stats = {
             "requests": 0, "samples": 0, "stream_chunks": 0, "swaps": 0,
+            # answered rows whose probabilities were not all finite
+            "nonfinite_rows": 0,
         }
         self._max_delay_ms = max_delay_ms
         self._max_queue = max_queue
@@ -301,73 +308,51 @@ class InferenceEngine:
         ``{lane/bucket: seconds}``. After this, the engine is armed: the
         CompileGuard snapshot makes any later compilation a hard failure.
 
-        Persistent-compile-cache caveat (jax 0.4.37 / jaxlib 0.4.36, CPU):
-        when ANY cache-DESERIALIZED executable lives in the process,
-        invoking the streaming step (whose session table is a donated,
-        input-output-aliased buffer) corrupts the heap — reproduced by
-        building the engine twice against one cache dir and streaming a few
-        chunks (segfault); fresh-compiled executables are fine, and so is a
-        cache-restart of the donation-free batched lane alone. The bypass is
-        gated on the KNOWN-BAD jaxlib range
-        (core/jaxcompat.py ``stream_cache_safe``): on those runtimes a
-        streaming engine pays a fresh compile per start (correctness over
-        restart latency); on fixed runtimes the cache-warm startup comes
-        back, and the tests/test_fleet.py subprocess probe re-runs the repro
-        so a still-broken jaxlib fails loudly. A batched-only engine keeps
-        the PR 4 cache's cold/warm win everywhere (``bench.py --serve``
-        measures it on exactly that shape)."""
-        import jax
+        With a persistent compile cache enabled (core/jaxcompat.py) a
+        restart loads every executable, streaming lane included, from disk
+        instead of recompiling."""
         import jax.numpy as jnp
 
         from ..checks.sanitize import CompileGuard
-        from ..core.jaxcompat import stream_cache_safe
 
         t0 = time.monotonic()
         times = {}
-        cache_prev = jax.config.jax_enable_compilation_cache
         with self.tracer.span("serve-warmup"):
-            try:
-                if self.streaming and not stream_cache_safe():
-                    jax.config.update("jax_enable_compilation_cache", False)
-                for b in self.row_buckets:
-                    tb = time.monotonic()
-                    x = jnp.zeros((b,) + self.sample_shape, jnp.float32)
-                    w = jnp.ones((b,), jnp.float32)
-                    self._exec[("infer", b)] = self._infer_jit.lower(
-                        self._params, self._stats, x, w
-                    ).compile()
-                    times[f"infer/{b}"] = round(time.monotonic() - tb, 4)
-                if self.streaming:
-                    a = self.cfg.ica_args
-                    t = self.stream_chunk
-                    for b in self.stream_buckets:
-                        tb = time.monotonic()
-                        args = (
-                            self._params, self._stats, self._table,
-                            jnp.zeros((b,), jnp.int32),
-                            jnp.zeros((b,), jnp.float32),
-                            jnp.zeros(
-                                (b, t, a.num_components, a.window_size),
-                                jnp.float32,
-                            ),
-                            jnp.zeros((b, t), jnp.float32),
-                            jnp.zeros((b,), jnp.float32),
-                        )
-                        self._exec[("stream", b)] = self._stream_jit.lower(
-                            *args
-                        ).compile()
-                        times[f"stream/{b}"] = round(
-                            time.monotonic() - tb, 4
-                        )
+            for b in self.row_buckets:
                 tb = time.monotonic()
-                self._exec[("swap", 0)] = self._swap_jit.lower(
-                    *self._live
+                x = jnp.zeros((b,) + self.sample_shape, jnp.float32)
+                w = jnp.ones((b,), jnp.float32)
+                self._exec[("infer", b)] = self._infer_jit.lower(
+                    self._params, self._stats, x, w
                 ).compile()
-                times["swap/0"] = round(time.monotonic() - tb, 4)
-            finally:
-                jax.config.update(
-                    "jax_enable_compilation_cache", cache_prev
-                )
+                times[f"infer/{b}"] = round(time.monotonic() - tb, 4)
+            if self.streaming:
+                a = self.cfg.ica_args
+                t = self.stream_chunk
+                for b in self.stream_buckets:
+                    tb = time.monotonic()
+                    args = (
+                        self._params, self._stats, self._table,
+                        jnp.zeros((b,), jnp.int32),
+                        jnp.zeros((b,), jnp.float32),
+                        jnp.zeros(
+                            (b, t, a.num_components, a.window_size),
+                            jnp.float32,
+                        ),
+                        jnp.zeros((b, t), jnp.float32),
+                        jnp.zeros((b,), jnp.float32),
+                    )
+                    self._exec[("stream", b)] = self._stream_jit.lower(
+                        *args
+                    ).compile()
+                    times[f"stream/{b}"] = round(
+                        time.monotonic() - tb, 4
+                    )
+            tb = time.monotonic()
+            self._exec[("swap", 0)] = self._swap_jit.lower(
+                *self._live
+            ).compile()
+            times["swap/0"] = round(time.monotonic() - tb, 4)
         self.warmup_seconds = round(time.monotonic() - t0, 4)
         # zero-compile proof: the jitted entries must gain NO cached programs
         # from here on (the request path runs only the stored executables —
@@ -459,8 +444,10 @@ class InferenceEngine:
             del self._mirror[:-self._mirror_cap]
         for r, lo, n in spans:
             r.future.set_result(probs[lo:lo + n])
+        bad = _nonfinite_rows(probs[:at])
         with self._lock:
             self.stats["samples"] += at
+            self.stats["nonfinite_rows"] += bad
         self._finish(reqs, "infer")
 
     def _dispatch_stream(self, reqs, bucket: int) -> None:
@@ -500,9 +487,11 @@ class InferenceEngine:
                  "generation": r.generation, "restarted": bool(r.fresh),
                  "trace_id": r.trace_id}
             )
+        bad = _nonfinite_rows(probs[:len(reqs)])
         with self._lock:
             self.stats["samples"] += len(reqs)
             self.stats["stream_chunks"] += len(reqs)
+            self.stats["nonfinite_rows"] += bad
         with self._session_lock:
             occupied, evictions = self.sessions.occupied, self.sessions.evictions
         self.bus.gauge(
@@ -827,6 +816,7 @@ class InferenceEngine:
             "deferrals": sum(L.stats["deferrals"] for L in lanes),
             "shed": sum(L.stats["shed"] for L in lanes),
             "swaps": self.stats["swaps"],
+            "nonfinite_rows": self.stats["nonfinite_rows"],
             **self._bus_labels,
             "checkpoint_traces": self.meta.get("traces") or {},
             "warmup_seconds": self.warmup_seconds,

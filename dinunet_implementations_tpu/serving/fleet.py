@@ -451,6 +451,7 @@ class ReplicaSet:
             "replicas": self.capacity,
             "restarts": self.restarts,
             "swaps": sum(p["swaps"] for p in parts),
+            "nonfinite_rows": sum(p["nonfinite_rows"] for p in parts),
             "requests": sum(p["requests"] for p in parts),
             "samples": sum(p["samples"] for p in parts),
             "stream_chunks": sum(p["stream_chunks"] for p in parts),

@@ -114,7 +114,8 @@ def render_fit(dirpath: str) -> None:
     print(
         f"env: jax {manifest.get('jax_version')} / jaxlib "
         f"{manifest.get('jaxlib_version')} · backend "
-        f"{manifest.get('backend')} · mesh "
+        f"{manifest.get('backend')} ({manifest.get('device_count')} × "
+        f"{manifest.get('device_kind')}) · mesh "
         f"{mesh if mesh else 'vmap-folded'} · pkg "
         f"{manifest.get('package_version')} · git "
         f"{(manifest.get('git_rev') or 'n/a')[:12]} · cfg "

@@ -5,8 +5,9 @@ One :class:`FitTelemetry` per fit (fold), rooted at
 ``<out_dir>/telemetry/fold_<k>/`` (or ``TrainConfig.telemetry_dir``):
 
 - ``manifest.json`` — written at open: config hash, jax/jaxlib versions,
-  backend, mesh topology, engine/task, git rev, package version. The "what
-  exactly ran" record every perf/robustness claim should ship with.
+  backend with its device kind and count, mesh topology, engine/task, git
+  rev, package version. The "what exactly ran" record every
+  perf/robustness claim should ship with.
 - ``metrics.jsonl`` — appended as the fit runs (one fsync-free line per
   record, crash-tolerant): per-epoch rows (loss, per-site grad/residual
   norms, transfer bytes, epoch seconds), instant events (checkpoint,
@@ -51,9 +52,9 @@ TRACE_CHROME_FILE = "trace.chrome.json"
 #: yields per-study artifacts that self-identify (null for solo fits).
 MANIFEST_REQUIRED = frozenset({
     "schema_version", "config_hash", "task_id", "agg_engine", "num_sites",
-    "pipeline", "fold", "jax_version", "jaxlib_version", "backend", "mesh",
-    "package_version", "git_rev", "fault_plan", "attack_plan", "privacy",
-    "tags",
+    "pipeline", "fold", "jax_version", "jaxlib_version", "backend",
+    "device_kind", "device_count", "mesh", "package_version", "git_rev",
+    "fault_plan", "attack_plan", "privacy", "tags",
 })
 
 #: required metrics.jsonl keys by row kind
@@ -151,6 +152,19 @@ def mesh_topology(mesh) -> dict | None:
     return {str(k): int(v) for k, v in dict(mesh.shape).items()}
 
 
+def tree_devices(tree) -> list[str]:
+    """Sorted ``"<platform>:<id>"`` of every device holding (a shard of) any
+    array leaf of ``tree`` — where a state actually lives, as opposed to
+    where the mesh says it should."""
+    import jax
+
+    return sorted({
+        f"{d.platform}:{d.id}"
+        for leaf in jax.tree.leaves(tree) if isinstance(leaf, jax.Array)
+        for d in leaf.devices()
+    })
+
+
 def privacy_manifest(cfg) -> dict | None:
     """The active privacy-plane configuration, verbatim (r20) — ``None``
     when the whole plane is off (dp off, secure_agg off, no personalized
@@ -197,6 +211,10 @@ def build_manifest(cfg, mesh=None, fold: int = 0, fault_plan=None,
         "jax_version": jax.__version__,
         "jaxlib_version": jaxlib.__version__,
         "backend": jax.default_backend(),
+        # which silicon: a number read off this artifact is a device number
+        # only if these say so (a CPU run reads "cpu" / "cpu" / N)
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": jax.device_count(),
         "mesh": mesh_topology(mesh),
         "package_version": __version__,
         "git_rev": _git_rev(),

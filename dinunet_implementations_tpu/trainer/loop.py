@@ -142,10 +142,9 @@ class FederatedTrainer:
         # state is donated to the epoch program (see run_epoch/_snapshot)
         self._pipeline = cfg.pipeline
         self._donate = bool(cfg.donate_epoch_state)
-        if cfg.compile_cache_dir:
-            from ..core.jaxcompat import enable_compile_cache
+        from ..core.jaxcompat import enable_compile_cache
 
-            enable_compile_cache(cfg.compile_cache_dir)
+        enable_compile_cache(cfg.compile_cache_dir)
         # unified telemetry (telemetry/): span tracer + on-device round
         # metrics + manifest/metrics artifacts. Off = a disabled (no-op)
         # tracer and a telemetry-free epoch program (bitwise-equal to the
@@ -1182,10 +1181,19 @@ class FederatedTrainer:
             results["dp_epsilon"] = self._dp_epsilon
             results["dp_delta"] = cfg.dp_delta
         if self._fit_tel is not None:
+            from ..telemetry.sink import tree_devices
+
             self._fit_summary.update(
                 best_val_epoch=int(best_epoch),
                 best_val_metric=best_metric,
                 dp_epsilon=self._dp_epsilon,
+                # where the final state lives: the replicated model, and
+                # the per-site leaves (engine state, health, round metrics)
+                # that a site mesh shards one block per member
+                params_devices=tree_devices(state.params),
+                site_state_devices=tree_devices(
+                    (state.engine_state, state.health, state.telemetry)
+                ),
             )
             for key in ("site_skipped_rounds", "site_quarantined"):
                 if results.get("site_health"):

@@ -30,7 +30,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 import optax
-from dinunet_implementations_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..engines.base import Engine, default_async_buffers, staleness_weights
@@ -1625,15 +1625,15 @@ def make_train_epoch_fn(
         # axis, which is contiguous, and under compile_epoch_aot's AUTO
         # input layouts XLA can choose a rounds-major storage order that
         # makes the moveaxis a layout assignment rather than a copy
-        # (interleaved A/B on the flagship: +9.5%/+21%,
-        # docs/bench_scanxs_ab_r5.jsonl; the r4 profile showed the strided
-        # per-round slice costing 3-7x its raw bytes). Without AOT layouts
-        # (plain jit, as the Trainer uses) the moveaxis may materialize one
-        # whole-epoch copy — no more bytes MOVED than the strided slices it
-        # replaces, but the copy coexists with the (non-donated) original,
-        # so peak HBM residency grows by ~1x the epoch-input size. For
-        # epoch inputs big enough for that to matter (multi-GB), pass
-        # rounds_scan_xs=False.
+        # (the r4 profile showed the strided per-round slice costing 3-7x
+        # its raw bytes; the r5 A/B that chose this default is not
+        # re-measured on the current toolchain — ROADMAP D3). Without AOT
+        # layouts (plain jit, as the Trainer uses) the moveaxis may
+        # materialize one whole-epoch copy — no more bytes MOVED than the
+        # strided slices it replaces, but the copy coexists with the
+        # (non-donated) original, so peak HBM residency grows by ~1x the
+        # epoch-input size. For epoch inputs big enough for that to matter
+        # (multi-GB), pass rounds_scan_xs=False.
         if use_scan_xs:
             if inventory is not None:
                 xs = (jnp.moveaxis(x_rounds, 1, 0),)
@@ -1964,9 +1964,11 @@ def compile_epoch_aot(epoch_fn, state: TrainState, x, y, w, live=None,
     after ``live`` in the positional order, so an attack-only build passes
     ``live=None`` explicitly at call time.
     """
-    from ..core.jaxcompat import auto_input_format, input_formats_of
+    from jax.experimental.layout import Format, Layout
 
-    in_sh = (jax.tree.map(lambda _: None, state), auto_input_format(), None, None)
+    in_sh = (
+        jax.tree.map(lambda _: None, state), Format(Layout.AUTO), None, None
+    )
     args = (state, x, y, w)
     if live is not None or attack is not None:
         in_sh = in_sh + (None,)
@@ -1975,7 +1977,7 @@ def compile_epoch_aot(epoch_fn, state: TrainState, x, y, w, live=None,
         in_sh = in_sh + (None,)
         args = args + (attack,)
     comp = jax.jit(epoch_fn, in_shardings=in_sh).lower(*args).compile()
-    x_fmt = input_formats_of(comp)[0][1]
+    x_fmt = comp.input_formats[0][1]
     return comp, lambda xs: jax.device_put(xs, x_fmt)
 
 

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from dinunet_implementations_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 
 from dinunet_implementations_tpu.parallel import (
     SITE_AXIS,

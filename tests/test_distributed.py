@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from dinunet_implementations_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from dinunet_implementations_tpu.parallel import (

@@ -42,7 +42,6 @@ import numpy as np
 import pytest
 
 from dinunet_implementations_tpu.core.config import NNComputation, TrainConfig
-from dinunet_implementations_tpu.core.jaxcompat import stream_cache_safe
 from dinunet_implementations_tpu.runner.registry import get_task
 from dinunet_implementations_tpu.serving import (
     AutotunerDaemon,
@@ -710,108 +709,6 @@ def test_hist_delta_rejects_backwards_series():
     b = _hist([1.0])
     with pytest.raises(HistogramShapeError, match="backwards"):
         b.delta(a)  # b is not a later snapshot of a's series
-
-
-# ---------------------------------------------------------------------------
-# streaming-warmup cache bypass: version gate + regression probe
-# ---------------------------------------------------------------------------
-
-
-def test_stream_cache_gate_versions():
-    """The PR 10 cache bypass is now a jaxlib-version gate: closed (bypass
-    on) through 0.4.x, open from 0.5 — and unparseable versions stay on
-    the safe side."""
-    assert stream_cache_safe("0.4.36") is False
-    assert stream_cache_safe("0.4.99") is False
-    assert stream_cache_safe("0.5.0") is True
-    assert stream_cache_safe("1.0.0") is True
-    assert stream_cache_safe("garbage") is False
-    import jaxlib
-
-    assert stream_cache_safe() is stream_cache_safe(jaxlib.__version__)
-
-
-@pytest.mark.slow
-def test_streaming_warmup_applies_gate(ica_env, monkeypatch):
-    """While the gate is closed on the running jaxlib, a streaming warmup
-    must turn the compilation cache OFF for the duration of warmup (the
-    heap-corruption guard) and restore it after; once a fixed jaxlib opens
-    the gate, warmup must NOT touch the cache toggle."""
-    cfg, task, params, stats = ica_env
-    toggles = []
-    real_update = jax.config.update
-
-    def spy(key, value):
-        if key == "jax_enable_compilation_cache":
-            toggles.append(value)
-        return real_update(key, value)
-
-    monkeypatch.setattr(jax.config, "update", spy)
-    prev = jax.config.jax_enable_compilation_cache
-    with InferenceEngine(
-        cfg, params=params, batch_stats=stats, row_buckets=(1,),
-        stream_buckets=(1,), stream_chunk=4, stream_slots=2,
-        max_delay_ms=1.0,
-    ) as eng:
-        eng.warmup()
-        assert eng.streaming
-    if stream_cache_safe():
-        assert toggles == [prev]  # gate open: no bypass, no-op restore only
-    else:
-        assert toggles == [False, prev]  # bypass on, then restored
-    assert jax.config.jax_enable_compilation_cache == prev
-
-
-@pytest.mark.skipif(
-    not stream_cache_safe(),
-    reason="jaxlib still in the cache-deserialization heap-corruption "
-           "range — the repro below is expected to crash; run it when a "
-           "fixed jaxlib opens the gate to retire the bypass",
-)
-def test_stream_cache_regression_probe(tmp_path):
-    """The retirement probe: on a gated-OPEN jaxlib, a subprocess that
-    deserializes a streaming executable from the compile cache and then
-    runs donated-table stream steps must exit cleanly. While the gate is
-    closed this test SKIPS (running it would segfault the worker)."""
-    import subprocess
-    import sys
-
-    code = """
-import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax, jax.numpy as jnp
-from dinunet_implementations_tpu.core.config import NNComputation, TrainConfig
-from dinunet_implementations_tpu.runner.registry import get_task
-from dinunet_implementations_tpu.serving.engine import InferenceEngine
-from dinunet_implementations_tpu.trainer.steps import FederatedTask
-import numpy as np
-
-cfg = TrainConfig(task_id=NNComputation.TASK_ICA).with_overrides({
-    "ica_args": {"num_components": 3, "window_size": 4,
-                 "temporal_size": 32, "window_stride": 4,
-                 "input_size": 8, "hidden_size": 6,
-                 "bidirectional": False},
-}).replace(compile_cache_dir=%r)
-task = FederatedTask(get_task(cfg.task_id).build_model(cfg))
-params, stats = task.init_variables(jax.random.PRNGKey(0),
-                                    jnp.ones((2, 8, 3, 4)))
-for round in range(2):  # round 1 compiles+serializes, round 2 deserializes
-    eng = InferenceEngine(cfg, params=params, batch_stats=stats,
-                          row_buckets=(1,), stream_buckets=(1,),
-                          stream_chunk=4, stream_slots=2, max_delay_ms=1.0)
-    eng.warmup()
-    x = np.zeros((4, 3, 4), np.float32)
-    for _ in range(8):
-        eng.stream("s", x).result()
-    eng.close()
-print("CLEAN")
-""" % str(tmp_path / "cache")
-    proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=300,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "CLEAN" in proc.stdout
 
 
 # ---------------------------------------------------------------------------

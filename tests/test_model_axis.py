@@ -150,7 +150,7 @@ def test_multimodal_ring_forward_matches_local():
     out_local = model_local.apply(variables, x, train=False)
 
     mesh = host_mesh(1, model_axis_size=4)
-    from dinunet_implementations_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     out_ring = shard_map(
@@ -185,7 +185,7 @@ def test_multimodal_ring_grads_match_local():
     g_local = jax.grad(loss_local)(variables["params"])
 
     mesh = host_mesh(1, model_axis_size=2)
-    from dinunet_implementations_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def sharded_grad(params):
@@ -214,7 +214,7 @@ def test_ica_ring_bf16_pallas_tracks_dense():
     """Review-finding regression (r3): ring + compute_dtype=bf16 + the fused
     kernel — the relayed carry must stay f32 at chunk boundaries, so the
     sharded forward tracks the dense forward within bf16 tolerance."""
-    from dinunet_implementations_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rng = np.random.default_rng(11)
@@ -243,7 +243,7 @@ def test_ring_dropout_decorrelated_across_chunks():
     """Train-mode dropout in the ring transformer must draw a DIFFERENT mask
     per token chunk: feed every device an identical chunk — correlated
     (tiled) dropout would make all per-device outputs identical."""
-    from dinunet_implementations_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from dinunet_implementations_tpu.models.transformer import TransformerBlock

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from dinunet_implementations_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from dinunet_implementations_tpu.engines import make_engine
 from dinunet_implementations_tpu.models import MSANNet
 from dinunet_implementations_tpu.parallel.collectives import (

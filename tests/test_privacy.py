@@ -644,7 +644,7 @@ def test_mask_psum_outside_rounds_scan_trips_s001():
         audit_jaxpr,
         check_collective_axes,
     )
-    from dinunet_implementations_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     from dinunet_implementations_tpu.parallel.mesh import SITE_AXIS, host_mesh
     from jax.sharding import PartitionSpec as P
 

@@ -34,7 +34,7 @@ from dinunet_implementations_tpu.checks.lowering import (
     normalize_lowering,
 )
 from dinunet_implementations_tpu.checks.rules import COLLECTIVE_AXIS_ARG
-from dinunet_implementations_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from dinunet_implementations_tpu.engines import make_engine
 from dinunet_implementations_tpu.engines.base import mask_dead_site
 from dinunet_implementations_tpu.parallel.collectives import (

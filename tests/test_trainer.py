@@ -574,7 +574,7 @@ def test_checkpoint_load_pre_meta_format(tmp_path):
 
 def test_rounds_scan_xs_arms_bitwise_identical():
     """The epoch's two round-delivery forms — rounds-leading scan xs (the
-    measured-faster default, docs/bench_scanxs_ab_r5.jsonl) and the
+    default) and the
     per-round dynamic-index A/B arm — must produce identical states and
     losses, so the benchmark arm can't silently rot."""
     S, steps, B, D = 3, 4, 8, 6
