@@ -17,6 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import scopes
+
 
 def is_compressible(g, min_rank_dim: int = 2) -> bool:
     return g.ndim >= 2 and min(_matrix_shape(g)) >= min_rank_dim
@@ -370,11 +372,12 @@ def subspace_iteration_grouped(groups, num_iters: int, tol: float,
             out_deltas.append(jnp.where(active, delta_new, deltas))
         return i + 1, tuple(out_Ps), tuple(out_sigs), tuple(out_deltas)
 
-    _, Ps_all, _, _ = jax.lax.while_loop(
-        cond, body,
-        (jnp.zeros((), jnp.int32), tuple(init_Ps), tuple(init_sigs),
-         tuple(init_deltas)),
-    )
+    with jax.named_scope(scopes.POWERITER):
+        _, Ps_all, _, _ = jax.lax.while_loop(
+            cond, body,
+            (jnp.zeros((), jnp.int32), tuple(init_Ps), tuple(init_sigs),
+             tuple(init_deltas)),
+        )
     return [
         [(P, mm(G.T, P, matmul_dtype)) for G, P in zip(Gs, Ps)]
         for (Gs, _), Ps in zip(prepped, Ps_all)

@@ -68,6 +68,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 B_TILE = 128
 
+# Stable kernel names (``pl.pallas_call(name=)``): the name becomes the Mosaic
+# custom call's ``kernel_name`` and a named scope around it, and the TPU
+# compiler names the instruction after it (``%lstm_fwd.22``; without a name,
+# after the enclosing flax module: ``%fwd.22``) — what a device trace shows
+# and what benchmarks/layer_metrics/lstm_{fwd,bwd}_kernel_ms_per_round.json
+# match. A rename here changes the compile-cache key and must be made there
+# too (benchmarks/tests/test_scope_metrics.py fails otherwise).
+LSTM_FWD = "lstm_fwd"
+LSTM_BWD = "lstm_bwd"
+BILSTM_FWD = "bilstm_fwd"
+BILSTM_BWD = "bilstm_bwd"
+BILSTM_POOL_FWD = "bilstm_pool_fwd"
+BILSTM_POOL_BWD = "bilstm_pool_bwd"
+KERNEL_NAMES = (LSTM_FWD, LSTM_BWD, BILSTM_FWD, BILSTM_BWD, BILSTM_POOL_FWD,
+                BILSTM_POOL_BWD)
+
 
 def _interpret() -> bool:
     # Pallas TPU kernels run in interpreter mode on CPU (tests / simulators)
@@ -163,6 +179,7 @@ def _fwd_fused_call(x, wih4, b4, whh4, h0, c0, compute_dtype=None):
         out_shape=[out_shape] * 6 + [carry_shape] * 2,
         scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 2,
         interpret=_interpret(),
+        name=LSTM_FWD,
     )(x, wih4, b4, whh4, h0, c0)
 
 
@@ -266,6 +283,7 @@ def _bwd_call(acts, cs, w4, c0, dhs, dhT, dcT, compute_dtype=None):
         out_shape=[t_shape] * 4 + [b_shape, b_shape],
         scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 2,
         interpret=_interpret(),
+        name=LSTM_BWD,
     )(*acts, cs, cs, w4T, c0, dhs, dhT, dcT)
     return outs  # dxi_i, dxi_f, dxi_o, dxi_g, dh0, dc0
 
@@ -569,6 +587,7 @@ def _fwd_bidir_call(x, wih2, b2, whh2, h02, c02, compute_dtype=None):
         out_shape=[t_shape] * 12 + [carry_shape] * 2,
         scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 4,
         interpret=_interpret(),
+        name=BILSTM_FWD,
     )(x, x, wih2, b2, whh2, h02, c02)
 
 
@@ -685,6 +704,7 @@ def _bwd_bidir_call(actsf, actsr, csf, csr, whh2, c02, dhsf, dhsr, dhT2, dcT2,
         out_shape=[t_shape] * 8 + [b2_shape, b2_shape],
         scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 4,
         interpret=_interpret(),
+        name=BILSTM_BWD,
     )(*actsf, *actsr, csf, csf, csr, csr, w2T, c02, dhsf, dhsr, dhT2, dcT2)
 
 
@@ -1027,6 +1047,7 @@ def _fwd_pool_call4(x, wih2, b2, whh2, h02, c02, compute_dtype=None):
         out_shape=[t_shape] * 12 + [c2_shape, c2_shape, p_shape, p_shape],
         scratch_shapes=[pltpu.VMEM((rows, H), jnp.float32)] * 6,
         interpret=_interpret(),
+        name=BILSTM_POOL_FWD,
     )(x, x, wih2, b2, whh2, h02, c02)
 
 
@@ -1137,6 +1158,7 @@ def _bwd_pool_call4(actsf, actsr, csf, csr, whh2, c02, dpoolf, dpoolr,
         out_shape=[t_shape] * 8 + [c2_shape, c2_shape],
         scratch_shapes=[pltpu.VMEM((rows, H), jnp.float32)] * 4,
         interpret=_interpret(),
+        name=BILSTM_POOL_BWD,
     )(*actsf, *actsr, csf, csf, csr, csr, w2T, c02, dpoolf, dpoolr,
       dhT2, dcT2)
 

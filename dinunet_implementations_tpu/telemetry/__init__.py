@@ -21,6 +21,21 @@ scripts/profile_epoch.py's one-off attribution. Now:
   configurable epoch range (``TrainConfig.xprof_dir`` / ``xprof_window``,
   CLI ``--xprof-dir``) plus the device-op trace summarizer
   scripts/profile_epoch.py consumes.
+- :mod:`.scopes` — the **device scopes**: the names of the five
+  ``jax.named_scope``s the epoch program always wears (``data/gather``,
+  ``model/fwd_bwd``, ``engine/aggregate`` with ``poweriter`` nested in it,
+  ``optimizer/update``; trainer/steps.py, engines/lowrank.py) beside the six
+  Pallas kernel names of ops/lstm_pallas.py (``lstm_fwd`` … ``bilstm_pool_bwd``).
+  Metadata only — no switch, the lowering is the same program with and
+  without them (tests/test_scopes.py). Under ``--xprof-dir`` an operator sees
+  every device op's scope as its ``tf_op`` / op name in xprof's op profile
+  and trace viewer (``jit(epoch_fn_impl)/while/body/.../model/fwd_bwd/
+  jvp(ICALstm)/lstm/fwd/lstm_fwd/pallas_call``; a transform wraps the scope
+  it maps: ``vmap(engine/aggregate)/poweriter/while/...``), and the Mosaic
+  calls as instructions ``%lstm_fwd.N`` / ``%lstm_bwd.N``. The scope lives in
+  the profile's per-instruction event METADATA, which
+  ``jax.profiler.ProfileData`` does not expose (PERF.md §3): the benchmark
+  reads the kernel names today, the scopes once its extractor reads metadata.
 - :mod:`.sink` — the per-fit ``manifest.json`` (config hash, jax versions,
   mesh topology, engine, git rev) and ``metrics.jsonl`` artifact writers,
   with the schema validators CI gates on.

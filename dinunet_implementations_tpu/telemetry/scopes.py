@@ -1,0 +1,14 @@
+"""Names of the device scopes (``jax.named_scope``) the epoch program wears.
+
+A scope is metadata on the operations traced under it (the HLO ``op_name``,
+shown by xprof as an op's ``tf_op``): no switch, no cost, always there, like
+a function's name. Constants, so that a rename is one edit here and fails
+tests/test_scopes.py and benchmarks/tests/test_scope_metrics.py instead of
+reading 0.0 on the chip.
+"""
+
+GATHER = "data/gather"  # trainer/steps.py _gather_batch
+MODEL = "model/fwd_bwd"  # trainer/steps.py grad_fn: forward, loss, backward
+ENGINE = "engine/aggregate"  # trainer/steps.py engine_aggregate
+POWERITER = "poweriter"  # engines/lowrank.py, nests: engine/aggregate/poweriter
+OPTIMIZER = "optimizer/update"  # trainer/steps.py: optimizer.update + apply

@@ -49,6 +49,7 @@ from ..parallel.mesh import (
     slice_count,
 )
 from ..robustness.health import default_health
+from ..telemetry import scopes
 from ..telemetry.metrics import (
     TELEMETRY_KEYS,
     dcn_bytes_of,
@@ -302,16 +303,17 @@ def _gather_batch(inv_x, inv_y, ixs, poison):
     traced scalar, non-None only when the epoch was compiled for a
     NaN-carrying FaultPlan) overwrites the whole round block with NaN exactly
     like ``poison_inputs`` does on host arrays."""
-    valid = ixs >= 0
-    flat = jnp.maximum(ixs, 0).reshape(-1)
-    xb = jnp.take(inv_x, flat, axis=0).reshape(ixs.shape + inv_x.shape[1:])
-    yb = jnp.take(inv_y, flat, axis=0).reshape(ixs.shape)
-    mask = valid.reshape(valid.shape + (1,) * (xb.ndim - valid.ndim))
-    xb = jnp.where(mask, xb, jnp.zeros((), xb.dtype))
-    yb = jnp.where(valid, yb, 0)
-    if poison is not None:
-        xb = jnp.where(poison > 0, jnp.full((), jnp.nan, xb.dtype), xb)
-    return xb, yb, valid.astype(jnp.float32)
+    with jax.named_scope(scopes.GATHER):
+        valid = ixs >= 0
+        flat = jnp.maximum(ixs, 0).reshape(-1)
+        xb = jnp.take(inv_x, flat, axis=0).reshape(ixs.shape + inv_x.shape[1:])
+        yb = jnp.take(inv_y, flat, axis=0).reshape(ixs.shape)
+        mask = valid.reshape(valid.shape + (1,) * (xb.ndim - valid.ndim))
+        xb = jnp.where(mask, xb, jnp.zeros((), xb.dtype))
+        yb = jnp.where(valid, yb, 0)
+        if poison is not None:
+            xb = jnp.where(poison > 0, jnp.full((), jnp.nan, xb.dtype), xb)
+        return xb, yb, valid.astype(jnp.float32)
 
 
 def make_train_epoch_fn(
@@ -576,10 +578,11 @@ def make_train_epoch_fn(
     )
 
     def engine_aggregate(grads, es, weight, axis, live, rnd):
-        if _agg_takes_rnd:
-            return engine.aggregate(grads, es, weight, axis, live=live,
-                                    rnd=rnd)
-        return engine.aggregate(grads, es, weight, axis, live=live)
+        with jax.named_scope(scopes.ENGINE):
+            if _agg_takes_rnd:
+                return engine.aggregate(grads, es, weight, axis, live=live,
+                                        rnd=rnd)
+            return engine.aggregate(grads, es, weight, axis, live=live)
 
     if min_slices < 1:
         raise ValueError(f"min_slices must be >= 1, got {min_slices}")
@@ -618,7 +621,11 @@ def make_train_epoch_fn(
             loss = loss * keep
         return loss, new_stats
 
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    _value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
+
+    def grad_fn(*args):
+        with jax.named_scope(scopes.MODEL):
+            return _value_and_grad(*args)
 
     def epoch_over_sites(state: TrainState, x, y, w, live, site_axes,
                          inner_axis, inventory=None, poison=None,
@@ -1096,8 +1103,9 @@ def make_train_epoch_fn(
                 hg = _strip(site_grad, head_paths, keep_head=True)
 
                 def upd(hp, ho, g):
-                    u, no = optimizer.update(g, ho, hp)
-                    return optax.apply_updates(hp, u), no
+                    with jax.named_scope(scopes.OPTIMIZER):
+                        u, no = optimizer.update(g, ho, hp)
+                        return optax.apply_updates(hp, u), no
 
                 if batched:
                     new_p, new_o = jax.vmap(upd)(pr["params"], pr["opt"], hg)
@@ -1572,22 +1580,27 @@ def make_train_epoch_fn(
                 total_live = jnp.where(
                     held, jnp.zeros_like(total_live), total_live
                 )
-            updates, new_opt_state = optimizer.update(agg, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            if guard:
-                # a round with zero live weight advances nothing: params AND
-                # optimizer state hold (Adam's moment decay on a zero
-                # gradient would otherwise drift the update direction)
-                params = jax.tree.map(
-                    lambda new, old: jnp.where(total_live > 0, new, old),
-                    new_params, params,
-                )
-                opt_state = jax.tree.map(
-                    lambda new, old: jnp.where(total_live > 0, new, old),
-                    new_opt_state, opt_state,
-                )
-            else:
-                params, opt_state = new_params, new_opt_state
+            # the hold is under the scope too: XLA fuses the update into
+            # the select (and into the engine's last einsum), and a fusion
+            # wears the name of its root
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, new_opt_state = optimizer.update(
+                    agg, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                if guard:
+                    # a round with zero live weight advances nothing: params
+                    # AND optimizer state hold (Adam's moment decay on a zero
+                    # gradient would otherwise drift the update direction)
+                    params = jax.tree.map(
+                        lambda new, old: jnp.where(total_live > 0, new, old),
+                        new_params, params,
+                    )
+                    opt_state = jax.tree.map(
+                        lambda new, old: jnp.where(total_live > 0, new, old),
+                        new_opt_state, opt_state,
+                    )
+                else:
+                    params, opt_state = new_params, new_opt_state
             if telem:
                 # the applied optimizer update's squared norm — global (the
                 # update is replicated), broadcast into every site's row; a

@@ -1,0 +1,120 @@
+"""Device scopes and kernel names (telemetry/scopes.py, ops/lstm_pallas.py).
+
+The epoch program names its own parts: five ``jax.named_scope``s and six
+Pallas kernel names. They are metadata: (a) every scope that applies is in
+the lowered program's debug info, (b) with the scopes taken away the
+lowering is the same program, (c) every ``pl.pallas_call`` of the LSTM
+kernels passes a distinct ``name=`` from the file's constants.
+"""
+
+import ast
+import contextlib
+import inspect
+import re
+
+import jax
+import pytest
+
+from dinunet_implementations_tpu.checks.lowering import diff_report
+from dinunet_implementations_tpu.checks.semantic import (
+    RANKDAD_IDENTITY_CELL,
+    TraceCell,
+    build_cell_inputs,
+)
+from dinunet_implementations_tpu.ops import lstm_pallas
+from dinunet_implementations_tpu.telemetry import scopes
+from dinunet_implementations_tpu.trainer.steps import (
+    epoch_program_artifacts,
+    make_train_epoch_fn,
+)
+
+#: the device pipeline, so that the on-device gather is in the program
+CELLS = {
+    "dSGD": TraceCell("dSGD", "vmap", "device"),
+    "rankDAD": TraceCell("rankDAD", "vmap", "device",
+                         engine_kw=RANKDAD_IDENTITY_CELL.engine_kw),
+}
+SCOPES = {name: getattr(scopes, name)
+          for name in ("GATHER", "MODEL", "ENGINE", "POWERITER", "OPTIMIZER")}
+
+
+def _lowered_text(cell: TraceCell, debug_info: bool) -> str:
+    task, engine, opt, _, args, mesh = build_cell_inputs(cell)
+    fn = make_train_epoch_fn(task, engine, opt, mesh=mesh,
+                             pipeline=cell.pipeline)
+    _, low, _ = epoch_program_artifacts(fn, *args, lowered=True)
+    return low.as_text(debug_info=debug_info)
+
+
+def _has_scope(text: str, name: str) -> bool:
+    """The scope as whole path pieces of some op's name (``jit(f)/a/b/dot``,
+    ``vmap(a/b)/dot``), not as a part of a longer word."""
+    return re.search(r"(?<![A-Za-z0-9_])" + re.escape(name)
+                     + r"(?![A-Za-z0-9_])", text) is not None
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    return {engine: _lowered_text(cell, debug_info=True)
+            for engine, cell in CELLS.items()}
+
+
+def test_scope_constants_are_distinct_paths():
+    assert len(set(SCOPES.values())) == len(SCOPES)
+    for s in SCOPES.values():
+        assert s == s.strip("/") and " " not in s
+
+
+@pytest.mark.parametrize("scope", sorted(SCOPES))
+@pytest.mark.parametrize("engine", sorted(CELLS))
+def test_scope_is_in_the_lowered_epoch_program(lowered, engine, scope):
+    text, name = lowered[engine], SCOPES[scope]
+    if scope == "POWERITER":
+        # nests under the engine's scope, and only rankDAD iterates; a
+        # transform wraps the scope it maps: vmap(engine/aggregate)/poweriter
+        nested = re.escape(scopes.ENGINE) + r"\)*/" + re.escape(name) + r"/while"
+        assert bool(re.search(nested, text)) == (engine == "rankDAD")
+        assert _has_scope(text, name) == (engine == "rankDAD")
+    else:
+        assert _has_scope(text, name), f"{name} missing from the {engine} program"
+
+
+@pytest.mark.parametrize("engine", sorted(CELLS))
+def test_scopes_change_metadata_and_nothing_else(monkeypatch, engine):
+    scoped = _lowered_text(CELLS[engine], debug_info=False)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _lowered_text(CELLS[engine], debug_info=True)
+    assert not _has_scope(plain, scopes.MODEL)  # the patch reached the program
+    assert diff_report(plain, scoped, "no-scopes", "scoped") is None
+
+
+def _pallas_calls():
+    tree = ast.parse(inspect.getsource(lstm_pallas))
+    return [n for n in ast.walk(tree)
+            if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+            and n.func.attr == "pallas_call"]
+
+
+def test_every_lstm_pallas_call_is_named_from_the_constants():
+    calls = _pallas_calls()
+    assert len(calls) == len(lstm_pallas.KERNEL_NAMES) == 6
+    used = []
+    for call in calls:
+        kw = {k.arg: k.value for k in call.keywords}
+        assert "name" in kw, f"pallas_call at line {call.lineno} has no name="
+        assert isinstance(kw["name"], ast.Name), call.lineno
+        used.append(getattr(lstm_pallas, kw["name"].id))
+    assert sorted(used) == sorted(set(lstm_pallas.KERNEL_NAMES))
+
+
+@pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES)
+def test_kernel_name_is_told_from_the_others_as_a_whole_word(name):
+    """The TPU compiler names a Mosaic call after the sanitized scope
+    (``%vmap_jvp_lstm_fwd__.6``); a metric tells ``lstm_fwd`` from
+    ``bilstm_fwd`` by the letters around it."""
+    word = re.compile(r"(?<![A-Za-z0-9])" + name + r"(?![A-Za-z0-9])")
+    assert re.fullmatch(r"[a-z]+(_[a-z]+)*", name)
+    for other in lstm_pallas.KERNEL_NAMES:
+        hit = word.search(f"%vmap_jvp_{other}__.6") is not None
+        assert hit == (other == name), (name, other)
