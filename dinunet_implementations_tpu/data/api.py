@@ -33,40 +33,124 @@ class SiteArrays:
         return SiteArrays(self.inputs[ix], self.labels[ix], self.indices[ix])
 
 
+# The device keeps an array's two minor dimensions in tiles of SUBLANE_TILE
+# rows by LANE_TILE columns (for 2- and 4-byte elements alike on the chips
+# this repo meets), each dimension padded up to whole tiles.
+LANE_TILE = 128
+SUBLANE_TILE = 8
+
+
+def merged_sample_shape(sample_shape) -> tuple:
+    """One sample with its trailing dimensions merged into the minor one
+    while the minor one is narrower than a lane tile: ICA ``[98, 100, 10]`` →
+    ``[98, 1000]``; FreeSurfer ``[66]`` and anything whose minor dimension
+    fills a tile stay as they are. A view of the sample's own memory."""
+    shape = tuple(int(d) for d in sample_shape)
+    while len(shape) > 1 and shape[-1] < LANE_TILE:
+        shape = shape[:-2] + (shape[-2] * shape[-1],)
+    return shape
+
+
+def stored_sample_shape(sample_shape) -> tuple:
+    """The shape ONE sample takes in the device-resident inventory: its
+    :func:`merged_sample_shape` with the rows (the dimension before the
+    minor one) rounded up to whole sublane tiles, ICA ``[98, 1000]`` →
+    ``[104, 1000]``; a sample of one dimension, or one the padding would
+    grow by more than an eighth (``[3, 128]``), is left alone.
+
+    Why: a stored sample is then a whole number of tiles, so the device's
+    default layout for ``[sites, rows, 104, 1000]`` is row-major, the
+    in-program row gather reads and writes whole tiles, and its ``[sites *
+    batch, 104, 1000]`` result is a bitcast of the ``[sites, batch, ...]``
+    operand the model's first contraction reads. With 98 rows the device
+    tiles the SITE axis with the features instead and the epoch program
+    relayouts the whole inventory before its first gather, every epoch, and
+    the gathered batch twice a round (PERF.md §5–6, PR 27). On the device
+    the pad rows are free: 98 rows occupy 104 either way."""
+    shape = merged_sample_shape(sample_shape)
+    if len(shape) >= 2:
+        rows = -(-shape[-2] // SUBLANE_TILE) * SUBLANE_TILE
+        if (rows - shape[-2]) * 8 <= shape[-2]:
+            shape = shape[:-2] + (rows, shape[-1])
+    return shape
+
+
+def stored_data_index(sample_shape) -> tuple:
+    """Index (after any leading axes) of a sample's own data inside its
+    :func:`stored_sample_shape`: everything but the pad rows."""
+    merged = merged_sample_shape(sample_shape)
+    if len(merged) < 2:
+        return (Ellipsis,)
+    return (Ellipsis, slice(0, merged[-2]), slice(None))
+
+
 @dataclass
 class SiteInventory:
-    """Every site's full dataset stacked on a common ``[S, N_max, ...]`` grid
-    — the unit of DEVICE residency (uploaded to the mesh once per fit; each
-    epoch then gathers its batches on-device from a compact index plan,
-    trainer/steps.py). Sites smaller than ``N_max`` are zero-padded; a plan
-    never points a live slot at a pad row (``counts`` bounds the valid
-    prefix), so the padding is inert ballast, not data."""
+    """Every site's full dataset stacked on a common grid — the unit of
+    DEVICE residency (uploaded to the mesh once per fit; each epoch then
+    gathers its batches on-device from a compact index plan,
+    trainer/steps.py). It is kept in the form the round's gather writes and
+    the model's first contraction reads (the RESIDENT FORM):
 
-    inputs: np.ndarray  # [S, N_max, ...] float32 (cast to compute dtype at upload)
-    labels: np.ndarray  # [S, N_max] int32
+    - ``inputs [S, rows + 1, *stored_sample_shape]``: a sample occupies its
+      :func:`stored_sample_shape` (``sample_shape`` keeps the true one, which
+      the epoch program restores on the gathered batch);
+    - the grid has ONE MORE ROW than ``rows``: the last, all zeros in inputs
+      and labels, is where a plan's ``-1`` padding slots point, so padding
+      needs no mask pass over the gathered batch;
+    - sites smaller than ``rows`` are zero-padded; a plan never points a live
+      slot at such a row (``counts`` bounds the valid prefix).
+
+    Every element that is not a subject's data is zero, and is WRITTEN so
+    again at upload (:meth:`clear_padding`)."""
+
+    inputs: np.ndarray  # [S, rows + 1, *stored] float32 (cast to compute dtype at upload)
+    labels: np.ndarray  # [S, rows + 1] int32
     counts: np.ndarray  # [S] int32 — valid rows per site
+    sample_shape: tuple  # one sample as the model takes it
 
     @property
     def num_sites(self):
         return self.inputs.shape[0]
 
     @property
+    def rows(self) -> int:
+        """Subject rows per site (``N_max`` or the pinned budget): also the
+        index of the zero row."""
+        return self.inputs.shape[1] - 1
+
+    @property
     def nbytes(self) -> int:
         return self.inputs.nbytes + self.labels.nbytes
+
+    def clear_padding(self, inputs: np.ndarray, labels: np.ndarray) -> None:
+        """Write zeros, in place, into everything of ``inputs`` / ``labels``
+        (arrays of this inventory's shape, e.g. its cast copy on the way to
+        the device) that is not a subject's data: the zero row, the rows past
+        each site's count, the pad rows of every stored sample. What reaches
+        the device is then written by the upload, whatever the arrays held."""
+        merged = merged_sample_shape(self.sample_shape)
+        if len(merged) >= 2 and merged[-2] != inputs.shape[-2]:
+            inputs[..., merged[-2]:, :] = 0
+        for si, n in enumerate(self.counts):
+            inputs[si, n:] = 0
+            labels[si, n:] = 0
 
 
 def stack_site_inventory(
     sites: list["SiteArrays"], rows: int | None = None
 ) -> SiteInventory:
     """Pad heterogeneous sites (73–120 subjects in the FS fixture) onto one
-    dense ``[S, N_max, ...]`` grid. Host-side and cheap: one copy of the
-    dataset, paid once per fit instead of once per epoch.
+    dense grid in the resident form (:class:`SiteInventory`): ``[S, N_max +
+    1, *stored_sample_shape]``, the last row all zeros. Host-side and cheap:
+    one copy of the dataset, paid once per fit instead of once per epoch
+    (merging a sample's trailing dimensions is a view of the site's array).
 
     ``rows`` PINS ``N_max`` (elastic rounds, r13): the daemon-mode runner
     re-stacks the inventory on every membership change, and a joining site
     larger than any predecessor would otherwise grow the resident grid's
     traced shape and retrace the epoch. Must cover the largest site (the
-    daemon enforces this at admission)."""
+    daemon enforces this at admission); the zero row then sits at ``rows``."""
     n_max = max((len(s) for s in sites), default=0)
     assert n_max > 0, "all sites empty"
     if rows is not None:
@@ -75,18 +159,21 @@ def stack_site_inventory(
             f"({n_max} samples)"
         )
         n_max = rows
-    feat_shape = next(s.inputs.shape[1:] for s in sites if len(s))
+    feat_shape = tuple(next(s.inputs.shape[1:] for s in sites if len(s)))
+    merged = merged_sample_shape(feat_shape)
+    data = stored_data_index(feat_shape)
     S = len(sites)
-    inputs = np.zeros((S, n_max) + feat_shape, np.float32)
-    labels = np.zeros((S, n_max), np.int32)
+    inputs = np.zeros((S, n_max + 1) + stored_sample_shape(feat_shape),
+                      np.float32)
+    labels = np.zeros((S, n_max + 1), np.int32)
     counts = np.zeros((S,), np.int32)
     for si, s in enumerate(sites):
         n = len(s)
         counts[si] = n
         if n:
-            inputs[si, :n] = s.inputs
+            inputs[si, :n][data] = s.inputs.reshape((n,) + merged)
             labels[si, :n] = s.labels
-    return SiteInventory(inputs, labels, counts)
+    return SiteInventory(inputs, labels, counts, feat_shape)
 
 
 class SiteDataset:

@@ -120,8 +120,12 @@ def _normalize_cols(Y):
     # powerSGD warm-starts its q factor from the previous round's P; a
     # P=0 here would make q die permanently (q_new = MᵀP = 0 forever)
     # while its error-feedback residual grows unflushed (review, r3).
+    # A column whose norm is not a number (a NaN entry, or an overflow of
+    # the sum of squares) takes the basis vector too: a CholeskyQR round that
+    # broke down hands the next one finite columns (_cholqr_multi).
     fallback = jnp.eye(Y.shape[0], Y.shape[1], dtype=Y.dtype)
-    return jnp.where(nc > 0, Y / jnp.maximum(nc, 1e-30), fallback), nc
+    usable = (nc > 0) & jnp.isfinite(nc)
+    return jnp.where(usable, Y / jnp.maximum(nc, 1e-30), fallback), nc
 
 
 def _small_cholesky(G):
@@ -171,7 +175,22 @@ def _small_tril_inverse(L):
     return X
 
 
-def _cholqr_once_multi(Ys, shift):
+def _sound_rows(Linv, delta):
+    """``Linv`` with every row that cannot be a row of ``L⁻¹`` replaced by
+    the identity's, so that ``Q = Y·L⁻ᵀ`` keeps the normalized input column
+    there. ``L`` factors ``G + δI`` with ``G`` a Gram matrix of unit
+    columns, so ``L⁻¹(G + δI)L⁻ᵀ = I`` and a row ``x`` of ``L⁻¹`` has
+    ``δ‖x‖² ≤ 1``. Rounding over nearly dependent columns puts it at 2 to
+    100 now and then, with the column of ``Q`` still of norm about one
+    (CPU rehearsal, PERF.md §6, PR 27), so those rows stand; a row past 1e4,
+    or not a number, comes from a factorization that broke down. A row that
+    stands is left as it is, bit for bit."""
+    x_sq = delta[:, :, 0] * jnp.sum(Linv * Linv, axis=-1)
+    eye = jnp.eye(Linv.shape[-1], dtype=Linv.dtype)
+    return jnp.where((x_sq <= 1e4)[:, :, None], Linv, eye)
+
+
+def _cholqr_once_multi(Ys, shift, sound: bool = False):
     """One column-normalized shifted CholeskyQR round, LOCKSTEP over a group
     of same-r matrices (possibly different row counts).
 
@@ -183,6 +202,9 @@ def _cholqr_once_multi(Ys, shift):
     ``Q = Y·L⁻ᵀ`` via the explicit inverse (numerically the same triangular
     system as solving against ``Yᵀ``, which cannot batch across differing
     row counts).
+
+    ``sound=True`` passes ``L⁻¹`` through :func:`_sound_rows`: every column
+    of ``Q`` is then finite and bounded, whatever the factorization did.
     """
     pairs = [_normalize_cols(Y) for Y in Ys]
     Yn = [p[0] for p in pairs]
@@ -191,7 +213,8 @@ def _cholqr_once_multi(Ys, shift):
     eye = jnp.eye(r, dtype=Yn[0].dtype)
     Gms = jnp.stack([Y.T @ Y for Y in Yn])  # [L, r, r]
     tr = jnp.trace(Gms, axis1=-2, axis2=-1)[:, None, None]
-    Gms = Gms + (shift * tr + 1e-30) * eye
+    delta = shift * tr + 1e-30
+    Gms = Gms + delta * eye
     if jax.default_backend() == "tpu":
         # on TPU the LAPACK custom-calls pay ~1 µs PER MATRIX regardless of
         # batching; the unrolled forms are fused vector ops (the engines
@@ -204,6 +227,8 @@ def _cholqr_once_multi(Ys, shift):
         Linv = jax.scipy.linalg.solve_triangular(
             Ls, jnp.broadcast_to(eye, Gms.shape), lower=True
         )
+    if sound:
+        Linv = _sound_rows(Linv, delta)
     Qs = [Y @ jnp.swapaxes(Linv[i], -1, -2) for i, Y in enumerate(Yn)]
     return Qs, ncs
 
@@ -231,9 +256,22 @@ def _cholqr_multi(Ys):
     gradient rank is routinely < r, e.g. bounded by the batch size).
     ``colnorm`` is the pre-normalization column-norm vector of the first
     round — the σ-scale convergence proxy.
+
+    Every ``Q`` is FINITE for every ``Y``. In float32 the Gram matrix of
+    unit columns is positive semidefinite only up to rounding, which a few
+    steps of elimination over nearly dependent columns amplify past either
+    shift: once a converged model's gradients are small and numerically
+    rank-deficient a pivot of the second round comes out negative, its root
+    is NaN, and the parameters are NaN from that round on (rankDAD at toy
+    widths: round 338, PERF.md §6, PR 27). A first round that broke down is
+    absorbed by the second's column normalization (:func:`_normalize_cols`);
+    the second keeps, for each column its factor cannot vouch for, the first
+    round's normalized column (:func:`_sound_rows`) — in the span, of unit
+    norm, orthogonalized once. Wherever the factorization holds, the result
+    is bit for bit what it was without the check.
     """
     Q1s, colnorms = _cholqr_once_multi(Ys, 1e-6)
-    Q2s, _ = _cholqr_once_multi(Q1s, 1e-7)
+    Q2s, _ = _cholqr_once_multi(Q1s, 1e-7, sound=True)
     return Q2s, colnorms
 
 

@@ -67,6 +67,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..engines.lowrank import (
     _small_cholesky,
     _small_tril_inverse,
+    _sound_rows,
     default_omega,
 )
 
@@ -119,13 +120,14 @@ def _normalize_cols_b(Y):
     fallback = jnp.broadcast_to(
         jnp.eye(Y.shape[1], Y.shape[2], dtype=Y.dtype)[None], Y.shape
     )
+    usable = (nc > 0) & jnp.isfinite(nc)
     Yn = jnp.where(
-        (nc > 0)[:, None, :], Y / jnp.maximum(nc, 1e-30)[:, None, :], fallback
+        usable[:, None, :], Y / jnp.maximum(nc, 1e-30)[:, None, :], fallback
     )
     return Yn, nc
 
 
-def _cholqr_once_b(Y, shift):
+def _cholqr_once_b(Y, shift, sound: bool = False):
     """One column-normalized shifted CholeskyQR round over the ``[L, m, r]``
     member stack — the batched form of ``lowrank._cholqr_once_multi``, with
     the same backend split: unrolled Cholesky/triangular-inverse on TPU (a
@@ -139,7 +141,8 @@ def _cholqr_once_b(Y, shift):
     eye = jnp.eye(r, dtype=Yn.dtype)
     Gm = jnp.einsum("lmr,lms->lrs", Yn, Yn)  # [L, r, r]
     tr = jnp.trace(Gm, axis1=-2, axis2=-1)[:, None, None]
-    Gm = Gm + (shift * tr + 1e-30) * eye
+    delta = shift * tr + 1e-30
+    Gm = Gm + delta * eye
     if _interpret():
         Ls = jnp.linalg.cholesky(Gm)
         Linv = jax.scipy.linalg.solve_triangular(
@@ -148,13 +151,15 @@ def _cholqr_once_b(Y, shift):
     else:
         Ls = _small_cholesky(Gm)
         Linv = _small_tril_inverse(Ls)
+    if sound:  # finite Q whatever the factorization did (lowrank._sound_rows)
+        Linv = _sound_rows(Linv, delta)
     Q = jnp.einsum("lmr,lsr->lms", Yn, Linv)  # Y @ L⁻ᵀ per member
     return Q, nc
 
 
 def _cholqr2_b(Y):
     Q1, colnorms = _cholqr_once_b(Y, 1e-6)
-    Q2, _ = _cholqr_once_b(Q1, 1e-7)
+    Q2, _ = _cholqr_once_b(Q1, 1e-7, sound=True)
     return Q2, colnorms
 
 

@@ -378,25 +378,30 @@ def put_site_batch(mesh, arr, dtype=None):
 
 
 def put_site_inventory(mesh, inventory, input_dtype=None):
-    """One-shot placement of a padded ``[S, N_max, ...]`` site inventory
-    (data/api.py SiteInventory) onto the mesh, split over the site axis —
-    the upload the device-resident pipeline pays ONCE per fit (inputs cast to
-    the compute dtype here, so no per-epoch convert+copy ever runs
-    on-device). ``mesh=None`` is the vmap-folded single-device path (plain
-    committed local arrays); multi-host meshes take each process's
-    addressable slices exactly like the per-epoch batches used to
-    (:func:`put_site_batch`)."""
-    import jax.numpy as jnp
-
+    """One-shot placement of a site inventory in its resident form
+    (data/api.py SiteInventory: ``[S, rows + 1, *stored_sample_shape]``, the
+    last row all zeros) onto the mesh, split over the site axis — the upload
+    the device-resident pipeline pays ONCE per fit. Inputs are cast to the
+    compute dtype here, on the host, so no per-epoch convert+copy ever runs
+    on-device and the device never holds a second, transient copy; the zero
+    row and every pad row are written as zeros into the cast copy
+    (``clear_padding``), so nothing the epoch program can gather depends on
+    what the host arrays held there. The arrays go up in the device's
+    DEFAULT layout: the stored shape is what makes that the layout the gather
+    reads (an explicit ``Layout`` at ``device_put`` is rejected by an
+    executable loaded from the persistent compile cache, and relayouts on
+    the device besides: PERF.md §6, PR 26). ``mesh=None`` is the vmap-folded
+    single-device path (plain local arrays); multi-host meshes
+    take each process's addressable slices exactly like the per-epoch
+    batches used to (:func:`put_site_batch`)."""
+    inputs = np.asarray(inventory.inputs)
+    # one cast pass, always into a fresh copy: clear_padding writes into it
+    inputs = inputs.astype(inputs.dtype if input_dtype is None else input_dtype)
+    labels = np.array(inventory.labels)
+    inventory.clear_padding(inputs, labels)
     if mesh is None:
-        return (
-            jnp.asarray(inventory.inputs, dtype=input_dtype),
-            jnp.asarray(inventory.labels),
-        )
-    return (
-        put_site_batch(mesh, inventory.inputs, input_dtype),
-        put_site_batch(mesh, inventory.labels),
-    )
+        return jax.device_put(inputs), jax.device_put(labels)
+    return put_site_batch(mesh, inputs), put_site_batch(mesh, labels)
 
 
 def put_replicated(mesh, arr, dtype=None):

@@ -398,7 +398,8 @@ class FedDaemon:
     admission (dataset load) is deadline-bounded via
     :func:`~..robustness.retry.with_retry` so a half-written site directory
     fails fast instead of wedging the service. Every traced shape — the
-    ``[capacity, N, ...]`` inventory grid, the ``[capacity, steps, B]``
+    ``[capacity, N + 1, ...]`` inventory grid (its last row the zero row
+    that padding slots gather), the ``[capacity, steps, B]``
     index plan, the liveness mask — is pinned at service start, so churn
     NEVER retraces (CompileGuard-assertable: one epoch compile across any
     join → straggle → leave → rejoin sequence).

@@ -326,8 +326,16 @@ class FederatedTrainer:
         return jax.tree.map(jnp.copy, state)
 
     def _ensure_inventory(self, train_sites):
-        """Device-resident inventory: uploaded once per fit, inputs pre-cast
-        to the compute dtype at placement. Keyed by a content fingerprint
+        """Device-resident inventory: uploaded once per fit in its RESIDENT
+        FORM (data/api.py SiteInventory: ``[S, rows + 1, *stored_sample_shape]``
+        — every site's subjects, then one all-zero row that the plan's ``-1``
+        padding slots gather, each sample with its narrow trailing dimensions
+        merged and its rows padded to whole tiles), inputs pre-cast to the
+        compute dtype and every pad element written as zero at placement
+        (parallel/distributed.py put_site_inventory). That form is what the
+        round's gather writes and the model's first contraction reads, so a
+        round's batch is one row gather (trainer/steps.py _gather_batch).
+        Keyed by a content fingerprint
         (per-site array identities + sizes), not list identity, so a caller
         rebuilding its site LIST per run_epoch call (``list(sites)``) still
         reuses the resident upload — re-uploading per epoch would silently

@@ -24,6 +24,7 @@ eval single-model. Documented TPU-design divergence.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.struct
@@ -33,6 +34,7 @@ import optax
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from ..data.api import stored_data_index, stored_sample_shape
 from ..engines.base import Engine, default_async_buffers, staleness_weights
 from ..parallel.collectives import (
     PackedAxis,
@@ -170,6 +172,10 @@ class FederatedTask:
     def __init__(self, model, has_batch_stats: bool | None = None):
         self.model = model
         self.has_batch_stats = has_batch_stats  # resolved at init_variables
+        # one sample as the model takes it (no batch axis), resolved at
+        # init_variables: the device pipeline gives it back to the batches
+        # it gathers from the resident inventory (_gather_batch)
+        self.sample_shape = None
 
     def init_variables(self, rng, sample_x):
         # init runs OUTSIDE shard_map (no mesh axis bound), so a model
@@ -188,6 +194,7 @@ class FederatedTask:
             {"params": rng, "dropout": rng}, sample_x, train=True
         )
         self.has_batch_stats = "batch_stats" in variables
+        self.sample_shape = tuple(sample_x.shape[1:])
         return variables["params"], variables.get("batch_stats", {})
 
     def apply(self, params, batch_stats, x, train, rng=None, mask=None, mutable=False):
@@ -294,22 +301,48 @@ def default_overlap_stash(num_sites: int, params, batch_stats) -> dict:
     }
 
 
-def _gather_batch(inv_x, inv_y, ixs, poison):
+def _gather_batch(inv_x, inv_y, ixs, poison=None, sample_shape=None):
     """On-device batch gather for ONE site: ``ixs [L, B]`` sample positions
-    into the site's resident inventory (``inv_x [N, ...]``, ``inv_y [N]``);
-    ``-1`` marks padding. Reproduces the host materialization bit-for-bit:
-    padding slots become zero inputs / zero labels / zero weight, and
-    ``poison`` (the round's NaN-injection gate, robustness/faults.py — a
-    traced scalar, non-None only when the epoch was compiled for a
-    NaN-carrying FaultPlan) overwrites the whole round block with NaN exactly
-    like ``poison_inputs`` does on host arrays."""
+    into the site's resident inventory (``inv_x [N + 1, *stored]``, ``inv_y
+    [N + 1]``); ``-1`` marks padding. Reproduces the host materialization
+    bit-for-bit: padding slots become zero inputs / zero labels / zero
+    weight, and ``poison`` (the round's NaN-injection gate,
+    robustness/faults.py — a traced scalar, non-None only when the epoch was
+    compiled for a NaN-carrying FaultPlan) overwrites the whole round block
+    with NaN exactly like ``poison_inputs`` does on host arrays.
+
+    The inventory is in its RESIDENT FORM (data/api.py SiteInventory), so the
+    batch is ONE in-bounds row gather:
+
+    - its LAST row is all zeros and ``-1`` is pointed at it on the KB-sized
+      index block: padding slots read their zeros from the store, with no
+      mask pass over the batch and no fill-mode bounds select;
+    - a row is a sample in its ``stored_sample_shape`` (narrow trailing
+      dimensions merged, rows padded to whole tiles), so the gathered
+      ``[L·B, *stored]`` block is a bitcast of the ``[sites, batch, ...]``
+      operand the model reads. ``sample_shape`` (static; ``task.sample_shape``)
+      is one sample as the model takes it: the pad rows are sliced off and
+      the shape given back here, both of which XLA folds into the model's
+      first contraction. An inventory that already holds samples in the
+      model's own shape is gathered as it is.
+
+    An inventory built by hand without the zero row works as long as its plan
+    holds no ``-1``."""
     with jax.named_scope(scopes.GATHER):
+        stored = tuple(inv_x.shape[1:])
+        feat = stored if sample_shape is None else tuple(sample_shape)
+        if feat != stored and stored_sample_shape(feat) != stored:
+            raise ValueError(
+                f"the inventory stores samples as {stored}, which is not "
+                f"the resident form of the model's sample shape {feat}"
+            )
         valid = ixs >= 0
-        flat = jnp.maximum(ixs, 0).reshape(-1)
-        xb = jnp.take(inv_x, flat, axis=0).reshape(ixs.shape + inv_x.shape[1:])
-        yb = jnp.take(inv_y, flat, axis=0).reshape(ixs.shape)
-        mask = valid.reshape(valid.shape + (1,) * (xb.ndim - valid.ndim))
-        xb = jnp.where(mask, xb, jnp.zeros((), xb.dtype))
+        flat = jnp.where(valid, ixs, inv_x.shape[0] - 1).reshape(-1)
+        xb = jnp.take(inv_x, flat, axis=0, mode="clip")
+        if feat != stored:
+            xb = xb[stored_data_index(feat)]
+        xb = xb.reshape(ixs.shape + feat)
+        yb = jnp.take(inv_y, flat, axis=0, mode="clip").reshape(ixs.shape)
         yb = jnp.where(valid, yb, 0)
         if poison is not None:
             xb = jnp.where(poison > 0, jnp.full((), jnp.nan, xb.dtype), xb)
@@ -350,13 +383,15 @@ def make_train_epoch_fn(
 
     ``pipeline="device"`` swaps the dense epoch inputs for the
     device-resident form: the returned function takes ``(state,
-    inv_x [S, N_max, ...], inv_y [S, N_max], idx [S, steps, B], live=None,
-    poison=None)`` — the inventory is uploaded once per fit and reused every
-    epoch, the per-epoch transfer is the int32 index plan
+    inv_x [S, N_max + 1, *stored], inv_y [S, N_max + 1], idx [S, steps, B],
+    live=None, poison=None)`` — the inventory is uploaded once per fit and
+    reused every epoch, the per-epoch transfer is the int32 index plan
     (data/batching.py EpochPlan), and batches are gathered on-device
-    round-by-round inside the scan (``jnp.take`` along the inventory axis;
-    weights/padding derived from ``idx``, bit-exact with the host
-    materialization). ``poison [S, rounds]`` is the FaultPlan NaN-injection
+    round-by-round inside the scan: ONE row gather a round from the
+    inventory's resident form (data/api.py SiteInventory; weights derived
+    from ``idx``, padding read from the inventory's zero row, bit-exact with
+    the host materialization — :func:`_gather_batch`). ``poison [S,
+    rounds]`` is the FaultPlan NaN-injection
     mask (a traced input like ``live`` — one compiled program per fit
     regardless of the fault pattern). The device path always delivers rounds
     as scan xs (the index plan is KB-sized; ``rounds_scan_xs`` only governs
@@ -938,12 +973,13 @@ def make_train_epoch_fn(
                 # on-device batch gather from the resident inventory — only
                 # this round's [k, L, B, ...] block is materialized
                 inv_x, inv_y = inventory
+                gather = functools.partial(
+                    _gather_batch, sample_shape=task.sample_shape
+                )
                 if pz is None:
-                    xb, yb, wb = jax.vmap(
-                        lambda ex, ey, ixs: _gather_batch(ex, ey, ixs, None)
-                    )(inv_x, inv_y, ib)
+                    xb, yb, wb = jax.vmap(gather)(inv_x, inv_y, ib)
                 else:
-                    xb, yb, wb = jax.vmap(_gather_batch)(inv_x, inv_y, ib, pz)
+                    xb, yb, wb = jax.vmap(gather)(inv_x, inv_y, ib, pz)
             rng, sub = jax.random.split(rng)
 
             def site_micro(xs, ys, ws, ab_site=None, pr_site=None):

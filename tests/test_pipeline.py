@@ -9,8 +9,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dinunet_implementations_tpu.checks.sanitize import jit_cache_size
 from dinunet_implementations_tpu.core.config import TrainConfig
-from dinunet_implementations_tpu.data.api import SiteArrays, stack_site_inventory
+from dinunet_implementations_tpu.data.api import (
+    SiteArrays,
+    merged_sample_shape,
+    stack_site_inventory,
+    stored_data_index,
+    stored_sample_shape,
+)
 from dinunet_implementations_tpu.data.batching import (
     epoch_steps,
     materialize_plan,
@@ -18,8 +25,9 @@ from dinunet_implementations_tpu.data.batching import (
     plan_epoch_positions,
 )
 from dinunet_implementations_tpu.engines import make_engine
-from dinunet_implementations_tpu.models import MSANNet
+from dinunet_implementations_tpu.models import ICALstm, MSANNet
 from dinunet_implementations_tpu.parallel import host_mesh
+from dinunet_implementations_tpu.parallel.distributed import put_site_inventory
 from dinunet_implementations_tpu.robustness import FaultPlan, Preempted, poison_inputs
 from dinunet_implementations_tpu.trainer import (
     FederatedTask,
@@ -28,6 +36,8 @@ from dinunet_implementations_tpu.trainer import (
     make_optimizer,
     make_train_epoch_fn,
 )
+from dinunet_implementations_tpu.trainer import loop as trainer_loop
+from dinunet_implementations_tpu.trainer.steps import _gather_batch
 
 
 def _mk_site(n, d=6, seed=0):
@@ -237,6 +247,190 @@ def test_trainer_device_fit_matches_host_fit_with_faults():
                                res["device"]["epoch_losses"], rtol=0, atol=0)
     assert res["host"]["site_health"] == res["device"]["site_health"]
     assert res["host"]["test_metrics"] == res["device"]["test_metrics"]
+
+
+# ---------------------------------------------------------------------------
+# the resident form (ISSUE 27): a round's batch is one row gather from an
+# inventory stored as the gather writes and the model reads
+# ---------------------------------------------------------------------------
+
+# windows x components x timepoints: merged to [29, 128], stored as [32, 128]
+# (29 is no multiple of the 8-row tile, like HCP's 98 windows)
+ICA_SHAPE = (29, 16, 8)
+
+
+def _sites_of(shape, sizes, seed=0):
+    rng = np.random.default_rng(seed)
+    out = []
+    for n in sizes:
+        x = rng.normal(size=(n,) + shape).astype(np.float32)
+        y = (x.reshape(n, -1).sum(-1) > 0).astype(np.int32)
+        out.append(SiteArrays(x, y, np.arange(n, dtype=np.int32)))
+    return out
+
+
+def _nan_in_padding(inv):
+    """NaN (inputs) and 7 (labels) in every element of the host inventory
+    that is not a subject's data: the zero row, rows past a site's count, the
+    tile padding of every stored sample."""
+    data_rows = merged_sample_shape(inv.sample_shape)[:1]
+    if len(inv.inputs.shape) > 3 and data_rows[0] != inv.inputs.shape[2]:
+        inv.inputs[:, :, data_rows[0]:] = np.nan
+    for si, n in enumerate(inv.counts):
+        inv.inputs[si, n:] = np.nan
+        inv.labels[si, n:] = 7
+    return inv
+
+
+GATHER_CASES = {
+    # name: (sample shape, site sizes, pad_mode, drop_last, pinned rows, dtype)
+    "ica_windows_not_x8": (ICA_SHAPE, [24, 24, 24], "wrap", True, None, None),
+    "ica_bf16_minus_one": (ICA_SHAPE, [20, 9, 13], "mask", False, None,
+                           jnp.bfloat16),
+    "rows_of_features": ((6,), [32, 32, 32], "wrap", True, None, None),
+    "unequal_sites_wrap": ((6,), [40, 21, 33], "wrap", True, None, None),
+    "unequal_sites_minus_one": ((6,), [40, 21, 33], "mask", False, None, None),
+    "fixed_inventory_rows": ((6,), [40, 21, 33], "mask", False, 48, None),
+    "minor_fills_a_tile": ((5, 128), [16, 11, 16], "mask", False, None, None),
+}
+
+
+def test_stored_sample_shape_is_a_function_of_the_shape_alone():
+    assert merged_sample_shape((98, 100, 10)) == (98, 1000)
+    assert stored_sample_shape((98, 100, 10)) == (104, 1000)  # HCP
+    assert stored_sample_shape(ICA_SHAPE) == (32, 128)
+    assert stored_sample_shape((66,)) == (66,)  # one dimension: no pad
+    assert stored_sample_shape((8, 4, 4)) == (128,)  # merges to one
+    assert stored_sample_shape((5, 128)) == (5, 128)  # 3 of 8 more: too much
+    assert stored_sample_shape((96, 256)) == (96, 256)  # whole tiles already
+    assert stored_sample_shape((4, 64, 200)) == (4, 64, 200)
+
+
+@pytest.mark.parametrize("poison", [False, True])
+@pytest.mark.parametrize("case", sorted(GATHER_CASES))
+def test_device_gather_equals_host_materialization(case, poison):
+    """Inputs, labels and weights of every round, bit for bit, from an
+    inventory whose host padding held NaN before the upload; the uploaded
+    zero row and pad rows are exactly zero."""
+    shape, sizes, pad_mode, drop_last, rows, dtype = GATHER_CASES[case]
+    sites = _sites_of(shape, sizes, seed=len(case))
+    plan = plan_epoch_positions(sites, 8, seed=5, pad_mode=pad_mode,
+                                drop_last=drop_last)
+    if pad_mode == "mask" and sizes != [sizes[0]] * len(sizes):
+        assert (plan.positions < 0).any()  # the case does hold -1 slots
+    fb = materialize_plan(sites, plan)
+    want = fb.inputs if dtype is None else fb.inputs.astype(dtype)
+    nan = np.zeros((len(sites), plan.steps), bool)
+    if poison:
+        nan[1, 0] = nan[2, plan.steps - 1] = True
+        want = poison_inputs(want, nan, 1)
+    inv = _nan_in_padding(stack_site_inventory(sites, rows))
+    n_max = rows or max(sizes)
+    assert inv.rows == n_max and inv.sample_shape == shape
+    assert inv.inputs.shape == (len(sites), n_max + 1) + stored_sample_shape(shape)
+    inv_x, inv_y = put_site_inventory(None, inv, dtype)
+
+    up_x, up_y = np.asarray(inv_x, np.float32), np.asarray(inv_y)
+    merged = merged_sample_shape(shape)
+    for si, s in enumerate(sites):
+        data = up_x[si, :len(s)][stored_data_index(shape)]
+        ref = s.inputs if dtype is None else np.asarray(
+            jnp.asarray(s.inputs, dtype), np.float32)
+        np.testing.assert_array_equal(data.reshape(s.inputs.shape), ref)
+        np.testing.assert_array_equal(up_y[si, :len(s)], s.labels)
+        assert not up_x[si, len(s):].any() and not up_y[si, len(s):].any()
+    if len(merged) > 1:
+        assert not up_x[:, :, merged[0]:].any()
+    assert not up_x[:, -1].any() and not up_y[:, -1].any()
+
+    # one round block a step ([L=1, B]), as the rounds scan gathers them
+    gather = jax.jit(jax.vmap(lambda ex, ey, ix, pz: _gather_batch(
+        ex, ey, ix, pz if poison else None, sample_shape=shape)))
+    for step in range(plan.steps):
+        xb, yb, wb = gather(inv_x, inv_y,
+                            jnp.asarray(plan.positions[:, step:step + 1]),
+                            jnp.asarray(nan[:, step], jnp.float32))
+        np.testing.assert_array_equal(
+            np.asarray(xb, np.float32)[:, 0],
+            np.asarray(want[:, step], np.float32))
+        np.testing.assert_array_equal(np.asarray(yb)[:, 0], fb.labels[:, step])
+        np.testing.assert_array_equal(np.asarray(wb)[:, 0], fb.weights[:, step])
+
+
+def test_gather_refuses_an_inventory_of_another_shape():
+    with pytest.raises(ValueError, match="resident form"):
+        _gather_batch(jnp.zeros((5, 30, 128)), jnp.zeros((5,), jnp.int32),
+                      jnp.zeros((1, 2), jnp.int32), sample_shape=ICA_SHAPE)
+
+
+def _resident_fit(monkeypatch, kind, nan_padding, epochs=3):
+    """``epochs`` epochs through FederatedTrainer's device pipeline; with
+    ``nan_padding`` the host inventory's padding holds NaN before upload."""
+    if kind == "ica":
+        model = ICALstm(input_size=8, hidden_size=8, num_comps=ICA_SHAPE[1],
+                        window_size=ICA_SHAPE[2], dropout_rate=0.0)
+        sites = _sites_of(ICA_SHAPE, [20, 9, 13], seed=2)
+        rows = None
+    else:
+        model = MSANNet(in_size=6, hidden_sizes=(16,), out_size=2)
+        sites = _hetero_sites()
+        rows = 48
+    if nan_padding:
+        monkeypatch.setattr(
+            trainer_loop, "stack_site_inventory",
+            lambda s, r=None: _nan_in_padding(stack_site_inventory(s, r)))
+    cfg = TrainConfig(epochs=epochs, batch_size=8, pipeline="device")
+    tr = FederatedTrainer(cfg, model, None)
+    tr.fixed_inventory_rows = rows
+    state = tr.init_state(jnp.ones((8,) + sites[0].inputs.shape[1:]),
+                          num_sites=len(sites))
+    losses, sizes = [], []
+    for epoch in range(1, epochs + 1):
+        state, epoch_losses = tr.run_epoch(state, sites, epoch, batch_size=8)
+        losses.append(np.asarray(epoch_losses))
+        sizes.append(jit_cache_size(tr.epoch_fn))
+    return np.concatenate(losses), jax.device_get(state.params), sizes
+
+
+@pytest.mark.parametrize("kind", ["ica", "rows_pinned"])
+def test_epoch_does_not_read_what_the_upload_did_not_write(kind, monkeypatch):
+    """The padding of the host arrays is overwritten at upload, not trusted:
+    NaN there changes nothing; and three epochs compile one program."""
+    clean = _resident_fit(monkeypatch, kind, nan_padding=False)
+    dirty = _resident_fit(monkeypatch, kind, nan_padding=True)
+    assert np.isfinite(clean[0]).all()
+    np.testing.assert_array_equal(clean[0], dirty[0])
+    jax.tree.map(np.testing.assert_array_equal, clean[1], dirty[1])
+    for sizes in (clean[2], dirty[2]):
+        assert sizes == [sizes[0]] * 3 and sizes[0] in (1, None)
+
+
+def test_device_epoch_matches_host_for_a_padded_sample_shape():
+    """A sample the inventory stores padded and merged (29 windows in 32
+    rows, 16 x 8 in 128 columns) trains bit for bit as the host pipeline's
+    dense ``[S, steps, B, 29, 16, 8]`` epoch does, ``-1`` slots included."""
+    sites = _sites_of(ICA_SHAPE, [20, 9, 13], seed=4)
+    task = FederatedTask(ICALstm(
+        input_size=8, hidden_size=8, num_comps=ICA_SHAPE[1],
+        window_size=ICA_SHAPE[2], dropout_rate=0.0))
+    engine, opt = make_engine("dSGD"), make_optimizer("adam", 1e-2)
+    plan = plan_epoch_positions(sites, 8, seed=7, pad_mode="mask",
+                                drop_last=False)
+    assert (plan.positions < 0).any()
+    fb = materialize_plan(sites, plan)
+    inv_x, inv_y = put_site_inventory(None, stack_site_inventory(sites))
+    s0 = init_train_state(task, engine, opt, jax.random.PRNGKey(0),
+                          jnp.ones((4,) + ICA_SHAPE), num_sites=3)
+    fh = make_train_epoch_fn(task, engine, opt, None, 1)
+    fd = make_train_epoch_fn(task, engine, opt, None, 1, pipeline="device")
+    sh, lh = fh(s0, jnp.asarray(fb.inputs), jnp.asarray(fb.labels),
+                jnp.asarray(fb.weights))
+    sd, ld = fd(s0, inv_x, inv_y, jnp.asarray(plan.positions))
+    np.testing.assert_array_equal(np.asarray(lh), np.asarray(ld))
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+        sh.params, sd.params,
+    )
 
 
 # ---------------------------------------------------------------------------

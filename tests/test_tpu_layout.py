@@ -1,0 +1,131 @@
+"""The resident inventory against the TPU's own compiler (ISSUE 27).
+
+Nothing runs and no chip is needed: the TPU compiler installed here compiles
+for a DESCRIBED v5e. Held: the device's default layout for the stored
+inventory is row-major (why ``stored_sample_shape`` pads 98 windows to 104),
+and the dSGD epoch program at HCP widths consumes the inventory argument as it
+arrives (no copy of it), gathers whole stored rows, and passes neither a
+select nor a copy over the gathered batch.
+
+The topology is described inside a fixture, never at import, and every test
+that needs it lives in this one file: only one process may hold libtpu.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dinunet_implementations_tpu.data.api import (
+    merged_sample_shape,
+    stored_sample_shape,
+)
+
+HCP_SAMPLE = (98, 100, 10)  # 98 windows of 100 components x 10 timepoints
+SITES, ROWS, BATCH, STEPS = 32, 40, 16, 2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _parameter_layout(text: str, index: int = 0) -> tuple:
+    """Minor-to-major order of entry parameter ``index`` in optimized HLO."""
+    entry = text[text.index("ENTRY"):]
+    m = re.search(r"= \w+\[[0-9,]*\]\{([0-9,]*)[:}][^\n]*? parameter\(%d\)"
+                  % index, entry)
+    assert m, "parameter not found"
+    return tuple(int(d) for d in m.group(1).split(","))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_default_layout_of_the_stored_inventory_is_row_major(
+        one_chip, no_compile_cache, dtype):
+    """Rows padded to whole tiles: row-major, a sample contiguous. The
+    sample's own 98 rows: the device tiles the SITE axis with the features,
+    and a row gather would first relayout the whole inventory."""
+    def layout_of(sample):
+        x = jax.ShapeDtypeStruct((SITES, ROWS + 1) + sample, dtype,
+                                 sharding=one_chip)
+        compiled = jax.jit(
+            lambda a: a.astype(jnp.float32).sum()).lower(x).compile()
+        return _parameter_layout(compiled.as_text())
+
+    assert stored_sample_shape(HCP_SAMPLE) == (104, 1000)
+    assert layout_of(stored_sample_shape(HCP_SAMPLE)) == (3, 2, 1, 0)
+    assert layout_of(merged_sample_shape(HCP_SAMPLE)) != (3, 2, 1, 0)
+
+
+def test_epoch_program_gathers_rows_and_nothing_else(
+        one_chip, no_compile_cache, monkeypatch):
+    from dinunet_implementations_tpu.engines import make_engine
+    from dinunet_implementations_tpu.models import ICALstm
+    from dinunet_implementations_tpu.ops import lstm_pallas
+    from dinunet_implementations_tpu.trainer import (
+        FederatedTask,
+        init_train_state,
+        make_optimizer,
+        make_train_epoch_fn,
+    )
+
+    # the program asks jax.default_backend() and would interpret its kernels
+    monkeypatch.setattr(lstm_pallas, "_interpret", lambda: False)
+    task = FederatedTask(ICALstm(
+        input_size=256, hidden_size=348, num_comps=100, window_size=10,
+        use_pallas=True, compute_dtype="bfloat16"))
+    engine, opt = make_engine("dSGD"), make_optimizer("adam", 1e-3)
+    state = jax.eval_shape(lambda: init_train_state(
+        task, engine, opt, jax.random.PRNGKey(0),
+        jnp.ones((BATCH,) + HCP_SAMPLE, jnp.float32), num_sites=SITES))
+
+    def put(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    state = jax.tree.map(lambda a: put(a.shape, a.dtype), state)
+    stored = stored_sample_shape(HCP_SAMPLE)
+    fn = make_train_epoch_fn(task, engine, opt, None, 1, pipeline="device",
+                             donate_state=True)
+    text = fn.lower(
+        state, put((SITES, ROWS + 1) + stored, jnp.bfloat16),
+        put((SITES, ROWS + 1), jnp.int32),
+        put((SITES, STEPS, BATCH), jnp.int32),
+    ).compile().as_text()
+    lines = text.splitlines()
+    assert text.count("tpu_custom_call") >= 4  # the kernels are in it
+    inv = "bf16[%d,%d,%d,%d]" % ((SITES, ROWS + 1) + stored)
+    assert inv + "{3,2,1,0" in text  # the argument, row-major
+    copies = [ln for ln in lines
+              if re.search(r"= %s\{[^}]*\} copy\(" % re.escape(inv), ln)]
+    assert not copies, copies[0][:300]
+    # the gathered batch, as [sites * batch, ...] or [sites, batch, ...],
+    # with or without the pad rows
+    batch = r"bf16\[(%d,%d|%d),(98|104),1000\]" % (SITES, BATCH, SITES * BATCH)
+    moved = [ln for ln in lines
+             if re.search(r"= %s\{[^}]*\} (select|copy)\(" % batch, ln)]
+    assert not moved, moved[0][:300]
+    assert any(re.search(r"= %s\{[^}]*\} gather\(" % batch, ln)
+               for ln in lines)
