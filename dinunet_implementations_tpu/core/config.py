@@ -39,8 +39,10 @@ class NNComputation:
     # TPU-build extensions (BASELINE.json configs):
     TASK_SMRI_3D = "sMRI-3D-Classification"
     TASK_MULTIMODAL = "Multimodal-Classification"
+    TASK_LM = "LM-NextToken"
 
-    ALL = (TASK_FREE_SURFER, TASK_ICA, TASK_SMRI_3D, TASK_MULTIMODAL)
+    ALL = (TASK_FREE_SURFER, TASK_ICA, TASK_SMRI_3D, TASK_MULTIMODAL,
+           TASK_LM)
 
 
 class AggEngine:
@@ -172,6 +174,56 @@ class MultimodalArgs:
 
 
 @dataclass
+class AFMoEArgs:
+    """AFMoE decoder as a federated next-token task (models/afmoe.py). The
+    widths default to the published Trinity-Mini ``config.json`` (source:
+    huggingface.co/arcee-ai/Trinity-Mini) under its own key names; what a
+    configuration CUTS is the depth (``num_hidden_layers``,
+    ``num_dense_layers``, ``layer_types``), the share of the routed experts
+    held here (``experts_held`` of ``num_experts``, from ``first_expert``;
+    the router keeps all ``num_experts`` outputs and ``num_experts_per_tok``)
+    and the share of the vocabulary (``vocab_rows`` of ``vocab_size``)."""
+
+    data_file: str = ""
+    seq_len: int = 8192  # a sample is seq_len + 1 token ids
+    vocab_size: int = 200192
+    vocab_rows: int = 0  # rows of the vocabulary held here; 0 = all
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    experts_held: int = 0  # routed experts held here; 0 = all
+    first_expert: int = 0  # index of the first held expert
+    num_hidden_layers: int = 32
+    num_dense_layers: int = 2
+    # one entry a layer; () = the published period, a full layer every
+    # global_attn_every_n_layers-th
+    layer_types: tuple = ()
+    global_attn_every_n_layers: int = 4
+    sliding_window: int = 2048
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    route_norm: bool = True
+    route_scale: float = 2.826
+    mup_enabled: bool = True
+    # "bfloat16" = bf16 matmuls, f32 accumulation/norms/softmax/router/loss
+    compute_dtype: str = ""
+    q_block: int = 512  # query rows an attention block holds
+    kv_chunk: int = 2048  # step in which a full layer's key prefix grows
+    loss_block: int = 1024  # positions the head and the loss hold at a time
+    dad_reduction_rank: int = 10
+    dad_num_pow_iters: int = 5
+    dad_tol: float = 1e-3
+    dad_warm_start: bool = True  # see FSArgs.dad_warm_start
+    split_files: tuple = ()
+
+
+@dataclass
 class PretrainArgs:
     """Pretraining arguments (reference ``compspec.json:128-148``)."""
 
@@ -240,6 +292,7 @@ class TrainConfig:
     ica_args: ICAArgs = field(default_factory=ICAArgs)
     smri3d_args: SMRI3DArgs = field(default_factory=SMRI3DArgs)
     multimodal_args: MultimodalArgs = field(default_factory=MultimodalArgs)
+    lm_args: AFMoEArgs = field(default_factory=AFMoEArgs)
     # --- TPU-build extras
     num_sites: int = 2
     sites_per_device: int = 1  # >1 folds several simulated sites onto one chip
@@ -446,6 +499,8 @@ class TrainConfig:
             return self.smri3d_args
         if self.task_id == NNComputation.TASK_MULTIMODAL:
             return self.multimodal_args
+        if self.task_id == NNComputation.TASK_LM:
+            return self.lm_args
         raise ValueError(f"Invalid task: {self.task_id}")
 
     def replace(self, **kw) -> "TrainConfig":
@@ -506,6 +561,7 @@ _COMPSPEC_KEY_ALIASES = {
     "ICA-Classification_args": "ica_args",
     "sMRI-3D-Classification_args": "smri3d_args",
     "Multimodal-Classification_args": "multimodal_args",
+    "LM-NextToken_args": "lm_args",
 }
 #: dataclass-typed TrainConfig fields that take dict merges, not raw replacement
 _BLOCK_FIELDS = {
@@ -513,6 +569,7 @@ _BLOCK_FIELDS = {
     "ica_args": ICAArgs,
     "smri3d_args": SMRI3DArgs,
     "multimodal_args": MultimodalArgs,
+    "lm_args": AFMoEArgs,
     "pretrain_args": PretrainArgs,
 }
 
@@ -639,6 +696,10 @@ COMPSPEC_META: dict[str, dict] = {
                             conditional=dict(variable="task_id", value="Multimodal-Classification"),
                             label="Multimodal FS+ICA transformer parameters.",
                             compspec_key="Multimodal-Classification_args"),
+    "lm_args": dict(type="object", source="owner", group="Computation", order=29,
+                    conditional=dict(variable="task_id", value="LM-NextToken"),
+                    label="AFMoE next-token language-model parameters.",
+                    compspec_key="LM-NextToken_args"),
 }
 
 

@@ -21,7 +21,7 @@ import numpy as np
 class SiteArrays:
     """One site's full dataset as dense arrays (the unit of SPMD feeding)."""
 
-    inputs: np.ndarray  # [n, ...] float32
+    inputs: np.ndarray  # [n, ...] float32 (token ids: int32)
     labels: np.ndarray  # [n] int32
     indices: np.ndarray  # [n] int32 — position in the site's sample inventory
 
@@ -104,7 +104,7 @@ class SiteInventory:
     Every element that is not a subject's data is zero, and is WRITTEN so
     again at upload (:meth:`clear_padding`)."""
 
-    inputs: np.ndarray  # [S, rows + 1, *stored] float32 (cast to compute dtype at upload)
+    inputs: np.ndarray  # [S, rows + 1, *stored] float32 (cast to compute dtype at upload) or int32 (as it is)
     labels: np.ndarray  # [S, rows + 1] int32
     counts: np.ndarray  # [S] int32 — valid rows per site
     sample_shape: tuple  # one sample as the model takes it
@@ -163,8 +163,11 @@ def stack_site_inventory(
     merged = merged_sample_shape(feat_shape)
     data = stored_data_index(feat_shape)
     S = len(sites)
-    inputs = np.zeros((S, n_max + 1) + stored_sample_shape(feat_shape),
-                      np.float32)
+    # integer samples (token ids) stay integers, host to gather: an id above
+    # 256 does not survive the upload's cast to bfloat16
+    first = next(s.inputs for s in sites if len(s))
+    dtype = np.int32 if np.issubdtype(first.dtype, np.integer) else np.float32
+    inputs = np.zeros((S, n_max + 1) + stored_sample_shape(feat_shape), dtype)
     labels = np.zeros((S, n_max + 1), np.int32)
     counts = np.zeros((S,), np.int32)
     for si, s in enumerate(sites):

@@ -1,0 +1,740 @@
+"""AFMoE decoder (the Trinity family's ``model_type: afmoe``) as a federated
+next-token task.
+
+One chip's SHARE of the model: the expert layer is told which routed experts
+it holds (``experts_held`` of ``num_experts``, from ``first_expert``), routes
+every token over all ``num_experts``, and computes its own experts' part of
+the result; the embedding and the head hold ``vocab_rows`` rows of the
+vocabulary. What the absent experts would add is left out (the exchange that
+would bring it lives on other chips); nothing here stands in for them.
+
+The block (x: ``[T, hidden]`` float32 residual stream; matmuls in
+``compute_dtype`` with float32 accumulation; norms, softmax, router, rotary
+angles, the residual and the loss in float32):
+
+- ``h0 = E[tok] * sqrt(hidden)`` (``mup_enabled``);
+- attention, every layer: ``a = RMSNorm(h)``; ``q, k, v = a Wq, a Wk, a Wv``;
+  ``q, k <- RMSNorm_head(q), RMSNorm_head(k)``; rotary positions on
+  ``sliding_attention`` layers only (a ``full_attention`` layer has no
+  positional term); grouped-query scores ``q k^T / sqrt(head_dim)``, query head
+  ``n`` reading key-value head ``n // (heads / kv_heads)``, masked to ``j <=
+  i`` and, on sliding layers, ``j > i - window``; softmax; ``o = P v``; ``o <-
+  o * sigmoid(a Wg)``; ``h <- h + RMSNorm(o Wo)``;
+- MLP, dense layers: ``m = RMSNorm(h)``; ``h <- h + RMSNorm(swiglu(m))``;
+- MLP, MoE layers: ``s = sigmoid(m Wr)``; ``sel = top_k(s + b)`` (``b`` the
+  ``expert_bias`` buffer: selection only, no gradient); ``w = s[sel]``,
+  normalized (``route_norm``) and scaled (``route_scale``); ``y = shared(m) +
+  sum over sel that are held of w_e expert_e(m)``; ``h <- h + RMSNorm(y)``;
+- ``logits = RMSNorm(h) W_head``; the loss is the mean over the sequence's
+  positions of the cross-entropy against the next token.
+
+Attention never builds a ``[T, T]`` tensor. On a TPU, at sequences of whole
+kernel blocks, it is jax's splash-attention Pallas kernels (block-sparse flash
+attention: a masked block is never visited); elsewhere it is XLA query blocks:
+a sliding layer reads, for each block, only the ``window + block`` keys its
+window touches, a full layer the causal prefix in ``kv_chunk`` steps. The
+routed experts run as grouped matrix products (``jax.lax.ragged_dot``) over
+the token assignments sorted by expert, walked in row chunks: dropless (the
+index arrays hold the worst case, every token on held experts), memory and
+work follow the real counts.
+One ``jax.checkpoint`` a block, which keeps the routed experts' output and
+nothing else (their backward pass runs their forward itself, chunk by chunk);
+the head and the loss run in sequence blocks so ``[T, vocab]`` is never whole
+in training.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..telemetry import scopes
+from .layers import compute_dtype_of
+
+SLIDING = "sliding_attention"
+FULL = "full_attention"
+
+
+def _init(std: float = 0.02):
+    return nn.initializers.normal(std)
+
+
+def rms_norm(x, scale, eps: float):
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def _mm(x, w, cdt):
+    """``x @ w`` with operands in the compute dtype, accumulated in float32."""
+    if cdt is not None:
+        x, w = x.astype(cdt), w.astype(cdt)
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+
+
+def rotary(x, positions, theta: float):
+    """Rotate-half rotary embedding. ``x [..., T, heads, d]``, float32."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]  # [T, d/2]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def _attend(q, k, v, qpos, kpos, window):
+    """One query block against one key span. ``q [B, Q, G, R, D]``, ``k, v
+    [B, S, G, D]``; ``qpos [Q]`` / ``kpos [S]`` absolute positions (a negative
+    ``kpos`` is padding). Float32 scores and softmax."""
+    scale = q.shape[-1] ** -0.5
+    s = jnp.einsum("bqgrd,bsgd->bgrqs", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    ok = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+    if window is not None:
+        ok &= kpos[None, :] > qpos[:, None] - window
+    s = jnp.where(ok[None, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bgrqs,bsgd->bqgrd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def blocked_attention(q, k, v, window, q_block: int, kv_chunk: int, cdt=None):
+    """Causal (``window=None``) or causal-window grouped-query attention in
+    query blocks. ``q [B, T, N, D]``, ``k, v [B, T, G, D]`` -> ``[B, T, N, D]``
+    float32. Each block is recomputed in the backward pass (its scores are
+    never kept)."""
+    B, T, N, D = q.shape
+    G = k.shape[2]
+    qb = min(q_block, T)
+    if T % qb:
+        raise ValueError(f"sequence {T} is not a multiple of q_block {qb}")
+    if cdt is not None:
+        q, k, v = q.astype(cdt), k.astype(cdt), v.astype(cdt)
+    q = q.reshape(B, T // qb, qb, G, N // G, D)
+    attend = jax.checkpoint(functools.partial(_attend, window=window))
+
+    if window is not None:
+        # keys of block i: positions [i*qb - window, (i+1)*qb), read from a
+        # front-padded copy so that every block has the same span
+        span = window + qb
+        pad = ((0, 0), (window, 0), (0, 0), (0, 0))
+        kp, vp = jnp.pad(k, pad), jnp.pad(v, pad)
+
+        def one(args):
+            i, qi = args
+            lo = i * qb
+            ks = jax.lax.dynamic_slice_in_dim(kp, lo, span, axis=1)
+            vs = jax.lax.dynamic_slice_in_dim(vp, lo, span, axis=1)
+            return attend(qi, ks, vs, lo + jnp.arange(qb),
+                          lo - window + jnp.arange(span))
+
+        out = jax.lax.map(one, (jnp.arange(T // qb), jnp.moveaxis(q, 1, 0)))
+        out = jnp.moveaxis(out, 0, 1)
+    else:
+        # the causal prefix, read in kv_chunk steps: the blocks of chunk c
+        # see keys [0, (c+1)*kv_chunk)
+        chunk = max(qb, min(kv_chunk, T) // qb * qb)
+        outs = []
+        for lo in range(0, T, chunk):
+            hi = min(lo + chunk, T)
+            ks, vs = k[:, :hi], v[:, :hi]
+            kpos = jnp.arange(hi)
+
+            def one(args, ks=ks, vs=vs, kpos=kpos):
+                i, qi = args
+                return attend(qi, ks, vs, i * qb + jnp.arange(qb), kpos)
+
+            blocks = jnp.arange(lo // qb, hi // qb)
+            outs.append(jax.lax.map(
+                one, (blocks, jnp.moveaxis(q[:, lo // qb: hi // qb], 1, 0))))
+        out = jnp.moveaxis(jnp.concatenate(outs, 0), 0, 1)
+    return out.reshape(B, T, N, D)
+
+
+# -- attention as a kernel ------------------------------------------------------
+#
+# On a TPU, at sequence lengths that are whole kernel blocks, attention runs as
+# the splash-attention Pallas kernels that ship with jax (block-sparse flash
+# attention: the causal and the causal-window mask are block maps, a masked
+# block is never visited, scores live in VMEM only). Their names, as the
+# compiler's instructions carry them (a transform wraps them), are constants
+# here so that a metric's pattern has something to hold on to.
+
+ATTN_FWD = "splash_mqa_fwd"  # forward, keeps the log-sum-exp for the backward
+ATTN_DQ = "splash_mqa_dq"  # backward: the queries' cotangent
+ATTN_DKV = "splash_mqa_dkv"  # backward: the keys' and values' cotangents
+KERNEL_BLOCK = 512  # query and key rows a kernel block holds
+
+
+def _auto_pallas() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+@functools.lru_cache(maxsize=None)
+def _splash(t: int, heads_per_kv: int, window: int | None, block: int,
+            interpret: bool):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    one = (sm.CausalMask((t, t)) if window is None
+           else sm.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    kernel = sk.make_splash_mqa_single_device(
+        sm.MultiHeadMask([one] * heads_per_kv), block_sizes=sizes,
+        interpret=interpret)
+    names = {sk.get_kernel_name(True, phase == "fwd", False, phase)
+             for phase in ("fwd", "dq", "dkv")}
+    assert all(n.startswith((ATTN_FWD, ATTN_DQ, ATTN_DKV)) for n in names), names
+    return kernel
+
+
+def kernel_attention(q, k, v, window, block: int = KERNEL_BLOCK, cdt=None):
+    """:func:`blocked_attention`'s result from the splash-attention kernels:
+    every key-value head's group of query heads is one multi-query call."""
+    B, T, N, D = q.shape
+    G = k.shape[2]
+    if cdt is not None:
+        q, k, v = q.astype(cdt), k.astype(cdt), v.astype(cdt)
+    with jax.ensure_compile_time_eval():
+        kernel = _splash(T, N // G, window, min(block, T), _interpret())
+    qh = jnp.moveaxis(q.reshape(B, T, G, N // G, D), 1, 3) * (D ** -0.5)
+    out = jax.vmap(jax.vmap(kernel))(  # over sequences and key-value heads
+        qh, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2))
+    return jnp.moveaxis(out, 3, 1).reshape(B, T, N, D).astype(jnp.float32)
+
+
+# -- the routed experts ------------------------------------------------------
+#
+# ``jax.lax.ragged_dot`` is the grouped product (on a TPU the compiler turns it
+# into a kernel that walks the groups' row tiles, so its work follows the
+# group sizes). It has no batched form there, and the trainer folds sites by
+# ``vmap``: the expert layer therefore carries its own vmap rule, which folds
+# the mapped axis INTO the groups (group = (site, expert), rows sorted
+# site-major, every site's assignments on experts held elsewhere at the very
+# end), and its own backward pass, so that what vmap sees of it are two plain
+# forward computations. The weight gradient comes out per fold, as the trainer
+# wants it.
+
+_CONTRACT_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def route(scores, bias, top_k: int, route_norm: bool, route_scale: float):
+    """``(sel [T, k] int32, w [T, k] float32)``: selection by ``scores +
+    bias``, weights from ``scores`` alone."""
+    _, sel = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if route_norm:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return sel, w * route_scale
+
+
+def _plan(sel, first_expert: int, held: int):
+    """Sort the assignments ``sel [S, T, k]`` of ``S`` folds by (held expert,
+    fold): ``(order, inverse, bounds [held * S + 1], is_held [S, T, k])``;
+    group ``e * S + s`` owns the sorted rows ``bounds[e * S + s] .. bounds[e *
+    S + s + 1] - 1``, so an expert's rows (all folds') are contiguous.
+    Assignments on experts held elsewhere sort last, after every fold's."""
+    folds = sel.shape[0]
+    local = sel - first_expert
+    is_held = (local >= 0) & (local < held)
+    key = local * folds + jnp.arange(folds)[:, None, None]
+    key = jnp.where(is_held, key, folds * held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    bounds = jnp.searchsorted(
+        jnp.take(key, order), jnp.arange(folds * held + 1, dtype=key.dtype)
+    ).astype(jnp.int32)
+    return order, inverse, bounds, is_held
+
+
+ROW_CHUNK = 8192  # sorted assignment rows the expert layer holds at a time
+ROUTED_OUT = "routed_experts_out"  # the one value a block's checkpoint keeps
+KEEP_ROUTED_OUT = jax.checkpoint_policies.save_only_these_names(ROUTED_OUT)
+
+
+def _row_chunk(rows: int) -> int:
+    """Largest divisor of ``rows`` that is at most ``ROW_CHUNK``."""
+    c = min(ROW_CHUNK, rows)
+    while rows % c:
+        c -= 1
+    return c
+
+
+class _Experts:
+    """The grouped products of one call: the sorted assignment rows are walked
+    in chunks of ``chunk`` rows, and a chunk that starts past the held
+    assignments is skipped (``lax.cond``), so that memory and work follow the
+    real counts while the index arrays hold the worst case (dropless)."""
+
+    def __init__(self, m, sel, w, w1, w3, w2, first_expert, cdt):
+        self.folds, self.t, self.h = m.shape
+        self.k, self.held = sel.shape[-1], w1.shape[0]
+        self.rows = self.folds * self.t * self.k
+        self.chunk = _row_chunk(self.rows)
+        self.cast = (lambda a: a) if cdt is None else (lambda a: a.astype(cdt))
+        self.order, self.inverse, self.bounds, self.is_held = _plan(
+            sel, first_expert, self.held)
+        self.wk = jnp.where(self.is_held, w, 0.0)
+        self.tokens = self.cast(m).reshape(self.folds * self.t, self.h)
+        # an expert's rows are contiguous over the folds: the products by the
+        # (shared) stacks group by expert, only the stacks' own cotangents by
+        # (expert, fold)
+        self.w1, self.w3, self.w2 = (self.cast(a) for a in (w1, w3, w2))
+
+    def walk(self, body, carry):
+        """``carry = body(lo, carry)`` for every chunk that holds a held
+        assignment."""
+        def step(c, carry):
+            lo = c * self.chunk
+            return jax.lax.cond(lo < self.bounds[-1],
+                                lambda x: body(lo, x), lambda x: x, carry)
+
+        return jax.lax.fori_loop(0, self.rows // self.chunk, step, carry)
+
+    def rows_of(self, lo):
+        """``(token of each row, sizes of the (expert, fold) groups inside the
+        chunk, live rows)``."""
+        rows = jax.lax.dynamic_slice_in_dim(self.order, lo, self.chunk)
+        inside = jnp.clip(self.bounds, lo, lo + self.chunk)
+        live = (lo + jnp.arange(self.chunk) < self.bounds[-1])[:, None]
+        return rows // self.k, jnp.diff(inside), live
+
+    def by_expert(self, sizes):
+        """Group sizes by expert, the folds merged."""
+        return sizes.reshape(self.held, self.folds).sum(axis=1)
+
+    def forward(self, tok, sizes, live):
+        dot = functools.partial(jax.lax.ragged_dot,
+                                group_sizes=self.by_expert(sizes),
+                                preferred_element_type=jnp.float32)
+        xs = jnp.take(self.tokens, tok, axis=0)
+        a, b = dot(xs, self.w1), dot(xs, self.w3)
+        mid = self.cast(jax.nn.silu(a) * b)
+        # rows past the held assignments belong to no group: whatever the
+        # grouped product leaves there is not a result
+        return xs, a, b, mid, jnp.where(live, dot(mid, self.w2), 0.0)
+
+    def per_token(self, buf):
+        """Sorted rows ``[rows, ...]`` back in token-major order ``[S * T, k,
+        ...]``: the inverse permutation's gather, no scatter."""
+        out = jnp.take(buf, self.inverse, axis=0)
+        return out.reshape((self.folds * self.t, self.k) + buf.shape[1:])
+
+
+def _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt):
+    """``m [S, T, H]``, ``sel, w [S, T, k]`` -> the held experts' part ``[S,
+    T, H]`` float32."""
+    ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt)
+
+    def body(lo, buf):
+        ys = ex.forward(*ex.rows_of(lo))[-1]
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, ys.astype(buf.dtype), lo, axis=0)
+
+    buf = ex.walk(body, jnp.zeros((ex.rows, ex.h), ex.tokens.dtype))
+    y = jnp.einsum("nkh,nk->nh", ex.per_token(buf), ex.wk.reshape(-1, ex.k),
+                   preferred_element_type=jnp.float32)
+    return y.reshape(m.shape)
+
+
+def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt):
+    """Cotangents ``(dm [S, T, H], dw [S, T, k], dw1, dw3, dw2 [S, E, ..])``
+    of :func:`_experts_forward` for ``dy [S, T, H]``, the forward recomputed
+    chunk by chunk."""
+    ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt)
+    wdot = functools.partial(
+        jax.lax.ragged_dot_general,
+        ragged_dot_dimension_numbers=_CONTRACT_ROWS,
+        preferred_element_type=jnp.float32)
+    dys_tok = ex.cast(dy).reshape(ex.folds * ex.t, ex.h)
+    wk_sorted = jnp.take(ex.wk.reshape(-1), ex.order)
+    w1t, w3t, w2t = (jnp.swapaxes(a, 1, 2) for a in (ex.w1, ex.w3, ex.w2))
+
+    def body(lo, carry):
+        dxs_buf, dwk_buf, dw1, dw3, dw2 = carry
+        tok, sizes, live = ex.rows_of(lo)
+        xs, a, b, mid, ys = ex.forward(tok, sizes, live)
+        dot = functools.partial(jax.lax.ragged_dot,
+                                group_sizes=ex.by_expert(sizes),
+                                preferred_element_type=jnp.float32)
+        g = jnp.take(dys_tok, tok, axis=0).astype(jnp.float32)
+        wk = jax.lax.dynamic_slice_in_dim(wk_sorted, lo, ex.chunk)
+        dys = ex.cast(jnp.where(live, g * wk[:, None], 0.0))
+        dmid = dot(dys, w2t)
+        sig = jax.nn.sigmoid(a)
+        da = ex.cast(jnp.where(live, dmid * b * sig * (1.0 + a * (1.0 - sig)), 0.0))
+        db = ex.cast(jnp.where(live, dmid * a * sig, 0.0))
+        dxs = jnp.where(live, dot(da, w1t) + dot(db, w3t), 0.0)
+        put = jax.lax.dynamic_update_slice_in_dim
+        return (put(dxs_buf, dxs.astype(dxs_buf.dtype), lo, axis=0),
+                put(dwk_buf, (ys * g).sum(-1), lo, axis=0),
+                dw1 + wdot(xs, da, sizes), dw3 + wdot(xs, db, sizes),
+                dw2 + wdot(mid, dys, sizes))
+
+    groups = ex.folds * ex.held
+    f, h = w1.shape[2], ex.h
+    dxs_buf, dwk_buf, dw1, dw3, dw2 = ex.walk(body, (
+        jnp.zeros((ex.rows, h), ex.tokens.dtype),
+        jnp.zeros((ex.rows,), jnp.float32),
+        jnp.zeros((groups, h, f), jnp.float32),
+        jnp.zeros((groups, h, f), jnp.float32),
+        jnp.zeros((groups, f, h), jnp.float32)))
+    dm = ex.per_token(dxs_buf).astype(jnp.float32).sum(axis=1).reshape(m.shape)
+    dwk = jnp.where(ex.is_held, ex.per_token(dwk_buf).reshape(w.shape), 0.0)
+
+    def per_fold(x):  # [held * S, ...] expert-major -> [S, held, ...]
+        return jnp.swapaxes(x.reshape((ex.held, ex.folds) + x.shape[1:]), 0, 1)
+
+    return dm, dwk, per_fold(dw1), per_fold(dw3), per_fold(dw2)
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_layer(first_expert: int, cdt):
+    """``experts(m [T, H], sel [T, k], w [T, k], w1, w3, w2) -> [T, H]`` for
+    one share of the experts, differentiable and mappable."""
+    from jax.custom_batching import custom_vmap
+
+    def folded(fn, n_tok, n_out):
+        """``fn`` over a leading fold axis as a custom_vmap function of
+        unfolded arguments: a vmap over the first ``n_tok`` (token) arguments
+        becomes the fold axis; the expert stacks after them are shared."""
+        @custom_vmap
+        def call(*args):
+            lead = tuple(a[None] for a in args[:n_tok]) + args[n_tok:]
+            return jax.tree.map(lambda o: o[0], fn(*lead))
+
+        @call.def_vmap
+        def rule(axis_size, in_batched, *args):
+            tok, stacks = in_batched[:n_tok], in_batched[n_tok:]
+            if not all(tok) or any(stacks):
+                raise NotImplementedError(
+                    "the expert layer maps over its token arguments only "
+                    "(the trainer's site fold)")
+            return fn(*args), (True,) * n_out if n_out > 1 else True
+
+        return call
+
+    def fwd_impl(m, sel, w, w1, w3, w2):
+        return _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt)
+
+    def bwd_impl(m, sel, w, dy, w1, w3, w2):
+        return _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt)
+
+    fwd_call, bwd_call = folded(fwd_impl, 3, 1), folded(bwd_impl, 4, 5)
+
+    @jax.custom_vjp
+    def experts(m, sel, w, w1, w3, w2):
+        return fwd_call(m, sel, w, w1, w3, w2)
+
+    def experts_fwd(m, sel, w, w1, w3, w2):
+        return fwd_call(m, sel, w, w1, w3, w2), (m, sel, w, w1, w3, w2)
+
+    def experts_bwd(res, dy):
+        m, sel, w, w1, w3, w2 = res
+        dm, dw, dw1, dw3, dw2 = bwd_call(m, sel, w, dy, w1, w3, w2)
+        return (dm.astype(m.dtype), None, dw.astype(w.dtype),
+                dw1.astype(w1.dtype), dw3.astype(w3.dtype),
+                dw2.astype(w2.dtype))
+
+    experts.defvjp(experts_fwd, experts_bwd)
+    return experts
+
+
+def routed_experts(m, sel, w, w1, w3, w2, first_expert: int, cdt=None):
+    """The held experts' part of the layer for the tokens ``m [T, H]`` with
+    assignments ``sel, w [T, k]`` over ALL experts; stacks ``w1, w3 [E, H,
+    F]``, ``w2 [E, F, H]`` of the experts ``first_expert .. + E - 1`` ->
+    ``[T, H]`` float32. Dropless: the buffer holds all ``T * k`` assignments,
+    the grouped products cover ``sum(group_sizes)`` rows."""
+    return _expert_layer(first_expert, cdt)(m, sel, w, w1, w3, w2)
+
+
+# -- modules -----------------------------------------------------------------
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return rms_norm(x, scale, self.eps)
+
+
+class SwiGLU(nn.Module):
+    width: int
+    compute_dtype: str | None = None
+
+    @nn.compact
+    def __call__(self, m):
+        cdt = compute_dtype_of(self.compute_dtype)
+        h = m.shape[-1]
+        w1 = self.param("w1", _init(), (h, self.width))
+        w3 = self.param("w3", _init(), (h, self.width))
+        w2 = self.param("w2", _init(), (self.width, h))
+        act = jax.nn.silu(_mm(m, w1, cdt)) * _mm(m, w3, cdt)
+        return _mm(act, w2, cdt)
+
+
+class Attention(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: int | None  # None = full attention, no positional term
+    rope_theta: float
+    eps: float
+    q_block: int
+    kv_chunk: int
+    compute_dtype: str | None = None
+
+    @nn.compact
+    def __call__(self, a):
+        cdt = compute_dtype_of(self.compute_dtype)
+        B, T, H = a.shape
+        N, G, D = self.num_heads, self.num_kv_heads, self.head_dim
+        wq = self.param("wq", _init(), (H, N * D))
+        wk = self.param("wk", _init(), (H, G * D))
+        wv = self.param("wv", _init(), (H, G * D))
+        wg = self.param("wg", _init(), (H, N * D))
+        wo = self.param("wo", _init(), (N * D, H))
+        q = _mm(a, wq, cdt).reshape(B, T, N, D)
+        k = _mm(a, wk, cdt).reshape(B, T, G, D)
+        v = _mm(a, wv, cdt).reshape(B, T, G, D)
+        q = RMSNorm(self.eps, name="q_norm")(q)
+        k = RMSNorm(self.eps, name="k_norm")(k)
+        if self.window is not None:
+            pos = jnp.arange(T)
+            q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
+        if _auto_pallas() and T % min(KERNEL_BLOCK, T) == 0 and T >= 128:
+            o = kernel_attention(q, k, v, self.window, cdt=cdt)
+        else:
+            o = blocked_attention(q, k, v, self.window, self.q_block,
+                                  self.kv_chunk, cdt)
+        o = o.reshape(B, T, N * D)
+        o = o * jax.nn.sigmoid(_mm(a, wg, cdt))
+        return _mm(o, wo, cdt)
+
+
+class MoE(nn.Module):
+    num_experts: int
+    top_k: int
+    experts_held: int
+    first_expert: int
+    width: int
+    shared_width: int
+    route_norm: bool
+    route_scale: float
+    compute_dtype: str | None = None
+
+    @nn.compact
+    def __call__(self, m):
+        cdt = compute_dtype_of(self.compute_dtype)
+        B, T, H = m.shape
+        E, F = self.experts_held, self.width
+        router = self.param("router", _init(), (H, self.num_experts))
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (self.num_experts,))
+        w1 = self.param("w1", _init(), (E, H, F))
+        w3 = self.param("w3", _init(), (E, H, F))
+        w2 = self.param("w2", _init(), (E, F, H))
+        with jax.named_scope(scopes.MOE_ROUTE):
+            # the router reads float32 (as the family's code does): a top-k
+            # over rounded scores picks other experts than the model's
+            scores = jax.nn.sigmoid(jnp.matmul(
+                m.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            sel, w = route(scores, bias, self.top_k, self.route_norm,
+                           self.route_scale)
+        # a routing counter for whoever asks (apply(..., mutable=
+        # ["intermediates"])): assignments on each held expert, [B, held]
+        local = sel - self.first_expert
+        self.sow("intermediates", "held_counts", jnp.sum(
+            local[..., None] == jnp.arange(E), axis=(1, 2), dtype=jnp.int32))
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            # a site's sequences are just tokens to the experts
+            y = routed_experts(
+                m.reshape(B * T, H), sel.reshape(B * T, -1),
+                w.reshape(B * T, -1), w1, w3, w2, self.first_expert, cdt,
+            ).reshape(B, T, H)
+            # kept across the block's recomputation: the backward pass runs
+            # the layer's forward in chunks itself and need not run it twice
+            y = checkpoint_name(y, ROUTED_OUT)
+        if self.shared_width:
+            with jax.named_scope(scopes.MOE_SHARED):
+                y = y + SwiGLU(self.shared_width, self.compute_dtype,
+                               name="shared")(m)
+        return y
+
+
+@dataclasses.dataclass(frozen=True)
+class Dims:
+    """The decoder's sizes, as a block reads them."""
+
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    experts_held: int = 128
+    first_expert: int = 0
+    num_dense_layers: int = 2
+    layer_types: tuple = (SLIDING, SLIDING, SLIDING, FULL) * 8
+    sliding_window: int = 2048
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    route_norm: bool = True
+    route_scale: float = 2.826
+    compute_dtype: str | None = None
+    q_block: int = 512  # query rows an attention block holds (XLA path)
+    kv_chunk: int = 2048  # step in which a full layer's key prefix grows
+
+
+class Block(nn.Module):
+    dims: Dims
+    layer: int
+
+    @nn.compact
+    def __call__(self, h):
+        c = self.dims
+        sliding = c.layer_types[self.layer] == SLIDING
+        a = RMSNorm(c.rms_norm_eps, name="input_norm")(h)
+        with jax.named_scope(
+                scopes.ATTENTION_WINDOW if sliding else scopes.ATTENTION_FULL):
+            o = Attention(
+                c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                c.sliding_window if sliding else None, c.rope_theta,
+                c.rms_norm_eps, c.q_block, c.kv_chunk, c.compute_dtype,
+                name="attn")(a)
+        h = h + RMSNorm(c.rms_norm_eps, name="post_attn_norm")(o)
+        m = RMSNorm(c.rms_norm_eps, name="pre_mlp_norm")(h)
+        if self.layer < c.num_dense_layers:
+            y = SwiGLU(c.intermediate_size, c.compute_dtype, name="mlp")(m)
+        else:
+            y = MoE(c.num_experts, c.num_experts_per_tok, c.experts_held,
+                    c.first_expert, c.moe_intermediate_size,
+                    c.moe_intermediate_size * c.num_shared_experts,
+                    c.route_norm, c.route_scale, c.compute_dtype,
+                    name="moe")(m)
+        return h + RMSNorm(c.rms_norm_eps, name="post_mlp_norm")(y)
+
+
+def _head_logits(h, norm_scale, head, eps, cdt):
+    return _mm(rms_norm(h, norm_scale, eps), head, cdt)
+
+
+class AFMoE(nn.Module):
+    """The decoder. A sample is ``seq_len + 1`` token ids: the model reads the
+    first ``seq_len``, the loss the last ``seq_len``. ``__call__`` returns
+    whole logits (inference, tests); training goes through
+    :meth:`task_loss`, which the trainer's ``FederatedTask`` finds."""
+
+    dims: Dims = Dims()
+    vocab_rows: int = 200192
+    mup_enabled: bool = True
+    loss_block: int = 1024  # positions the head and the loss hold at a time
+    init_tokens: int = 8  # positions the forward traces under init()
+
+    @property
+    def compute_dtype(self):
+        return self.dims.compute_dtype
+
+    def setup(self):
+        d = self.dims
+        self.embed = self.param(
+            "embed", _init(), (self.vocab_rows, d.hidden_size))
+        self.blocks = [
+            nn.remat(Block, policy=KEEP_ROUTED_OUT)(d, i, name=f"layer_{i}")
+            for i in range(len(d.layer_types))
+        ]
+        self.final_norm = self.param(
+            "final_norm", nn.initializers.ones, (d.hidden_size,))
+        self.lm_head = self.param(
+            "lm_head", _init(), (d.hidden_size, self.vocab_rows))
+
+    def init(self, rngs, *args, **kwargs):
+        """``nn.Module.init`` under one ``jax.jit``: op by op, half a billion
+        normal draws and a traced forward are minutes on a chip."""
+        fn = functools.partial(nn.Module.init, self, **kwargs)
+        return jax.jit(fn)(rngs, *args)
+
+    def hidden(self, tokens):
+        """``tokens [B, T]`` -> the last block's output ``[B, T, hidden]``."""
+        tokens = tokens.astype(jnp.int32)
+        if self.is_initializing():
+            # no parameter's shape depends on the sequence length, and init
+            # runs op by op: a handful of positions declares everything
+            tokens = tokens[:, : self.init_tokens]
+        h = jnp.take(self.embed, tokens, axis=0)
+        if self.mup_enabled:
+            h = h * math.sqrt(self.dims.hidden_size)
+        for block in self.blocks:
+            h = block(h)
+        return h
+
+    def _logits_fn(self):
+        return functools.partial(
+            _head_logits, norm_scale=self.final_norm, head=self.lm_head,
+            eps=self.dims.rms_norm_eps,
+            cdt=compute_dtype_of(self.dims.compute_dtype))
+
+    def __call__(self, x, train: bool = True, mask=None):
+        """Logits ``[B, T, vocab_rows]`` for the first ``T = x.shape[1] - 1``
+        ids of each row (the last id is only ever a target)."""
+        h = self.hidden(x[:, :-1])
+        with jax.named_scope(scopes.LM_HEAD):
+            return self._logits_fn()(h)
+
+    def token_losses(self, x):
+        """Mean next-token cross-entropy of each row, ``[B]`` float32, the
+        head and the softmax over ``loss_block`` positions at a time."""
+        tokens = x.astype(jnp.int32)
+        h = self.hidden(tokens[:, :-1])
+        targets = tokens[:, 1:]
+        B, T, H = h.shape
+        lb = min(self.loss_block, T)
+        if T % lb:
+            raise ValueError(f"sequence {T} is not a multiple of loss_block {lb}")
+        logits_of = self._logits_fn()
+
+        @jax.checkpoint
+        def block_nll(args):
+            hb, tb = args  # [B, lb, H], [B, lb]
+            logits = logits_of(hb)  # [B, lb, V] float32
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
+            return (lse - picked).sum(axis=-1)
+
+        def blocks(a):
+            return jnp.moveaxis(a.reshape((B, T // lb, lb) + a.shape[2:]), 1, 0)
+
+        with jax.named_scope(scopes.LM_HEAD):
+            nll = jax.lax.map(block_nll, (blocks(h), blocks(targets)))
+        return nll.sum(axis=0) / T
+
+    def task_loss(self, variables, x, w):
+        """The task's training loss for ``FederatedTask``: rows weighted by
+        ``w [B]`` (0 = padding)."""
+        per_row = self.apply(variables, x, method=AFMoE.token_losses)
+        return (per_row * w).sum() / jnp.maximum(w.sum(), 1.0)

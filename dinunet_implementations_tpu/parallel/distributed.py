@@ -377,6 +377,14 @@ def put_site_batch(mesh, arr, dtype=None):
     return jax.device_put(a, sh)
 
 
+def input_cast_dtype(inputs, compute_dtype):
+    """The dtype host inputs are cast to on their way up (None = as they
+    are): the model's compute dtype for floating inputs; integer samples
+    (token ids, which a cast to bfloat16 would destroy) stay what they are."""
+    floating = np.issubdtype(np.asarray(inputs).dtype, np.floating)
+    return compute_dtype if floating else None
+
+
 def put_site_inventory(mesh, inventory, input_dtype=None):
     """One-shot placement of a site inventory in its resident form
     (data/api.py SiteInventory: ``[S, rows + 1, *stored_sample_shape]``, the
@@ -396,7 +404,7 @@ def put_site_inventory(mesh, inventory, input_dtype=None):
     batches used to (:func:`put_site_batch`)."""
     inputs = np.asarray(inventory.inputs)
     # one cast pass, always into a fresh copy: clear_padding writes into it
-    inputs = inputs.astype(inputs.dtype if input_dtype is None else input_dtype)
+    inputs = inputs.astype(input_cast_dtype(inputs, input_dtype) or inputs.dtype)
     labels = np.array(inventory.labels)
     inventory.clear_padding(inputs, labels)
     if mesh is None:

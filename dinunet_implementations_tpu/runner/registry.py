@@ -19,6 +19,8 @@ from ..data.freesurfer import FreeSurferDataset, FSVDataHandle
 from ..data.ica import ICADataHandle, ICADataset
 from ..data.multimodal import MultimodalDataHandle, MultimodalDataset
 from ..data.smri import SMRIDataHandle, SMRIDataset
+from ..data.tokens import TokenDataHandle, TokenDataset
+from ..models.afmoe import FULL, SLIDING, AFMoE, Dims
 from ..models.cnn3d import SMRI3DNet
 from ..models.icalstm import ICALstm
 from ..models.msannet import MSANNet
@@ -134,6 +136,44 @@ def _build_multimodal(cfg: TrainConfig):
     )
 
 
+def afmoe_layer_types(a) -> tuple:
+    """One attention kind a layer: ``layer_types`` as given, else the
+    published period (a full layer every ``global_attn_every_n_layers``-th)."""
+    if a.layer_types:
+        return tuple(a.layer_types)
+    n = a.global_attn_every_n_layers
+    return tuple(FULL if (i + 1) % n == 0 else SLIDING
+                 for i in range(a.num_hidden_layers))
+
+
+def _build_afmoe(cfg: TrainConfig):
+    a = cfg.lm_args
+    layer_types = afmoe_layer_types(a)
+    if len(layer_types) != a.num_hidden_layers:
+        raise ValueError(
+            f"layer_types names {len(layer_types)} layers, num_hidden_layers "
+            f"is {a.num_hidden_layers}")
+    held = a.experts_held or a.num_experts
+    if a.first_expert < 0 or a.first_expert + held > a.num_experts:
+        raise ValueError(
+            f"experts {a.first_expert}..{a.first_expert + held - 1} are not "
+            f"among the model's {a.num_experts}")
+    # Dims reads AFMoEArgs' own field names; what differs is resolved here
+    resolved = dict(
+        experts_held=held, layer_types=layer_types,
+        rope_theta=float(a.rope_theta), compute_dtype=a.compute_dtype or None,
+    )
+    return AFMoE(
+        dims=Dims(**{
+            f.name: resolved.get(f.name, getattr(a, f.name, f.default))
+            for f in dataclasses.fields(Dims)
+        }),
+        vocab_rows=a.vocab_rows or a.vocab_size,
+        mup_enabled=a.mup_enabled,
+        loss_block=a.loss_block,
+    )
+
+
 TASKS: dict[str, TaskSpec] = {
     NNComputation.TASK_FREE_SURFER: TaskSpec(
         NNComputation.TASK_FREE_SURFER, _build_msannet, FreeSurferDataset,
@@ -180,6 +220,13 @@ TASKS: dict[str, TaskSpec] = {
                 * cfg.multimodal_args.num_components
                 * cfg.multimodal_args.window_size,
             ),
+        ),
+    ),
+    NNComputation.TASK_LM: TaskSpec(
+        NNComputation.TASK_LM, _build_afmoe, TokenDataset, TokenDataHandle,
+        serving=ServingSpec(
+            # a sample is the ids the model reads plus the last target
+            sample_shape=lambda cfg: (cfg.lm_args.seq_len + 1,),
         ),
     ),
 }

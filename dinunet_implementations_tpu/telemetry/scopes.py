@@ -12,3 +12,10 @@ MODEL = "model/fwd_bwd"  # trainer/steps.py grad_fn: forward, loss, backward
 ENGINE = "engine/aggregate"  # trainer/steps.py engine_aggregate
 POWERITER = "poweriter"  # engines/lowrank.py, nests: engine/aggregate/poweriter
 OPTIMIZER = "optimizer/update"  # trainer/steps.py: optimizer.update + apply
+# models/afmoe.py, inside model/fwd_bwd
+ATTENTION_WINDOW = "model/attention_window"  # a sliding layer's attention
+ATTENTION_FULL = "model/attention_full"  # a full layer's attention
+MOE_ROUTE = "model/moe_route"  # router scores, top-k, weights
+MOE_EXPERTS = "model/moe_experts"  # sort, grouped products, combine
+MOE_SHARED = "model/moe_shared"  # the shared expert
+LM_HEAD = "model/lm_head"  # final norm, head, softmax, in sequence blocks

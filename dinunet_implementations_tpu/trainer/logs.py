@@ -185,11 +185,12 @@ def write_test_metrics_csv(dirpath: str, fold: int, metrics: dict) -> str:
     """``metrics``: mapping name → value; accuracy and f1 must be present (the
     notebook indexes columns 1 and 2)."""
     names = ["accuracy", "f1"] + [k for k in metrics if k not in ("accuracy", "f1")]
+    names = names if metrics else []  # no classes: trainer/metrics.py NoClassMetrics
     os.makedirs(dirpath, exist_ok=True)
     path = os.path.join(dirpath, "test_metrics.csv")
     with open(path, "w") as fh:
-        fh.write("fold," + ",".join(names) + "\n")
-        fh.write(f"fold_{fold}," + ",".join(f"{metrics[n]:.5f}" for n in names) + "\n")
+        fh.write(",".join(["fold"] + names) + "\n")
+        fh.write(",".join([f"fold_{fold}"] + [f"{metrics[n]:.5f}" for n in names]) + "\n")
     return path
 
 

@@ -56,7 +56,13 @@ from .logs import (
     write_test_metrics_csv,
     zip_global_results,
 )
-from .metrics import Averages, ClassificationMetrics, MulticlassMetrics, is_improvement
+from .metrics import (
+    Averages,
+    ClassificationMetrics,
+    MulticlassMetrics,
+    NoClassMetrics,
+    is_improvement,
+)
 from .prefetch import EpochPlanPrefetcher
 from .steps import (
     FederatedTask,
@@ -237,18 +243,19 @@ class FederatedTrainer:
         return jax.process_index() == 0
 
     def _put_batch(self, fb):
-        """Device-side epoch arrays: inputs pre-cast to the compute dtype;
-        on a mesh, committed ``P(site)`` arrays (multi-host aware)."""
-        if self.mesh is not None:
-            from ..parallel.distributed import put_site_batch
+        """Device-side epoch arrays: floating inputs pre-cast to the compute
+        dtype; on a mesh, committed ``P(site)`` arrays (multi-host aware)."""
+        from ..parallel.distributed import input_cast_dtype, put_site_batch
 
+        dtype = input_cast_dtype(fb.inputs, self._input_dtype)
+        if self.mesh is not None:
             return (
-                put_site_batch(self.mesh, fb.inputs, self._input_dtype),
+                put_site_batch(self.mesh, fb.inputs, dtype),
                 put_site_batch(self.mesh, fb.labels),
                 put_site_batch(self.mesh, fb.weights),
             )
         return (
-            jnp.asarray(fb.inputs, dtype=self._input_dtype),
+            jnp.asarray(fb.inputs, dtype=dtype),
             jnp.asarray(fb.labels),
             jnp.asarray(fb.weights),
         )
@@ -593,10 +600,14 @@ class FederatedTrainer:
         """Binary: score = positive-class probability (reference semantics,
         AUC on prob[:,1], comps/icalstm/__init__.py:64-65); multiclass:
         argmax-based macro metrics."""
+        if num_class == 0:  # a task with its own loss and no classes
+            return NoClassMetrics()
         return ClassificationMetrics() if num_class == 2 else MulticlassMetrics()
 
     @staticmethod
     def _add_probs(m, probs, labels, weights):
+        if isinstance(m, NoClassMetrics):
+            return m
         if isinstance(m, ClassificationMetrics):
             m.add(probs[..., 1].reshape(-1), labels.reshape(-1), weights.reshape(-1))
         else:
@@ -1258,8 +1269,7 @@ class FederatedTrainer:
             "stopped_epoch": stop_epoch,
             "test_metrics": [[round(test_avg.avg, 5), round(monitored, 5)]],
             "test_scores": {
-                n: test_metrics.value(n)
-                for n in ("accuracy", "f1", "precision", "recall", "auc")
+                n: test_metrics.value(n) for n in test_metrics.NAMES
             },
             "site_test_metrics": [
                 [[round(a.avg, 5),

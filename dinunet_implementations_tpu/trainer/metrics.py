@@ -48,6 +48,8 @@ class Averages:
 class _MetricValues:
     """Shared ``value()``/``get()`` dispatch over the named scalar metrics."""
 
+    NAMES = ("accuracy", "f1", "precision", "recall", "auc")
+
     def value(self, name: str) -> float:
         name = name.lower()
         fns = {
@@ -220,6 +222,27 @@ class MulticlassMetrics(_MetricValues):
 
     def auc(self) -> float:
         return self._ovr("auc")
+
+
+class NoClassMetrics(_MetricValues):
+    """The metrics of a task that has no classes (the next-token task): the
+    validation loss is all there is, so ``monitor_metric`` must be ``loss``."""
+
+    NAMES = ()
+
+    def add(self, *args, **kw):
+        return self
+
+    def merge(self, other: "NoClassMetrics"):
+        return self
+
+    def value(self, name: str) -> float:
+        raise ValueError(
+            f"metric {name!r} needs classes and this task has none: set "
+            "monitor_metric='loss' (metric_direction='minimize')")
+
+    def get(self, *names) -> list[float]:
+        return []
 
 
 def is_improvement(new: float, best: float | None, direction: str = "maximize") -> bool:

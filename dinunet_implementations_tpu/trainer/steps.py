@@ -210,6 +210,20 @@ class FederatedTask:
         logits = self.model.apply(variables, x, train=train, mask=mask, rngs=rngs)
         return logits, batch_stats
 
+    def loss(self, params, batch_stats, rng, x, y, w):
+        """The task's training loss ``-> (loss, new_stats)``. Default: the
+        model's logits against the batch's labels (:func:`cross_entropy`). A
+        model whose targets come from its own input and whose loss is
+        computed in blocks (models/afmoe.py: next-token loss per position)
+        brings its own as ``model.task_loss(variables, x, w)``."""
+        own = getattr(self.model, "task_loss", None)
+        if own is not None:
+            return own({"params": params}, x, w), batch_stats
+        logits, new_stats = self.apply(
+            params, batch_stats, x, train=True, rng=rng, mask=w, mutable=True
+        )
+        return cross_entropy(logits, y, w), new_stats
+
 
 def init_train_state(
     task: FederatedTask,
@@ -640,10 +654,7 @@ def make_train_epoch_fn(
         )
 
     def loss_fn(params, batch_stats, rng, x, y, w):
-        logits, new_stats = task.apply(
-            params, batch_stats, x, train=True, rng=rng, mask=w, mutable=True
-        )
-        loss = cross_entropy(logits, y, w)
+        loss, new_stats = task.loss(params, batch_stats, rng, x, y, w)
         if model_axis is not None:
             # The forward runs on every model-axis member (sequence-sharded
             # inside the model, logits replicated by its final gather), so an
@@ -2045,6 +2056,19 @@ def eval_forward(task: FederatedTask, params, batch_stats, x, y=None, w=None):
     labels ``y`` also returns the per-example cross-entropy (the eval loss
     path); ``y=None`` (serving) returns probs only — a trace-time branch,
     so the serving program carries no label ops at all."""
+    if getattr(task.model, "task_loss", None) is not None:
+        # a task with its own loss has no classes: its eval is that loss, row
+        # by row, and a zero-wide probability block (trainer/metrics.py
+        # NoClassMetrics)
+        probs = jnp.zeros((x.shape[0], 0), jnp.float32)
+        if y is None:
+            return probs
+        one = jnp.ones((1,), jnp.float32)
+        ce = jax.lax.map(
+            lambda xy: task.loss(
+                params, batch_stats, None, xy[0][None], xy[1][None], one)[0],
+            (x, y))
+        return probs, ce
     logits, _ = task.apply(params, batch_stats, x, train=False, mask=w)
     probs = jax.nn.softmax(logits, -1)
     if y is None:

@@ -1,0 +1,453 @@
+"""The AFMoE decoder (models/afmoe.py) as a federated next-token task, against
+the plain reference (benchmarks/reference/afmoe.py), at toy widths on the CPU.
+
+Held: logits, loss and gradients with every share of the experts; the shares'
+routed parts plus the shared expert once add up to the uncut layer; no token
+is dropped at any imbalance; a token outside the window reaches a full layer
+and not a sliding one; one ``FederatedTrainer`` round equals the reference
+round; token ids survive the resident inventory; the program wears its
+scopes; and the comparison notices each term of the block that goes missing.
+"""
+
+import dataclasses
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import afmoe as ref
+from benchmarks.reference import federated as fed
+from dinunet_implementations_tpu.core.config import NNComputation, TrainConfig
+from dinunet_implementations_tpu.data.api import SiteArrays, stack_site_inventory
+from dinunet_implementations_tpu.models import afmoe
+from dinunet_implementations_tpu.models.afmoe import FULL, SLIDING
+from dinunet_implementations_tpu.parallel.distributed import put_site_inventory
+from dinunet_implementations_tpu.runner.registry import (
+    afmoe_layer_types,
+    get_task,
+)
+from dinunet_implementations_tpu.telemetry import scopes
+from dinunet_implementations_tpu.trainer.loop import FederatedTrainer
+from dinunet_implementations_tpu.trainer.steps import _gather_batch
+
+T, VOCAB, EXPERTS, HELD, TOP_K, WINDOW = 32, 96, 16, 4, 4, 8
+LAYERS = (SLIDING, SLIDING, SLIDING, SLIDING, FULL)
+TOY = dict(
+    seq_len=T, vocab_size=VOCAB, vocab_rows=VOCAB, hidden_size=64,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    intermediate_size=96, moe_intermediate_size=32, num_experts=EXPERTS,
+    num_experts_per_tok=TOP_K, experts_held=HELD, first_expert=0,
+    num_hidden_layers=len(LAYERS), num_dense_layers=1, layer_types=LAYERS,
+    sliding_window=WINDOW, q_block=8, kv_chunk=16, loss_block=8,
+)
+
+
+def toy_cfg(**over) -> TrainConfig:
+    train = {k: over.pop(k) for k in list(over)
+             if k in ("num_sites", "batch_size", "learning_rate", "epochs",
+                      "monitor_metric", "metric_direction", "patience",
+                      "validation_epochs")}
+    return TrainConfig(task_id=NNComputation.TASK_LM, **train).with_overrides(
+        {"lm_args": {**TOY, **over}})
+
+
+def build(**over):
+    cfg = toy_cfg(**over)
+    model = get_task(cfg.task_id).build_model(cfg)
+    dims = ref.Dims.of(dataclasses.asdict(cfg.lm_args),
+                       layer_types=afmoe_layer_types(cfg.lm_args),
+                       q_block=8, head_block=8)
+    return cfg, model, dims
+
+
+def tokens(seed: int, rows: int = 2):
+    return jax.random.randint(jax.random.PRNGKey(seed), (rows, T + 1), 0, VOCAB)
+
+
+def init_params(model, scale: float = 5.0):
+    """Seeded random weights, the matrices scaled up so that every term of
+    the block moves the result (at std 0.02 the norms hide most of them)."""
+    params = model.init({"params": jax.random.PRNGKey(0)}, tokens(9),
+                        train=True)["params"]
+    return jax.tree.map(lambda a: a * scale if a.ndim >= 2 else a, params)
+
+
+def rel_rms(got, want) -> float:
+    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
+
+
+def ref_logits(params, x, dims):
+    with jax.default_matmul_precision("highest"):
+        return jnp.stack([ref.forward(params, row[:-1], dims) for row in x])
+
+
+# -- the model against the reference, every share -----------------------------
+
+
+@pytest.mark.parametrize("first", range(0, EXPERTS, HELD))
+def test_logits_loss_and_gradients_match_the_reference(first):
+    _, model, dims = build(first_expert=first)
+    params, x = init_params(model), tokens(1)
+    assert float(jnp.abs(
+        model.apply({"params": params}, x) - ref_logits(params, x, dims)
+    ).max()) < 5e-5
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(lambda p: model.task_loss(
+            {"params": p}, x[:1], jnp.ones(1)))(params)
+        want_loss, want = jax.value_and_grad(
+            lambda p: ref.loss(p, x[0], dims))(params)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want)):
+        scale = max(float(jnp.abs(w).max()), 1e-3)
+        assert float(jnp.abs(g - w).max()) < 2e-4 * scale, jax.tree_util.keystr(path)
+
+
+def test_row_weights_pick_the_rows_the_loss_reads():
+    _, model, dims = build()
+    params, x = init_params(model), tokens(2, rows=3)
+    got = model.task_loss({"params": params}, x, jnp.asarray([1.0, 0.0, 1.0]))
+    with jax.default_matmul_precision("highest"):
+        want = (ref.loss(params, x[0], dims) + ref.loss(params, x[2], dims)) / 2
+    assert abs(float(got) - float(want)) < 1e-5
+
+
+def test_vmap_over_sites_folds_into_the_groups():
+    """The trainer's fold: vmap(value_and_grad) over sites equals site by
+    site, through the expert layer's own vmap rule (no batched ragged dot)."""
+    _, model, _ = build()
+    params = init_params(model)
+    xs = jnp.stack([tokens(s) for s in (3, 4, 5)])
+    vg = jax.value_and_grad(
+        lambda p, x: model.task_loss({"params": p}, x, jnp.ones(2)))
+    losses, grads = jax.jit(jax.vmap(vg, in_axes=(None, 0)))(params, xs)
+    for s in range(3):
+        loss, g = vg(params, xs[s])
+        assert abs(float(losses[s]) - float(loss)) < 1e-5
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(g)):
+            assert float(jnp.abs(a[s] - b).max()) <= 1e-4 * max(
+                float(jnp.abs(b).max()), 1e-3)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_walking_the_assignments_in_chunks_changes_nothing(monkeypatch, chunk):
+    _, model, _ = build()
+    params, x = init_params(model), tokens(6)
+    vg = jax.value_and_grad(
+        lambda p: model.task_loss({"params": p}, x, jnp.ones(2)))
+    whole = vg(params)
+    monkeypatch.setattr(afmoe, "ROW_CHUNK", chunk)
+    afmoe._expert_layer.cache_clear()
+    try:
+        parts = vg(params)
+    finally:
+        afmoe._expert_layer.cache_clear()
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(parts)):
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * max(
+            float(jnp.abs(a).max()), 1e-3)
+
+
+# -- the expert layer ----------------------------------------------------------
+
+
+def _moe_layer(seed: int = 0):
+    """An uncut MoE layer's reference parameters and a token block."""
+    h, f = 64, 32
+    k = jax.random.split(jax.random.PRNGKey(seed), 8)
+    p = {"router": jax.random.normal(k[0], (h, EXPERTS)),
+         "expert_bias": 0.1 * jax.random.normal(k[1], (EXPERTS,)),
+         "w1": 0.2 * jax.random.normal(k[2], (EXPERTS, h, f)),
+         "w3": 0.2 * jax.random.normal(k[3], (EXPERTS, h, f)),
+         "w2": 0.2 * jax.random.normal(k[4], (EXPERTS, f, h)),
+         "shared": {"w1": 0.2 * jax.random.normal(k[5], (h, f)),
+                    "w3": 0.2 * jax.random.normal(k[6], (h, f)),
+                    "w2": 0.2 * jax.random.normal(k[7], (f, h))}}
+    m = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, T, h))
+    return p, m
+
+
+def _share(p, first: int, shared: bool):
+    """The system's MoE module holding experts ``first .. first + HELD - 1``
+    of the layer ``p``, and its parameters."""
+    module = afmoe.MoE(EXPERTS, TOP_K, HELD, first, 32, 32 if shared else 0,
+                       True, 2.826)
+    mine = {k: p[k] for k in ("router", "expert_bias")}
+    mine.update({k: p[k][first: first + HELD] for k in ("w1", "w3", "w2")})
+    if shared:
+        mine["shared"] = p["shared"]
+    return module, mine
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The routed parts of all 16/4 shares, plus the shared expert counted
+    once, equal the uncut reference's layer."""
+    p, m = _moe_layer()
+    dims = ref.Dims(num_experts_per_tok=TOP_K, first_expert=0)
+    with jax.default_matmul_precision("highest"):
+        whole = jnp.stack([ref.moe(p, m[b], dims) for b in range(2)])
+        total = 0.0
+        for i, first in enumerate(range(0, EXPERTS, HELD)):
+            module, mine = _share(p, first, shared=(i == 0))
+            total = total + module.apply({"params": mine}, m)
+    assert float(jnp.abs(total - whole).max()) < 1e-4 * float(jnp.abs(whole).max())
+
+
+@pytest.mark.parametrize("first", [0, HELD])
+def test_no_token_is_dropped_when_all_pick_the_same_held_expert(first):
+    """Every token's assignments fall on the same four experts, all held by
+    one share (the worst case of its buffer) and none by the next."""
+    p, m = _moe_layer(seed=3)
+    bias = jnp.full((EXPERTS,), -10.0).at[:HELD].set(10.0)
+    p = {**p, "expert_bias": bias}
+    dims = ref.Dims(num_experts_per_tok=TOP_K, first_expert=first)
+    module, mine = _share(p, first, shared=False)
+    held = {k: (v[first: first + HELD] if k in ("w1", "w3", "w2") else v)
+            for k, v in p.items() if k != "shared"}
+    with jax.default_matmul_precision("highest"):
+        got, inter = module.apply({"params": mine}, m, mutable=["intermediates"])
+        want = jnp.stack([ref.moe(held, m[b], dims) for b in range(2)])
+    counts = np.asarray(inter["intermediates"]["held_counts"][0])
+    assert counts.sum() == (2 * T * TOP_K if first == 0 else 0)
+    assert float(jnp.abs(got - want).max()) < 1e-4 * max(
+        float(jnp.abs(want).max()), 1.0)
+    if first == 0:
+        assert float(jnp.abs(want).max()) > 0.1  # the share did the work
+
+
+# -- attention ----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kind,reaches", [(SLIDING, False), (FULL, True)])
+def test_a_token_outside_the_window_reaches_full_layers_only(kind, reaches):
+    _, model, _ = build(num_hidden_layers=1, num_dense_layers=1,
+                        layer_types=(kind,))
+    params, x = init_params(model), tokens(7, rows=1)
+    other = x.at[0, 0].set((x[0, 0] + 1) % VOCAB)  # position 0 changes
+    a = model.apply({"params": params}, x)[0]
+    b = model.apply({"params": params}, other)[0]
+    inside = float(jnp.abs(a[:WINDOW] - b[:WINDOW]).max())
+    outside = float(jnp.abs(a[WINDOW:] - b[WINDOW:]).max())
+    assert inside > 1e-3
+    assert (outside > 1e-3) == reaches
+    if not reaches:
+        assert outside == 0.0
+
+
+def test_blocked_attention_is_plain_attention():
+    q = jax.random.normal(jax.random.PRNGKey(0), (2, T, 4, 16))
+    k = jax.random.normal(jax.random.PRNGKey(1), (2, T, 2, 16))
+    v = jax.random.normal(jax.random.PRNGKey(2), (2, T, 2, 16))
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    for window in (None, WINDOW):
+        mask = (j <= i) if window is None else (j <= i) & (j > i - window)
+        s = jnp.einsum("btnd,bsnd->bnts", q, jnp.repeat(k, 2, axis=2)) / 4.0
+        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+        want = jnp.einsum("bnts,bsnd->btnd", p, jnp.repeat(v, 2, axis=2))
+        got = afmoe.blocked_attention(q, k, v, window, q_block=8, kv_chunk=16)
+        assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("window", [None, 100])
+def test_kernel_attention_is_blocked_attention(window):
+    """The splash-attention kernels (interpret mode here) against the XLA
+    blocks, forward and backward; the causal-window mask keeps ``j > i -
+    window``."""
+    t = 256
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, t, 4, 128))
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, t, 2, 128))
+    v = jax.random.normal(jax.random.PRNGKey(2), (1, t, 2, 128))
+    plain = lambda q, k, v: afmoe.blocked_attention(q, k, v, window, 64, 128)
+    kernel = lambda q, k, v: afmoe.kernel_attention(q, k, v, window, block=128)
+    assert float(jnp.abs(plain(q, k, v) - kernel(q, k, v)).max()) < 1e-5
+    grad = lambda f: jax.grad(lambda *a: (f(*a) ** 2).sum(), argnums=(0, 1, 2))
+    for a, b in zip(grad(plain)(q, k, v), grad(kernel)(q, k, v)):
+        assert float(jnp.abs(a - b).max()) < 1e-4
+
+
+def test_kernel_names_are_the_librarys():
+    """The constants a metric's pattern holds on to are prefixes of the names
+    the splash-attention kernels give their Pallas calls."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    names = {phase: sk.get_kernel_name(True, phase == "fwd", False, phase)
+             for phase in ("fwd", "dq", "dkv")}
+    assert names["fwd"].startswith(afmoe.ATTN_FWD)
+    assert names["dq"].startswith(afmoe.ATTN_DQ)
+    assert names["dkv"].startswith(afmoe.ATTN_DKV)
+    assert len({afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV}) == 3
+
+
+def test_the_model_picks_the_kernels_on_a_tpu_only(monkeypatch):
+    """On the CPU the XLA blocks run; where the backend is a TPU (steered
+    here) and the sequence is whole kernel blocks, the kernels do."""
+    calls = []
+    monkeypatch.setattr(afmoe, "kernel_attention", lambda q, k, v, w, **kw: (
+        calls.append(w), afmoe.blocked_attention(q, k, v, w, 8, 16))[1])
+    _, model, _ = build(seq_len=128)
+    x = jax.random.randint(jax.random.PRNGKey(0), (1, 129), 0, VOCAB)
+    params = model.init({"params": jax.random.PRNGKey(0)}, x, train=True)["params"]
+    model.apply({"params": params}, x)
+    assert calls == []
+    monkeypatch.setattr(afmoe, "_auto_pallas", lambda: True)
+    model.apply({"params": params}, x)
+    assert calls == [WINDOW] * 4 + [None]
+
+
+# -- the task through the trainer ---------------------------------------------
+
+
+def _sites(seed: int, n_sites: int = 2, rows: int = 4):
+    rng = np.random.default_rng(seed)
+    return [SiteArrays(rng.integers(0, VOCAB, (rows, T + 1)).astype(np.int32),
+                       np.zeros((rows,), np.int32),
+                       np.arange(rows, dtype=np.int32))
+            for _ in range(n_sites)]
+
+
+def test_one_trainer_round_matches_the_reference_round():
+    """2 sites, dSGD, Adam, the device pipeline, bfloat16 compute: the
+    parameters after one epoch of one round against the reference's round."""
+    cfg, model, dims = build(num_sites=2, batch_size=1, learning_rate=1e-3,
+                             compute_dtype="bfloat16")
+    sites = _sites(0, rows=1)
+    trainer = FederatedTrainer(cfg, model, None)
+    assert trainer._pipeline == "device" and trainer._donate
+    state = trainer.init_state(jnp.ones((1, T + 1), jnp.int32), num_sites=2)
+    before = jax.device_get(state.params)
+    state, losses = trainer.run_epoch(state, sites, 1, batch_size=1)
+    after = jax.device_get(state.params)
+    with jax.default_matmul_precision("highest"):
+        outs = [jax.value_and_grad(lambda p: ref.loss(
+            p, jnp.asarray(s.inputs[0]), dims))(before) for s in sites]
+        agg = fed.weighted_mean(
+            jax.tree.map(lambda *g: jnp.stack(g), *[g for _, g in outs]),
+            jnp.ones((2,)))
+        want, _, _ = fed.adam_step(before, agg, lr=1e-3)
+    assert len(losses) == 1
+    assert abs(float(losses[0]) - float(np.mean([l for l, _ in outs]))) < 5e-3
+    delta = lambda a: jax.tree.map(lambda x, y: np.asarray(x) - np.asarray(y),
+                                   a, before)
+    # Adam's first step is lr * sign(g): elements whose gradient is near zero
+    # flip with bfloat16 rounding, so the cosine is the comparison
+    assert fed.tree_cosine(delta(after), delta(want)) > 0.9
+
+
+def test_fit_validates_on_the_token_loss(tmp_path):
+    cfg, model, _ = build(num_sites=2, batch_size=1, epochs=2,
+                          monitor_metric="loss", metric_direction="minimize",
+                          validation_epochs=1, patience=5)
+    trainer = FederatedTrainer(cfg, model, None, out_dir=str(tmp_path))
+    out = trainer.fit(_sites(1), _sites(2, rows=2), _sites(3, rows=2),
+                      verbose=False)
+    loss, monitored = out["test_metrics"][0]
+    assert monitored == loss and 3.0 < loss < 6.0  # ln 96 = 4.56
+    assert out["test_scores"] == {}  # no classes, no class metrics
+
+
+def test_token_ids_survive_the_resident_inventory():
+    """Ids above 256 come out of the gather as they went in: integer samples
+    are stacked, uploaded (compute dtype bfloat16) and gathered as int32."""
+    sites = [SiteArrays(
+        np.asarray([[257, 25023, 4097, 300, 65535], [1, 2, 3, 4, 5]], np.int32)
+        + s, np.zeros((2,), np.int32), np.arange(2, dtype=np.int32))
+        for s in range(2)]
+    inv = stack_site_inventory(sites)
+    assert inv.inputs.dtype == np.int32
+    inv_x, inv_y = put_site_inventory(None, inv, jnp.bfloat16)
+    assert inv_x.dtype == jnp.int32
+    xb, _, wb = _gather_batch(inv_x[1], inv_y[1], jnp.asarray([[0, -1]]),
+                              sample_shape=(5,))
+    assert xb.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(xb[0, 0]), sites[1].inputs[0])
+    np.testing.assert_array_equal(np.asarray(xb[0, 1]), 0)
+    np.testing.assert_array_equal(np.asarray(wb), [[1.0, 0.0]])
+
+
+def test_float_inventories_are_still_cast_at_upload():
+    sites = [SiteArrays(np.ones((2, 5), np.float32), np.zeros((2,), np.int32),
+                        np.arange(2, dtype=np.int32))]
+    inv_x, _ = put_site_inventory(None, stack_site_inventory(sites), jnp.bfloat16)
+    assert inv_x.dtype == jnp.bfloat16
+
+
+# -- scopes ---------------------------------------------------------------------
+
+MODEL_SCOPES = ("ATTENTION_WINDOW", "ATTENTION_FULL", "MOE_ROUTE",
+                "MOE_EXPERTS", "MOE_SHARED", "LM_HEAD")
+
+
+@pytest.fixture(scope="module")
+def lowered_epoch():
+    cfg, model, _ = build(num_sites=2, batch_size=1)
+    trainer = FederatedTrainer(cfg, model, None)
+    state = trainer.init_state(jnp.ones((1, T + 1), jnp.int32), num_sites=2)
+    inv_x = jnp.zeros((2, 3, T + 1), jnp.int32)
+    inv_y = jnp.zeros((2, 3), jnp.int32)
+    idx = jnp.zeros((2, 2, 1), jnp.int32)
+    return trainer.epoch_fn.lower(
+        state, inv_x, inv_y, idx, None, None, None, None
+    ).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", MODEL_SCOPES)
+def test_model_scope_is_in_the_lowered_epoch_program(lowered_epoch, scope):
+    name = getattr(scopes, scope)
+    assert name.startswith("model/")
+    assert re.search(r"(?<![A-Za-z0-9_])" + re.escape(scopes.MODEL) + r"\)*/.*"
+                     + re.escape(name) + r"(?![A-Za-z0-9_])", lowered_epoch), name
+
+
+# -- the comparison notices a missing term --------------------------------------
+
+
+def _dropped(term: str, monkeypatch):
+    """The system with one term of the block taken away; the reference keeps
+    the published block."""
+    over, edit = {}, (lambda p: p)
+    if term == "route_scale":
+        over = {"route_scale": 1.0}
+    elif term == "window":
+        over = {"sliding_window": T}
+    elif term == "shared_expert":
+        def edit(p):
+            return jax.tree_util.tree_map_with_path(
+                lambda path, a: a * 0 if "shared" in jax.tree_util.keystr(path)
+                else a, p)
+    elif term == "output_gate":
+        # a constant gate: the post-norm takes the constant out again
+        def edit(p):
+            return jax.tree_util.tree_map_with_path(
+                lambda path, a: a * 0 if "wg" in jax.tree_util.keystr(path)
+                else a, p)
+    elif term == "qk_norm":
+        plain = afmoe.rms_norm
+        monkeypatch.setattr(afmoe, "rms_norm", lambda x, scale, eps: (
+            x.astype(jnp.float32) if x.ndim == 4 else plain(x, scale, eps)))
+    return over, edit
+
+
+@pytest.mark.parametrize("term", ["none", "output_gate", "qk_norm",
+                                  "shared_expert", "window", "route_scale"])
+def test_the_comparison_notices_a_dropped_term(monkeypatch, term):
+    """``logit_rel_rms`` (benchmarks/lib/refcheck_lm.py) of the bfloat16
+    system against the float32 reference stays inside the configuration's
+    limit, and leaves it when a term of the block goes missing."""
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "trinity-mini-ep16.json")) as fh:
+        limit = json.load(fh)["check"]["logit_rel_rms_max"]
+    _, reference_model, dims = build()
+    params, x = init_params(reference_model), tokens(8)
+    want = ref_logits(params, x, dims)
+    over, edit = _dropped(term, monkeypatch)
+    _, model, _ = build(compute_dtype="bfloat16", **over)
+    err = rel_rms(model.apply({"params": edit(params)}, x), want)
+    if term == "none":
+        assert err < limit, err
+    else:
+        assert err > 2 * limit, (term, err)

@@ -136,7 +136,27 @@ def test_block_dict_overrides():
 def test_all_tasks_have_args():
     for task in NNComputation.ALL:
         args = TrainConfig(task_id=task).task_args()
-        assert args.num_class == 2
+        if task == NNComputation.TASK_LM:
+            # a language model has no classes: its targets are its own input
+            assert not hasattr(args, "num_class")
+            assert args.num_experts == 128 and args.num_experts_per_tok == 8
+        else:
+            assert args.num_class == 2
+
+
+def test_lm_args_defaults_are_the_published_widths_and_take_overrides():
+    cfg = TrainConfig(task_id=NNComputation.TASK_LM)
+    a = cfg.task_args()
+    assert (a.hidden_size, a.num_attention_heads, a.num_key_value_heads,
+            a.head_dim, a.intermediate_size, a.moe_intermediate_size,
+            a.sliding_window, a.vocab_size, a.num_hidden_layers) == (
+        2048, 32, 4, 128, 6144, 1024, 2048, 200192, 32)
+    cut = cfg.with_overrides({"LM-NextToken_args": {
+        "experts_held": 8, "vocab_rows": 25024, "num_hidden_layers": 5,
+        "layer_types": ["sliding_attention"] * 4 + ["full_attention"]}})
+    assert cut.lm_args.experts_held == 8 and cut.lm_args.num_experts == 128
+    assert cut.lm_args.layer_types == ("sliding_attention",) * 4 + (
+        "full_attention",)
 
 
 @needs_reference

@@ -39,7 +39,9 @@ def test_the_file_and_its_per_layer_entry_agree():
     assert len(entries) == 1
     for key, val in entries[0].items():
         assert spec()[key] == val, key
-    assert cells.benchmark_json()["per_layer"][-1]["name"] == METRIC  # appended
+    # appended to what PR 24 left (later PRs append after it)
+    names = [m["name"] for m in cells.benchmark_json()["per_layer"]]
+    assert names.index(METRIC) == names.index("lstm_bwd_kernel_ms_per_round") + 1
     assert spec()["layer"] == cells.layer_metric("device_ms_per_round")["layer"]
     assert spec()["reader"] == "device_ops_matching"
     assert spec()["args"] == {"pattern": r"^copy(\.\d+)?$", "field": "name",

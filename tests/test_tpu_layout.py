@@ -129,3 +129,65 @@ def test_epoch_program_gathers_rows_and_nothing_else(
     assert not moved, moved[0][:300]
     assert any(re.search(r"= %s\{[^}]*\} gather\(" % batch, ln)
                for ln in lines)
+
+
+# -- the next-token model's kernels at the cell's sizes (ISSUE 28) -------------
+
+
+def test_attention_kernels_compile_for_the_chip_under_the_site_fold(
+        one_chip, no_compile_cache, monkeypatch):
+    """The splash-attention kernels at Trinity-Mini's widths (32 query / 4
+    key-value heads of 128, 8,192 positions, window 2,048 and full), under
+    the trainer's vmap over two sites and its gradient: three Mosaic calls a
+    mask, named after the model's constants."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    monkeypatch.setattr(afmoe, "_interpret", lambda: False)
+    afmoe._splash.cache_clear()
+    t, n, g, d = 8192, 32, 4, 128
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    try:
+        for window in (2048, None):
+            def loss(q, k, v):
+                return afmoe.kernel_attention(q, k, v, window).sum()
+
+            text = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 1, 2)))).lower(
+                sds(2, 1, t, n, d), sds(2, 1, t, g, d), sds(2, 1, t, g, d)
+            ).compile().as_text()
+            for name in (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV):
+                assert re.search(r"%[\w.]*" + name + r"[\w.]* = .*tpu_custom_call",
+                                 text), name
+    finally:
+        afmoe._splash.cache_clear()
+
+
+def test_grouped_products_lower_to_the_compilers_kernel(one_chip,
+                                                         no_compile_cache):
+    """``jax.lax.ragged_dot`` (rows by group) and its row-contracting form
+    (the stacks' cotangent) become the compiler's own ragged-dot kernels, whose
+    work follows the group sizes; the batched form has no lowering on the
+    chip, which is why the expert layer folds the sites into the groups."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    rows, h, f, e = 8192, 2048, 1024, 8
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    by_rows = jax.jit(lambda x, w, s: jax.lax.ragged_dot(
+        x, w, s, preferred_element_type=jnp.float32))
+    text = by_rows.lower(sds((rows, h)), sds((e, h, f)),
+                         sds((e,), jnp.int32)).compile().as_text()
+    assert re.search(r"%ragged-dot[\w.-]* = .*tpu_custom_call", text)
+    contracting = jax.jit(lambda x, y, s: jax.lax.ragged_dot_general(
+        x, y, s, afmoe._CONTRACT_ROWS, preferred_element_type=jnp.float32))
+    text = contracting.lower(sds((rows, h)), sds((rows, f)),
+                             sds((2 * e,), jnp.int32)).compile().as_text()
+    assert re.search(r"%ragged-dot[\w.-]* = .*tpu_custom_call", text)
+    with pytest.raises(Exception, match="batch dimensions"):
+        jax.jit(jax.vmap(lambda x, w, s: jax.lax.ragged_dot(x, w, s))).lower(
+            sds((2, rows, h)), sds((2, e, h, f)), sds((2, e), jnp.int32)
+        ).compile()

@@ -153,6 +153,13 @@ def test_every_task_has_a_serving_spec():
         assert get_task(task_id).serving is not None, task_id
 
 
+def test_lm_sample_is_the_sequence_plus_its_last_target():
+    cfg = TrainConfig(task_id=NNComputation.TASK_LM)
+    assert get_task(cfg.task_id).serving.sample_shape(cfg) == (8193,)
+    short = cfg.with_overrides({"lm_args": {"seq_len": 32}})
+    assert get_task(cfg.task_id).serving.sample_shape(short) == (33,)
+
+
 def test_ica_streaming_gate_is_causality():
     """The streaming lane exists only for the causal (unidirectional)
     config — a biLSTM's reverse direction reads the future."""
