@@ -37,10 +37,17 @@ routed experts run as grouped matrix products (``jax.lax.ragged_dot``) over
 the token assignments sorted by expert, walked in row chunks: dropless (the
 index arrays hold the worst case, every token on held experts), memory and
 work follow the real counts.
-One ``jax.checkpoint`` a block, which keeps the routed experts' output and
-nothing else (their backward pass runs their forward itself, chunk by chunk);
-the head and the loss run in sequence blocks so ``[T, vocab]`` is never whole
-in training.
+One ``jax.checkpoint`` a block, which keeps what ``BLOCK_KEEPS`` names and
+recomputes the rest: the routed experts' output (their backward pass runs
+their forward itself, chunk by chunk); where attention is the kernels, the
+forward kernel's output and log-sum-exp, which are all of its result that the
+backward kernels read, so the kernel runs once a layer a round; and the raw
+key and value projections. The kernels' own inputs (``q``, ``k``, ``v`` after
+QK-norm and rotary) are not kept: the norms' and rotary's backward passes need
+the raw projections anyway, and from those the inputs are elementwise work.
+The raw query projection, eight times a key's size, is recomputed too: kept,
+it did not pay for its memory on the chip (PERF.md, PR 29). The head and the
+loss run in sequence blocks so ``[T, vocab]`` is never whole in training.
 """
 
 from __future__ import annotations
@@ -171,6 +178,9 @@ ATTN_FWD = "splash_mqa_fwd"  # forward, keeps the log-sum-exp for the backward
 ATTN_DQ = "splash_mqa_dq"  # backward: the queries' cotangent
 ATTN_DKV = "splash_mqa_dkv"  # backward: the keys' and values' cotangents
 KERNEL_BLOCK = 512  # query and key rows a kernel block holds
+# the forward kernel's output and log-sum-exp, as a block's checkpoint knows
+# them (the XLA path has no such value: its blocks are recomputed)
+ATTN_OUT = "attention_kernel_out"
 
 
 def _auto_pallas() -> bool:
@@ -197,7 +207,7 @@ def _splash(t: int, heads_per_kv: int, window: int | None, block: int,
         block_q_dq=block, block_kv_dq=block)
     kernel = sk.make_splash_mqa_single_device(
         sm.MultiHeadMask([one] * heads_per_kv), block_sizes=sizes,
-        interpret=interpret)
+        residual_checkpoint_name=ATTN_OUT, interpret=interpret)
     names = {sk.get_kernel_name(True, phase == "fwd", False, phase)
              for phase in ("fwd", "dq", "dkv")}
     assert all(n.startswith((ATTN_FWD, ATTN_DQ, ATTN_DKV)) for n in names), names
@@ -266,8 +276,11 @@ def _plan(sel, first_expert: int, held: int):
 
 
 ROW_CHUNK = 8192  # sorted assignment rows the expert layer holds at a time
-ROUTED_OUT = "routed_experts_out"  # the one value a block's checkpoint keeps
-KEEP_ROUTED_OUT = jax.checkpoint_policies.save_only_these_names(ROUTED_OUT)
+ROUTED_OUT = "routed_experts_out"
+KV_PROJ = "attention_kv_proj"  # a layer's raw key and value projections
+# what a block's checkpoint keeps; everything else is recomputed
+BLOCK_KEEPS = jax.checkpoint_policies.save_only_these_names(
+    ROUTED_OUT, ATTN_OUT, KV_PROJ)
 
 
 def _row_chunk(rows: int) -> int:
@@ -516,8 +529,10 @@ class Attention(nn.Module):
         wg = self.param("wg", _init(), (H, N * D))
         wo = self.param("wo", _init(), (N * D, H))
         q = _mm(a, wq, cdt).reshape(B, T, N, D)
-        k = _mm(a, wk, cdt).reshape(B, T, G, D)
-        v = _mm(a, wv, cdt).reshape(B, T, G, D)
+        # the raw key and value projections are kept across the block's
+        # recomputation (an eighth of the queries' bytes); q is recomputed
+        k = checkpoint_name(_mm(a, wk, cdt), KV_PROJ).reshape(B, T, G, D)
+        v = checkpoint_name(_mm(a, wv, cdt), KV_PROJ).reshape(B, T, G, D)
         q = RMSNorm(self.eps, name="q_norm")(q)
         k = RMSNorm(self.eps, name="k_norm")(k)
         if self.window is not None:
@@ -574,8 +589,9 @@ class MoE(nn.Module):
                 m.reshape(B * T, H), sel.reshape(B * T, -1),
                 w.reshape(B * T, -1), w1, w3, w2, self.first_expert, cdt,
             ).reshape(B, T, H)
-            # kept across the block's recomputation: the backward pass runs
-            # the layer's forward in chunks itself and need not run it twice
+            # kept across the block's recomputation (BLOCK_KEEPS): the
+            # backward pass runs the layer's forward in chunks itself and
+            # need not run it twice
             y = checkpoint_name(y, ROUTED_OUT)
         if self.shared_width:
             with jax.named_scope(scopes.MOE_SHARED):
@@ -665,7 +681,7 @@ class AFMoE(nn.Module):
         self.embed = self.param(
             "embed", _init(), (self.vocab_rows, d.hidden_size))
         self.blocks = [
-            nn.remat(Block, policy=KEEP_ROUTED_OUT)(d, i, name=f"layer_{i}")
+            nn.remat(Block, policy=BLOCK_KEEPS)(d, i, name=f"layer_{i}")
             for i in range(len(d.layer_types))
         ]
         self.final_norm = self.param(

@@ -6,7 +6,9 @@ routed parts plus the shared expert once add up to the uncut layer; no token
 is dropped at any imbalance; a token outside the window reaches a full layer
 and not a sliding one; one ``FederatedTrainer`` round equals the reference
 round; token ids survive the resident inventory; the program wears its
-scopes; and the comparison notices each term of the block that goes missing.
+scopes; the comparison notices each term of the block that goes missing; and
+a block's checkpoint keeps the forward kernel's result (one forward kernel a
+layer in the gradient's program) without moving a gradient.
 """
 
 import dataclasses
@@ -21,6 +23,7 @@ import pytest
 
 from benchmarks.reference import afmoe as ref
 from benchmarks.reference import federated as fed
+from dinunet_implementations_tpu.checks.semantic import _sub_jaxprs
 from dinunet_implementations_tpu.core.config import NNComputation, TrainConfig
 from dinunet_implementations_tpu.data.api import SiteArrays, stack_site_inventory
 from dinunet_implementations_tpu.models import afmoe
@@ -297,6 +300,78 @@ def test_the_model_picks_the_kernels_on_a_tpu_only(monkeypatch):
     monkeypatch.setattr(afmoe, "_auto_pallas", lambda: True)
     model.apply({"params": params}, x)
     assert calls == [WINDOW] * 4 + [None]
+
+
+# -- what a block's checkpoint keeps -------------------------------------------
+
+TWO_BLOCKS = dict(num_hidden_layers=2, num_dense_layers=1,
+                  layer_types=(SLIDING, FULL))
+KERNEL_TOY = dict(seq_len=128, **TWO_BLOCKS)  # 128 rows = one kernel block
+
+
+def _kernel_calls(jaxpr) -> list[str]:
+    """Names of the Pallas calls in ``jaxpr``, sub-programs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        for sub in _sub_jaxprs(eqn.params):
+            found += _kernel_calls(sub)
+    return found
+
+
+def _loss_gradient(model, x):
+    return jax.grad(lambda p: model.task_loss(
+        {"params": p}, x, jnp.ones(x.shape[0])))
+
+
+def test_the_forward_kernel_runs_once_a_layer(monkeypatch):
+    """The mechanism's engagement counter: in the gradient of ``task_loss``
+    (one sliding and one full layer, the kernel path steered on) every layer
+    has ONE forward kernel, one dq and one dkv. A checkpoint that does not
+    keep the forward's output and log-sum-exp runs it twice a layer."""
+    monkeypatch.setattr(afmoe, "_auto_pallas", lambda: True)
+    _, model, _ = build(**KERNEL_TOY)
+    x = jax.random.randint(jax.random.PRNGKey(0), (1, 129), 0, VOCAB)
+    params = model.init({"params": jax.random.PRNGKey(0)}, x, train=True)["params"]
+
+    def counts():
+        calls = _kernel_calls(jax.make_jaxpr(_loss_gradient(model, x))(params).jaxpr)
+        assert all(c.startswith(kernels) for c in calls), calls
+        return {name: sum(c.startswith(name) for c in calls) for name in kernels}
+
+    kernels = (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV)
+    layers = len(KERNEL_TOY["layer_types"])
+    assert counts() == dict.fromkeys(kernels, layers)
+    # the counter counts: with only the routed experts' output kept, the
+    # block's recomputation runs the forward kernel again
+    monkeypatch.setattr(afmoe, "BLOCK_KEEPS", jax.checkpoint_policies
+                        .save_only_these_names(afmoe.ROUTED_OUT))
+    assert counts()[afmoe.ATTN_FWD] == 2 * layers
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_what_the_checkpoint_keeps_moves_no_gradient(monkeypatch, path):
+    """Gradients of ``task_loss`` under ``BLOCK_KEEPS`` against a checkpoint
+    that keeps nothing, on the kernel path (interpret mode) and on the XLA
+    path (which has no named attention value). Float32 round-off is the
+    limit; on this CPU backend both paths read bit-equal."""
+    over = TWO_BLOCKS
+    if path == "kernel":
+        monkeypatch.setattr(afmoe, "_auto_pallas", lambda: True)
+        over = KERNEL_TOY
+    _, model, _ = build(**over)
+    x = jax.random.randint(jax.random.PRNGKey(1),
+                           (1, over.get("seq_len", T) + 1), 0, VOCAB)
+    params = init_params(model)
+    kept = _loss_gradient(model, x)(params)
+    monkeypatch.setattr(afmoe, "BLOCK_KEEPS",
+                        jax.checkpoint_policies.nothing_saveable)
+    recomputed = _loss_gradient(model, x)(params)
+    for (where, a), b in zip(jax.tree_util.tree_leaves_with_path(kept),
+                             jax.tree.leaves(recomputed)):
+        assert float(jnp.abs(a - b).max()) <= 1e-6 * max(
+            float(jnp.abs(b).max()), 1e-3), jax.tree_util.keystr(where)
 
 
 # -- the task through the trainer ---------------------------------------------
