@@ -220,7 +220,7 @@ def flops_per_sample() -> float:
 
 
 def _flagship_arm(engine_name: str = "dSGD", engine_kw: dict | None = None,
-                  dims: dict | None = None, fused_bidir: bool | None = None):
+                  dims: dict | None = None):
     """Shared flagship-arm construction for every bench mode: the dims dict
     (flagship HCP defaults overridden by ``--small``), the ICA-LSTM
     model/task/engine/optimizer, and the synthetic per-site epoch data as
@@ -231,9 +231,7 @@ def _flagship_arm(engine_name: str = "dSGD", engine_kw: dict | None = None,
 
     bf16 matmuls AND streamed activations with f32 carries/accumulation;
     the fused Pallas kernel keeps W_ih/W_hh resident in VMEM and streams
-    the raw x once per step (ops/lstm_pallas.py). ``fused_bidir=False`` is
-    the A/B arm: two single-direction kernel sweeps instead of the fused
-    bidirectional pooled kernel (VERDICT r4 #1b)."""
+    the raw x once per step (ops/lstm_pallas.py)."""
     import numpy as np
 
     from dinunet_implementations_tpu.engines import make_engine
@@ -249,7 +247,7 @@ def _flagship_arm(engine_name: str = "dSGD", engine_kw: dict | None = None,
     d.update(dims or {})
     model = ICALstm(input_size=d["enc_out"], hidden_size=d["hidden"],
                     num_comps=d["comps"], window_size=d["wlen"], num_cls=2,
-                    compute_dtype=d["compute_dtype"], fused_bidir=fused_bidir)
+                    compute_dtype=d["compute_dtype"])
     task = FederatedTask(model)
     engine = make_engine(engine_name, **(engine_kw or {}))
     opt = make_optimizer("adam", 1e-3)
@@ -264,8 +262,8 @@ def _flagship_arm(engine_name: str = "dSGD", engine_kw: dict | None = None,
 
 
 def _setup_epoch(engine_name: str = "dSGD", engine_kw: dict | None = None,
-                 fused_bidir: bool | None = None, dims: dict | None = None,
-                 fault_plan=None, epoch_kw: dict | None = None):
+                 dims: dict | None = None, fault_plan=None,
+                 epoch_kw: dict | None = None):
     """Build the compiled flagship epoch for one bench arm.
 
     Returns ``(run_chain, samples_per_epoch)``: ``run_chain(k)`` times a
@@ -287,7 +285,7 @@ def _setup_epoch(engine_name: str = "dSGD", engine_kw: dict | None = None,
     )
 
     d, task, engine, opt, np_x, np_y, np_w = _flagship_arm(
-        engine_name, engine_kw, dims, fused_bidir
+        engine_name, engine_kw, dims
     )
     S, steps, B = d["sites"], d["steps"], d["batch"]
     # ship inputs pre-cast to the model's compute dtype (what the input
@@ -341,11 +339,9 @@ def _setup_epoch(engine_name: str = "dSGD", engine_kw: dict | None = None,
     return run_chain, S * steps * B
 
 
-def measure_tpu(fused_bidir: bool | None = None, repeats: int = 5,
-                with_distribution: bool = False, fault_plan=None,
-                dims: dict | None = None):
-    run_chain, samples = _setup_epoch(fused_bidir=fused_bidir,
-                                      fault_plan=fault_plan, dims=dims)
+def measure_tpu(repeats: int = 5, with_distribution: bool = False,
+                fault_plan=None, dims: dict | None = None):
+    run_chain, samples = _setup_epoch(fault_plan=fault_plan, dims=dims)
     run_chain(1)  # compile + lazy-runtime warmup
     # N paired observations per endpoint: contended windows last minutes, so
     # more samples raise the odds of catching an uncontended one; the pairs
@@ -421,26 +417,6 @@ def measure_rankdad_ab(obs: int = 5, n: int = TIMED_EPOCHS,
     return records
 
 
-# fused power-iteration A/B arms (--ab-poweriter, r14): the Pallas kernel
-# (ops/poweriter_pallas.py) against the legacy XLA loop, warm- and
-# cold-started (cold runs the full dad_num_pow_iters trip count — the
-# kernel's HBM-round-trip savings scale with trips), with the dSGD ceiling
-# for scale. On CPU the kernel runs in interpret mode — the artifact records
-# the kernel mode so a CPU number is never mistaken for a TPU one.
-_DAD10 = dict(dad_reduction_rank=10, dad_num_pow_iters=5, dad_tol=1e-3)
-POWERITER_AB_ARMS = {
-    "dsgd-ceiling": ("dSGD", {}),
-    "rankdad-warm-legacy": ("rankDAD", dict(
-        _DAD10, dad_warm_start=True, fused_poweriter=False)),
-    "rankdad-warm-fused": ("rankDAD", dict(
-        _DAD10, dad_warm_start=True, fused_poweriter=True)),
-    "rankdad-cold-legacy": ("rankDAD", dict(
-        _DAD10, dad_tol=0.0, dad_warm_start=False, fused_poweriter=False)),
-    "rankdad-cold-fused": ("rankDAD", dict(
-        _DAD10, dad_tol=0.0, dad_warm_start=False, fused_poweriter=True)),
-}
-
-
 def _engine_ab_records(arms: dict, metric: str, obs: int, n: int,
                        dims: dict | None, extra=None) -> list[dict]:
     """Shared paired-interleaved engine A/B driver (the --ab-rankdad
@@ -479,29 +455,6 @@ def _engine_ab_records(arms: dict, metric: str, obs: int, n: int,
             extra(arm, rec)
         records.append(rec)
     return records
-
-
-def measure_poweriter_ab(obs: int = 5, n: int = TIMED_EPOCHS,
-                         dims: dict | None = None) -> list[dict]:
-    """Paired interleaved A/B of the fused power-iteration kernel
-    (``--ab-poweriter``), one JSON record per arm."""
-    import jax
-
-    def extra(arm, rec):
-        if "fused" in arm:
-            rec["poweriter_kernel"] = (
-                "pallas" if jax.default_backend() == "tpu"
-                else "pallas-interpret"
-            )
-        elif "rankdad" in arm:
-            rec["poweriter_kernel"] = "xla-legacy"
-
-    return _engine_ab_records(
-        POWERITER_AB_ARMS,
-        "samples/sec/chip (ICA-LSTM federated round, fused power-iteration "
-        "A/B)",
-        obs, n, dims, extra=extra,
-    )
 
 
 def _flagship_params_template(engine_name: str, dims: dict | None):
@@ -2081,18 +2034,6 @@ def main():
         for rec in measure_rankdad_ab(obs=obs, n=n, dims=dims):
             print(json.dumps(rec), flush=True)
         return
-    if "--ab-poweriter" in sys.argv:
-        # paired interleaved A/B of the fused Pallas power-iteration kernel
-        # against the legacy XLA loop (r14; same protocol as --ab-rankdad).
-        # On CPU the kernel runs in interpret mode and the records say so —
-        # regen on TPU with the same command for the flagship numbers.
-        obs = int(sys.argv[sys.argv.index("--obs") + 1]) if "--obs" in sys.argv else 5
-        n = (int(sys.argv[sys.argv.index("--epochs") + 1])
-             if "--epochs" in sys.argv else TIMED_EPOCHS)
-        dims = SMALL_DIMS if "--small" in sys.argv else None
-        for rec in measure_poweriter_ab(obs=obs, n=n, dims=dims):
-            print(json.dumps(rec), flush=True)
-        return
     if "--wire-quant" in sys.argv:
         # quantized-wire A/B (r14): the listed codecs (comma list from
         # {bf16,int8,fp8}) against the f32 wire, paired interleaved; each
@@ -2221,23 +2162,6 @@ def main():
         elif value is not None:
             rec["vs_baseline"] = round(value / baseline, 2)
         print(json.dumps(rec))
-        return
-    if "--ab-bidir" in sys.argv:
-        # A/B the fused bidirectional pooled kernel against two
-        # single-direction sweeps, same process, interleaved endpoints are
-        # not needed — each arm uses the least-contended-minimum estimator.
-        for arm, fused in (("fused-bidir", True), ("per-direction", False)):
-            v, stats = measure_tpu(fused_bidir=fused, repeats=3,
-                                   with_distribution=True)
-            rec = {
-                "metric": f"samples/sec/chip (flagship, {arm})",
-                "arm": arm, "value": v,
-                "unit": "samples/sec/chip",
-                "samples_per_sec": stats,
-            }
-            if v is not None:
-                rec["mfu"] = round(v * flops_per_sample() / V5E_BF16_PEAK_FLOPS, 4)
-            print(json.dumps(rec), flush=True)
         return
     value, stats = measure_tpu(with_distribution=True)
     rec = {

@@ -878,7 +878,7 @@ class TraceCell:
     # to the shared subtree
     epoch_kw: tuple = ()
     # free-form label suffix for cells distinguished only by engine_kw
-    # (e.g. "+fused" for the Pallas power-iteration corner) — labels key
+    # (e.g. "+overlap" for the overlapped-rounds corners) — labels key
     # the semantic baseline, so they must stay unique per cell
     tag: str = ""
 
@@ -1142,21 +1142,6 @@ def default_matrix() -> list:
             "dSGD", "mesh", "host", wire_quant="int8",
             engine_kw=(("wire_stochastic", True),), tag="+sr",
         ),
-        # fused Pallas power iteration in the traced program (interpret on
-        # CPU): the kernel changes where the factorization computes, never
-        # what ships — S001/S002 must stay green with the pallas_call in
-        # the jaxpr, incl. on a packed (K=4) cell where the kernel runs
-        # under the custom_vmap member fold
-        TraceCell(
-            "rankDAD", "mesh", "host", tag="+fused",
-            engine_kw=(("dad_num_pow_iters", 2), ("dad_reduction_rank", 2),
-                       ("fused_poweriter", True)),
-        ),
-        TraceCell(
-            "rankDAD", "fold4", "host", tag="+fused", wire_quant="int8",
-            engine_kw=(("dad_num_pow_iters", 2), ("dad_reduction_rank", 2),
-                       ("fused_poweriter", True)),
-        ),
         # overlapped rounds (r14): the stash apply's collectives are the
         # SAME wire as the legacy round (S002), and the stash selects stay
         # inside the rounds scan (S001)
@@ -1344,31 +1329,13 @@ IDENTITY_CASES = {
     "personalize-on": (dict(personalize=("fc_out",)), False),
 }
 
-#: the rankDAD corner's cases — the fused power-iteration kernel only
-#: exists in the compression engines' program. The BASE cell pins
-#: fused_poweriter=False (what the None default resolves to on every
-#: backend), so off == baseline and on must inject the pallas_call.
-IDENTITY_CASES_RANKDAD = {
-    "poweriter-fused-off": (dict(engine=dict(fused_poweriter=False)), True),
-    "poweriter-fused-on": (dict(engine=dict(fused_poweriter=True)), False),
-}
-
-#: the corner IDENTITY_CASES_RANKDAD runs on (small ranks keep the trace
-#: cheap; vmap/host is the cheapest topology with a factorization path)
-RANKDAD_IDENTITY_CELL = TraceCell(
-    "rankDAD", "vmap", "host",
-    engine_kw=(("dad_num_pow_iters", 2), ("dad_reduction_rank", 2),
-               ("fused_poweriter", False)),
-)
-
-
 def identity_text_fn(cell: TraceCell):
     """``text(**case_kw)`` builder for one identity corner — the ONE
     implementation behind the S005 CLI gate and the tier-1 mirror
     (tests/test_lowering_identity.py). ``case_kw`` may carry the reserved
     ``engine`` key: a dict of ``make_engine`` overrides layered onto the
     cell's engine kwargs (for knobs that live in the engine — wire_quant,
-    fused_poweriter)."""
+    secure_agg)."""
     from ..engines import make_engine
     from ..trainer.steps import make_train_epoch_fn
 
@@ -1473,24 +1440,19 @@ def slices_identity_pairs() -> list:
 
 def _identity_gate() -> list:
     """The S005 program-identity pairs (:data:`IDENTITY_CASES` on the
-    flagship dSGD corner, :data:`IDENTITY_CASES_RANKDAD` on the rankDAD
-    one, plus the r18 multi-slice pairs)."""
+    flagship dSGD corner, plus the r18 multi-slice pairs)."""
     import jax
 
     pairs = []
-    for cell, cases in (
-        (TraceCell("dSGD", "vmap", "host"), IDENTITY_CASES),
-        (RANKDAD_IDENTITY_CELL, IDENTITY_CASES_RANKDAD),
-    ):
-        text = identity_text_fn(cell)
-        base = text()
-        for label, (kw, expect_identical) in cases.items():
-            if kw is None:
-                with jax.checking_leaks():
-                    variant = text()
-            else:
-                variant = text(**kw)
-            pairs.append((label, base, variant, expect_identical))
+    text = identity_text_fn(TraceCell("dSGD", "vmap", "host"))
+    base = text()
+    for label, (kw, expect_identical) in IDENTITY_CASES.items():
+        if kw is None:
+            with jax.checking_leaks():
+                variant = text()
+        else:
+            variant = text(**kw)
+        pairs.append((label, base, variant, expect_identical))
     pairs += slices_identity_pairs()
     return check_lowering_identity(pairs)
 

@@ -402,14 +402,6 @@ class TrainConfig:
     # stochastic rounding on the int8 wire grid (unbiased in expectation;
     # value-hashed dither, no RNG state): False = round-to-nearest-even
     wire_stochastic: bool = False
-    # fused Pallas power-iteration kernel (r14, ops/poweriter_pallas.py):
-    # one VMEM-resident kernel per rank class for the rankDAD subspace
-    # iteration — no HBM round trips between power refinements. None and
-    # False = the XLA loop (program-identical, S005-gated); True = the
-    # kernel: interpret mode on a CPU (parity tests / A/B bench), and on a
-    # TPU the compiler's error — the kernel does not lower there yet
-    # (ROADMAP S2).
-    fused_poweriter: bool | None = None
     # overlapped rounds (r14, trainer/steps.py): issue round t's
     # aggregation collective while round t+1's batch gather + compute run
     # (double-buffered TrainState.overlap stash; one-round-delayed

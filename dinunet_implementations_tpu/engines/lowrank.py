@@ -282,7 +282,7 @@ def _cholqr(Y):
 
 
 def subspace_iteration_grouped(groups, num_iters: int, tol: float,
-                               matmul_dtype=None, fused: bool = False):
+                               matmul_dtype=None):
     """Rank-r factorizations ``G ≈ P @ Qᵀ`` for SEVERAL same-rank groups in
     ONE shared ``lax.while_loop``.
 
@@ -322,36 +322,6 @@ def subspace_iteration_grouped(groups, num_iters: int, tol: float,
         # nothing to factorize — the engines' dense fallback carries the
         # whole exchange. The while_loop below cannot carry an empty tuple.
         return []
-    if fused:
-        # fused Pallas power iteration (ops/poweriter_pallas.py, r14): one
-        # VMEM-resident pallas_call per rank class — same math, same
-        # per-member trip semantics, no HBM round trips between
-        # refinements. Classes whose padded working set would blow the VMEM
-        # budget fall back to the legacy XLA loop below (a trace-time
-        # static split; on the flagship shapes every class fits).
-        from ..ops import poweriter_pallas as pp
-
-        fusable = [
-            i for i, (Gs, rank, _) in enumerate(groups)
-            if pp.class_fits_vmem(Gs, rank, matmul_dtype)
-        ]
-        if fusable:
-            results: list = [None] * len(groups)
-            fused_out = pp.fused_subspace_iteration_grouped(
-                [groups[i] for i in fusable], num_iters, tol,
-                matmul_dtype=matmul_dtype,
-            )
-            for i, res in zip(fusable, fused_out):
-                results[i] = res
-            rest = [i for i in range(len(groups)) if i not in set(fusable)]
-            if rest:
-                legacy = subspace_iteration_grouped(
-                    [groups[i] for i in rest], num_iters, tol,
-                    matmul_dtype=matmul_dtype, fused=False,
-                )
-                for i, res in zip(rest, legacy):
-                    results[i] = res
-            return results
     prepped = []  # (Gs_f32, omegas_f32) per group, ranks clamped
     for Gs, rank, omegas in groups:
         Gs = [G.astype(jnp.float32) for G in Gs]

@@ -81,7 +81,6 @@ def make_rankdad(
     dad_warm_start: bool = True,
     wire_quant="none",
     wire_stochastic=False,
-    fused_poweriter: bool | None = None,
     robust_agg="none",
     robust_trim_frac=0.2,
     robust_clip_mult=2.5,
@@ -130,14 +129,6 @@ def make_rankdad(
         precision_bits, wire_quant, dcn_wire_quant, wire_stochastic
     )
     ddtype = np.dtype(dcn.dtype) if dcn is not None else None
-
-    # fused Pallas power iteration (ops/poweriter_pallas.py): explicit opt-in
-    # on every backend. The kernel has only ever run in interpret mode and
-    # does not lower for a TPU (Mosaic has no `scatter`; ROADMAP S2), so
-    # None resolves to the XLA loop; True on a TPU fails with the compiler's
-    # own error rather than falling back. (A factory kwarg from
-    # TrainConfig.fused_poweriter, never a tracer.)
-    fused = fused_poweriter is True
 
     def _effective_rank(g) -> int:
         # shape arithmetic only (g may be a ShapeDtypeStruct row template on
@@ -329,7 +320,6 @@ def make_rankdad(
                 return subspace_iteration_grouped(
                     [(ms, r, oms) for r, (ms, oms) in zip(rs, groups_in)],
                     dad_num_pow_iters, dad_tol, matmul_dtype=mm_dtype,
-                    fused=fused,
                 )
 
             results = jax.vmap(factorize)(arg)
@@ -341,7 +331,6 @@ def make_rankdad(
                     for r, idxs in order
                 ],
                 dad_num_pow_iters, dad_tol, matmul_dtype=mm_dtype,
-                fused=fused,
             )
         for (r, idxs), pqs in zip(order, results):
             # weight one factor so the gathered reconstruction sums to the

@@ -129,9 +129,8 @@ class LSTMCell(nn.Module):
 class _LSTMCellParams(nn.Module):
     """Parameter-only twin of :class:`LSTMCell` — declares the exact same
     param tree (names, shapes, inits) without running the recurrence, so the
-    fused bidirectional kernel (one pallas_call spanning both directions,
-    ops/lstm_pallas.py) can own the compute while checkpoints/params remain
-    interchangeable with the per-direction cell modules."""
+    streaming step (:class:`_StreamLSTM`) can own the compute while
+    checkpoints/params remain interchangeable with the cell modules."""
 
     in_dim: int
     hidden: int
@@ -165,14 +164,6 @@ class BiLSTM(nn.Module):
     sequence_axis: str | None = None
     # ring-LSTM wavefront microbatches (parallel/sequence.py): 0 = auto
     sequence_microbatches: int = 0
-    # True opts in to the fused bidirectional pooled kernel (one pallas
-    # sweep advancing both directions, site-native residuals under vmap —
-    # ops/lstm_pallas.py). Default (None/False) runs the per-direction
-    # kernels: the r5 A/B on the flagship 32-site bench measured the fused
-    # path 27% SLOWER (80,531 vs 110,009 samples/sec/chip,
-    # docs/bench_ab_bidir_r5.jsonl) despite its fewer relayout copies, so
-    # the measured winner is the default and the fused path is the A/B arm.
-    fused_bidir: bool | None = None
     # time_pool="mean": return the time-mean [B, H_total] instead of the
     # hidden sequence. Numerically identical to mean-pooling the concat
     # (column blocks reduce independently), but the [B, T, 2*per_dir] concat
@@ -180,7 +171,9 @@ class BiLSTM(nn.Module):
     # aligned feature offset (e.g. 174), and profiling the 32-site bench
     # showed XLA spending ~0.5 ms/round on relayout copies plus a slowed
     # reverse-direction backward kernel whose dhs cotangent arrived
-    # lane-rotated. Dense path only (the ring path pools in ICALstm).
+    # lane-rotated. Dense path only (the ring path pools in ICALstm). Each
+    # direction is its own kernel call: one sweep advancing both measured
+    # 27 % slower on the chip (docs/bench_ab_bidir_r5.jsonl).
     time_pool: str | None = None
 
     @nn.compact
@@ -194,36 +187,6 @@ class BiLSTM(nn.Module):
             raise ValueError("time_pool requires sequence_axis=None")
         pool = (lambda s: jnp.mean(s, axis=1)) if self.time_pool == "mean" else (lambda s: s)
         per_dir = self.hidden_size // (2 if self.bidirectional else 1)
-
-        use_pallas = (
-            self.use_pallas if self.use_pallas is not None else _auto_pallas()
-        ) and not self.double_sigmoid_gates
-        if (self.bidirectional and use_pallas and self.time_pool == "mean"
-                and self.fused_bidir is True):
-            # fused bidirectional kernel: ONE pallas sweep advances both
-            # directions (rev reads x through a time-flipped index map) and
-            # the VJP runs flip-free. Param trees are identical to the
-            # per-cell path (_LSTMCellParams). Restricted to the mean-pooled
-            # path because the kernel returns hs_r in x-time convention —
-            # the pool is time-order-invariant, while the sequence-returning
-            # path must preserve the reference's no-flip-back concat order.
-            # (time_pool == "mean" implies sequence_axis is None, checked
-            # above.)
-            from ..ops.lstm_pallas import bilstm_pool_forward_fused
-
-            pf = _LSTMCellParams(x.shape[-1], per_dir, name="fwd")()
-            pr = _LSTMCellParams(x.shape[-1], per_dir, name="rev")()
-            h02 = None if h0 is None else jnp.stack([h0[0], h0[0]])
-            c02 = None if h0 is None else jnp.stack([h0[1], h0[1]])
-            pooled, (hT2, cT2) = bilstm_pool_forward_fused(
-                x, pf, pr, h02, c02,
-                compute_dtype=compute_dtype_of(self.compute_dtype),
-            )
-            return (
-                pooled,
-                (jnp.concatenate([hT2[0], hT2[1]], 1),
-                 jnp.concatenate([cT2[0], cT2[1]], 1)),
-            )
 
         fwd_cell = LSTMCell(
             per_dir, self.double_sigmoid_gates, self.use_pallas,
@@ -406,7 +369,6 @@ class ICALstm(nn.Module):
     dropout_rate: float = 0.25
     use_pallas: bool | None = None  # None = auto (kernel on accelerators)
     compute_dtype: str | None = None  # "bfloat16" = mixed precision (f32 accum)
-    fused_bidir: bool | None = None  # True = opt-in fused bidir kernel (A/B loser, see BiLSTM)
     sequence_microbatches: int = 0  # ring wavefront microbatches; 0 = auto
     # Sequence parallelism (TPU extension, SURVEY.md §2.2): a bound mesh axis
     # name (parallel.mesh.MODEL_AXIS) shards the window axis S across that
@@ -446,7 +408,6 @@ class ICALstm(nn.Module):
             self.use_pallas,
             self.compute_dtype,
             self.sequence_axis,
-            fused_bidir=self.fused_bidir,
             sequence_microbatches=self.sequence_microbatches,
             # dense path: pool inside BiLSTM per direction — same values as
             # mean-pooling the concat (models.py:109) without materializing

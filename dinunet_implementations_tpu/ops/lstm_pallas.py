@@ -77,12 +77,7 @@ B_TILE = 128
 # too (benchmarks/tests/test_scope_metrics.py fails otherwise).
 LSTM_FWD = "lstm_fwd"
 LSTM_BWD = "lstm_bwd"
-BILSTM_FWD = "bilstm_fwd"
-BILSTM_BWD = "bilstm_bwd"
-BILSTM_POOL_FWD = "bilstm_pool_fwd"
-BILSTM_POOL_BWD = "bilstm_pool_bwd"
-KERNEL_NAMES = (LSTM_FWD, LSTM_BWD, BILSTM_FWD, BILSTM_BWD, BILSTM_POOL_FWD,
-                BILSTM_POOL_BWD)
+KERNEL_NAMES = (LSTM_FWD, LSTM_BWD)
 
 
 def _interpret() -> bool:
@@ -460,998 +455,6 @@ def _vjp_fused_bwd(compute_dtype, res, grads):
 
 
 lstm_recurrence_fused.defvjp(_vjp_fused_fwd, _vjp_fused_bwd)
-
-
-# ---------------------------------------------------------------------------
-# fused BIDIRECTIONAL kernels (VERDICT r3 #3): both directions advance in ONE
-# grid sweep — the fwd direction consumes x block t while the rev direction
-# consumes x block T-1-t (the time flip lives in the index map; no flipped
-# copy of x is ever materialized). Each direction's recurrence is a serial
-# dependency chain on its own carry; interleaving two independent chains in
-# one kernel gives the MXU a second stream of ready matmuls while the other
-# chain's h@W_hh waits on its carry — the single-direction kernel ran the
-# directions as two back-to-back passes with that latency exposed twice.
-# Both weight stacks stay VMEM-resident ([2, 4, D, H] + [2, 4, H, H]).
-#
-# EVERY rev-direction stream is stored in X-TIME convention (the rev state
-# computed while consuming x[t] lands at block t, via the same flipped index
-# map that reads x): the VJP then pairs dpc_rev with x/W by plain identity
-# index — no jnp.flip of any [T, B, ·] array anywhere (the first cut kept
-# rev streams in flipped-s order and paid ~0.7 ms/step of pure reverse-copy
-# traffic in the epoch, measured on v5e). It also lets dx/dW_ih consume the
-# two directions' cotangents as ONE [T, B, 8H]-wide concat matmul.
-# The backward walks fwd time descending (blocks T-1-t) while the rev chain
-# drains through identity maps (block t) — one kernel, both chains.
-# ---------------------------------------------------------------------------
-
-
-def _fwd_bidir_kernel(
-    xf, xr, wih, b, whh, h0, c0,
-    hsf, csf, aif, aff, aof, agf, hsr, csr, air, afr, aor, agr, hT, cT,
-    hf_s, cf_s, hr_s, cr_s,
-):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _():
-        hf_s[:] = h0[0]
-        cf_s[:] = c0[0]
-        hr_s[:] = h0[1]
-        cr_s[:] = c0[1]
-
-    f32 = jnp.float32
-
-    def advance(xt, h_s, c_s, d):
-        h = h_s[:].astype(whh.dtype)
-        pre = [
-            jnp.dot(xt, wih[d, k], preferred_element_type=f32)
-            + jnp.dot(h, whh[d, k], preferred_element_type=f32)
-            + b[d, k].astype(f32)
-            for k in range(4)
-        ]
-        i = jax.nn.sigmoid(pre[0])
-        f = jax.nn.sigmoid(pre[1])
-        o = jax.nn.sigmoid(pre[2])
-        g = jnp.tanh(pre[3])
-        c = f * c_s[:] + i * g
-        h = o * jnp.tanh(c)
-        h_s[:] = h
-        c_s[:] = c
-        return h, c, i, f, o, g
-
-    h, c, i, f, o, g = advance(xf[0], hf_s, cf_s, 0)
-    hsf[0] = h.astype(hsf.dtype)
-    csf[0] = c.astype(csf.dtype)
-    aif[0] = i.astype(aif.dtype)
-    aff[0] = f.astype(aff.dtype)
-    aof[0] = o.astype(aof.dtype)
-    agf[0] = g.astype(agf.dtype)
-
-    h, c, i, f, o, g = advance(xr[0], hr_s, cr_s, 1)
-    hsr[0] = h.astype(hsr.dtype)
-    csr[0] = c.astype(csr.dtype)
-    air[0] = i.astype(air.dtype)
-    afr[0] = f.astype(afr.dtype)
-    aor[0] = o.astype(aor.dtype)
-    agr[0] = g.astype(agr.dtype)
-
-    # terminal carries at full f32 (same contract as the single-direction
-    # kernel: straight from VMEM scratch, never the possibly-bf16 streams)
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _():
-        hT[0] = hf_s[:]
-        cT[0] = cf_s[:]
-        hT[1] = hr_s[:]
-        cT[1] = cr_s[:]
-
-
-def _fwd_bidir_call(x, wih2, b2, whh2, h02, c02, compute_dtype=None):
-    T, B, D = x.shape
-    H = wih2.shape[-1]
-    bt = min(B_TILE, B)
-    assert B % bt == 0, (
-        f"batch {B} must be a multiple of the kernel tile {bt}; "
-        "use bilstm_forward_fused(), which pads"
-    )
-    if compute_dtype is not None:
-        x = x.astype(compute_dtype)
-        wih2 = wih2.astype(compute_dtype)
-        whh2 = whh2.astype(compute_dtype)
-    grid = (B // bt, T)
-    spec_xf = pl.BlockSpec((1, bt, D), lambda b, t: (t, b, 0), memory_space=pltpu.VMEM)
-    spec_xr = pl.BlockSpec(
-        (1, bt, D), lambda b, t: (T - 1 - t, b, 0), memory_space=pltpu.VMEM
-    )
-    spec_t = pl.BlockSpec((1, bt, H), lambda b, t: (t, b, 0), memory_space=pltpu.VMEM)
-    # rev streams land at the SAME time index their x block came from
-    # (x-time convention; see the section comment)
-    spec_tr = pl.BlockSpec(
-        (1, bt, H), lambda b, t: (T - 1 - t, b, 0), memory_space=pltpu.VMEM
-    )
-    spec_b2 = pl.BlockSpec((2, bt, H), lambda b, t: (0, b, 0), memory_space=pltpu.VMEM)
-    spec_wih = pl.BlockSpec(
-        (2, 4, D, H), lambda b, t: (0, 0, 0, 0), memory_space=pltpu.VMEM
-    )
-    spec_whh = pl.BlockSpec(
-        (2, 4, H, H), lambda b, t: (0, 0, 0, 0), memory_space=pltpu.VMEM
-    )
-    spec_bias = pl.BlockSpec((2, 4, H), lambda b, t: (0, 0, 0), memory_space=pltpu.VMEM)
-    stream = jnp.dtype(compute_dtype) if compute_dtype is not None else jnp.float32
-    t_shape = jax.ShapeDtypeStruct((T, B, H), stream)
-    carry_shape = jax.ShapeDtypeStruct((2, B, H), jnp.float32)
-    return pl.pallas_call(
-        _fwd_bidir_kernel,
-        grid=grid,
-        in_specs=[spec_xf, spec_xr, spec_wih, spec_bias, spec_whh, spec_b2, spec_b2],
-        out_specs=[spec_t] * 6 + [spec_tr] * 6 + [spec_b2] * 2,
-        out_shape=[t_shape] * 12 + [carry_shape] * 2,
-        scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 4,
-        interpret=_interpret(),
-        name=BILSTM_FWD,
-    )(x, x, wih2, b2, whh2, h02, c02)
-
-
-def _bwd_bidir_kernel(
-    T_total,
-    aif, aff, aof, agf, air, afr, aor, agr,
-    csf, csf_prev, csr, csr_prev, wT, c0, dhsf, dhsr, dhT, dcT,
-    dxf_i, dxf_f, dxf_o, dxf_g, dxr_i, dxr_f, dxr_o, dxr_g, dh0, dc0,
-    dhf_s, dcf_s, dhr_s, dcr_s,
-):
-    t = pl.program_id(1)  # both directions walk their own time backwards
-    first_time = t == 0
-    last_time = t == T_total - 1
-
-    @pl.when(first_time)
-    def _():
-        dhf_s[:] = dhT[0].astype(jnp.float32)
-        dcf_s[:] = dcT[0].astype(jnp.float32)
-        dhr_s[:] = dhT[1].astype(jnp.float32)
-        dcr_s[:] = dcT[1].astype(jnp.float32)
-
-    f32 = jnp.float32
-    cdt = wT.dtype
-
-    def drain(acts, c, c_prev, dhs_blk, dh_s, dc_s, d, outs):
-        i, f, o, g = (a[0].astype(f32) for a in acts)
-        c = c[0].astype(f32)
-        c_prev = jnp.where(last_time, c0[d].astype(f32), c_prev[0].astype(f32))
-        tanh_c = jnp.tanh(c)
-        dh = dhs_blk[0].astype(f32) + dh_s[:]
-        do = dh * tanh_c
-        dc = dh * o * (1.0 - tanh_c * tanh_c) + dc_s[:]
-        di = dc * g
-        df = dc * c_prev
-        dg = dc * i
-        dpi = di * i * (1.0 - i)
-        dpf = df * f * (1.0 - f)
-        dpo = do * o * (1.0 - o)
-        dpg = dg * (1.0 - g * g)
-        outs[0][0] = dpi.astype(outs[0].dtype)
-        outs[1][0] = dpf.astype(outs[1].dtype)
-        outs[2][0] = dpo.astype(outs[2].dtype)
-        outs[3][0] = dpg.astype(outs[3].dtype)
-        dh_s[:] = (
-            jnp.dot(dpi.astype(cdt), wT[d, 0], preferred_element_type=f32)
-            + jnp.dot(dpf.astype(cdt), wT[d, 1], preferred_element_type=f32)
-            + jnp.dot(dpo.astype(cdt), wT[d, 2], preferred_element_type=f32)
-            + jnp.dot(dpg.astype(cdt), wT[d, 3], preferred_element_type=f32)
-        )
-        dc_s[:] = dc * f
-
-    drain((aif, aff, aof, agf), csf, csf_prev, dhsf, dhf_s, dcf_s, 0,
-          (dxf_i, dxf_f, dxf_o, dxf_g))
-    drain((air, afr, aor, agr), csr, csr_prev, dhsr, dhr_s, dcr_s, 1,
-          (dxr_i, dxr_f, dxr_o, dxr_g))
-
-    @pl.when(last_time)
-    def _():
-        dh0[0] = dhf_s[:].astype(dh0.dtype)
-        dc0[0] = dcf_s[:].astype(dc0.dtype)
-        dh0[1] = dhr_s[:].astype(dh0.dtype)
-        dc0[1] = dcr_s[:].astype(dc0.dtype)
-
-
-def _bwd_bidir_call(actsf, actsr, csf, csr, whh2, c02, dhsf, dhsr, dhT2, dcT2,
-                    compute_dtype=None):
-    """``dhsf``/``dhsr`` may be full ``[T, B, H]`` cotangent streams or
-    ``[1, B, H]`` per-row constants (the mean-pool backward: every step gets
-    the same ``dpool/T`` block through a constant index map — no broadcast
-    materialization, no stream traffic)."""
-    T, B, H = csf.shape
-    bt = min(B_TILE, B)
-    assert B % bt == 0, f"batch {B} must be a multiple of the kernel tile {bt}"
-    if compute_dtype is not None:
-        whh2 = whh2.astype(compute_dtype)
-    w2T = jnp.swapaxes(whh2, 2, 3)  # transpose ONCE in XLA, VMEM-resident
-    grid = (B // bt, T)
-
-    # fwd streams walk time descending; rev streams are stored in x-time
-    # convention, so the rev chain (its own time also descending) walks
-    # x-time ASCENDING — identity maps. rev's c_prev (one step earlier in
-    # its own time) sits one x-time block LATER.
-    rev = lambda b, t: (T - 1 - t, b, 0)
-    fwd = lambda b, t: (t, b, 0)
-    spec_rev = pl.BlockSpec((1, bt, H), rev, memory_space=pltpu.VMEM)
-    spec_fwd = pl.BlockSpec((1, bt, H), fwd, memory_space=pltpu.VMEM)
-    spec_prev_f = pl.BlockSpec(
-        (1, bt, H), lambda b, t: (jnp.maximum(T - 2 - t, 0), b, 0),
-        memory_space=pltpu.VMEM,
-    )
-    spec_prev_r = pl.BlockSpec(
-        (1, bt, H), lambda b, t: (jnp.minimum(t + 1, T - 1), b, 0),
-        memory_space=pltpu.VMEM,
-    )
-    spec_b2 = pl.BlockSpec((2, bt, H), lambda b, t: (0, b, 0), memory_space=pltpu.VMEM)
-    spec_w = pl.BlockSpec(
-        (2, 4, H, H), lambda b, t: (0, 0, 0, 0), memory_space=pltpu.VMEM
-    )
-    t_shape = jax.ShapeDtypeStruct((T, B, H), actsf[0].dtype)
-    b2_shape = jax.ShapeDtypeStruct((2, B, H), jnp.float32)
-    spec_const = pl.BlockSpec(
-        (1, bt, H), lambda b, t: (0, b, 0), memory_space=pltpu.VMEM
-    )
-    spec_dhf = spec_const if dhsf.shape[0] == 1 else spec_rev
-    spec_dhr = spec_const if dhsr.shape[0] == 1 else spec_fwd
-
-    return pl.pallas_call(
-        functools.partial(_bwd_bidir_kernel, T),
-        grid=grid,
-        in_specs=[spec_rev] * 4 + [spec_fwd] * 4
-        + [spec_rev, spec_prev_f, spec_fwd, spec_prev_r, spec_w, spec_b2,
-           spec_dhf, spec_dhr, spec_b2, spec_b2],
-        out_specs=[spec_rev] * 4 + [spec_fwd] * 4 + [spec_b2, spec_b2],
-        out_shape=[t_shape] * 8 + [b2_shape, b2_shape],
-        scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 4,
-        interpret=_interpret(),
-        name=BILSTM_BWD,
-    )(*actsf, *actsr, csf, csf, csr, csr, w2T, c02, dhsf, dhsr, dhT2, dcT2)
-
-
-@functools.lru_cache(maxsize=None)
-def _fwd_bidir_callable(cdt_name: str | None):
-    cdt = jnp.dtype(cdt_name) if cdt_name else None
-
-    @custom_vmap
-    def f(x, wih2, b2, whh2, h02, c02):
-        return tuple(_fwd_bidir_call(x, wih2, b2, whh2, h02, c02, cdt))
-
-    @f.def_vmap
-    def _rule(axis_size, in_batched, *args):
-        if any(in_batched[k] for k in (1, 2, 3)):  # per-element weights
-            batched = _broadcast_unbatched(args, in_batched, axis_size)
-            outs = jax.lax.map(lambda a: f(*a), tuple(batched))
-            return tuple(outs), (True,) * 14
-        S = axis_size
-        batched = _broadcast_unbatched(
-            args, [b or i in (1, 2, 3) for i, b in enumerate(in_batched)], S
-        )
-        x = _fold_rows(batched[0])  # [S, T, B, D] → [T, S*B, D]
-        B = batched[4].shape[2]  # [S, 2, B, H]
-        h02 = jnp.moveaxis(batched[4], 0, 1).reshape(2, S * B, -1)
-        c02 = jnp.moveaxis(batched[5], 0, 1).reshape(2, S * B, -1)
-        (x,), _ = _pad_rows([x], S * B, axis=-2)
-        (h02, c02), _ = _pad_rows([h02, c02], S * B, axis=-2)
-        outs = f(x, args[1], args[2], args[3], h02, c02)
-        t_outs = [_unfold_rows(o[:, : S * B], S, B) for o in outs[:12]]
-        b_outs = [
-            jnp.moveaxis(o[:, : S * B].reshape(2, S, B, -1), 1, 0)
-            for o in outs[12:]
-        ]
-        return tuple(t_outs + b_outs), (True,) * 14
-
-    return f
-
-
-@functools.lru_cache(maxsize=None)
-def _bwd_bidir_callable(cdt_name: str | None):
-    cdt = jnp.dtype(cdt_name) if cdt_name else None
-
-    @custom_vmap
-    def f(aif, aff, aof, agf, air, afr, aor, agr, csf, csr, whh2, c02,
-          dhsf, dhsr, dhT2, dcT2):
-        return tuple(_bwd_bidir_call(
-            (aif, aff, aof, agf), (air, afr, aor, agr), csf, csr, whh2, c02,
-            dhsf, dhsr, dhT2, dcT2, cdt,
-        ))
-
-    @f.def_vmap
-    def _rule(axis_size, in_batched, *args):
-        if in_batched[10]:  # per-element weights
-            batched = _broadcast_unbatched(args, in_batched, axis_size)
-            outs = jax.lax.map(lambda a: f(*a), tuple(batched))
-            return tuple(outs), (True,) * 10
-        S = axis_size
-        batched = _broadcast_unbatched(
-            args, [b or i == 10 for i, b in enumerate(in_batched)], S
-        )
-        t_arrs = [_fold_rows(batched[i]) for i in (0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 12, 13)]
-        B = batched[11].shape[2]  # [S, 2, B, H]
-        b_arrs = [
-            jnp.moveaxis(batched[i], 0, 1).reshape(2, S * B, -1)
-            for i in (11, 14, 15)
-        ]
-        rows = S * B
-        t_arrs, _ = _pad_rows(t_arrs, rows, axis=-2)
-        b_arrs, _ = _pad_rows(b_arrs, rows, axis=-2)
-        outs = f(*t_arrs[:10], args[10], b_arrs[0], t_arrs[10], t_arrs[11],
-                 b_arrs[1], b_arrs[2])
-        dxi = [_unfold_rows(o[:, :rows], S, B) for o in outs[:8]]
-        db = [
-            jnp.moveaxis(o[:, :rows].reshape(2, S, B, -1), 1, 0)
-            for o in outs[8:]
-        ]
-        return tuple(dxi + db), (True,) * 10
-
-    return f
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def bilstm_recurrence_fused(x, wih2, b2, whh2, h02, c02, compute_dtype=None):
-    """Fused BIDIRECTIONAL LSTM: both directions in ONE kernel sweep.
-
-    Args:
-      x: ``[T, B, D]`` raw per-step inputs. The reverse direction reads x
-        through a time-flipped index map — callers never materialize a
-        flipped copy (the reference flips in torch, ``models.py:60-65``).
-      wih2: ``[2, 4, D, H]`` per-direction input projections (fwd, rev).
-      b2: ``[2, 4, H]`` combined biases; whh2: ``[2, 4, H, H]``.
-      h02, c02: ``[2, B, H]`` initial carries.
-
-    Returns ``(hs_f [T, B, H], hs_r [T, B, H], (hT2, cT2) [2, B, H] f32)``.
-    ``hs_r`` is in X-TIME convention: ``hs_r[t]`` is the rev state computed
-    while consuming ``x[t]`` (i.e. after seeing ``x[T-1..t]``) — the
-    cuDNN-style bidirectional alignment, equal to ``flip(rev_cell(flip(x)))``.
-    Time-order-invariant consumers (the model's mean-pool) use it directly;
-    a caller needing the reference's no-flip-back concat order must flip.
-    This convention is what lets the VJP run entirely flip-free (see the
-    section comment above).
-    """
-    outs = _fwd_bidir_callable(_cdt_name(compute_dtype))(
-        x, wih2, b2, whh2, h02, c02
-    )
-    hsf, hsr, hT2, cT2 = outs[0], outs[6], outs[12], outs[13]
-    return hsf, hsr, (hT2, cT2)
-
-
-def _vjp_bidir_fwd(x, wih2, b2, whh2, h02, c02, compute_dtype):
-    outs = _fwd_bidir_callable(_cdt_name(compute_dtype))(
-        x, wih2, b2, whh2, h02, c02
-    )
-    (hsf, csf, aif, aff, aof, agf,
-     hsr, csr, air, afr, aor, agr, hT2, cT2) = outs
-    res = (x, wih2, b2, whh2, h02, c02, hsf, csf, (aif, aff, aof, agf),
-           hsr, csr, (air, afr, aor, agr))
-    return (hsf, hsr, (hT2, cT2)), res
-
-
-def _bidir_weight_grads(cdt_name, x, wih2, b2, whh2, h02, hsf, hsr, outs):
-    """The XLA-side einsums shared by both bidir VJPs: turn the backward
-    kernel's pre-activation cotangents into (dx, dwih2, db2, dwhh2, dh02,
-    dc02). All inputs are in folded/x-time layout."""
-    dpf = outs[0:4]
-    dpr = outs[4:8]
-    dh02, dc02 = outs[8], outs[9]
-    cdt = jnp.dtype(cdt_name) if cdt_name else x.dtype
-    H = dpf[0].shape[-1]
-
-    # Same concat-on-feature-axis trick as the single-direction VJP (see
-    # _vjp_fused_bwd), doubled: BOTH directions' cotangents are already in
-    # x-time convention (the kernels' flipped index maps paid for this), so
-    # they concat into ONE [T, B, 8H] array and dx / dW_ih are single
-    # 1392-wide MXU matmuls — no jnp.flip of any time array.
-    dpc = jnp.concatenate([*dpf, *dpr], axis=-1).astype(cdt)
-
-    def cat_w(w4):  # [4, D, H] → [D, 4H]
-        return jnp.swapaxes(w4, 0, 1).reshape(w4.shape[1], -1)
-
-    w_cat8 = jnp.concatenate(
-        [cat_w(wih2[0]), cat_w(wih2[1])], axis=-1
-    ).astype(cdt)  # [D, 8H]
-    xc = x.astype(cdt)
-    dx = jnp.einsum(
-        "tbg,dg->tbd", dpc, w_cat8, preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
-    dwih_cat = jnp.einsum(
-        "tbd,tbg->dg", xc, dpc, preferred_element_type=jnp.float32,
-    )  # [D, 8H]
-    dwih2 = jnp.stack([
-        dwih_cat[:, : 4 * H].reshape(-1, 4, H).swapaxes(0, 1),
-        dwih_cat[:, 4 * H:].reshape(-1, 4, H).swapaxes(0, 1),
-    ]).astype(wih2.dtype)
-    db_cat = dpc.astype(jnp.float32).sum(axis=(0, 1))
-    db2 = jnp.stack([
-        db_cat[: 4 * H].reshape(4, H), db_cat[4 * H:].reshape(4, H),
-    ]).astype(b2.dtype)
-
-    # h_prev in x-time convention: fwd is the usual shift-right with h0 in
-    # front; rev state one step earlier in ITS time sits one x-time step
-    # LATER (hs_r[t+1]), with h0 at the tail.
-    h_prevf = jnp.concatenate([h02[0][None].astype(hsf.dtype), hsf[:-1]], 0)
-    h_prevr = jnp.concatenate([hsr[1:], h02[1][None].astype(hsr.dtype)], 0)
-    dpcf, dpcr = dpc[..., : 4 * H], dpc[..., 4 * H:]
-
-    def dwhh_of(h_prev, dpc_dir):
-        return jnp.einsum(
-            "tbh,tbg->hg", h_prev.astype(cdt), dpc_dir,
-            preferred_element_type=jnp.float32,
-        ).reshape(H, 4, H).swapaxes(0, 1)
-
-    dwhh2 = jnp.stack([
-        dwhh_of(h_prevf, dpcf), dwhh_of(h_prevr, dpcr),
-    ]).astype(whh2.dtype)
-    return dx, dwih2, db2, dwhh2, dh02, dc02
-
-
-def _vjp_bidir_bwd(compute_dtype, res, grads):
-    (x, wih2, b2, whh2, h02, c02, hsf, csf, actsf, hsr, csr, actsr) = res
-    dhsf, dhsr, (dhT2, dcT2) = grads
-    cdt_name = _cdt_name(compute_dtype)
-    outs = _bwd_bidir_callable(cdt_name)(
-        *actsf, *actsr, csf, csr, whh2, c02, dhsf, dhsr, dhT2, dcT2
-    )
-    return _bidir_weight_grads(cdt_name, x, wih2, b2, whh2, h02, hsf, hsr, outs)
-
-
-bilstm_recurrence_fused.defvjp(_vjp_bidir_fwd, _vjp_bidir_bwd)
-
-
-# ---------------------------------------------------------------------------
-# pooled bidirectional op — ICALstm's opt-in fused path (mean-pool of the
-# hidden sequence, reference ``models.py:109``). NOTE (r5): the flagship A/B
-# measured this fused path 27% SLOWER than two single-direction sweeps
-# (80,531 vs 110,009 samples/sec/chip, docs/bench_ab_bidir_r5.jsonl), so the
-# per-direction path is the model default and this op is reached only via
-# ``ICALstm(fused_bidir=True)``. Two structural ideas on top of the
-# bidirectional kernels above (kept for the record and for shapes where the
-# trade may flip):
-#
-# 1. The mean-pool lives INSIDE the op: the forward kernel accumulates the
-#    time-sum in VMEM scratch and emits [B, H] per direction (the hidden
-#    sequences are still written — they are BPTT residuals — but nothing
-#    re-reads them to pool), and the backward kernel consumes the pool
-#    cotangent as a per-row CONSTANT block (``dpool/T`` through a constant
-#    index map) instead of a broadcast [T, B, H] stream.
-# 2. Residual layout is SITE-NATIVE under the trainer's vmap. The plain ops
-#    above fold the vmapped site axis into kernel rows with moveaxis+reshape
-#    copies — and because vmap applies that rule per op, every ~17 MB
-#    residual stream was unfolded after the forward and refolded before the
-#    backward (~400 MB of relayout copies per flagship training step; this,
-#    not kernel time, dominated the round-3 epoch profile). Here the
-#    custom_vmap rules dispatch to 4D kernels over ``[S, T, B, ·]`` arrays
-#    whose BLOCKS gather ``s_tile × B`` rows per (site-tile, time) grid
-#    step — every residual is WRITTEN by the forward kernel and READ by the
-#    backward kernel in that one layout; only x/dx pay one transpose each
-#    ([S, B, T, D] ↔ [S, T, B, D]). Mosaic constrains the last two block
-#    dims to (8·, 128·) or the full array dims, which (B, H) satisfies —
-#    this is why the site axis tiles the FIRST block dim, time sits second,
-#    and rows are (s_tile · B). (A packed [.., 4, H] gate layout was tried
-#    and rejected: Mosaic cannot shape-cast stores that insert singleton
-#    dims mid-vector; the separate-array gate streams keep every store a
-#    plain leading-dim split, and the VJP's feature-axis concat is cheap.)
-#
-# Logical layouts (what the custom_vjp-level code sees): x [B, T, D] in,
-# residual streams [T, Bp, H] (Bp = row-padded batch; under vmap these
-# batch to [S, T, B, H] with NO row padding — site padding is handled
-# privately inside each rule), carries [2, B, H]. The dW/dx einsums are
-# _bidir_weight_grads, shared with the sequence-returning op.
-# ---------------------------------------------------------------------------
-
-
-def _pool_s_tile(S: int, B: int) -> int:
-    """Sites per kernel block: fill ~B_TILE rows (padding covers any
-    non-dividing remainder of S)."""
-    return max(1, min(S, B_TILE // max(B, 1) or 1))
-
-
-def _fwd_pool_kernel4(
-    xf, xr, wih, b, whh, h0, c0,
-    hsf, csf, aif, aff, aof, agf, hsr, csr, air, afr, aor, agr,
-    hT, cT, poolf, poolr,
-    hf_s, cf_s, hr_s, cr_s, pf_s, pr_s,
-):
-    t = pl.program_id(1)
-    st, _, B, H = hsf.shape
-    rows = st * B
-    f32 = jnp.float32
-
-    @pl.when(t == 0)
-    def _():
-        hf_s[:] = h0[0].reshape(rows, H)
-        cf_s[:] = c0[0].reshape(rows, H)
-        hr_s[:] = h0[1].reshape(rows, H)
-        cr_s[:] = c0[1].reshape(rows, H)
-        pf_s[:] = jnp.zeros_like(pf_s)
-        pr_s[:] = jnp.zeros_like(pr_s)
-
-    def advance(xblk, h_s, c_s, p_s, d):
-        xt = xblk[:, 0].reshape(rows, xblk.shape[-1])
-        h = h_s[:].astype(whh.dtype)
-        pre = [
-            jnp.dot(xt, wih[d, k], preferred_element_type=f32)
-            + jnp.dot(h, whh[d, k], preferred_element_type=f32)
-            + b[d, k].astype(f32)
-            for k in range(4)
-        ]
-        i = jax.nn.sigmoid(pre[0])
-        f = jax.nn.sigmoid(pre[1])
-        o = jax.nn.sigmoid(pre[2])
-        g = jnp.tanh(pre[3])
-        c = f * c_s[:] + i * g
-        h = o * jnp.tanh(c)
-        h_s[:] = h
-        c_s[:] = c
-        p_s[:] = p_s[:] + h
-        return h, c, i, f, o, g
-
-    def put(ref, v):
-        ref[:, 0] = v.reshape(st, B, H).astype(ref.dtype)
-
-    h, c, i, f, o, g = advance(xf, hf_s, cf_s, pf_s, 0)
-    put(hsf, h), put(csf, c), put(aif, i), put(aff, f), put(aof, o), put(agf, g)
-    h, c, i, f, o, g = advance(xr, hr_s, cr_s, pr_s, 1)
-    put(hsr, h), put(csr, c), put(air, i), put(afr, f), put(aor, o), put(agr, g)
-
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _():
-        inv_T = 1.0 / pl.num_programs(1)
-        hT[0] = hf_s[:].reshape(st, B, H)
-        cT[0] = cf_s[:].reshape(st, B, H)
-        hT[1] = hr_s[:].reshape(st, B, H)
-        cT[1] = cr_s[:].reshape(st, B, H)
-        poolf[:] = (pf_s[:] * inv_T).reshape(st, B, H)
-        poolr[:] = (pr_s[:] * inv_T).reshape(st, B, H)
-
-
-def _fwd_pool_call4(x, wih2, b2, whh2, h02, c02, compute_dtype=None):
-    # x [S, T, B, D]; h02/c02 [2, S, B, H] — S pre-padded to an s_tile multiple
-    S, T, B, D = x.shape
-    H = wih2.shape[-1]
-    st = _pool_s_tile(S, B)
-    assert S % st == 0
-    rows = st * B
-    if compute_dtype is not None:
-        x = x.astype(compute_dtype)
-        wih2 = wih2.astype(compute_dtype)
-        whh2 = whh2.astype(compute_dtype)
-    grid = (S // st, T)
-    V = pltpu.VMEM
-    spec_xf = pl.BlockSpec((st, 1, B, D), lambda r, t: (r, t, 0, 0), memory_space=V)
-    spec_xr = pl.BlockSpec(
-        (st, 1, B, D), lambda r, t: (r, T - 1 - t, 0, 0), memory_space=V
-    )
-    spec_tf = pl.BlockSpec((st, 1, B, H), lambda r, t: (r, t, 0, 0), memory_space=V)
-    spec_tr = pl.BlockSpec(
-        (st, 1, B, H), lambda r, t: (r, T - 1 - t, 0, 0), memory_space=V
-    )
-    spec_c2 = pl.BlockSpec((2, st, B, H), lambda r, t: (0, r, 0, 0), memory_space=V)
-    spec_p = pl.BlockSpec((st, B, H), lambda r, t: (r, 0, 0), memory_space=V)
-    spec_wih = pl.BlockSpec(
-        (2, 4, D, H), lambda r, t: (0, 0, 0, 0), memory_space=V
-    )
-    spec_whh = pl.BlockSpec(
-        (2, 4, H, H), lambda r, t: (0, 0, 0, 0), memory_space=V
-    )
-    spec_bias = pl.BlockSpec((2, 4, H), lambda r, t: (0, 0, 0), memory_space=V)
-    stream = jnp.dtype(compute_dtype) if compute_dtype is not None else jnp.float32
-    t_shape = jax.ShapeDtypeStruct((S, T, B, H), stream)
-    c2_shape = jax.ShapeDtypeStruct((2, S, B, H), jnp.float32)
-    p_shape = jax.ShapeDtypeStruct((S, B, H), jnp.float32)
-    return pl.pallas_call(
-        _fwd_pool_kernel4,
-        grid=grid,
-        in_specs=[spec_xf, spec_xr, spec_wih, spec_bias, spec_whh,
-                  spec_c2, spec_c2],
-        out_specs=[spec_tf] * 6 + [spec_tr] * 6
-        + [spec_c2, spec_c2, spec_p, spec_p],
-        out_shape=[t_shape] * 12 + [c2_shape, c2_shape, p_shape, p_shape],
-        scratch_shapes=[pltpu.VMEM((rows, H), jnp.float32)] * 6,
-        interpret=_interpret(),
-        name=BILSTM_POOL_FWD,
-    )(x, x, wih2, b2, whh2, h02, c02)
-
-
-def _bwd_pool_kernel4(
-    T_total,
-    aif, aff, aof, agf, air, afr, aor, agr,
-    csf, csf_prev, csr, csr_prev, wT, c0, dpoolf, dpoolr, dhT, dcT,
-    dxf_i, dxf_f, dxf_o, dxf_g, dxr_i, dxr_f, dxr_o, dxr_g, dh0, dc0,
-    dhf_s, dcf_s, dhr_s, dcr_s,
-):
-    t = pl.program_id(1)
-    st, _, B, H = dxf_i.shape
-    rows = st * B
-    first_time = t == 0
-    last_time = t == T_total - 1
-    f32 = jnp.float32
-    cdt = wT.dtype
-
-    @pl.when(first_time)
-    def _():
-        dhf_s[:] = dhT[0].reshape(rows, H).astype(f32)
-        dcf_s[:] = dcT[0].reshape(rows, H).astype(f32)
-        dhr_s[:] = dhT[1].reshape(rows, H).astype(f32)
-        dcr_s[:] = dcT[1].reshape(rows, H).astype(f32)
-
-    def drain(acts, c_blk, c_prev_blk, dpool, dh_s, dc_s, d, outs):
-        i, f, o, g = (a[:, 0].reshape(rows, H).astype(f32) for a in acts)
-        c = c_blk[:, 0].reshape(rows, H).astype(f32)
-        c_prev = jnp.where(
-            last_time,
-            c0[d].reshape(rows, H).astype(f32),
-            c_prev_blk[:, 0].reshape(rows, H).astype(f32),
-        )
-        tanh_c = jnp.tanh(c)
-        dh = dpool[:].reshape(rows, H) + dh_s[:]
-        do = dh * tanh_c
-        dc = dh * o * (1.0 - tanh_c * tanh_c) + dc_s[:]
-        di = dc * g
-        df = dc * c_prev
-        dg = dc * i
-        dpi = di * i * (1.0 - i)
-        dpf = df * f * (1.0 - f)
-        dpo = do * o * (1.0 - o)
-        dpg = dg * (1.0 - g * g)
-        for ref, v in zip(outs, (dpi, dpf, dpo, dpg)):
-            ref[:, 0] = v.reshape(st, B, H).astype(ref.dtype)
-        dh_s[:] = (
-            jnp.dot(dpi.astype(cdt), wT[d, 0], preferred_element_type=f32)
-            + jnp.dot(dpf.astype(cdt), wT[d, 1], preferred_element_type=f32)
-            + jnp.dot(dpo.astype(cdt), wT[d, 2], preferred_element_type=f32)
-            + jnp.dot(dpg.astype(cdt), wT[d, 3], preferred_element_type=f32)
-        )
-        dc_s[:] = dc * f
-
-    drain((aif, aff, aof, agf), csf, csf_prev, dpoolf, dhf_s, dcf_s, 0,
-          (dxf_i, dxf_f, dxf_o, dxf_g))
-    drain((air, afr, aor, agr), csr, csr_prev, dpoolr, dhr_s, dcr_s, 1,
-          (dxr_i, dxr_f, dxr_o, dxr_g))
-
-    @pl.when(last_time)
-    def _():
-        dh0[0] = dhf_s[:].reshape(st, B, H)
-        dc0[0] = dcf_s[:].reshape(st, B, H)
-        dh0[1] = dhr_s[:].reshape(st, B, H)
-        dc0[1] = dcr_s[:].reshape(st, B, H)
-
-
-def _bwd_pool_call4(actsf, actsr, csf, csr, whh2, c02, dpoolf, dpoolr,
-                    dhT2, dcT2, compute_dtype=None):
-    # all [S, T, B, H] site-native; dpool* [S, B, H] f32 (pre-divided by T)
-    S, T, B, H = csf.shape
-    st = _pool_s_tile(S, B)
-    assert S % st == 0
-    rows = st * B
-    if compute_dtype is not None:
-        whh2 = whh2.astype(compute_dtype)
-    w2T = jnp.swapaxes(whh2, 2, 3)
-    grid = (S // st, T)
-    V = pltpu.VMEM
-    # fwd-direction streams walk their time DESCENDING (block T-1-t); rev
-    # streams are x-time stored, so the rev chain walks blocks ASCENDING
-    spec_f = pl.BlockSpec(
-        (st, 1, B, H), lambda r, t: (r, T - 1 - t, 0, 0), memory_space=V
-    )
-    spec_r = pl.BlockSpec((st, 1, B, H), lambda r, t: (r, t, 0, 0), memory_space=V)
-    spec_f_prev = pl.BlockSpec(
-        (st, 1, B, H), lambda r, t: (r, jnp.maximum(T - 2 - t, 0), 0, 0),
-        memory_space=V,
-    )
-    spec_r_prev = pl.BlockSpec(
-        (st, 1, B, H), lambda r, t: (r, jnp.minimum(t + 1, T - 1), 0, 0),
-        memory_space=V,
-    )
-    spec_c2 = pl.BlockSpec((2, st, B, H), lambda r, t: (0, r, 0, 0), memory_space=V)
-    spec_p = pl.BlockSpec((st, B, H), lambda r, t: (r, 0, 0), memory_space=V)
-    spec_w = pl.BlockSpec(
-        (2, 4, H, H), lambda r, t: (0, 0, 0, 0), memory_space=V
-    )
-    t_shape = jax.ShapeDtypeStruct((S, T, B, H), actsf[0].dtype)
-    c2_shape = jax.ShapeDtypeStruct((2, S, B, H), jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_bwd_pool_kernel4, T),
-        grid=grid,
-        in_specs=[spec_f] * 4 + [spec_r] * 4
-        + [spec_f, spec_f_prev, spec_r, spec_r_prev, spec_w, spec_c2,
-           spec_p, spec_p, spec_c2, spec_c2],
-        out_specs=[spec_f] * 4 + [spec_r] * 4 + [spec_c2, spec_c2],
-        out_shape=[t_shape] * 8 + [c2_shape, c2_shape],
-        scratch_shapes=[pltpu.VMEM((rows, H), jnp.float32)] * 4,
-        interpret=_interpret(),
-        name=BILSTM_POOL_BWD,
-    )(*actsf, *actsr, csf, csf, csr, csr, w2T, c02, dpoolf, dpoolr,
-      dhT2, dcT2)
-
-
-def _pad_sites(arrs, S, st, axis=0):
-    pad = (-S) % st
-    if pad == 0:
-        return arrs
-    out = []
-    for a in arrs:
-        widths = [(0, 0)] * a.ndim
-        widths[axis] = (0, pad)
-        out.append(jnp.pad(a, widths))
-    return out
-
-
-@functools.lru_cache(maxsize=None)
-def _pool_fwd_kcall(cdt_name: str | None):
-    """custom_vmap forward. Unbatched → the 3D kernels above (row padding,
-    one x transpose — the single-site debug path); vmapped → site-native 4D
-    kernels (one x transpose, zero residual copies)."""
-    cdt = jnp.dtype(cdt_name) if cdt_name else None
-
-    @custom_vmap
-    def f(x, wih2, b2, whh2, h02, c02):
-        B, T, D = x.shape
-        H = wih2.shape[-1]
-        bt = min(B_TILE, B)
-        pad = (-B) % bt
-        xp = x.astype(cdt if cdt is not None else jnp.float32)
-        h02p, c02p = h02.astype(jnp.float32), c02.astype(jnp.float32)
-        if pad:
-            xp = jnp.concatenate([xp, jnp.zeros((pad, T, D), xp.dtype)], 0)
-            zb = jnp.zeros((2, pad, H), jnp.float32)
-            h02p = jnp.concatenate([h02p, zb], 1)
-            c02p = jnp.concatenate([c02p, zb], 1)
-        xT = jnp.swapaxes(xp, 0, 1)  # [T, Bp, D]
-        outs = _fwd_bidir_call(xT, wih2, b2, whh2, h02p, c02p, cdt)
-        hsf, hsr, hT2, cT2 = outs[0], outs[6], outs[12], outs[13]
-        poolf = hsf[:, :B].mean(axis=0, dtype=jnp.float32)
-        poolr = hsr[:, :B].mean(axis=0, dtype=jnp.float32)
-        return (poolf, poolr, hT2[:, :B], cT2[:, :B], xT) + tuple(outs[:12])
-
-    @f.def_vmap
-    def _rule(axis_size, in_batched, *args):
-        if any(in_batched[k] for k in (1, 2, 3)):  # per-element weights
-            batched = _broadcast_unbatched(args, in_batched, axis_size)
-            outs = jax.lax.map(lambda a: f(*a), tuple(batched))
-            return tuple(outs), (True,) * 17
-        S = axis_size
-        batched = _broadcast_unbatched(
-            args, [b or i in (1, 2, 3) for i, b in enumerate(in_batched)], S
-        )
-        B = batched[0].shape[1]
-        st = _pool_s_tile(S, B)
-        # THE one x relayout: [S, B, T, D] → [S, T, B, D] (XLA can often
-        # fuse it into the producing matmul's epilogue)
-        xT = jnp.swapaxes(batched[0], 1, 2)
-        xT = xT.astype(cdt if cdt is not None else jnp.float32)
-        h02 = jnp.moveaxis(batched[4], 0, 1)  # [2, S, B, H] (small)
-        c02 = jnp.moveaxis(batched[5], 0, 1)
-        (xTp,) = _pad_sites([xT], S, st)
-        h02, c02 = _pad_sites([h02, c02], S, st, axis=1)
-        outs = _fwd_pool_call4(xTp, args[1], args[2], args[3], h02, c02, cdt)
-        streams = [a[:S] for a in outs[:12]]
-        hT2, cT2, poolf, poolr = outs[12], outs[13], outs[14], outs[15]
-        mv = lambda a: jnp.moveaxis(a[:, :S], 0, 1)  # [2,S,·]→[S,2,·] (small)
-        return (
-            (poolf[:S], poolr[:S], mv(hT2), mv(cT2), xT) + tuple(streams),
-            (True,) * 17,
-        )
-
-    return f
-
-
-@functools.lru_cache(maxsize=None)
-def _pool_bwd_kcall(cdt_name: str | None):
-    """custom_vmap backward: kernel-only (row-wise outputs). dW einsums live
-    OUTSIDE in the custom_vjp bwd (_bidir_weight_grads) — they batch
-    per-site under vmap and JAX sums the cotangent for the shared
-    (unbatched) weights."""
-    cdt = jnp.dtype(cdt_name) if cdt_name else None
-
-    @custom_vmap
-    def f(aif, aff, aof, agf, air, afr, aor, agr, csf, csr, whh2, c02,
-          dpoolf, dpoolr, dhT2, dcT2):
-        Bp = csf.shape[1]
-        B = dpoolf.shape[0]
-        pad = Bp - B
-        stream = csf.dtype
-
-        def padb(a, axis=1):  # pad the row axis of [2, B, H] / [1, B, H]
-            if not pad:
-                return a
-            widths = [(0, 0)] * a.ndim
-            widths[axis] = (0, pad)
-            return jnp.pad(a, widths)
-
-        return _bwd_bidir_call(
-            (aif, aff, aof, agf), (air, afr, aor, agr), csf, csr, whh2,
-            padb(c02.astype(jnp.float32)),
-            padb(dpoolf.astype(stream)[None]), padb(dpoolr.astype(stream)[None]),
-            padb(dhT2.astype(jnp.float32)), padb(dcT2.astype(jnp.float32)),
-            cdt,
-        )
-
-    @f.def_vmap
-    def _rule(axis_size, in_batched, *args):
-        if in_batched[10]:  # per-element weights
-            batched = _broadcast_unbatched(args, in_batched, axis_size)
-            outs = jax.lax.map(lambda a: f(*a), tuple(batched))
-            return tuple(outs), (True,) * 10
-        S = axis_size
-        batched = _broadcast_unbatched(
-            args, [b or i == 10 for i, b in enumerate(in_batched)], S
-        )
-        B = batched[8].shape[2]  # csf [S, T, B, H]
-        st = _pool_s_tile(S, B)
-        c02 = jnp.moveaxis(batched[11], 0, 1).astype(jnp.float32)  # [2,S,B,H]
-        dhT2 = jnp.moveaxis(batched[14], 0, 1).astype(jnp.float32)
-        dcT2 = jnp.moveaxis(batched[15], 0, 1).astype(jnp.float32)
-        dpoolf = batched[12].astype(jnp.float32)
-        dpoolr = batched[13].astype(jnp.float32)
-        streams = _pad_sites(list(batched[:10]) + [dpoolf, dpoolr], S, st)
-        c02, dhT2, dcT2 = _pad_sites([c02, dhT2, dcT2], S, st, axis=1)
-        outs = _bwd_pool_call4(
-            tuple(streams[0:4]), tuple(streams[4:8]), streams[8], streams[9],
-            args[10], c02, streams[10], streams[11], dhT2, dcT2, cdt,
-        )
-        mv = lambda a: jnp.moveaxis(a[:, :S], 0, 1)
-        return (
-            tuple(a[:S] for a in outs[:8]) + (mv(outs[8]), mv(outs[9])),
-            (True,) * 10,
-        )
-
-    return f
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def bilstm_pool_fused_op(x, wih2, b2, whh2, h02, c02, compute_dtype=None):
-    """Fused bidirectional LSTM + time mean-pool (stacked-weight layout).
-
-    x [B, T, D]; wih2 [2, 4, D, H]; b2 [2, 4, H]; whh2 [2, 4, H, H];
-    h02/c02 [2, B, H]. Returns ``(pooled [B, 2H] f32, (hT2, cT2) f32)``
-    where ``pooled = concat([hs_f.mean(time), hs_r.mean(time)], -1)``.
-    See the section comment for the layout/batching design.
-    """
-    outs = _pool_fwd_kcall(_cdt_name(compute_dtype))(
-        x, wih2, b2, whh2, h02, c02
-    )
-    poolf, poolr, hT2, cT2 = outs[:4]
-    return jnp.concatenate([poolf, poolr], axis=-1), (hT2, cT2)
-
-
-def _vjp_pool_fwd(x, wih2, b2, whh2, h02, c02, compute_dtype):
-    outs = _pool_fwd_kcall(_cdt_name(compute_dtype))(
-        x, wih2, b2, whh2, h02, c02
-    )
-    (poolf, poolr, hT2, cT2, xT,
-     hsf, csf, aif, aff, aof, agf, hsr, csr, air, afr, aor, agr) = outs
-    # xT (the transposed/padded input actually fed to the kernel) is the
-    # residual — the dW einsums need x in stream layout, and saving the
-    # transposed copy avoids a second transpose in the backward. x_wit is a
-    # zero-size dtype witness so dx can be cast back to the primal dtype.
-    x_wit = jnp.zeros((0,), x.dtype)
-    res = (xT, x_wit, wih2, b2, whh2, h02, c02, hsf, csf,
-           (aif, aff, aof, agf), hsr, csr, (air, afr, aor, agr))
-    return (jnp.concatenate([poolf, poolr], axis=-1), (hT2, cT2)), res
-
-
-def _vjp_pool_bwd(compute_dtype, res, grads):
-    (xT, x_wit, wih2, b2, whh2, h02, c02,
-     hsf, csf, actsf, hsr, csr, actsr) = res
-    dpooled, (dhT2, dcT2) = grads
-    B = dpooled.shape[0]
-    T = xT.shape[0]
-    H = hsf.shape[-1]
-    cdt_name = _cdt_name(compute_dtype)
-    dpoolf = dpooled[:, :H] / T
-    dpoolr = dpooled[:, H:] / T
-    outs = _pool_bwd_kcall(cdt_name)(
-        *actsf, *actsr, csf, csr, whh2, c02, dpoolf, dpoolr, dhT2, dcT2
-    )
-    # row-pad h0 to the streams' padded width for the h_prev shift (no-op
-    # under vmap, where rows are never padded)
-    pad = hsf.shape[1] - h02.shape[1]
-    h02p = jnp.pad(h02, ((0, 0), (0, pad), (0, 0))) if pad else h02
-    dxT, dwih2, db2, dwhh2, dh02, dc02 = _bidir_weight_grads(
-        cdt_name, xT, wih2, b2, whh2, h02p, hsf, hsr, outs
-    )
-    dx = jnp.swapaxes(dxT, 0, 1)[:B].astype(x_wit.dtype)
-    # The kernel streams are row-padded to the batch tile; dx is sliced back
-    # above, and the carry cotangents need the same trim (pad rows carry
-    # exactly-zero gradient, so slicing is exact).
-    dh02 = dh02[:, :B]
-    dc02 = dc02[:, :B]
-    return dx, dwih2, db2, dwhh2, dh02, dc02
-
-
-bilstm_pool_fused_op.defvjp(_vjp_pool_fwd, _vjp_pool_bwd)
-
-
-def bilstm_pool_forward_fused(x, params_fwd, params_rev, h02=None, c02=None,
-                              compute_dtype=None):
-    """Model-layout wrapper over :func:`bilstm_pool_fused_op`.
-
-    Args:
-      x: ``[B, T, D]`` raw inputs (shared by both directions).
-      params_fwd / params_rev: ``(w_ih [D, 4H], b [4H], w_hh [H, 4H])`` in
-        LSTMCell blocked layout (b = b_ih + b_hh).
-      h02, c02: optional ``[2, B, H]`` initial carries (zeros by default).
-
-    Returns ``(pooled [B, 2H] f32, (hT2, cT2) [2, B, H] f32)``.
-    """
-    B = x.shape[0]
-    H = params_fwd[2].shape[0]
-
-    def stack_dir(p):
-        w_ih, b, w_hh = (a.astype(jnp.float32) for a in p)
-        wih4 = jnp.stack([w_ih[:, k * H: (k + 1) * H] for k in range(4)])
-        b4 = jnp.stack([b[k * H: (k + 1) * H] for k in range(4)])
-        whh4 = jnp.stack([w_hh[:, k * H: (k + 1) * H] for k in range(4)])
-        return wih4, b4, whh4
-
-    wf, bf, whf = stack_dir(params_fwd)
-    wr, br, whr = stack_dir(params_rev)
-    if h02 is None:
-        h02 = jnp.zeros((2, B, H), jnp.float32)
-    if c02 is None:
-        c02 = jnp.zeros((2, B, H), jnp.float32)
-    return bilstm_pool_fused_op(
-        x, jnp.stack([wf, wr]), jnp.stack([bf, br]), jnp.stack([whf, whr]),
-        h02.astype(jnp.float32), c02.astype(jnp.float32), compute_dtype,
-    )
-
-
-
-
-def bilstm_forward_fused(x, params_fwd, params_rev, h02=None, c02=None,
-                         compute_dtype=None):
-    """Model-layout convenience wrapper over :func:`bilstm_recurrence_fused`.
-
-    Args:
-      x: ``[B, T, D]`` raw inputs (shared by both directions).
-      params_fwd / params_rev: ``(w_ih [D, 4H], b [4H], w_hh [H, 4H])`` in
-        LSTMCell blocked layout (b = b_ih + b_hh).
-      h02, c02: optional ``[2, B, H]`` initial carries (zeros by default).
-
-    Returns ``(hs_f [B, T, H], hs_r [B, T, H], (hT2, cT2) [2, B, H] f32)``
-    with ``hs_r`` in x-time convention (see the op docstring). Pads the
-    batch to the kernel tile.
-    """
-    B, T, D = x.shape
-    H = params_fwd[2].shape[0]
-    in_dtype = x.dtype
-    x = x.astype(compute_dtype if compute_dtype is not None else jnp.float32)
-
-    def stack_dir(p):
-        w_ih, b, w_hh = (a.astype(jnp.float32) for a in p)
-        wih4 = jnp.stack([w_ih[:, k * H: (k + 1) * H] for k in range(4)])
-        b4 = jnp.stack([b[k * H: (k + 1) * H] for k in range(4)])
-        whh4 = jnp.stack([w_hh[:, k * H: (k + 1) * H] for k in range(4)])
-        return wih4, b4, whh4
-
-    wf, bf, whf = stack_dir(params_fwd)
-    wr, br, whr = stack_dir(params_rev)
-    wih2 = jnp.stack([wf, wr])
-    b2 = jnp.stack([bf, br])
-    whh2 = jnp.stack([whf, whr])
-    if h02 is None:
-        h02 = jnp.zeros((2, B, H), jnp.float32)
-    if c02 is None:
-        c02 = jnp.zeros((2, B, H), jnp.float32)
-    h02 = h02.astype(jnp.float32)
-    c02 = c02.astype(jnp.float32)
-
-    bt = min(B_TILE, B)
-    pad = (-B) % bt
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad, T, D), x.dtype)], 0)
-        zb = jnp.zeros((2, pad, H), jnp.float32)
-        h02 = jnp.concatenate([h02, zb], 1)
-        c02 = jnp.concatenate([c02, zb], 1)
-    x_t = jnp.swapaxes(x, 0, 1)  # [T, B, D]
-    hsf, hsr, (hT2, cT2) = bilstm_recurrence_fused(
-        x_t, wih2, b2, whh2, h02, c02, compute_dtype
-    )
-    hsf = jnp.swapaxes(hsf, 0, 1)
-    hsr = jnp.swapaxes(hsr, 0, 1)
-    if pad:
-        hsf, hsr = hsf[:B], hsr[:B]
-        hT2, cT2 = hT2[:, :B], cT2[:, :B]
-    return hsf.astype(in_dtype), hsr.astype(in_dtype), (hT2, cT2)
 
 
 def lstm_forward_fused(x, w_ih, b, w_hh, h0, c0, compute_dtype=None):

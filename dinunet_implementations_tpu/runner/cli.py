@@ -224,13 +224,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="overlap round t's aggregation collective with "
                         "round t+1's batch gather + compute (one-round-"
                         "delayed pipelined update; trainer/steps.py)")
-    p.add_argument("--fused-poweriter", default=None,
-                   choices=["auto", "on", "off"],
-                   help="fused Pallas power-iteration kernel for the "
-                        "rankDAD subspace iteration (ops/poweriter_pallas"
-                        ".py). auto = off on every backend: the kernel "
-                        "does not lower for a TPU yet, and 'on' there "
-                        "fails with the compiler's error")
     p.add_argument("--dp-clip", type=float, default=None, metavar="C",
                    help="privacy plane (r20, privacy/dpsgd.py): clip each "
                         "site's round-gradient L2 norm to C inside the "
@@ -290,10 +283,6 @@ def main(argv: list[str] | None = None) -> int:
         ("wire_quant", args.wire_quant),
         ("robust_agg", args.robust_agg),
         ("overlap_rounds", args.overlap_rounds),
-        ("fused_poweriter", (
-            None if args.fused_poweriter in (None, "auto")
-            else args.fused_poweriter == "on"
-        )),
         ("dp_clip", args.dp_clip),
         ("dp_noise_multiplier", args.dp_noise),
         ("dp_epsilon_budget", args.dp_epsilon_budget),

@@ -24,8 +24,8 @@ scripts/profile_epoch.py's one-off attribution. Now:
 - :mod:`.scopes` — the **device scopes**: the names of the five
   ``jax.named_scope``s the epoch program always wears (``data/gather``,
   ``model/fwd_bwd``, ``engine/aggregate`` with ``poweriter`` nested in it,
-  ``optimizer/update``; trainer/steps.py, engines/lowrank.py) beside the six
-  Pallas kernel names of ops/lstm_pallas.py (``lstm_fwd`` … ``bilstm_pool_bwd``).
+  ``optimizer/update``; trainer/steps.py, engines/lowrank.py) beside the two
+  Pallas kernel names of ops/lstm_pallas.py (``lstm_fwd``, ``lstm_bwd``).
   Metadata only — no switch, the lowering is the same program with and
   without them (tests/test_scopes.py). Under ``--xprof-dir`` an operator sees
   every device op's scope as its ``tf_op`` / op name in xprof's op profile

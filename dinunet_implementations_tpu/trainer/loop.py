@@ -101,7 +101,6 @@ class FederatedTrainer:
         self.engine = make_engine(
             cfg.agg_engine, precision_bits=cfg.precision_bits, seed=cfg.seed,
             wire_quant=cfg.wire_quant, wire_stochastic=cfg.wire_stochastic,
-            fused_poweriter=cfg.fused_poweriter,
             robust_agg=cfg.robust_agg,
             robust_trim_frac=cfg.robust_trim_frac,
             robust_clip_mult=cfg.robust_clip_mult,

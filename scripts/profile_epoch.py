@@ -193,14 +193,6 @@ def attribution(argv):
         "rankdad-cold-5iter": ("rankDAD", dict(
             dad, dad_num_pow_iters=5, dad_tol=0.0, dad_warm_start=False)),
         "rankdad-warm-default": ("rankDAD", dict(dad, dad_warm_start=True)),
-        # r14: the fused Pallas power-iteration twins — the differential
-        # against the legacy arms IS the post-fusion power-iteration share
-        # (interpret mode on CPU; regen on TPU for the flagship figures)
-        "rankdad-cold-5iter-fused": ("rankDAD", dict(
-            dad, dad_num_pow_iters=5, dad_tol=0.0, dad_warm_start=False,
-            fused_poweriter=True)),
-        "rankdad-warm-fused": ("rankDAD", dict(
-            dad, dad_warm_start=True, fused_poweriter=True)),
     }
     chains, samples = {}, None
     for arm, (engine, kw) in arms.items():
@@ -231,12 +223,6 @@ def attribution(argv):
          (marg["rankdad-cold-5iter"] - marg["rankdad-cold-1iter"]) / 4),
         ("compression with warm-started Ω (warm-default − exchange-only)",
          marg["rankdad-warm-default"] - marg["exchange-only"]),
-        ("power-iteration FUSED, 5 cold trips (fused-cold-5iter − "
-         "exchange-only)",
-         marg["rankdad-cold-5iter-fused"] - marg["exchange-only"]),
-        ("compression FUSED with warm-started Ω (warm-fused − "
-         "exchange-only)",
-         marg["rankdad-warm-fused"] - marg["exchange-only"]),
     ]
     for arm, dist in dists.items():
         print(json.dumps({

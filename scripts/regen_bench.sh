@@ -57,6 +57,5 @@ run fleet-sliced bench_fleet_sliced_r22.jsonl --serve --replicas 1,2 --swap 4 --
 run tenants  bench_tenants_r22.jsonl          --tenants 2
 run attacks  bench_attacks_ab_r17.jsonl       --attacks '{"sign_flip": [[3, 0, -1], [11, 0, -1], [19, 0, -1]], "scale": [[27, 0, -1]], "scale_factor": 25}' --robust-agg trimmed_mean
 run privacy  bench_privacy_ab_r20.jsonl       --dp-noise 0.5 --dp-clip 1.0 --secure-agg mask
-run poweriter bench_poweriter_ab_r14.jsonl    --ab-poweriter --small
 
 echo "done: $(ls "$OUT_DIR" | wc -l) artifact(s) in $OUT_DIR" >&2

@@ -353,6 +353,8 @@ def test_rankdad_warm_state_roundtrips_epoch_scan():
     the first one produced."""
     import jax.numpy as jnp
 
+    from dinunet_implementations_tpu.checks import CompileGuard
+    from dinunet_implementations_tpu.engines.lowrank import lowrank_rank_groups
     from dinunet_implementations_tpu.models import MSANNet
     from dinunet_implementations_tpu.trainer import (
         FederatedTask,
@@ -377,6 +379,10 @@ def test_rankdad_warm_state_roundtrips_epoch_scan():
     # per-site leading axis, like powerSGD's q/e
     assert all(o.shape[0] == Ssites for o in om0)
     epoch_fn = make_train_epoch_fn(task, eng, opt, mesh=None, local_iterations=2)
+    # two rank classes (r=3 for [6, 8], r=2 for the [8, 2] head) share the one
+    # loop, and the warm state a round hands on never changes the program
+    assert [r for r, _ in lowrank_rank_groups(state.params, 3)[0]] == [2, 3]
+    guard = CompileGuard({"epoch_fn": epoch_fn})
     state1, losses1 = epoch_fn(state, x, y, w)
     om1 = [np.asarray(o) for o in jax.tree.leaves(state1.engine_state["omega"])]
     assert all(np.isfinite(o).all() for o in om1)
@@ -384,6 +390,7 @@ def test_rankdad_warm_state_roundtrips_epoch_scan():
     assert any(not np.allclose(a, b) for a, b in zip(om0, om1))
     state2, losses2 = epoch_fn(state1, x, y, w)
     assert np.isfinite(np.asarray(losses2)).all()
+    assert guard.check(context="rankDAD, two rank classes") == {"epoch_fn": 1}
 
 
 def test_rankdad_mixed_precision_iteration_close_to_f32():
@@ -424,6 +431,24 @@ def test_subspace_iteration_grouped_mixed_ranks_matches_per_group():
         np.testing.assert_allclose(
             np.asarray(Pg @ Qg.T), np.asarray(Ps @ Qs_.T), atol=1e-4
         )
+
+
+def test_subspace_iteration_grouped_nothing_to_factorize_traces_no_loop():
+    """A gradient tree of vectors only has no rank class: the grouped
+    iteration returns at once (a ``while_loop`` cannot carry an empty tuple)
+    and rankDAD's dense fallback carries the whole exchange."""
+    from dinunet_implementations_tpu.engines.lowrank import (
+        subspace_iteration_grouped,
+    )
+
+    assert subspace_iteration_grouped([], 5, 1e-3) == []
+    traced = jax.make_jaxpr(lambda: subspace_iteration_grouped([], 5, 1e-3))()
+    assert not traced.eqns
+    tree = {"bias": _tree(11)["dense"]["bias"]}
+    out = _run_engine("rankDAD", tree, _weights(), dad_reduction_rank=3)
+    np.testing.assert_allclose(
+        out["bias"], _pooled(tree, _weights())["bias"], atol=1e-6
+    )
 
 
 def test_rankdad_zero_gradient_round_recovers():

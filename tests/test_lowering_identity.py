@@ -5,20 +5,18 @@ replaces the ad-hoc ``lowered.as_text() == ...`` comparisons that used to be
 duplicated across tests/test_telemetry.py and tests/test_robustness.py:
 
 - every OFF-form (telemetry off, faults at their default resolution, the
-  sanitizer's leak-checking observation mode, wire_quant="none", the fused
-  power-iteration kernel off, overlap_rounds off) must be lowering-identical
-  to the baseline epoch program;
+  sanitizer's leak-checking observation mode, wire_quant="none",
+  overlap_rounds off) must be lowering-identical to the baseline epoch
+  program;
 - every static OPT-OUT/OPT-IN (``quarantine_rounds=-1``, ``telemetry=True``,
-  a quantized wire codec, the fused kernel, overlapped rounds) must
-  genuinely diverge — if these become identical, "compiled out" has
-  silently stopped being true.
+  a quantized wire codec, overlapped rounds) must genuinely diverge — if
+  these become identical, "compiled out" has silently stopped being true.
 
 The same pairs gate the CLI via rule S005
 (``python -m dinunet_implementations_tpu.checks --semantic``); this file is
 the fast tier-1 mirror with per-pair failure reports. The engine-knob cases
-(``{"engine": {...}}``) and the rankDAD corner ride the semantic tier's
-``identity_text_fn``/table definitions, so the two gates can never test
-different pair sets.
+(``{"engine": {...}}``) ride the semantic tier's ``identity_text_fn``/table
+definitions, so the two gates can never test different pair sets.
 """
 
 import jax
@@ -27,8 +25,6 @@ import pytest
 from dinunet_implementations_tpu.checks.lowering import diff_report
 from dinunet_implementations_tpu.checks.semantic import (
     IDENTITY_CASES,
-    IDENTITY_CASES_RANKDAD,
-    RANKDAD_IDENTITY_CELL,
     TraceCell,
     identity_text_fn,
 )
@@ -41,13 +37,6 @@ def corner():
     the S005 CLI gate compares."""
     text = identity_text_fn(TraceCell("dSGD", "vmap", "host"))
     # the default build's text once, not once per test
-    return text(), text
-
-
-@pytest.fixture(scope="module")
-def rankdad_corner():
-    """The rankDAD corner the fused-power-iteration pairs run on."""
-    text = identity_text_fn(RANKDAD_IDENTITY_CELL)
     return text(), text
 
 
@@ -66,7 +55,6 @@ def _split(cases):
 #: gate can never test different pair sets. kwargs=None is the
 #: checking_leaks observation mode (its own test below).
 IDENTICAL_CASES, DIVERGENT_CASES = _split(IDENTITY_CASES)
-IDENTICAL_RD, DIVERGENT_RD = _split(IDENTITY_CASES_RANKDAD)
 
 
 @pytest.mark.parametrize("case", sorted(IDENTICAL_CASES))
@@ -86,26 +74,6 @@ def test_opt_out_really_changes_the_program(corner, case):
     base, text = corner
     assert diff_report(
         base, text(**DIVERGENT_CASES[case]), "default-build", case
-    ) is not None
-
-
-@pytest.mark.parametrize("case", sorted(IDENTICAL_RD))
-def test_rankdad_off_form_is_lowering_identical(rankdad_corner, case):
-    """fused_poweriter=False (and the CPU auto default) must compile the
-    exact legacy XLA power-iteration loop."""
-    base, text = rankdad_corner
-    report = diff_report(
-        base, text(**IDENTICAL_RD[case]), "default-build", case
-    )
-    assert report is None, report
-
-
-@pytest.mark.parametrize("case", sorted(DIVERGENT_RD))
-def test_rankdad_opt_in_really_changes_the_program(rankdad_corner, case):
-    """fused_poweriter=True must genuinely inject the Pallas kernel."""
-    base, text = rankdad_corner
-    assert diff_report(
-        base, text(**DIVERGENT_RD[case]), "default-build", case
     ) is not None
 
 

@@ -111,7 +111,7 @@ def test_icalstm_pallas_end_to_end_grad():
     g_s = jax.grad(loss)(variables, m_scan)["params"]
     m_pal = ICALstm(
         input_size=16, hidden_size=12, num_comps=5, window_size=4,
-        use_pallas=True, fused_bidir=True,  # cover the opt-in fused arm too
+        use_pallas=True,
     )
     g_p = jax.grad(loss)(variables, m_pal)["params"]
     jax.tree.map(
@@ -298,24 +298,16 @@ def test_fused_terminal_carry_is_f32_even_under_bf16():
 
 
 # ---------------------------------------------------------------------------
-# fused BIDIRECTIONAL kernels (ADVICE r4: direct parity tests; VERDICT r4 #2:
-# the production composition — vmapped over a site axis — must be exercised
-# by the suite, not only at bench time on the TPU)
+# the bidirectional MODULE on the per-direction kernels: BiLSTM(time_pool=
+# "mean") is what ICALstm's dense path runs (two kernel calls, the reverse one
+# over the flipped input, each direction pooled on its own)
 # ---------------------------------------------------------------------------
 
 
-def _blocked(key, D, H):
-    """Params in LSTMCell blocked layout (w_ih [D,4H], b [4H], w_hh [H,4H])."""
-    ks = jax.random.split(key, 3)
-    return (
-        jax.random.normal(ks[0], (D, 4 * H)) * 0.2,
-        jax.random.normal(ks[1], (4 * H,)) * 0.1,
-        jax.random.normal(ks[2], (H, 4 * H)) * 0.2,
-    )
-
-
 def _scan_lstm(x, p, h0, c0):
-    w_ih, b, w_hh = p
+    """Plain reference, independent of the package: ``p`` is an LSTMCell
+    param dict (blocked ``[D, 4H]`` / ``[H, 4H]`` layout, gates i, f, o, g)."""
+    w_ih, b, w_hh = p["w_ih"], p["b_ih"] + p["b_hh"], p["w_hh"]
     H = w_hh.shape[0]
     xi = x @ w_ih + b
 
@@ -334,243 +326,168 @@ def _scan_lstm(x, p, h0, c0):
     return jnp.swapaxes(hs, 0, 1), (hT, cT)
 
 
-def _scan_bilstm_pool(x, pf, pr, h02, c02):
-    hsf, (hTf, cTf) = _scan_lstm(x, pf, h02[0], c02[0])
-    hsr, (hTr, cTr) = _scan_lstm(jnp.flip(x, 1), pr, h02[1], c02[1])
-    pooled = jnp.concatenate([hsf.mean(1), hsr.mean(1)], -1)
-    return pooled, (jnp.stack([hTf, hTr]), jnp.stack([cTf, cTr]))
+def _scan_bidir_pool(x, params, h0, c0):
+    """What BiLSTM(time_pool="mean") must return: both directions start from
+    the same ``(h0, c0)``, the reverse one reads the flipped input, the pooled
+    halves and the terminal carries concatenate on the feature axis."""
+    hsf, (hTf, cTf) = _scan_lstm(x, params["fwd"], h0, c0)
+    hsr, (hTr, cTr) = _scan_lstm(jnp.flip(x, 1), params["rev"], h0, c0)
+    return (
+        jnp.concatenate([hsf.mean(1), hsr.mean(1)], -1),
+        (jnp.concatenate([hTf, hTr], 1), jnp.concatenate([cTf, cTr], 1)),
+    )
 
 
-@pytest.mark.parametrize("B,T,D,H", [(4, 6, 5, 8), (3, 5, 4, 8)])
-def test_bilstm_forward_fused_matches_scan(B, T, D, H):
-    """bilstm_forward_fused vs two scan LSTMCells, incl. the x-time (flip)
-    convention of hs_r and the terminal carries."""
-    from dinunet_implementations_tpu.ops.lstm_pallas import bilstm_forward_fused
+def _assert_trees_close(got, want, atol):
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=atol
+        ),
+        got, want,
+    )
 
-    key = jax.random.PRNGKey(20)
-    x = jax.random.normal(key, (B, T, D))
-    pf = _blocked(jax.random.PRNGKey(21), D, H)
-    pr = _blocked(jax.random.PRNGKey(22), D, H)
-    hsf, hsr, (hT2, cT2) = bilstm_forward_fused(x, pf, pr)
+
+def _bidir_case(B, T, D, H, with_h0, seed=20):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (B, T, D))
+    params = {"fwd": _params(ks[1], D, H), "rev": _params(ks[2], D, H)}
+    h0 = (
+        (jax.random.normal(ks[3], (B, H)) * 0.3,
+         jax.random.normal(ks[4], (B, H)) * 0.3)
+        if with_h0 else None
+    )
+    return x, params, h0
+
+
+# tile 2 at B=3 pads the rows to 4 and runs two batch tiles
+@pytest.mark.parametrize("with_h0", [False, True], ids=["zero-h0", "given-h0"])
+@pytest.mark.parametrize(
+    "B,T,D,H,tile", [(4, 6, 5, 8, 128), (3, 5, 4, 8, 2)],
+    ids=["whole-tile", "rows-padded"],
+)
+def test_bidir_module_pooled_on_per_direction_kernels(
+    monkeypatch, B, T, D, H, tile, with_h0
+):
+    from dinunet_implementations_tpu.models.icalstm import BiLSTM
+    from dinunet_implementations_tpu.ops import lstm_pallas
+
+    monkeypatch.setattr(lstm_pallas, "B_TILE", tile)
+    x, params, h0 = _bidir_case(B, T, D, H, with_h0)
+    got = BiLSTM(2 * H, use_pallas=True, time_pool="mean").apply(
+        {"params": params}, x, h0
+    )
+    scan = BiLSTM(2 * H, use_pallas=False, time_pool="mean").apply(
+        {"params": params}, x, h0
+    )
     z = jnp.zeros((B, H))
-    ref_f, (hTf, cTf) = _scan_lstm(x, pf, z, z)
-    ref_r_own, (hTr, cTr) = _scan_lstm(jnp.flip(x, 1), pr, z, z)
-    np.testing.assert_allclose(np.asarray(hsf), np.asarray(ref_f), atol=1e-5)
-    # hs_r is stored in x-time convention: flip of the rev scan's own-time seq
-    np.testing.assert_allclose(
-        np.asarray(hsr), np.asarray(jnp.flip(ref_r_own, 1)), atol=1e-5
-    )
-    np.testing.assert_allclose(np.asarray(hT2[0]), np.asarray(hTf), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(hT2[1]), np.asarray(hTr), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(cT2[0]), np.asarray(cTf), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(cT2[1]), np.asarray(cTr), atol=1e-5)
+    ref = _scan_bidir_pool(x, params, *(h0 if with_h0 else (z, z)))
+    assert got[0].shape == (B, 2 * H)
+    assert got[1][0].shape == got[1][1].shape == (B, 2 * H)
+    _assert_trees_close(got, scan, atol=1e-5)
+    _assert_trees_close(got, ref, atol=1e-5)
 
 
-def test_bilstm_pool_fused_matches_scan_with_carries():
-    from dinunet_implementations_tpu.ops.lstm_pallas import (
-        bilstm_pool_forward_fused,
-    )
-
-    B, T, D, H = 4, 6, 5, 8
-    key = jax.random.PRNGKey(23)
-    x = jax.random.normal(key, (B, T, D))
-    pf = _blocked(jax.random.PRNGKey(24), D, H)
-    pr = _blocked(jax.random.PRNGKey(25), D, H)
-    h02 = jax.random.normal(jax.random.PRNGKey(26), (2, B, H)) * 0.3
-    c02 = jax.random.normal(jax.random.PRNGKey(27), (2, B, H)) * 0.3
-    pooled, (hT2, cT2) = bilstm_pool_forward_fused(x, pf, pr, h02, c02)
-    ref_p, (ref_h, ref_c) = _scan_bilstm_pool(x, pf, pr, h02, c02)
-    np.testing.assert_allclose(np.asarray(pooled), np.asarray(ref_p), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(hT2), np.asarray(ref_h), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(cT2), np.asarray(ref_c), atol=1e-5)
-
-
-def test_pool_bwd_row_padded_carry_cotangents():
-    """ADVICE r4 (medium) regression: when the unbatched pool path row-pads
-    the batch (B not a tile multiple), dh02/dc02 must come back [2, B, H] —
-    not [2, Bp, H] — and match the scan-path gradient exactly."""
+@pytest.mark.parametrize(
+    "compute_dtype,tol", [(None, 1e-4), ("bfloat16", 0.06)],
+    ids=["float32", "bfloat16"],
+)
+def test_bidir_module_grad_through_pool_and_carries_rows_padded(
+    monkeypatch, compute_dtype, tol
+):
+    """Every cotangent path of the module at a row-padded shape: the pooled
+    output (dhs of both kernels), the terminal carries (dhT, dcT), the input,
+    the start state and both directions' weights, against the plain float32
+    reference (bfloat16: relative to the largest entry, as the cell's own
+    mixed-precision test does)."""
+    from dinunet_implementations_tpu.models.icalstm import BiLSTM
     from dinunet_implementations_tpu.ops import lstm_pallas
 
-    old = lstm_pallas.B_TILE
-    lstm_pallas.B_TILE = 8
-    try:
-        B, T, D, H = 12, 5, 4, 8  # pads to Bp=16
-        x = jax.random.normal(jax.random.PRNGKey(28), (B, T, D))
-        pf = _blocked(jax.random.PRNGKey(29), D, H)
-        pr = _blocked(jax.random.PRNGKey(30), D, H)
-        h02 = jax.random.normal(jax.random.PRNGKey(31), (2, B, H)) * 0.3
-        c02 = jax.random.normal(jax.random.PRNGKey(32), (2, B, H)) * 0.3
-
-        def loss(fused):
-            def f(x, h02, c02):
-                if fused:
-                    pooled, (hT2, cT2) = lstm_pallas.bilstm_pool_forward_fused(
-                        x, pf, pr, h02, c02
-                    )
-                else:
-                    pooled, (hT2, cT2) = _scan_bilstm_pool(x, pf, pr, h02, c02)
-                return (
-                    jnp.sum(pooled**2)
-                    + jnp.sum(jnp.sin(hT2))
-                    + jnp.sum(cT2**2)
-                )
-
-            return f
-
-        gx, gh, gc = jax.grad(loss(True), argnums=(0, 1, 2))(x, h02, c02)
-        rx, rh, rc = jax.grad(loss(False), argnums=(0, 1, 2))(x, h02, c02)
-        assert gh.shape == (2, B, H), gh.shape
-        assert gc.shape == (2, B, H), gc.shape
-        np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), atol=1e-4)
-        np.testing.assert_allclose(np.asarray(gh), np.asarray(rh), atol=1e-4)
-        np.testing.assert_allclose(np.asarray(gc), np.asarray(rc), atol=1e-4)
-    finally:
-        lstm_pallas.B_TILE = old
-
-
-@pytest.mark.slow
-def test_pool_vmapped_grad_parity():
-    """The production composition (VERDICT r4 #2): the trainer vmaps the
-    pooled op over a leading site axis — the 4D dispatch rules must agree
-    with the scan path, forward AND backward (shared weights sum over
-    sites)."""
-    from dinunet_implementations_tpu.ops.lstm_pallas import (
-        bilstm_pool_forward_fused,
+    monkeypatch.setattr(lstm_pallas, "B_TILE", 2)
+    B, T, D, H = 3, 5, 4, 8
+    x, params, h0 = _bidir_case(B, T, D, H, with_h0=True, seed=28)
+    module = BiLSTM(
+        2 * H, use_pallas=True, time_pool="mean", compute_dtype=compute_dtype
     )
 
-    S, B, T, D, H = 3, 4, 6, 5, 8
-    x = jax.random.normal(jax.random.PRNGKey(33), (S, B, T, D))
-    pf = _blocked(jax.random.PRNGKey(34), D, H)
-    pr = _blocked(jax.random.PRNGKey(35), D, H)
+    def loss(forward):
+        def f(x, params, h0):
+            pooled, (hT, cT) = forward(x, params, h0)
+            return (
+                jnp.sum(pooled.astype(jnp.float32) ** 2)
+                + jnp.sum(jnp.sin(hT)) + jnp.sum(cT**2)
+            )
 
-    def loss(params, fused):
-        pf, pr = params
+        return f
 
-        def per_site(xs):
-            if fused:
-                pooled, (hT2, cT2) = bilstm_pool_forward_fused(xs, pf, pr)
-            else:
-                z = jnp.zeros((2, xs.shape[0], H))
-                pooled, (hT2, cT2) = _scan_bilstm_pool(xs, pf, pr, z, z)
-            return jnp.sum(pooled**2) + jnp.sum(jnp.sin(hT2) + cT2**2)
+    got = jax.jit(jax.grad(
+        loss(lambda x, p, h0: module.apply({"params": p}, x, h0)),
+        argnums=(0, 1, 2),
+    ))(x, params, h0)
+    ref = jax.jit(jax.grad(
+        loss(lambda x, p, h0: _scan_bidir_pool(x, p, *h0)), argnums=(0, 1, 2)
+    ))(x, params, h0)
+    assert got[2][0].shape == got[2][1].shape == (B, H)
 
-        return jnp.sum(jax.vmap(per_site)(x))
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        assert a.shape == b.shape
+        assert np.abs(a - b).max() / max(np.abs(b).max(), 1.0) < tol
 
-    out_f = loss((pf, pr), True)
-    out_s = loss((pf, pr), False)
-    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_s), rtol=1e-5)
-    g_f = jax.grad(loss)((pf, pr), True)
-    g_s = jax.grad(loss)((pf, pr), False)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=2e-4
-        ),
-        g_f,
-        g_s,
-    )
+    jax.tree.map(close, got, ref)
 
 
-@pytest.mark.slow
-def test_pool_vmapped_site_padding_branch():
-    """S not a multiple of the site tile: the _pad_sites branch inside the 4D
-    rules must pad and slice back, forward and backward."""
+@pytest.mark.parametrize("weights", ["shared", "per-site"])
+def test_bidir_module_vmapped_over_sites(monkeypatch, weights):
+    """Both branches of the kernels' vmap rules, forward and backward, at the
+    module: weights shared by the sites fold the site axis into kernel rows
+    (3 sites x 4 rows = 12, padded to two tiles of 8); weights that differ by
+    site (a personalized layer) run site by site under ``lax.map``."""
+    from dinunet_implementations_tpu.models.icalstm import BiLSTM
     from dinunet_implementations_tpu.ops import lstm_pallas
 
-    old = lstm_pallas.B_TILE
-    lstm_pallas.B_TILE = 8
-    try:
-        S, B, T, D, H = 3, 4, 5, 4, 8  # st = 8//4 = 2 → S pads 3 → 4
-        assert lstm_pallas._pool_s_tile(S, B) == 2
-        x = jax.random.normal(jax.random.PRNGKey(36), (S, B, T, D))
-        pf = _blocked(jax.random.PRNGKey(37), D, H)
-        pr = _blocked(jax.random.PRNGKey(38), D, H)
-
-        def loss(x, fused):
-            def per_site(xs):
-                if fused:
-                    pooled, (hT2, cT2) = lstm_pallas.bilstm_pool_forward_fused(
-                        xs, pf, pr
-                    )
-                else:
-                    z = jnp.zeros((2, xs.shape[0], H))
-                    pooled, (hT2, cT2) = _scan_bilstm_pool(xs, pf, pr, z, z)
-                return jnp.sum(pooled**2) + jnp.sum(hT2 + cT2)
-
-            return jnp.sum(jax.vmap(per_site)(x))
-
-        np.testing.assert_allclose(
-            np.asarray(loss(x, True)), np.asarray(loss(x, False)), rtol=1e-5
+    monkeypatch.setattr(lstm_pallas, "B_TILE", 8)
+    S, B, T, D, H = 3, 4, 5, 4, 8
+    x, params, _ = _bidir_case(S * B, T, D, H, with_h0=False, seed=33)
+    x = x.reshape(S, B, T, D)
+    p_axis = None
+    if weights == "per-site":
+        scale = jnp.arange(1.0, S + 1.0) / S
+        params = jax.tree.map(
+            lambda a: a[None] * scale.reshape(S, *([1] * a.ndim)), params
         )
-        gx_f = jax.grad(loss)(x, True)
-        gx_s = jax.grad(loss)(x, False)
-        np.testing.assert_allclose(
-            np.asarray(gx_f), np.asarray(gx_s), atol=1e-4
-        )
-    finally:
-        lstm_pallas.B_TILE = old
+        p_axis = 0
+    module = BiLSTM(2 * H, use_pallas=True, time_pool="mean")
+    z = jnp.zeros((B, H))
+
+    def run(forward):
+        def f(x, params):
+            pooled, (hT, cT) = jax.vmap(forward, in_axes=(0, p_axis))(x, params)
+            return (
+                jnp.sum(pooled**2) + jnp.sum(jnp.sin(hT)) + jnp.sum(cT**2),
+                (pooled, hT, cT),
+            )
+
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(x, params)
+
+    (_, got), got_g = run(lambda xs, p: module.apply({"params": p}, xs))
+    (_, ref), ref_g = run(lambda xs, p: _scan_bidir_pool(xs, p, z, z))
+    assert got[0].shape == (S, B, 2 * H)
+    _assert_trees_close(got, ref, atol=1e-5)
+    _assert_trees_close(got_g, ref_g, atol=1e-4)
 
 
 @pytest.mark.slow
-def test_pool_per_element_weights_lax_map_branch():
-    """vmap with BATCHED weights (per-element params) must take the lax.map
-    fallback in both the forward and backward custom_vmap rules."""
-    from dinunet_implementations_tpu.ops.lstm_pallas import (
-        bilstm_pool_forward_fused,
-    )
-
-    S, B, T, D, H = 2, 4, 5, 4, 8
-    x = jax.random.normal(jax.random.PRNGKey(39), (S, B, T, D))
-    pfs = jax.vmap(lambda k: _blocked(k, D, H))(
-        jax.random.split(jax.random.PRNGKey(40), S)
-    )
-    prs = jax.vmap(lambda k: _blocked(k, D, H))(
-        jax.random.split(jax.random.PRNGKey(41), S)
-    )
-
-    def loss(params, fused):
-        pfs, prs = params
-
-        def per_site(xs, pf, pr):
-            if fused:
-                pooled, (hT2, cT2) = bilstm_pool_forward_fused(xs, pf, pr)
-            else:
-                z = jnp.zeros((2, xs.shape[0], H))
-                pooled, (hT2, cT2) = _scan_bilstm_pool(xs, pf, pr, z, z)
-            return jnp.sum(pooled**2) + jnp.sum(hT2 * cT2)
-
-        return jnp.sum(jax.vmap(per_site)(x, pfs, prs))
-
-    np.testing.assert_allclose(
-        np.asarray(loss((pfs, prs), True)),
-        np.asarray(loss((pfs, prs), False)),
-        rtol=1e-5,
-    )
-    g_f = jax.grad(loss)((pfs, prs), True)
-    g_s = jax.grad(loss)((pfs, prs), False)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=1e-4
-        ),
-        g_f,
-        g_s,
-    )
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("fused_bidir", [False, True])
-def test_icalstm_pallas_vmapped_over_sites_end_to_end(fused_bidir):
+def test_icalstm_pallas_vmapped_over_sites_end_to_end():
     """The EXACT program the federated bench compiles: the full
     ICALstm(use_pallas=True) model vmapped over a leading site axis — logits
-    and parameter gradients must match the scan path. Covers BOTH kernel
-    arms: per-direction (the measured default) and the opt-in fused
-    bidirectional pooled kernel."""
+    and parameter gradients must match the scan path."""
     S = 3
     key = jax.random.PRNGKey(42)
     x = jax.random.normal(key, (S, 4, 6, 5, 4))  # [S, B, windows, C, W]
     y = jnp.tile(jnp.array([0, 1, 0, 1]), (S, 1))
     kwargs = dict(input_size=16, hidden_size=12, num_comps=5, window_size=4)
     m_scan = ICALstm(use_pallas=False, **kwargs)
-    m_pal = ICALstm(use_pallas=True, fused_bidir=fused_bidir, **kwargs)
+    m_pal = ICALstm(use_pallas=True, **kwargs)
     variables = m_scan.init({"params": key, "dropout": key}, x[0], train=True)
 
     def loss(v, module):

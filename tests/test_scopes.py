@@ -1,15 +1,19 @@
 """Device scopes and kernel names (telemetry/scopes.py, ops/lstm_pallas.py).
 
-The epoch program names its own parts: five ``jax.named_scope``s and six
+The epoch program names its own parts: five ``jax.named_scope``s and two
 Pallas kernel names. They are metadata: (a) every scope that applies is in
 the lowered program's debug info, (b) with the scopes taken away the
 lowering is the same program, (c) every ``pl.pallas_call`` of the LSTM
-kernels passes a distinct ``name=`` from the file's constants.
+kernels passes a distinct ``name=`` from the file's constants, (d) every
+kernel name is read by a metric file of the benchmark and every module of
+``ops/`` is imported by a model or an engine.
 """
 
 import ast
 import contextlib
 import inspect
+import json
+import pathlib
 import re
 
 import jax
@@ -17,7 +21,6 @@ import pytest
 
 from dinunet_implementations_tpu.checks.lowering import diff_report
 from dinunet_implementations_tpu.checks.semantic import (
-    RANKDAD_IDENTITY_CELL,
     TraceCell,
     build_cell_inputs,
 )
@@ -31,8 +34,10 @@ from dinunet_implementations_tpu.trainer.steps import (
 #: the device pipeline, so that the on-device gather is in the program
 CELLS = {
     "dSGD": TraceCell("dSGD", "vmap", "device"),
+    # small ranks keep the trace cheap
     "rankDAD": TraceCell("rankDAD", "vmap", "device",
-                         engine_kw=RANKDAD_IDENTITY_CELL.engine_kw),
+                         engine_kw=(("dad_num_pow_iters", 2),
+                                    ("dad_reduction_rank", 2))),
 }
 SCOPES = {name: getattr(scopes, name)
           for name in ("GATHER", "MODEL", "ENGINE", "POWERITER", "OPTIMIZER")}
@@ -98,7 +103,7 @@ def _pallas_calls():
 
 def test_every_lstm_pallas_call_is_named_from_the_constants():
     calls = _pallas_calls()
-    assert len(calls) == len(lstm_pallas.KERNEL_NAMES) == 6
+    assert len(calls) == len(lstm_pallas.KERNEL_NAMES) == 2
     used = []
     for call in calls:
         kw = {k.arg: k.value for k in call.keywords}
@@ -108,13 +113,54 @@ def test_every_lstm_pallas_call_is_named_from_the_constants():
     assert sorted(used) == sorted(set(lstm_pallas.KERNEL_NAMES))
 
 
+def _whole_word(name: str) -> re.Pattern:
+    return re.compile(r"(?<![A-Za-z0-9])" + name + r"(?![A-Za-z0-9])")
+
+
 @pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES)
 def test_kernel_name_is_told_from_the_others_as_a_whole_word(name):
     """The TPU compiler names a Mosaic call after the sanitized scope
-    (``%vmap_jvp_lstm_fwd__.6``); a metric tells ``lstm_fwd`` from
-    ``bilstm_fwd`` by the letters around it."""
-    word = re.compile(r"(?<![A-Za-z0-9])" + name + r"(?![A-Za-z0-9])")
+    (``%vmap_jvp_lstm_fwd__.6``); a metric tells ``lstm_fwd`` from a longer
+    word that ends in it by the letters around it."""
+    word = _whole_word(name)
     assert re.fullmatch(r"[a-z]+(_[a-z]+)*", name)
+    assert word.search(f"%vmap_jvp_bi{name}__.6") is None
     for other in lstm_pallas.KERNEL_NAMES:
         hit = word.search(f"%vmap_jvp_{other}__.6") is not None
         assert hit == (other == name), (name, other)
+
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+PACKAGE = REPO / "dinunet_implementations_tpu"
+
+
+@pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES)
+def test_every_kernel_name_is_read_by_a_metric_of_the_benchmark(name):
+    """A kernel no metric file reads is a kernel no cell can judge: its
+    metric's pattern holds the name as it stands and its ``what`` names the
+    constant, so a new kernel comes with its metric or not at all."""
+    constant = next(k for k, v in vars(lstm_pallas).items()
+                    if k.isupper() and v == name)
+    readers = []
+    for path in sorted((REPO / "benchmarks" / "layer_metrics").glob("*.json")):
+        metric = json.loads(path.read_text())
+        pattern = str(metric.get("args", {}).get("pattern", ""))
+        if _whole_word(name).search(pattern):
+            assert re.search(rf"\b{constant}\b", metric["what"]), path.name
+            readers.append(path.name)
+    assert readers, f"no benchmarks/layer_metrics/*.json reads {constant}"
+
+
+def test_every_ops_module_is_imported_by_a_model_or_an_engine():
+    imported = set()
+    for path in [*(PACKAGE / "models").glob("*.py"),
+                 *(PACKAGE / "engines").glob("*.py")]:
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.ImportFrom) and node.module:
+                parts = node.module.split(".")
+                if parts[-1] == "ops":  # from ..ops import <module>
+                    imported.update(a.name for a in node.names)
+                elif "ops" in parts[:-1] and node.level:  # from ..ops.<module> import
+                    imported.add(parts[parts.index("ops") + 1])
+    modules = {p.stem for p in (PACKAGE / "ops").glob("*.py")} - {"__init__"}
+    assert modules and modules <= imported, sorted(modules - imported)

@@ -210,12 +210,11 @@ def _sites(rng, n_sites, n, feat):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("make_cfg,feat,strict", [
-    (_fs_cfg, (6,), False),
-    (_ica_cfg, (12, 5, 4), True),
+@pytest.mark.parametrize("make_cfg,feat", [
+    (_fs_cfg, (6,)),
+    (_ica_cfg, (12, 5, 4)),
 ], ids=["freesurfer-mlp", "ica-lstm"])
-def test_served_checkpoint_reproduces_trainer_eval(tmp_path, make_cfg, feat,
-                                                   strict):
+def test_served_checkpoint_reproduces_trainer_eval(tmp_path, make_cfg, feat):
     """Train a fold, then serve its checkpoint against the trainer's own
     eval batches (same rows, same masks — for the batch-stat MSANNet the
     eval plan's pad rows ride as weight-0 request rows, keeping them out of
@@ -224,11 +223,11 @@ def test_served_checkpoint_reproduces_trainer_eval(tmp_path, make_cfg, feat,
     Three layers of the bridge:
 
     - served probs are BITWISE the shared ``eval_forward`` program's output
-      (the engine's AOT executable is that exact program — always strict);
-    - served probs vs the trainer's vmap+scan-wrapped eval: bitwise for the
-      ICA-LSTM; for MSANNet, XLA's fusion may reassociate the masked
-      batch-stat reductions across the two wrappers (observed ≤ 1 ulp on
-      CPU), so the prob comparison is 1e-6-tight there while the
+      (the engine's AOT executable is that exact program);
+    - served probs vs the trainer's vmap+scan-wrapped eval: two DIFFERENT
+      compiled programs, whose fusions may reassociate a float32 reduction
+      (observed at most 1 ulp on CPU: 6e-08 at a probability of 0.54 on
+      the ICA-LSTM), so the probs are held to 4 ulp while the
     - recorded eval SCORES (rank/argmax metrics from those probs) must
       reproduce bit-for-bit on both tasks."""
     cfg = make_cfg()
@@ -271,12 +270,9 @@ def test_served_checkpoint_reproduces_trainer_eval(tmp_path, make_cfg, feat,
                     eng._params, eng._stats, jnp.asarray(fb.inputs[s, t]),
                     jnp.asarray(fb.weights[s, t]),
                 )))
-                if strict:
-                    np.testing.assert_array_equal(got, probs_ref[s, t])
-                else:
-                    np.testing.assert_allclose(
-                        got, probs_ref[s, t], atol=1e-6
-                    )
+                np.testing.assert_array_max_ulp(
+                    got, probs_ref[s, t], maxulp=4
+                )
     finally:
         eng.close()
     # the recorded eval scores reproduce bit-for-bit from the served probs

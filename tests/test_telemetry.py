@@ -248,8 +248,8 @@ def _host_recompute_round(task, engine, opt, state, x, y, w):
 ])
 def test_on_device_metrics_match_host_recompute(engine_name, engine_kw):
     """The acceptance gate: the accumulators the rounds scan maintains equal
-    a from-scratch host recomputation of the same quantities BIT-EXACTLY,
-    under the CompileGuard (one program per fit)."""
+    a from-scratch host recomputation of the same quantities to a few ulp
+    (two programs), under the CompileGuard (one program per fit)."""
     task, engine, opt, state0, x, y, w = _epoch_setup(
         engine_name, engine_kw=engine_kw
     )
@@ -258,17 +258,19 @@ def test_on_device_metrics_match_host_recompute(engine_name, engine_kw):
     st, _ = fn(state0, x, y, w)
     t = {k: np.asarray(v) for k, v in st.telemetry.items()}
     gsq, rsq, usq = _host_recompute_round(task, engine, opt, state0, x, y, w)
-    np.testing.assert_array_equal(t["grad_sq_last"], np.asarray(gsq))
-    np.testing.assert_array_equal(t["grad_sq_sum"], np.asarray(gsq))
-    np.testing.assert_array_equal(t["grad_sq_max"], np.asarray(gsq))
-    np.testing.assert_array_equal(t["residual_sq_sum"], np.asarray(rsq))
-    # Adam's update norm goes through rsqrt chains whose fusion the mirror
-    # cannot pin across two distinct programs — held to a couple of ULPs
-    # rather than bit-exact (the norms above ARE bit-exact)
-    np.testing.assert_array_max_ulp(
-        t["update_sq_last"],
-        np.full_like(t["update_sq_last"], np.asarray(usq)), maxulp=4,
-    )
+    # The mirror is a DIFFERENT compiled program: XLA fuses and orders its
+    # float32 sums otherwise, so a norm lands an ulp or two away (5.96e-08 on
+    # grad_sq_last since the PR-21 toolchain). Across the two programs the
+    # accumulators are held to 4 ulp; what one program computes twice stays
+    # bit-exact (after one round sum, max and last are the same number).
+    for name, want in (("grad_sq_last", gsq), ("residual_sq_sum", rsq),
+                       ("update_sq_last", usq)):
+        np.testing.assert_array_max_ulp(
+            t[name], np.broadcast_to(np.asarray(want), t[name].shape),
+            maxulp=4,
+        )
+    np.testing.assert_array_equal(t["grad_sq_sum"], t["grad_sq_last"])
+    np.testing.assert_array_equal(t["grad_sq_max"], t["grad_sq_last"])
     assert (t["payload_bytes"] == payload_bytes_of(engine, state0.params)).all()
     assert (t["rounds"] == 1).all()
     # a second chained epoch accumulates (and still compiles nothing new)
