@@ -12,11 +12,12 @@ Layout choice: gates live in four separate ``[T, B, H]`` arrays (not one
 ``[T, B, 4H]``) so every block's lane dimension is H and no slice ever crosses
 a lane boundary (Mosaic-friendly; see pallas_guide.md pitfall #2).
 
-Grid: ``(batch_tiles, T)`` — TPU grids execute sequentially, so VMEM scratch
-carries (h, c) across the T dimension; time-reversed index maps drive the
-backward kernel.
+Grid: ``(row_tiles, time_blocks)`` — TPU grids execute sequentially, so VMEM
+scratch carries (h, c) across the time dimension, and one grid step walks the
+``Tb`` timesteps of its ``(Tb, R, ·)`` blocks itself (``lstm_block`` chooses
+``(Tb, R)``); time-reversed index maps drive the backward kernel.
 
-Four measured design points (flagship shape, 32 vmapped sites, v5e):
+Five measured design points (flagship shape, 32 vmapped sites, v5e):
 
 - **The i2h projection is fused into the forward kernel** (round 3): W_ih
   lives in VMEM beside W_hh and the kernel streams the raw ``x [T, B, D]``
@@ -45,6 +46,21 @@ Four measured design points (flagship shape, 32 vmapped sites, v5e):
   matmuls, full MXU rows), padding rows to the kernel tile as needed. The
   fold is valid because every kernel output is row-wise (see previous point).
 
+- **A grid step carries a block of timesteps, not one** (PR 31). A grid
+  step costs 0.35 µs whatever it holds, and at ``(1, 128, ·)`` blocks a call
+  paid it 392 times, a third of its time. The forward (bound by the MXU at
+  the padded widths, 0.68 µs a 128-row timestep) now walks 2 timesteps of
+  all 512 rows a step, the backward (bound by HBM) 7 timesteps of 128 rows,
+  and takes ``c_{t-1}`` from the row above in the same ``cs`` block, fetching
+  only a one-timestep halo a block where it fetched ``cs`` twice: 1.449 →
+  1.085 ms a round, 51 → 69 % of the kernels' roofline. Two things that
+  looked right lost on the chip: computing the block's ``x @ W_ih`` ahead of
+  the serial chain into VMEM scratch (forward +10 %: the MXU was the wall,
+  not the chain, and the detour adds VMEM traffic and VPU adds), and asking
+  for more scoped VMEM for larger blocks (backward +29 %, and the rest of
+  the round slower too). A rolled ``fori_loop`` walk was 6 % slower than
+  the unrolled one.
+
 The terminal carry (hT, cT) is emitted from the f32 VMEM scratch — never
 quantized to the bf16 streams — because the ring LSTM (parallel/sequence.py)
 relays it across sequence chunks.
@@ -66,6 +82,8 @@ from jax.custom_batching import custom_vmap
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Row granule: callers pad the row count to a multiple of ``min(B_TILE, rows)``
+# and a grid step's row tile R is a whole number of granules.
 B_TILE = 128
 
 # Stable kernel names (``pl.pallas_call(name=)``): the name becomes the Mosaic
@@ -79,6 +97,18 @@ LSTM_FWD = "lstm_fwd"
 LSTM_BWD = "lstm_bwd"
 KERNEL_NAMES = (LSTM_FWD, LSTM_BWD)
 
+# What one grid step may hold in VMEM, as ``block_vmem_bytes`` counts it: 14 of
+# the 16 MiB to which the TPU compiler scopes a kernel by default (the rest is
+# for the kernel body's own temporaries). NO larger scope is asked for
+# (``vmem_limit_bytes``): with 64 MiB the backward call ran 29 % slower and the
+# rest of the round 0.2 ms slower on a v5e (PERF.md §6, PR 31) — XLA keeps
+# small operands and its fusions' data in the VMEM that kernels leave free.
+VMEM_BUDGET = 14 << 20
+# Longest walk of timesteps inside one grid step (the walk is unrolled).
+MAX_BLOCK_STEPS = 16
+# Most rows of one grid step of the forward call.
+MAX_BLOCK_ROWS = 512
+
 
 def _interpret() -> bool:
     # Pallas TPU kernels run in interpreter mode on CPU (tests / simulators)
@@ -87,6 +117,117 @@ def _interpret() -> bool:
 
 def _cdt_name(compute_dtype) -> str | None:
     return jnp.dtype(compute_dtype).name if compute_dtype is not None else None
+
+
+# ---------------------------------------------------------------------------
+# the block a grid step carries
+# ---------------------------------------------------------------------------
+
+
+def _round_up(n: int, m: int) -> int:
+    return pl.cdiv(n, m) * m
+
+
+def block_vmem_bytes(Tb: int, R: int, D: int | None, H: int, stream_dtype) -> int:
+    """VMEM one grid step of ``(Tb, R)`` holds: every block twice (the
+    pipeline's double buffer) plus scratch, as stored (lanes padded to 128,
+    rows to the dtype's sublane tile). An upper bound: XLA may hand a small
+    operand over in VMEM, outside the kernel's scope.
+
+    With ``D``, the forward call: the ``x`` block and six output blocks at the
+    stream dtype, both weight stacks, the bias, ``h0``/``c0``/``hT``/``cT`` and
+    the carry scratch. With ``D`` None, the backward call, which sees no ``x``:
+    six input and four output stream blocks, the one-timestep halo, one weight
+    stack, five ``[R, H]`` carries in and out, the carry scratch.
+    """
+    item = jnp.dtype(stream_dtype).itemsize
+    sub = 32 // item  # rows of one (sublane, 128) tile: 8 float32, 16 bfloat16
+    Hp = _round_up(H, 128)
+    step = _round_up(R, sub) * Hp * item  # one timestep of one stream
+    carry = _round_up(R, 8) * Hp * 4
+    whh = 4 * _round_up(H, sub) * Hp * item
+    if D is None:
+        return 2 * (10 * Tb * step + step + whh + 5 * carry) + 2 * carry
+    x_step = _round_up(R, sub) * _round_up(D, 128) * item
+    wih = 4 * _round_up(D, sub) * Hp * item
+    bias = 8 * Hp * 4
+    return 2 * (Tb * (x_step + 6 * step) + wih + whh + bias + 4 * carry) + 2 * carry
+
+
+def lstm_block(T: int, rows: int, D: int | None, H: int, stream_dtype,
+               override: tuple[int, int] | None = None) -> tuple[int, int]:
+    """``(Tb, R)``: the timesteps and rows of the block one grid step carries.
+
+    A pure function of what a call can see: ``T``, the padded row count
+    ``rows`` (a multiple of the granule ``min(B_TILE, rows)``), ``H``, the
+    stream dtype, and ``D`` for the forward call (None for the backward,
+    which sees no ``x``). What was measured on a v5e (PERF.md §6, PR 31): a
+    grid step costs 0.35 µs whatever it carries, so both calls want few of
+    them; the forward is bound by the MXU at the padded widths and gains from
+    rows (a weight tile meets more of them), the backward by HBM and gains
+    from timesteps (the halo is one stream block in ``10 * Tb``).
+
+    - ``R``: the backward takes one granule. The forward takes the largest
+      whole number of granules that divides ``rows``, up to MAX_BLOCK_ROWS,
+      for which two timesteps still fit VMEM_BUDGET.
+    - ``Tb``: among 1..MAX_BLOCK_STEPS under VMEM_BUDGET, the one with the
+      fewest grid steps, a step walked past ``T`` counting as three of them
+      (the last time block may hold such steps; they leave the carry alone).
+      A divisor of ``T`` where a good one fits (98 → 14, or 7 where only ten
+      fit), else a tail (97 → 14, one step past); ``T`` 1 gives 1, the kernel
+      as it was before the block.
+
+    ``override`` is for tests and ``scripts/kernel_tune.py``: a ``(Tb, R)`` to
+    use as given, checked only for what the kernels cannot run.
+    """
+    if override is not None:
+        Tb, R = override
+        if not (1 <= Tb <= T and R >= 1 and rows % R == 0):
+            raise ValueError(
+                f"block {override} does not fit T={T}, rows={rows}: "
+                "need 1 <= Tb <= T and R dividing rows"
+            )
+        return Tb, R
+    gran = min(B_TILE, rows)
+    assert rows % gran == 0, (
+        f"batch {rows} must be a multiple of the kernel tile {gran}; "
+        "use lstm_forward_fused(), which pads"
+    )
+
+    def fits(Tb, R):
+        return block_vmem_bytes(Tb, R, D, H, stream_dtype) <= VMEM_BUDGET
+
+    R = gran
+    if D is not None:
+        tiles = rows // gran
+        R = max(
+            (gran * d for d in range(1, tiles + 1)
+             if tiles % d == 0 and gran * d <= MAX_BLOCK_ROWS and fits(2, gran * d)),
+            default=gran,
+        )
+
+    def cost(Tb):
+        blocks = pl.cdiv(T, Tb)
+        past = blocks * Tb - T
+        return blocks + 3 * past, past, -Tb
+
+    steps = [Tb for Tb in range(1, min(T, MAX_BLOCK_STEPS) + 1) if fits(Tb, R)]
+    return min(steps or [1], key=cost), R
+
+
+def _block_start_prev(tb, Tb):
+    """Time of the cell state BEFORE time block ``tb``'s earliest step (the
+    backward's halo; at ``tb`` 0 there is none and ``c0`` stands in)."""
+    return jnp.maximum(tb * Tb - 1, 0)
+
+
+def _c_before_block(tb, c0, halo):
+    return jnp.where(tb == 0, c0, halo)
+
+
+def _hold(live, new, old):
+    """A step past ``T`` (the last time block's tail) leaves the carry alone."""
+    return jnp.where(live, new, old)
 
 
 # ---------------------------------------------------------------------------
@@ -100,79 +241,82 @@ def _cdt_name(compute_dtype) -> str | None:
 
 
 def _fwd_fused_kernel(
-    x, wih, b, whh, h0, c0, hs, cs, ai, af, ao, ag, hT, cT, h_s, c_s
+    T, x, wih, b, whh, h0, c0, hs, cs, ai, af, ao, ag, hT, cT, h_s, c_s
 ):
-    t = pl.program_id(1)
+    Tb = x.shape[0]
+    j = pl.program_id(1)  # time block: steps j*Tb .. j*Tb + Tb - 1
+    last = pl.num_programs(1) - 1
 
-    @pl.when(t == 0)
+    @pl.when(j == 0)
     def _():
         h_s[:] = h0[:]
         c_s[:] = c0[:]
 
     f32 = jnp.float32
-    xt = x[0]  # [bt, D] this step's input block, at stream dtype
-    h = h_s[:].astype(whh.dtype)
-    # preact_k = x_t @ Wih_k + b_k + h @ Whh_k  (both W stacks VMEM-resident)
-    pre = [
-        jnp.dot(xt, wih[k], preferred_element_type=f32)
-        + jnp.dot(h, whh[k], preferred_element_type=f32)
-        + b[k].astype(f32)
-        for k in range(4)
-    ]
-    i = jax.nn.sigmoid(pre[0])
-    f = jax.nn.sigmoid(pre[1])
-    o = jax.nn.sigmoid(pre[2])
-    g = jnp.tanh(pre[3])
-    c = f * c_s[:] + i * g
-    h = o * jnp.tanh(c)
-    h_s[:] = h
-    c_s[:] = c
-    hs[0] = h.astype(hs.dtype)
-    cs[0] = c.astype(cs.dtype)
-    ai[0] = i.astype(ai.dtype)
-    af[0] = f.astype(af.dtype)
-    ao[0] = o.astype(ao.dtype)
-    ag[0] = g.astype(ag.dtype)
+    bias = [b[k].astype(f32) for k in range(4)]
+    tail = T % Tb or Tb  # steps from here on are past T in the last time block
+    for s in range(Tb):
+        xt = x[s]  # [R, D] this step's input rows, at stream dtype
+        h = h_s[:].astype(whh.dtype)
+        # preact_k = x_t @ Wih_k + h @ Whh_k + b_k  (both W stacks VMEM-resident)
+        pre = [
+            jnp.dot(xt, wih[k], preferred_element_type=f32)
+            + jnp.dot(h, whh[k], preferred_element_type=f32)
+            + bias[k]
+            for k in range(4)
+        ]
+        i = jax.nn.sigmoid(pre[0])
+        f = jax.nn.sigmoid(pre[1])
+        o = jax.nn.sigmoid(pre[2])
+        g = jnp.tanh(pre[3])
+        c = f * c_s[:] + i * g
+        h = o * jnp.tanh(c)
+        hs[s] = h.astype(hs.dtype)
+        cs[s] = c.astype(cs.dtype)
+        ai[s] = i.astype(ai.dtype)
+        af[s] = f.astype(af.dtype)
+        ao[s] = o.astype(ao.dtype)
+        ag[s] = g.astype(ag.dtype)
+        if s >= tail:
+            h, c = _hold(j < last, h, h_s[:]), _hold(j < last, c, c_s[:])
+        h_s[:] = h
+        c_s[:] = c
 
     # terminal carry at FULL f32 (straight from VMEM scratch, not the possibly
     # bf16 hs/cs streams): the ring-LSTM relays this carry between sequence
     # chunks, and quantizing it at each chunk boundary would silently diverge
     # the sharded run from the dense one (review finding, round 3)
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(j == last)
     def _():
         hT[:] = h_s[:]
         cT[:] = c_s[:]
 
 
-def _fwd_fused_call(x, wih4, b4, whh4, h0, c0, compute_dtype=None):
+def _fwd_fused_call(x, wih4, b4, whh4, h0, c0, compute_dtype=None, block=None):
     T, B, D = x.shape
     H = wih4.shape[-1]
-    bt = min(B_TILE, B)
-    assert B % bt == 0, (
-        f"batch {B} must be a multiple of the kernel tile {bt}; "
-        "use lstm_forward_fused(), which pads"
-    )
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
         wih4 = wih4.astype(compute_dtype)
         whh4 = whh4.astype(compute_dtype)
-    grid = (B // bt, T)
-    spec_x = pl.BlockSpec((1, bt, D), lambda b, t: (t, b, 0), memory_space=pltpu.VMEM)
-    spec_t = pl.BlockSpec((1, bt, H), lambda b, t: (t, b, 0), memory_space=pltpu.VMEM)
-    spec_b = pl.BlockSpec((bt, H), lambda b, t: (b, 0), memory_space=pltpu.VMEM)
-    spec_wih = pl.BlockSpec((4, D, H), lambda b, t: (0, 0, 0), memory_space=pltpu.VMEM)
-    spec_whh = pl.BlockSpec((4, H, H), lambda b, t: (0, 0, 0), memory_space=pltpu.VMEM)
-    spec_bias = pl.BlockSpec((4, H), lambda b, t: (0, 0), memory_space=pltpu.VMEM)
     stream_dtype = jnp.dtype(compute_dtype) if compute_dtype is not None else jnp.float32
+    Tb, R = lstm_block(T, B, D, H, stream_dtype, override=block)
+    grid = (B // R, pl.cdiv(T, Tb))
+    spec_x = pl.BlockSpec((Tb, R, D), lambda b, j: (j, b, 0), memory_space=pltpu.VMEM)
+    spec_t = pl.BlockSpec((Tb, R, H), lambda b, j: (j, b, 0), memory_space=pltpu.VMEM)
+    spec_b = pl.BlockSpec((R, H), lambda b, j: (b, 0), memory_space=pltpu.VMEM)
+    spec_wih = pl.BlockSpec((4, D, H), lambda b, j: (0, 0, 0), memory_space=pltpu.VMEM)
+    spec_whh = pl.BlockSpec((4, H, H), lambda b, j: (0, 0, 0), memory_space=pltpu.VMEM)
+    spec_bias = pl.BlockSpec((4, H), lambda b, j: (0, 0), memory_space=pltpu.VMEM)
     out_shape = jax.ShapeDtypeStruct((T, B, H), stream_dtype)
     carry_shape = jax.ShapeDtypeStruct((B, H), jnp.float32)
     return pl.pallas_call(
-        _fwd_fused_kernel,
+        functools.partial(_fwd_fused_kernel, T),
         grid=grid,
         in_specs=[spec_x, spec_wih, spec_bias, spec_whh, spec_b, spec_b],
         out_specs=[spec_t] * 6 + [spec_b] * 2,
         out_shape=[out_shape] * 6 + [carry_shape] * 2,
-        scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((R, H), jnp.float32)] * 2,
         interpret=_interpret(),
         name=LSTM_FWD,
     )(x, wih4, b4, whh4, h0, c0)
@@ -184,16 +328,17 @@ def _fwd_fused_call(x, wih4, b4, whh4, h0, c0, compute_dtype=None):
 
 
 def _bwd_kernel(
-    T_total,
-    ai, af, ao, ag, cs, cs_prev, wT, c0, dhs, dhT, dcT,
+    T,
+    ai, af, ao, ag, cs, halo, wT, c0, dhs, dhT, dcT,
     dxi_i, dxi_f, dxi_o, dxi_g, dh0, dc0,
     dh_s, dc_s,
 ):
-    t = pl.program_id(1)  # 0..T-1, walking time backwards: time = T-1-t
-    first_time = t == 0  # time T-1
-    last_time = t == T_total - 1  # time 0
+    Tb = cs.shape[0]
+    j = pl.program_id(1)  # walking time backwards, block by block
+    last = pl.num_programs(1) - 1
+    tb = last - j  # time block: steps tb*Tb + Tb - 1 down to tb*Tb
 
-    @pl.when(first_time)
+    @pl.when(j == 0)
     def _():
         # seed the carries with the terminal-state cotangents (exact dcT/dhT);
         # re-seeded at the start of every batch tile (per-tile state)
@@ -201,69 +346,80 @@ def _bwd_kernel(
         dc_s[:] = dcT[:].astype(jnp.float32)
 
     f32 = jnp.float32
-    i, f, o, g = (ai[0].astype(f32), af[0].astype(f32),
-                  ao[0].astype(f32), ag[0].astype(f32))
-    c = cs[0].astype(f32)
-    c_prev = jnp.where(last_time, c0[:].astype(f32), cs_prev[0].astype(f32))
-
-    tanh_c = jnp.tanh(c)
-    dh = dhs[0].astype(f32) + dh_s[:]
-    do = dh * tanh_c
-    dc = dh * o * (1.0 - tanh_c * tanh_c) + dc_s[:]
-    di = dc * g
-    df = dc * c_prev
-    dg = dc * i
-
-    dpi = di * i * (1.0 - i)
-    dpf = df * f * (1.0 - f)
-    dpo = do * o * (1.0 - o)
-    dpg = dg * (1.0 - g * g)
-
-    dxi_i[0] = dpi.astype(dxi_i.dtype)
-    dxi_f[0] = dpf.astype(dxi_f.dtype)
-    dxi_o[0] = dpo.astype(dxi_o.dtype)
-    dxi_g[0] = dpg.astype(dxi_g.dtype)
-
-    # dh_{t-1} = Σ_k dp_k @ W_kᵀ (matmuls in w's dtype, f32 accumulation).
-    # wT holds the PRE-transposed weights: transposing inside the kernel
-    # (w[k].T) re-ran a lane/sublane transpose on every one of the T grid
-    # steps and dominated the whole backward pass — measured ~20× slower
-    # than this resident-transpose layout on v5e.
     cdt = wT.dtype
-    dh_prev = (
-        jnp.dot(dpi.astype(cdt), wT[0], preferred_element_type=jnp.float32)
-        + jnp.dot(dpf.astype(cdt), wT[1], preferred_element_type=jnp.float32)
-        + jnp.dot(dpo.astype(cdt), wT[2], preferred_element_type=jnp.float32)
-        + jnp.dot(dpg.astype(cdt), wT[3], preferred_element_type=jnp.float32)
-    )
+    tail = T % Tb or Tb  # steps from here on are past T in time block `last`
+    for s in reversed(range(Tb)):
+        i, f, o, g = (ai[s].astype(f32), af[s].astype(f32),
+                      ao[s].astype(f32), ag[s].astype(f32))
+        c = cs[s].astype(f32)
+        # c_{t-1} is the row above in the SAME block; only the block's
+        # earliest step reaches outside it, into the one-timestep halo
+        if s:
+            c_prev = cs[s - 1].astype(f32)
+        else:
+            c_prev = _c_before_block(tb, c0[:].astype(f32), halo[0].astype(f32))
 
-    dh_s[:] = dh_prev
-    dc_s[:] = dc * f
+        tanh_c = jnp.tanh(c)
+        dh = dhs[s].astype(f32) + dh_s[:]
+        do = dh * tanh_c
+        dc = dh * o * (1.0 - tanh_c * tanh_c) + dc_s[:]
+        di = dc * g
+        df = dc * c_prev
+        dg = dc * i
 
-    @pl.when(last_time)
+        dpi = di * i * (1.0 - i)
+        dpf = df * f * (1.0 - f)
+        dpo = do * o * (1.0 - o)
+        dpg = dg * (1.0 - g * g)
+
+        dxi_i[s] = dpi.astype(dxi_i.dtype)
+        dxi_f[s] = dpf.astype(dxi_f.dtype)
+        dxi_o[s] = dpo.astype(dxi_o.dtype)
+        dxi_g[s] = dpg.astype(dxi_g.dtype)
+
+        # dh_{t-1} = Σ_k dp_k @ W_kᵀ (matmuls in w's dtype, f32 accumulation).
+        # wT holds the PRE-transposed weights: transposing inside the kernel
+        # (w[k].T) re-ran a lane/sublane transpose on every one of the T grid
+        # steps and dominated the whole backward pass — measured ~20× slower
+        # than this resident-transpose layout on v5e.
+        dh_prev = (
+            jnp.dot(dpi.astype(cdt), wT[0], preferred_element_type=f32)
+            + jnp.dot(dpf.astype(cdt), wT[1], preferred_element_type=f32)
+            + jnp.dot(dpo.astype(cdt), wT[2], preferred_element_type=f32)
+            + jnp.dot(dpg.astype(cdt), wT[3], preferred_element_type=f32)
+        )
+        dc_prev = dc * f
+        if s >= tail:  # the walk's FIRST steps
+            dh_prev = _hold(j > 0, dh_prev, dh_s[:])
+            dc_prev = _hold(j > 0, dc_prev, dc_s[:])
+        dh_s[:] = dh_prev
+        dc_s[:] = dc_prev
+
+    @pl.when(j == last)
     def _():
         dh0[:] = dh_s[:].astype(dh0.dtype)
         dc0[:] = dc_s[:].astype(dc0.dtype)
 
 
-def _bwd_call(acts, cs, w4, c0, dhs, dhT, dcT, compute_dtype=None):
+def _bwd_call(acts, cs, w4, c0, dhs, dhT, dcT, compute_dtype=None, block=None):
     T, B, H = cs.shape
-    bt = min(B_TILE, B)
-    assert B % bt == 0, f"batch {B} must be a multiple of the kernel tile {bt}"
     if compute_dtype is not None:
         w4 = w4.astype(compute_dtype)
     w4T = jnp.swapaxes(w4, 1, 2)  # transpose ONCE in XLA, resident in VMEM
-    grid = (B // bt, T)
+    Tb, R = lstm_block(T, B, None, H, cs.dtype, override=block)
+    nT = pl.cdiv(T, Tb)
+    grid = (B // R, nT)
 
-    rev = lambda b, t: (T - 1 - t, b, 0)
-    b_block = lambda b, t: (b, 0)
-    spec_rev = pl.BlockSpec((1, bt, H), rev, memory_space=pltpu.VMEM)
-    spec_prev = pl.BlockSpec(
-        (1, bt, H), lambda b, t: (jnp.maximum(T - 2 - t, 0), b, 0),
+    spec_rev = pl.BlockSpec(
+        (Tb, R, H), lambda b, j: (nT - 1 - j, b, 0), memory_space=pltpu.VMEM
+    )
+    # the second operand over cs: one timestep a time block, not one a step
+    spec_halo = pl.BlockSpec(
+        (1, R, H), lambda b, j: (_block_start_prev(nT - 1 - j, Tb), b, 0),
         memory_space=pltpu.VMEM,
     )
-    spec_b = pl.BlockSpec((bt, H), b_block, memory_space=pltpu.VMEM)
-    spec_w = pl.BlockSpec((4, H, H), lambda b, t: (0, 0, 0), memory_space=pltpu.VMEM)
+    spec_b = pl.BlockSpec((R, H), lambda b, j: (b, 0), memory_space=pltpu.VMEM)
+    spec_w = pl.BlockSpec((4, H, H), lambda b, j: (0, 0, 0), memory_space=pltpu.VMEM)
     # dxi dtype must match the xi primal dtype (= the streamed act dtype);
     # dh0/dc0 match the f32 h0/c0 primals
     t_shape = jax.ShapeDtypeStruct((T, B, H), acts[0].dtype)
@@ -273,10 +429,10 @@ def _bwd_call(acts, cs, w4, c0, dhs, dhT, dcT, compute_dtype=None):
         functools.partial(_bwd_kernel, T),
         grid=grid,
         in_specs=[spec_rev] * 4  # i, f, o, g
-        + [spec_rev, spec_prev, spec_w, spec_b, spec_rev, spec_b, spec_b],
+        + [spec_rev, spec_halo, spec_w, spec_b, spec_rev, spec_b, spec_b],
         out_specs=[spec_rev] * 4 + [spec_b, spec_b],
         out_shape=[t_shape] * 4 + [b_shape, b_shape],
-        scratch_shapes=[pltpu.VMEM((bt, H), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((R, H), jnp.float32)] * 2,
         interpret=_interpret(),
         name=LSTM_BWD,
     )(*acts, cs, cs, w4T, c0, dhs, dhT, dcT)

@@ -1,14 +1,22 @@
-"""Tune the fused LSTM kernel's batch tile on the real chip.
+"""Sweep the block a grid step of the LSTM kernels carries, on the real chip.
 
-Times fwd+bwd of lstm_recurrence_fused at the bench's folded shape
-(T=98, rows=32 sites x 16 batch = 512, D=256, H=174, bf16 streams) for a
-range of B_TILE values, using the chained-iteration methodology from
-bench.py (a long dependent chain ended by a host fetch of every output,
-so that the fixed cost of ending the chain cancels in the marginal).
+Times the forward call and the backward call ALONE at the benchmark's folded
+shape (T=98, rows=32 sites x 16 batch = 512, D=256, H=174, bf16 streams) for
+a list of ``(Tb, R)`` blocks, handed to the kernels through
+``lstm_pallas.lstm_block``'s ``override`` argument (the ``block=`` of
+``_fwd_fused_call`` / ``_bwd_call``). ``auto`` is what ``lstm_block`` chooses
+itself. Every block's outputs are compared with the first block's.
 
-Usage: python scripts/kernel_tune.py [--tiles 128,256,512]
+A call is timed inside ONE jitted ``fori_loop`` that feeds the call's carry
+outputs (``hT, cT`` / ``dh0, dc0``) back as its carry inputs, so the device
+runs the iterations back to back with no host in between; the marginal
+between a long and a short loop cancels what starting and ending one costs.
+
+Usage: python scripts/kernel_tune.py [--blocks 1x128,7x256,auto]
+           [--shape T,rows,D,H] [--dtype bfloat16|float32]
 """
 
+import functools
 import os
 import sys
 import time
@@ -21,75 +29,113 @@ import numpy as np
 
 from dinunet_implementations_tpu.ops import lstm_pallas
 
-T, ROWS, D, H = 98, 512, 256, 174
-CHAIN = 60
+SHAPE = (98, 512, 256, 174)
+BLOCKS = [(1, 128), (2, 128), (7, 128), (10, 128), (14, 128), (1, 256), (2, 256),
+          (4, 256), (7, 256), (1, 512), (2, 512), (3, 512), None]
+ITERS = 200
 
 
-def make_step(cdt):
-    def loss(x, wih4, b4, whh4, h0, c0):
-        hs, (hT, cT) = lstm_pallas.lstm_recurrence_fused(
-            x, wih4, b4, whh4, h0, c0, cdt
-        )
-        return (hs.astype(jnp.float32).sum() + hT.sum() + cT.sum())
-
-    g = jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))
-
-    def step(x, wih4, b4, whh4, h0, c0):
-        dx, dwih, db, dwhh, dh0, dc0 = g(x, wih4, b4, whh4, h0, c0)
-        # chain: feed gradient signal back into the inputs so iterations
-        # depend on each other and the lazy backend cannot skip any
-        return (
-            x + dx.astype(x.dtype) * 1e-6,
-            wih4 + dwih * 1e-6,
-            b4 + db * 1e-6,
-            whh4 + dwhh * 1e-6,
-            h0 + dh0 * 1e-6,
-            c0 + dc0 * 1e-6,
-        )
-
-    return jax.jit(step)
-
-
-def run(tile, cdt="bfloat16", chain=CHAIN, repeats=3):
-    lstm_pallas.B_TILE = tile
-    lstm_pallas._fwd_fused_callable.cache_clear()
-    lstm_pallas._bwd_callable.cache_clear()
+@functools.lru_cache(maxsize=1)
+def make_inputs(T, rows, D, H, cdt):
     rng = np.random.default_rng(0)
-    args = (
-        jnp.asarray(rng.normal(size=(T, ROWS, D)).astype(np.float32)),
-        jnp.asarray(rng.normal(size=(4, D, H)).astype(np.float32) * 0.05),
-        jnp.asarray(rng.normal(size=(4, H)).astype(np.float32) * 0.05),
-        jnp.asarray(rng.normal(size=(4, H, H)).astype(np.float32) * 0.05),
-        jnp.zeros((ROWS, H), jnp.float32),
-        jnp.zeros((ROWS, H), jnp.float32),
-    )
-    step = make_step(cdt)
 
-    def chain_run(n):
-        a = args
-        t0 = time.time()
-        for _ in range(n):
-            a = step(*a)
-        jax.tree.map(np.asarray, a)
-        return time.time() - t0
+    def arr(*shape, scale=1.0, dtype=jnp.float32):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32) * scale, dtype)
 
-    chain_run(2)  # compile
-    from bench import least_contended_marginal  # shared clamped estimator
-
-    dt = least_contended_marginal(chain_run, chain, repeats=repeats)
-    sps = ROWS / dt
-    print(f"B_TILE={tile:4d} cdt={cdt}: {dt*1e3:8.3f} ms/iter  "
-          f"({sps:,.0f} rows/s)", flush=True)
-    return dt
+    fwd = (arr(T, rows, D, dtype=cdt), arr(4, D, H, scale=0.05),
+           arr(4, H, scale=0.05), arr(4, H, H, scale=0.05),
+           arr(rows, H, scale=0.3), arr(rows, H, scale=0.3))
+    cots = (arr(T, rows, H, dtype=cdt), arr(rows, H), arr(rows, H))
+    return fwd, cots
 
 
-def main():
-    tiles = [128, 256, 512]
-    if "--tiles" in sys.argv:
-        tiles = [int(t) for t in sys.argv[sys.argv.index("--tiles") + 1].split(",")]
-    for tile in tiles:
-        run(tile)
+def looped(call, iters):
+    """``call(*fixed, *carry) -> outs`` whose last two outputs replace the
+    carry: ``iters`` dependent calls in one device program."""
+    def run(fixed, carry):
+        def body(_, carry):
+            return tuple(call(*fixed, *carry)[-2:])
+
+        return jax.lax.fori_loop(0, iters, body, carry)
+
+    return jax.jit(run)
+
+
+def marginal_ms(call, fixed, carry, iters=ITERS, repeats=5):
+    def wall(fn):
+        jax.block_until_ready(fn(fixed, carry))  # compile, warm
+        best = float("inf")
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(fixed, carry))
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    full, half = wall(looped(call, iters)), wall(looped(call, iters // 2))
+    return (full - half) / (iters - iters // 2) * 1e3
+
+
+def run(block, shape=SHAPE, cdt=jnp.bfloat16, reference=None):
+    """Time one block; returns ``(fwd_ms, bwd_ms, outputs)``."""
+    T, rows, D, H = shape
+    (x, wih, b, whh, h0, c0), (dhs, dhT, dcT) = make_inputs(T, rows, D, H, cdt)
+
+    def fwd(x, wih, b, whh, h0, c0):
+        return lstm_pallas._fwd_fused_call(x, wih, b, whh, h0, c0, cdt, block=block)
+
+    def bwd(ai, af, ao, ag, cs, whh, c0, dhs, dhT, dcT):
+        return lstm_pallas._bwd_call(
+            (ai, af, ao, ag), cs, whh, c0, dhs, dhT, dcT, cdt, block=block)
+
+    hs, cs, ai, af, ao, ag, hT, cT = outs = jax.jit(fwd)(x, wih, b, whh, h0, c0)
+    grads = jax.jit(bwd)(ai, af, ao, ag, cs, whh, c0, dhs, dhT, dcT)
+    got = [np.asarray(a, np.float32) for a in (*outs, *grads)]
+    err = (max(float(np.abs(a - r).max()) for a, r in zip(got, reference))
+           if reference is not None else 0.0)
+
+    fwd_ms = marginal_ms(fwd, (x, wih, b, whh), (h0, c0))
+    # the backward's carry outputs (dh0, dc0) re-enter as (dhT, dcT)
+    bwd_ms = marginal_ms(bwd, (ai, af, ao, ag, cs, whh, c0, dhs), (dhT, dcT))
+
+    dtype = jnp.dtype(cdt)
+
+    def describe(ms, d):  # d: D for the forward call, None for the backward
+        Tb, R = lstm_pallas.lstm_block(T, rows, d, H, dtype, override=block)
+        steps = (rows // R) * -(-T // Tb)
+        vmem = lstm_pallas.block_vmem_bytes(Tb, R, d, H, dtype) / 2**20
+        return (f"{ms:.4f} ms  Tb={Tb:2d} R={R:3d} {steps:3d} grid steps of "
+                f"{ms * 1e3 / steps:6.2f} us, vmem {vmem:4.1f} MiB")
+
+    print(f"block={'auto' if block is None else block}: fwd {describe(fwd_ms, D)} | "
+          f"bwd {describe(bwd_ms, None)} | sum {fwd_ms + bwd_ms:.4f} ms  "
+          f"max|diff| vs first {err:.3g}", flush=True)
+    return fwd_ms, bwd_ms, got
+
+
+def parse_block(text):
+    return None if text == "auto" else tuple(int(v) for v in text.split("x"))
+
+
+def main(argv):
+    def option(name, default):
+        return argv[argv.index(name) + 1] if name in argv else default
+
+    blocks = BLOCKS
+    if "--blocks" in argv:
+        blocks = [parse_block(t) for t in option("--blocks", "").split(",")]
+    shape = tuple(int(v) for v in option("--shape", ",".join(map(str, SHAPE))).split(","))
+    cdt = jnp.dtype(option("--dtype", "bfloat16"))
+    d = jax.devices()[0]
+    print(f"device={d.platform} {d.device_kind}  T,rows,D,H={shape} {cdt.name}", flush=True)
+    reference = None
+    for block in blocks:
+        try:
+            _, _, got = run(block, shape, cdt, reference)
+        except Exception as e:  # a block the compiler refuses (VMEM) is a result
+            print(f"block={block}: REFUSED {str(e).splitlines()[0][:300]}", flush=True)
+            continue
+        reference = reference or got
 
 
 if __name__ == "__main__":
-    main()
+    main(sys.argv[1:])

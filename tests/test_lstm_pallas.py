@@ -515,3 +515,243 @@ def test_icalstm_pallas_vmapped_over_sites_end_to_end():
         g_p,
         g_s,
     )
+
+
+# ---------------------------------------------------------------------------
+# the block a grid step carries (ISSUE 31): Tb timesteps of R rows, walked
+# inside the kernel; the backward's c_{t-1} from the same block plus a
+# one-timestep halo; steps past T in the last time block leave the carry alone
+# ---------------------------------------------------------------------------
+
+
+def _force_block(monkeypatch, Tb, R=None, granule=None):
+    """Every kernel call takes the block ``(Tb, R)`` through ``lstm_block``'s
+    override argument (``R`` None: all of the call's rows; a ``Tb`` beyond a
+    call's ``T`` is cut to it). ``granule`` re-points the row granule the
+    callers pad to."""
+    from dinunet_implementations_tpu.ops import lstm_pallas
+
+    chosen = lstm_pallas.lstm_block
+    if granule is not None:
+        monkeypatch.setattr(lstm_pallas, "B_TILE", granule)
+    monkeypatch.setattr(
+        lstm_pallas, "lstm_block",
+        lambda T, rows, D, H, dtype, override=None: chosen(
+            T, rows, D, H, dtype, override=(min(Tb, T), R or rows)),
+    )
+
+
+def _cell_case(B, T, D, H, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (
+        jax.random.normal(ks[0], (B, T, D)),
+        _params(ks[1], D, H),
+        (jax.random.normal(ks[2], (B, H)) * 0.3,
+         jax.random.normal(ks[3], (B, H)) * 0.3),
+    )
+
+
+def _cell_value_and_grads(forward, x, params, h0):
+    """Outputs and every gradient of a cell: ``hs`` feeds ``dhs``, and the
+    terminal carry enters the loss so that ``dhT`` and ``dcT`` are non-zero."""
+    def loss(x, params, h0):
+        hs, (hT, cT) = forward(x, params, h0)
+        return (
+            jnp.sum(hs.astype(jnp.float32) ** 2)
+            + jnp.sum(jnp.sin(hT)) + jnp.sum(cT**2),
+            (hs, hT, cT),
+        )
+
+    (_, outs), grads = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+    )(x, params, h0)
+    return outs, grads
+
+
+def _assert_rel_close(got, want, tol):
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape
+        assert np.abs(a - b).max() / max(np.abs(b).max(), 1.0) < tol
+
+    jax.tree.map(close, got, want)
+
+
+def _check_block_against_scan_and_reference(monkeypatch, case, compute_dtype):
+    T, Tb, B, R, granule = case
+    D, H = 5, 8
+    _force_block(monkeypatch, Tb, R, granule)
+    x, params, h0 = _cell_case(B, T, D, H, seed=31)
+    pal = LSTMCell(H, use_pallas=True, compute_dtype=compute_dtype)
+    scan = LSTMCell(H, use_pallas=False, compute_dtype=compute_dtype)
+    got = _cell_value_and_grads(
+        lambda x, p, h0: pal.apply({"params": p}, x, h0), x, params, h0)
+    want_scan = _cell_value_and_grads(
+        lambda x, p, h0: scan.apply({"params": p}, x, h0), x, params, h0)
+    want_ref = _cell_value_and_grads(
+        lambda x, p, h0: _scan_lstm(x, p, *h0), x, params, h0)
+    assert got[0][1].dtype == got[0][2].dtype == jnp.float32
+    if compute_dtype is None:
+        _assert_trees_close(got[0], want_scan[0], atol=1e-5)
+        _assert_trees_close(got[1], want_scan[1], atol=1e-4)
+        _assert_trees_close(got[0], want_ref[0], atol=1e-5)
+        _assert_trees_close(got[1], want_ref[1], atol=1e-4)
+    else:
+        _assert_rel_close(got, want_scan, 0.06)
+        _assert_rel_close(got, want_ref, 0.06)
+
+
+# (T, Tb, rows, R, row granule): several time blocks; a T that Tb does not
+# divide (the last block's tail leaves the carry alone), prime or not; rows
+# padded to R, and R of two granules; one block that is the whole sequence
+BLOCK_CASES = {
+    "T7-Tb2-tail1": (7, 2, 8, 8, 8),
+    "T7-Tb7-one-block": (7, 7, 8, 8, 8),
+    "T13-Tb4-tail3-rows6-padded-to-R8-of-two-granules": (13, 4, 6, 8, 4),
+    "T13-Tb5-two-row-tiles": (13, 5, 16, 8, 8),
+    "T98-Tb14-seven-blocks": (98, 14, 4, 4, 4),
+    "T98-Tb16-tail14": (98, 16, 4, 4, 4),
+}
+
+
+@pytest.mark.parametrize(
+    "compute_dtype", [None, "bfloat16"], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", BLOCK_CASES.values(), ids=BLOCK_CASES.keys())
+def test_block_forward_and_gradients_match_scan_and_reference(
+        monkeypatch, case, compute_dtype):
+    _check_block_against_scan_and_reference(monkeypatch, case, compute_dtype)
+
+
+SEEDED_DEFECTS = {
+    # the block boundary's c_{t-1} read from the block's own first step
+    "halo-from-the-wrong-timestep": (
+        "_block_start_prev", lambda tb, Tb: tb * Tb,
+        "T13-Tb5-two-row-tiles"),
+    # time 0 reads the (clamped) halo, not the start state
+    "c0-not-used-at-time-0": (
+        "_c_before_block", lambda tb, c0, halo: halo,
+        "T7-Tb7-one-block"),
+    # the tail's steps past T advance the carry, and hT / cT come after them
+    "terminal-carry-written-after-a-padded-step": (
+        "_hold", lambda live, new, old: new,
+        "T7-Tb2-tail1"),
+}
+
+
+@pytest.mark.parametrize(
+    "defect", SEEDED_DEFECTS.values(), ids=SEEDED_DEFECTS.keys())
+def test_block_cases_fail_under_a_seeded_defect(monkeypatch, defect):
+    """The parity cases above are sharp: each of the three ways a block can
+    go wrong turns the named case red (and it is green without the defect)."""
+    from dinunet_implementations_tpu.ops import lstm_pallas
+
+    name, broken, case = defect
+    _check_block_against_scan_and_reference(monkeypatch, BLOCK_CASES[case], None)
+    monkeypatch.setattr(lstm_pallas, name, broken)
+    with pytest.raises(AssertionError):
+        _check_block_against_scan_and_reference(
+            monkeypatch, BLOCK_CASES[case], None)
+
+
+@pytest.mark.parametrize("weights", ["shared", "per-site"])
+def test_block_under_the_vmap_fold(monkeypatch, weights):
+    """The vmap rules with a block of several timesteps: shared weights fold 3
+    sites x 4 rows into 12 kernel rows, padded to 16 = one R of two granules;
+    per-site weights run site by site, each call's 4 rows one tile. T = 5
+    with Tb = 2: three time blocks, the last with a one-step tail."""
+    from dinunet_implementations_tpu.models.icalstm import BiLSTM
+
+    _force_block(monkeypatch, Tb=2, granule=8)
+    S, B, T, D, H = 3, 4, 5, 4, 8
+    x, params, _ = _bidir_case(S * B, T, D, H, with_h0=False, seed=35)
+    x = x.reshape(S, B, T, D)
+    p_axis = None
+    if weights == "per-site":
+        scale = jnp.arange(1.0, S + 1.0) / S
+        params = jax.tree.map(
+            lambda a: a[None] * scale.reshape(S, *([1] * a.ndim)), params
+        )
+        p_axis = 0
+    module = BiLSTM(2 * H, use_pallas=True, time_pool="mean")
+    z = jnp.zeros((B, H))
+
+    def run(forward):
+        def f(x, params):
+            pooled, (hT, cT) = jax.vmap(forward, in_axes=(0, p_axis))(x, params)
+            return (
+                jnp.sum(pooled**2) + jnp.sum(jnp.sin(hT)) + jnp.sum(cT**2),
+                (pooled, hT, cT),
+            )
+
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(x, params)
+
+    (_, got), got_g = run(lambda xs, p: module.apply({"params": p}, xs))
+    (_, ref), ref_g = run(lambda xs, p: _scan_bidir_pool(xs, p, z, z))
+    _assert_trees_close(got, ref, atol=1e-5)
+    _assert_trees_close(got_g, ref_g, atol=1e-4)
+
+
+# -- lstm_block as a function ------------------------------------------------
+
+# the shapes the repo's configurations hand the kernels (D 256, H 174 a
+# direction): the benchmark's cells fold 32 sites x 16 rows; a site alone (the
+# per-site-weights path, a ring microbatch) brings 16, 8 or 4 rows; the ring
+# LSTM hands chunks of T / model_axis steps (98 / 2; 96 and 100 over 4)
+CONFIG_SHAPES = [
+    (98, 512), (98, 2048), (98, 128), (98, 16),
+    (49, 16), (49, 8), (25, 16), (24, 4), (24, 128),
+]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("T,rows", CONFIG_SHAPES)
+def test_lstm_block_stays_under_its_vmem_budget(T, rows, dtype):
+    from dinunet_implementations_tpu.ops import lstm_pallas
+
+    for D in (256, None):  # the forward call, the backward call
+        Tb, R = lstm_pallas.lstm_block(T, rows, D, 174, dtype)
+        assert 1 <= Tb <= min(T, lstm_pallas.MAX_BLOCK_STEPS)
+        assert rows % R == 0 and R % min(lstm_pallas.B_TILE, rows) == 0
+        assert lstm_pallas.block_vmem_bytes(Tb, R, D, 174, dtype) <= (
+            lstm_pallas.VMEM_BUDGET)
+        assert lstm_pallas.VMEM_BUDGET < 16 << 20  # the compiler's own scope
+
+
+def test_lstm_block_engages_at_the_benchmark_cells_shape():
+    """T 98, 512 folded rows, bfloat16: several timesteps a grid step in both
+    calls, with no step past T; the forward takes all four row granules."""
+    from dinunet_implementations_tpu.ops.lstm_pallas import lstm_block
+
+    Tb_f, R_f = lstm_block(98, 512, 256, 174, "bfloat16")
+    Tb_b, R_b = lstm_block(98, 512, None, 174, "bfloat16")
+    assert Tb_f > 1 and Tb_b > 1
+    assert 98 % Tb_f == 0 and 98 % Tb_b == 0
+    assert (R_f, R_b) == (512, 128)
+    assert (512 // R_f) * (98 // Tb_f) <= 392 // 4
+    assert (512 // R_b) * (98 // Tb_b) <= 392 // 4
+
+
+@pytest.mark.parametrize(
+    "T,rows,want_Tb",
+    [
+        (1, 512, 1),  # nothing to walk
+        (98, 3, 14),  # a row tile that is no whole sublane tile walks too
+        (13, 16, 13),  # a short prime T: one block, no tail
+        (97, 16, 14),  # a long prime T: seven blocks, ONE step past T
+        (98, 16, 14),
+    ],
+)
+def test_lstm_block_degrades_and_pads_as_documented(T, rows, want_Tb):
+    from dinunet_implementations_tpu.ops.lstm_pallas import lstm_block
+
+    for D in (256, None):
+        assert lstm_block(T, rows, D, 174, "bfloat16")[0] == want_Tb
+
+
+def test_lstm_block_override_is_checked_for_what_cannot_run():
+    from dinunet_implementations_tpu.ops.lstm_pallas import lstm_block
+
+    assert lstm_block(98, 512, 256, 174, "bfloat16", override=(5, 256)) == (5, 256)
+    for bad in [(0, 128), (99, 128), (7, 96), (7, 0)]:
+        with pytest.raises(ValueError, match="does not fit"):
+            lstm_block(98, 512, 256, 174, "bfloat16", override=bad)

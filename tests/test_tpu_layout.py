@@ -191,3 +191,45 @@ def test_grouped_products_lower_to_the_compilers_kernel(one_chip,
         jax.jit(jax.vmap(lambda x, w, s: jax.lax.ragged_dot(x, w, s))).lower(
             sds((2, rows, h)), sds((2, e, h, f)), sds((2, e), jnp.int32)
         ).compile()
+
+
+# -- the LSTM kernels' blocks against the chip's own limits (ISSUE 31) ---------
+
+
+@pytest.mark.parametrize(
+    "T,rows,dtype",
+    [
+        (98, 512, jnp.bfloat16),  # the benchmark's cells
+        (97, 512, jnp.bfloat16),  # a tail: one step past T in the last block
+        (98, 384, jnp.bfloat16),  # three row granules in one forward tile
+        (49, 16, jnp.bfloat16),  # a ring chunk of one site's rows
+        (98, 512, jnp.float32),  # float32 streams: twice the bytes a block
+        (98, 3, jnp.float32),  # rows that are no whole sublane tile
+    ],
+    ids=["cell", "tail", "three-granules", "ring-chunk", "float32", "odd-rows"],
+)
+def test_lstm_kernels_compile_with_the_block_lstm_block_chooses(
+        one_chip, no_compile_cache, monkeypatch, T, rows, dtype):
+    """What interpret mode cannot show: the chosen ``(Tb, R)`` fits the 16 MiB
+    the compiler scopes a kernel to (no ``vmem_limit_bytes`` is asked for) and
+    Mosaic takes the partial last time block and the one-timestep halo."""
+    from dinunet_implementations_tpu.ops import lstm_pallas
+
+    monkeypatch.setattr(lstm_pallas, "_interpret", lambda: False)
+    D, H = 256, 174
+    cdt = None if dtype == jnp.float32 else dtype
+    f32 = jnp.float32
+
+    def sds(shape, dt=f32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fwd = jax.jit(lambda *a: lstm_pallas._fwd_fused_call(*a, cdt)).lower(
+        sds((T, rows, D), dtype), sds((4, D, H)), sds((4, H)), sds((4, H, H)),
+        sds((rows, H)), sds((rows, H))).compile().as_text()
+    assert re.search(r"%[\w.]*lstm_fwd[\w.]* = .*tpu_custom_call", fwd)
+    s, c = sds((T, rows, H), dtype), sds((rows, H))
+    bwd = jax.jit(
+        lambda i, f, o, g, cs, w, c0, dhs, dhT, dcT: lstm_pallas._bwd_call(
+            (i, f, o, g), cs, w, c0, dhs, dhT, dcT, cdt)
+    ).lower(s, s, s, s, s, sds((4, H, H)), c, s, c, c).compile().as_text()
+    assert re.search(r"%[\w.]*lstm_bwd[\w.]* = .*tpu_custom_call", bwd)
