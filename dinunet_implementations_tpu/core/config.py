@@ -175,16 +175,29 @@ class MultimodalArgs:
 
 @dataclass
 class AFMoEArgs:
-    """AFMoE decoder as a federated next-token task (models/afmoe.py). The
+    """A mixture-of-experts decoder as a federated next-token task
+    (models/afmoe.py), of the type ``model_type`` names: ``afmoe`` (the
+    Trinity family) or ``glm4_moe_lite`` (latent attention, two pre-norms a
+    block, no embedding scale, multi-token prediction). The
     widths default to the published Trinity-Mini ``config.json`` (source:
     huggingface.co/arcee-ai/Trinity-Mini) under its own key names; what a
     configuration CUTS is the depth (``num_hidden_layers``,
     ``num_dense_layers``, ``layer_types``), the share of the routed experts
     held here (``experts_held`` of ``num_experts``, from ``first_expert``;
     the router keeps all ``num_experts`` outputs and ``num_experts_per_tok``)
-    and the share of the vocabulary (``vocab_rows`` of ``vocab_size``)."""
+    and the share of the vocabulary (``vocab_rows`` of ``vocab_size``).
+
+    A ``glm4_moe_lite`` configuration gives its ``config.json``'s sizes under
+    these names where the two families name one thing differently
+    (``n_routed_experts`` -> ``num_experts``, ``n_shared_experts`` ->
+    ``num_shared_experts``, ``first_k_dense_replace`` -> ``num_dense_layers``,
+    ``norm_topk_prob`` -> ``route_norm``, ``routed_scaling_factor`` ->
+    ``route_scale``) and the latent widths under its own; it reads neither
+    ``num_key_value_heads``, ``head_dim``, ``sliding_window`` nor
+    ``mup_enabled``, and every layer of it is ``full_attention``."""
 
     data_file: str = ""
+    model_type: str = "afmoe"  # or "glm4_moe_lite"
     seq_len: int = 8192  # a sample is seq_len + 1 token ids
     vocab_size: int = 200192
     vocab_rows: int = 0  # rows of the vocabulary held here; 0 = all
@@ -211,6 +224,18 @@ class AFMoEArgs:
     route_norm: bool = True
     route_scale: float = 2.826
     mup_enabled: bool = True
+    # glm4_moe_lite's published keys (0 = the type has none): the ranks of
+    # the query and key-value latents, a head's content and rotary widths
+    # (one rotary key a position, shared by the heads), the value width
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # prediction depths beyond the next token (0 or 1), and the weight of
+    # that depth's loss beside the next token's (the config gives none)
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # "bfloat16" = bf16 matmuls, f32 accumulation/norms/softmax/router/loss
     compute_dtype: str = ""
     q_block: int = 512  # query rows an attention block holds
@@ -690,7 +715,7 @@ COMPSPEC_META: dict[str, dict] = {
                             compspec_key="Multimodal-Classification_args"),
     "lm_args": dict(type="object", source="owner", group="Computation", order=29,
                     conditional=dict(variable="task_id", value="LM-NextToken"),
-                    label="AFMoE next-token language-model parameters.",
+                    label="Next-token language-model parameters (model_type afmoe | glm4_moe_lite).",
                     compspec_key="LM-NextToken_args"),
 }
 

@@ -1,5 +1,9 @@
-"""AFMoE decoder (the Trinity family's ``model_type: afmoe``) as a federated
-next-token task.
+"""Mixture-of-experts decoders as a federated next-token task: the Trinity
+family's ``model_type: afmoe`` and ``model_type: glm4_moe_lite`` (latent
+attention on every layer, one multi-token-prediction module). The two share
+the expert layer, the norms, the rotary term, the attention kernels, the
+blocked head and the loss; they differ in the attention's projections and in
+the block around them, which ``Dims.model_type`` selects.
 
 One chip's SHARE of the model: the expert layer is told which routed experts
 it holds (``experts_held`` of ``num_experts``, from ``first_expert``), routes
@@ -8,9 +12,9 @@ the result; the embedding and the head hold ``vocab_rows`` rows of the
 vocabulary. What the absent experts would add is left out (the exchange that
 would bring it lives on other chips); nothing here stands in for them.
 
-The block (x: ``[T, hidden]`` float32 residual stream; matmuls in
-``compute_dtype`` with float32 accumulation; norms, softmax, router, rotary
-angles, the residual and the loss in float32):
+TRINITY'S block, ``afmoe`` (x: ``[T, hidden]`` float32 residual stream;
+matmuls in ``compute_dtype`` with float32 accumulation; norms, softmax,
+router, rotary angles, the residual and the loss in float32):
 
 - ``h0 = E[tok] * sqrt(hidden)`` (``mup_enabled``);
 - attention, every layer: ``a = RMSNorm(h)``; ``q, k, v = a Wq, a Wk, a Wv``;
@@ -28,6 +32,31 @@ angles, the residual and the loss in float32):
 - ``logits = RMSNorm(h) W_head``; the loss is the mean over the sequence's
   positions of the cross-entropy against the next token.
 
+THE OTHER TYPE'S block, ``glm4_moe_lite`` (DeepSeek-V2/V3's equations, which
+this ``model_type`` reuses; same precisions). Of the above it keeps the MoE
+layer's equations, the dense MLP, the head and the loss, and replaces the rest:
+
+- ``h0 = E[tok]`` (no scale); two pre-norms a block and no post-norm:
+  ``h <- h + Attn(RMSNorm(h))``, ``h <- h + FFN(RMSNorm(h))``;
+- latent attention (MLA), every layer, ``a = RMSNorm(h)``: ``c_q = RMSNorm(a
+  W_qa)`` (``q_lora_rank``); ``q = c_q W_qb`` as heads of ``qk_nope_head_dim
+  + qk_rope_head_dim``, split ``q_nope | q_rope``; ``[c_kv | k_r] = a W_kva``
+  (``kv_lora_rank | qk_rope_head_dim``); ``c_kv <- RMSNorm(c_kv)``; ``[k_nope
+  | v] = c_kv W_kvb`` as heads of ``qk_nope_head_dim | v_head_dim``; rotary
+  positions on ``q_rope`` of every head and on ``k_r``, which all heads
+  SHARE; ``k = [k_nope | k_r]``; scores ``q k^T / sqrt(qk_nope_head_dim +
+  qk_rope_head_dim)``, causal, softmax; ``o = P v``; ``out = o W_o``. No gate,
+  no QK-norm, no window: every layer is full causal attention WITH a
+  positional term. The up-projected form only (training): the absorbed form
+  and a latent cache are a serving path's, which this task has not;
+- multi-token prediction, depth 1 (DeepSeek-V3 section 2.2;
+  ``num_nextn_predict_layers`` 1): ``h'_i = [RMSNorm_e(E[t_{i+1}]) ;
+  RMSNorm_h(h_i)] W_eh`` with ``h`` the last block's output before the final
+  norm, one more MoE block, then the main model's head form with the module's
+  own norm and the SHARED ``W_head`` and ``E``, predicting ``t_{i+2}``; the
+  training loss is ``L_main + mtp_loss_weight * L_mtp``, ``L_mtp`` the mean
+  over the ``T - 1`` positions that have such a target.
+
 Attention never builds a ``[T, T]`` tensor. On a TPU, at sequences of whole
 kernel blocks, it is jax's splash-attention Pallas kernels (block-sparse flash
 attention: a masked block is never visited); elsewhere it is XLA query blocks:
@@ -42,7 +71,9 @@ recomputes the rest: the routed experts' output (their backward pass runs
 their forward itself, chunk by chunk); where attention is the kernels, the
 forward kernel's output and log-sum-exp, which are all of its result that the
 backward kernels read, so the kernel runs once a layer a round; and the raw
-key and value projections. The kernels' own inputs (``q``, ``k``, ``v`` after
+key and value projections (of latent attention: the raw latent ``[c_kv |
+k_r]``, a ninth of its keys' and values' width, from which the up-projection
+is recomputed). The kernels' own inputs (``q``, ``k``, ``v`` after
 QK-norm and rotary) are not kept: the norms' and rotary's backward passes need
 the raw projections anyway, and from those the inputs are elementwise work.
 The raw query projection, eight times a key's size, is recomputed too: kept,
@@ -66,6 +97,8 @@ from .layers import compute_dtype_of
 
 SLIDING = "sliding_attention"
 FULL = "full_attention"
+AFMOE = "afmoe"  # Dims.model_type: Trinity's block
+GLM4_MOE_LITE = "glm4_moe_lite"  # latent attention, two pre-norms, MTP
 
 
 def _init(std: float = 0.02):
@@ -229,6 +262,15 @@ def kernel_attention(q, k, v, window, block: int = KERNEL_BLOCK, cdt=None):
     return jnp.moveaxis(out, 3, 1).reshape(B, T, N, D).astype(jnp.float32)
 
 
+def masked_attention(q, k, v, window, q_block: int, kv_chunk: int, cdt):
+    """The kernels on a TPU at sequences of whole kernel blocks, the XLA
+    blocks elsewhere."""
+    T = q.shape[1]
+    if _auto_pallas() and T % min(KERNEL_BLOCK, T) == 0 and T >= 128:
+        return kernel_attention(q, k, v, window, cdt=cdt)
+    return blocked_attention(q, k, v, window, q_block, kv_chunk, cdt)
+
+
 # -- the routed experts ------------------------------------------------------
 #
 # ``jax.lax.ragged_dot`` is the grouped product (on a TPU the compiler turns it
@@ -278,9 +320,10 @@ def _plan(sel, first_expert: int, held: int):
 ROW_CHUNK = 8192  # sorted assignment rows the expert layer holds at a time
 ROUTED_OUT = "routed_experts_out"
 KV_PROJ = "attention_kv_proj"  # a layer's raw key and value projections
+MLA_LATENT = "attention_mla_latent"  # latent attention's raw [c_kv | k_r]
 # what a block's checkpoint keeps; everything else is recomputed
 BLOCK_KEEPS = jax.checkpoint_policies.save_only_these_names(
-    ROUTED_OUT, ATTN_OUT, KV_PROJ)
+    ROUTED_OUT, ATTN_OUT, KV_PROJ, MLA_LATENT)
 
 
 def _row_chunk(rows: int) -> int:
@@ -538,14 +581,61 @@ class Attention(nn.Module):
         if self.window is not None:
             pos = jnp.arange(T)
             q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
-        if _auto_pallas() and T % min(KERNEL_BLOCK, T) == 0 and T >= 128:
-            o = kernel_attention(q, k, v, self.window, cdt=cdt)
-        else:
-            o = blocked_attention(q, k, v, self.window, self.q_block,
-                                  self.kv_chunk, cdt)
+        o = masked_attention(q, k, v, self.window, self.q_block,
+                             self.kv_chunk, cdt)
         o = o.reshape(B, T, N * D)
         o = o * jax.nn.sigmoid(_mm(a, wg, cdt))
         return _mm(o, wo, cdt)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) in its up-projected form: queries,
+    keys and values come out of low-rank latents, and the keys' rotary slice
+    is one vector a position that every head shares. One query head a
+    key-value head, all of one width (``qk_nope_head_dim + qk_rope_head_dim
+    == v_head_dim``: the attention paths carry one head width)."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    eps: float
+    q_block: int
+    kv_chunk: int
+    compute_dtype: str | None = None
+
+    @nn.compact
+    def __call__(self, a):
+        cdt = compute_dtype_of(self.compute_dtype)
+        B, T, H = a.shape
+        N, rq, rkv = self.num_heads, self.q_lora_rank, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        wq_a = self.param("wq_a", _init(), (H, rq))
+        wq_b = self.param("wq_b", _init(), (rq, N * (dn + dr)))
+        wkv_a = self.param("wkv_a", _init(), (H, rkv + dr))
+        wkv_b = self.param("wkv_b", _init(), (rkv, N * (dn + dv)))
+        wo = self.param("wo", _init(), (N * dv, H))
+        with jax.named_scope(scopes.MLA_LATENT):
+            c_q = RMSNorm(self.eps, name="q_a_norm")(_mm(a, wq_a, cdt))
+            q = _mm(c_q, wq_b, cdt).reshape(B, T, N, dn + dr)
+            # the raw latent is kept across the block's recomputation: 576
+            # columns for the 10,240 of the keys and values it expands to
+            latent = checkpoint_name(_mm(a, wkv_a, cdt), MLA_LATENT)
+            c_kv = RMSNorm(self.eps, name="kv_a_norm")(latent[..., :rkv])
+            kv = _mm(c_kv, wkv_b, cdt).reshape(B, T, N, dn + dv)
+        pos = jnp.arange(T)
+        q_r = rotary(q[..., dn:], pos, self.rope_theta)
+        k_r = rotary(latent[..., None, rkv:], pos, self.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_r], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (B, T, N, dr))], axis=-1)
+        o = masked_attention(q, k, kv[..., dn:], None, self.q_block,
+                             self.kv_chunk, cdt)
+        return _mm(o.reshape(B, T, N * dv), wo, cdt)
 
 
 class MoE(nn.Module):
@@ -625,6 +715,16 @@ class Dims:
     compute_dtype: str | None = None
     q_block: int = 512  # query rows an attention block holds (XLA path)
     kv_chunk: int = 2048  # step in which a full layer's key prefix grows
+    model_type: str = AFMOE
+    # latent attention's widths and the prediction depths beyond the next
+    # token (model_type glm4_moe_lite; 0 = the type has none)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
 
 class Block(nn.Module):
@@ -634,17 +734,35 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, h):
         c = self.dims
-        sliding = c.layer_types[self.layer] == SLIDING
-        a = RMSNorm(c.rms_norm_eps, name="input_norm")(h)
-        with jax.named_scope(
-                scopes.ATTENTION_WINDOW if sliding else scopes.ATTENTION_FULL):
-            o = Attention(
-                c.num_attention_heads, c.num_key_value_heads, c.head_dim,
-                c.sliding_window if sliding else None, c.rope_theta,
-                c.rms_norm_eps, c.q_block, c.kv_chunk, c.compute_dtype,
-                name="attn")(a)
-        h = h + RMSNorm(c.rms_norm_eps, name="post_attn_norm")(o)
-        m = RMSNorm(c.rms_norm_eps, name="pre_mlp_norm")(h)
+        latent = c.model_type == GLM4_MOE_LITE
+
+        def norm(name):
+            return RMSNorm(c.rms_norm_eps, name=name)
+
+        def branch(name, y):
+            # Trinity's block norms a branch's output too, inside the
+            # residual; the other type has the two pre-norms only
+            return y if latent else norm(name)(y)
+
+        a = norm("input_norm")(h)
+        if latent:
+            with jax.named_scope(scopes.ATTENTION_MLA):
+                o = LatentAttention(
+                    c.num_attention_heads, c.q_lora_rank, c.kv_lora_rank,
+                    c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
+                    c.rope_theta, c.rms_norm_eps, c.q_block, c.kv_chunk,
+                    c.compute_dtype, name="attn")(a)
+        else:
+            sliding = c.layer_types[self.layer] == SLIDING
+            with jax.named_scope(scopes.ATTENTION_WINDOW if sliding
+                                 else scopes.ATTENTION_FULL):
+                o = Attention(
+                    c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                    c.sliding_window if sliding else None, c.rope_theta,
+                    c.rms_norm_eps, c.q_block, c.kv_chunk, c.compute_dtype,
+                    name="attn")(a)
+        h = h + branch("post_attn_norm", o)
+        m = norm("pre_mlp_norm")(h)
         if self.layer < c.num_dense_layers:
             y = SwiGLU(c.intermediate_size, c.compute_dtype, name="mlp")(m)
         else:
@@ -653,7 +771,29 @@ class Block(nn.Module):
                     c.moe_intermediate_size * c.num_shared_experts,
                     c.route_norm, c.route_scale, c.compute_dtype,
                     name="moe")(m)
-        return h + RMSNorm(c.rms_norm_eps, name="post_mlp_norm")(y)
+        return h + branch("post_mlp_norm", y)
+
+
+class NextDepth(nn.Module):
+    """One multi-token-prediction module: from the hidden states ``h [B, T,
+    hidden]`` of the depth before and the embeddings ``e`` of the tokens one
+    position on, the hidden states that predict the token after those, and
+    the scale of the module's own norm before the (shared) head."""
+
+    dims: Dims
+
+    @nn.compact
+    def __call__(self, h, e):
+        c = self.dims
+        both = jnp.concatenate([RMSNorm(c.rms_norm_eps, name="enorm")(e),
+                                RMSNorm(c.rms_norm_eps, name="hnorm")(h)], -1)
+        eh = self.param("eh_proj", _init(), (both.shape[-1], h.shape[-1]))
+        # an expert block of the model's kind: its index is past the dense
+        # layers
+        block = nn.remat(Block, policy=BLOCK_KEEPS)(
+            c, max(len(c.layer_types), c.num_dense_layers), name="block")
+        out = block(_mm(both, eh, compute_dtype_of(c.compute_dtype)))
+        return out, self.param("norm", nn.initializers.ones, (h.shape[-1],))
 
 
 def _head_logits(h, norm_scale, head, eps, cdt):
@@ -661,10 +801,11 @@ def _head_logits(h, norm_scale, head, eps, cdt):
 
 
 class AFMoE(nn.Module):
-    """The decoder. A sample is ``seq_len + 1`` token ids: the model reads the
-    first ``seq_len``, the loss the last ``seq_len``. ``__call__`` returns
-    whole logits (inference, tests); training goes through
-    :meth:`task_loss`, which the trainer's ``FederatedTask`` finds."""
+    """The decoder, of either type (``dims.model_type``). A sample is
+    ``seq_len + 1`` token ids: the model reads the first ``seq_len``, the loss
+    the last ``seq_len``. ``__call__`` returns whole logits of the next-token
+    depth (inference, tests); training goes through :meth:`task_loss`, which
+    the trainer's ``FederatedTask`` finds."""
 
     dims: Dims = Dims()
     vocab_rows: int = 200192
@@ -688,6 +829,8 @@ class AFMoE(nn.Module):
             "final_norm", nn.initializers.ones, (d.hidden_size,))
         self.lm_head = self.param(
             "lm_head", _init(), (d.hidden_size, self.vocab_rows))
+        if d.num_nextn_predict_layers:
+            self.mtp = NextDepth(d, name="mtp")
 
     def init(self, rngs, *args, **kwargs):
         """``nn.Module.init`` under one ``jax.jit``: op by op, half a billion
@@ -702,17 +845,23 @@ class AFMoE(nn.Module):
             # no parameter's shape depends on the sequence length, and init
             # runs op by op: a handful of positions declares everything
             tokens = tokens[:, : self.init_tokens]
+        h = self._embed(tokens)
+        for block in self.blocks:
+            h = block(h)
+        if self.is_initializing() and self.dims.num_nextn_predict_layers:
+            self.mtp(h, h)  # the second depth's parameters, by shape
+        return h
+
+    def _embed(self, tokens):
         h = jnp.take(self.embed, tokens, axis=0)
         if self.mup_enabled:
             h = h * math.sqrt(self.dims.hidden_size)
-        for block in self.blocks:
-            h = block(h)
         return h
 
-    def _logits_fn(self):
+    def _logits_fn(self, norm_scale=None):
         return functools.partial(
-            _head_logits, norm_scale=self.final_norm, head=self.lm_head,
-            eps=self.dims.rms_norm_eps,
+            _head_logits, head=self.lm_head, eps=self.dims.rms_norm_eps,
+            norm_scale=self.final_norm if norm_scale is None else norm_scale,
             cdt=compute_dtype_of(self.dims.compute_dtype))
 
     def __call__(self, x, train: bool = True, mask=None):
@@ -722,32 +871,54 @@ class AFMoE(nn.Module):
         with jax.named_scope(scopes.LM_HEAD):
             return self._logits_fn()(h)
 
-    def token_losses(self, x):
-        """Mean next-token cross-entropy of each row, ``[B]`` float32, the
-        head and the softmax over ``loss_block`` positions at a time."""
-        tokens = x.astype(jnp.int32)
-        h = self.hidden(tokens[:, :-1])
-        targets = tokens[:, 1:]
+    def _summed_nll(self, h, targets, logits_of, counts=None):
+        """Cross-entropy of ``h [B, T, H]`` against ``targets [B, T]`` summed
+        over the positions (those where ``counts [B, T]`` holds, if given),
+        ``[B]`` float32, the head and the softmax over ``loss_block``
+        positions at a time."""
         B, T, H = h.shape
         lb = min(self.loss_block, T)
         if T % lb:
             raise ValueError(f"sequence {T} is not a multiple of loss_block {lb}")
-        logits_of = self._logits_fn()
 
         @jax.checkpoint
         def block_nll(args):
-            hb, tb = args  # [B, lb, H], [B, lb]
+            hb, tb, *cb = args  # [B, lb, H], [B, lb]
             logits = logits_of(hb)  # [B, lb, V] float32
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
-            return (lse - picked).sum(axis=-1)
+            nll = lse - picked
+            if cb:
+                nll = jnp.where(cb[0], nll, 0.0)
+            return nll.sum(axis=-1)
 
         def blocks(a):
             return jnp.moveaxis(a.reshape((B, T // lb, lb) + a.shape[2:]), 1, 0)
 
+        parts = (h, targets) if counts is None else (h, targets, counts)
         with jax.named_scope(scopes.LM_HEAD):
-            nll = jax.lax.map(block_nll, (blocks(h), blocks(targets)))
-        return nll.sum(axis=0) / T
+            nll = jax.lax.map(block_nll, tuple(blocks(a) for a in parts))
+        return nll.sum(axis=0)
+
+    def token_losses(self, x):
+        """The training loss of each row, ``[B]`` float32: the mean next-token
+        cross-entropy and, where the model has a second prediction depth,
+        ``mtp_loss_weight`` times that depth's mean."""
+        tokens = x.astype(jnp.int32)
+        h = self.hidden(tokens[:, :-1])
+        T = h.shape[1]
+        loss = self._summed_nll(h, tokens[:, 1:], self._logits_fn()) / T
+        if self.dims.num_nextn_predict_layers:
+            with jax.named_scope(scopes.MTP):
+                # position i reads h_i and E[t_{i+1}] and predicts t_{i+2};
+                # the last position has no such target and does not count
+                h2, norm2 = self.mtp(h, self._embed(tokens[:, 1:]))
+                targets = jnp.pad(tokens[:, 2:], ((0, 0), (0, 1)))
+                counts = jnp.broadcast_to(jnp.arange(T) < T - 1, targets.shape)
+                deeper = self._summed_nll(
+                    h2, targets, self._logits_fn(norm2), counts) / max(T - 1, 1)
+            loss = loss + self.dims.mtp_loss_weight * deeper
+        return loss
 
     def task_loss(self, variables, x, w):
         """The task's training loss for ``FederatedTask``: rows weighted by

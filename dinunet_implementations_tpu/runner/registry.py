@@ -20,7 +20,7 @@ from ..data.ica import ICADataHandle, ICADataset
 from ..data.multimodal import MultimodalDataHandle, MultimodalDataset
 from ..data.smri import SMRIDataHandle, SMRIDataset
 from ..data.tokens import TokenDataHandle, TokenDataset
-from ..models.afmoe import FULL, SLIDING, AFMoE, Dims
+from ..models.afmoe import AFMOE, FULL, GLM4_MOE_LITE, SLIDING, AFMoE, Dims
 from ..models.cnn3d import SMRI3DNet
 from ..models.icalstm import ICALstm
 from ..models.msannet import MSANNet
@@ -138,9 +138,12 @@ def _build_multimodal(cfg: TrainConfig):
 
 def afmoe_layer_types(a) -> tuple:
     """One attention kind a layer: ``layer_types`` as given, else the
-    published period (a full layer every ``global_attn_every_n_layers``-th)."""
+    published period (a full layer every ``global_attn_every_n_layers``-th;
+    latent attention has no window: every layer full)."""
     if a.layer_types:
         return tuple(a.layer_types)
+    if a.model_type == GLM4_MOE_LITE:
+        return (FULL,) * a.num_hidden_layers
     n = a.global_attn_every_n_layers
     return tuple(FULL if (i + 1) % n == 0 else SLIDING
                  for i in range(a.num_hidden_layers))
@@ -158,6 +161,26 @@ def _build_afmoe(cfg: TrainConfig):
         raise ValueError(
             f"experts {a.first_expert}..{a.first_expert + held - 1} are not "
             f"among the model's {a.num_experts}")
+    latent = a.model_type == GLM4_MOE_LITE
+    if not latent and a.model_type != AFMOE:
+        raise ValueError(
+            f"model_type {a.model_type!r} is neither {AFMOE!r} nor "
+            f"{GLM4_MOE_LITE!r}")
+    if latent:
+        widths = (a.q_lora_rank, a.kv_lora_rank, a.qk_nope_head_dim,
+                  a.qk_rope_head_dim, a.v_head_dim)
+        if min(widths) <= 0 or set(layer_types) != {FULL}:
+            raise ValueError(
+                f"{GLM4_MOE_LITE} needs its five latent widths (got "
+                f"{widths}) and full attention on every layer")
+        if a.qk_nope_head_dim + a.qk_rope_head_dim != a.v_head_dim:
+            raise ValueError(
+                "the attention paths carry one head width: qk_nope_head_dim "
+                "+ qk_rope_head_dim must equal v_head_dim")
+    if a.num_nextn_predict_layers not in ((0, 1) if latent else (0,)):
+        raise ValueError(
+            f"num_nextn_predict_layers {a.num_nextn_predict_layers}: one "
+            f"multi-token-prediction module, and only of {GLM4_MOE_LITE}")
     # Dims reads AFMoEArgs' own field names; what differs is resolved here
     resolved = dict(
         experts_held=held, layer_types=layer_types,
@@ -169,7 +192,7 @@ def _build_afmoe(cfg: TrainConfig):
             for f in dataclasses.fields(Dims)
         }),
         vocab_rows=a.vocab_rows or a.vocab_size,
-        mup_enabled=a.mup_enabled,
+        mup_enabled=a.mup_enabled and not latent,
         loss_block=a.loss_block,
     )
 

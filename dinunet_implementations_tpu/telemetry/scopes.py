@@ -19,3 +19,7 @@ MOE_ROUTE = "model/moe_route"  # router scores, top-k, weights
 MOE_EXPERTS = "model/moe_experts"  # sort, grouped products, combine
 MOE_SHARED = "model/moe_shared"  # the shared expert
 LM_HEAD = "model/lm_head"  # final norm, head, softmax, in sequence blocks
+ATTENTION_MLA = "model/attention_mla"  # a latent-attention layer's attention
+# nests in it: the low-rank down- and up-projections and the latent norms
+MLA_LATENT = "model/mla_latent"
+MTP = "model/mtp"  # the second prediction depth: projection, block, head

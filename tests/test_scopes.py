@@ -1,7 +1,8 @@
 """Device scopes and kernel names (telemetry/scopes.py, ops/lstm_pallas.py).
 
 The epoch program names its own parts: five ``jax.named_scope``s and two
-Pallas kernel names. They are metadata: (a) every scope that applies is in
+Pallas kernel names (and, of the next-token model with latent attention, three
+scopes inside ``model/fwd_bwd`` and the attention kernels' three names). They are metadata: (a) every scope that applies is in
 the lowered program's debug info, (b) with the scopes taken away the
 lowering is the same program, (c) every ``pl.pallas_call`` of the LSTM
 kernels passes a distinct ``name=`` from the file's constants, (d) every
@@ -17,6 +18,7 @@ import pathlib
 import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from dinunet_implementations_tpu.checks.lowering import diff_report
@@ -134,21 +136,27 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 PACKAGE = REPO / "dinunet_implementations_tpu"
 
 
-@pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES)
-def test_every_kernel_name_is_read_by_a_metric_of_the_benchmark(name):
-    """A kernel no metric file reads is a kernel no cell can judge: its
-    metric's pattern holds the name as it stands and its ``what`` names the
-    constant, so a new kernel comes with its metric or not at all."""
-    constant = next(k for k, v in vars(lstm_pallas).items()
+def _metric_files_reading(module, name: str, glob: str = "*.json") -> list[str]:
+    """The metric files whose pattern holds the kernel name ``name`` as it
+    stands; each one's ``what`` must name ``module``'s constant for it."""
+    constant = next(k for k, v in vars(module).items()
                     if k.isupper() and v == name)
     readers = []
-    for path in sorted((REPO / "benchmarks" / "layer_metrics").glob("*.json")):
+    for path in sorted((REPO / "benchmarks" / "layer_metrics").glob(glob)):
         metric = json.loads(path.read_text())
         pattern = str(metric.get("args", {}).get("pattern", ""))
         if _whole_word(name).search(pattern):
             assert re.search(rf"\b{constant}\b", metric["what"]), path.name
             readers.append(path.name)
-    assert readers, f"no benchmarks/layer_metrics/*.json reads {constant}"
+    return readers
+
+
+@pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES)
+def test_every_kernel_name_is_read_by_a_metric_of_the_benchmark(name):
+    """A kernel no metric file reads is a kernel no cell can judge: its
+    metric's pattern holds the name as it stands and its ``what`` names the
+    constant, so a new kernel comes with its metric or not at all."""
+    assert _metric_files_reading(lstm_pallas, name), name
 
 
 def test_every_ops_module_is_imported_by_a_model_or_an_engine():
@@ -164,3 +172,77 @@ def test_every_ops_module_is_imported_by_a_model_or_an_engine():
                     imported.add(parts[parts.index("ops") + 1])
     modules = {p.stem for p in (PACKAGE / "ops").glob("*.py")} - {"__init__"}
     assert modules and modules <= imported, sorted(modules - imported)
+
+
+# -- the next-token model with latent attention (ISSUE 32) ----------------------
+
+LATENT_SCOPES = ("ATTENTION_MLA", "MLA_LATENT", "MTP")
+def _latent_lowered_text(debug_info: bool) -> str:
+    """The lowered epoch program of a small latent-attention model with its
+    second prediction depth: 2 sites folded, dSGD, the device pipeline."""
+    from test_glm4_moe_lite import TOY
+
+    from dinunet_implementations_tpu.core.config import NNComputation, TrainConfig
+    from dinunet_implementations_tpu.runner.registry import get_task
+    from dinunet_implementations_tpu.trainer.loop import FederatedTrainer
+
+    cfg = TrainConfig(task_id=NNComputation.TASK_LM, num_sites=2,
+                      batch_size=1).with_overrides(
+                          {"lm_args": {**TOY, "num_nextn_predict_layers": 1}})
+    trainer = FederatedTrainer(cfg, get_task(cfg.task_id).build_model(cfg), None)
+    state = trainer.init_state(jnp.ones((1, 33), jnp.int32), num_sites=2)
+    return trainer.epoch_fn.lower(
+        state, jnp.zeros((2, 3, 33), jnp.int32), jnp.zeros((2, 3), jnp.int32),
+        jnp.zeros((2, 2, 1), jnp.int32), None, None, None, None,
+    ).as_text(debug_info=debug_info)
+
+
+@pytest.fixture(scope="module")
+def latent_lowered():
+    return _latent_lowered_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", LATENT_SCOPES)
+def test_latent_scope_is_in_the_lowered_epoch_program(latent_lowered, scope):
+    name, inside = getattr(scopes, scope), re.escape(scopes.MODEL) + r"\)*/.*"
+    assert name.startswith("model/")
+    if scope == "MLA_LATENT":  # nests in the layer's attention scope
+        inside += re.escape(scopes.ATTENTION_MLA) + r"\)*/[^\"]*"
+    assert re.search(r"(?<![A-Za-z0-9_])" + inside + re.escape(name)
+                     + r"(?![A-Za-z0-9_])", latent_lowered), name
+    if scope == "MTP":  # the second depth wears the block's scopes inside
+        assert re.search(re.escape(name) + r"\)*/.*"
+                         + re.escape(scopes.ATTENTION_MLA), latent_lowered)
+        assert re.search(re.escape(name) + r"\)*/.*"
+                         + re.escape(scopes.LM_HEAD), latent_lowered)
+
+
+def test_latent_scopes_change_metadata_and_nothing_else(monkeypatch):
+    scoped = _latent_lowered_text(debug_info=False)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _latent_lowered_text(debug_info=True)
+    assert not _has_scope(plain, scopes.ATTENTION_MLA)  # the patch reached it
+    assert diff_report(plain, scoped, "no-scopes", "scoped") is None
+
+
+def test_every_kernel_latent_attention_launches_is_read_by_a_new_metric():
+    """The kernels latent attention launches are the three splash-attention
+    calls: each name is read, as a whole word, by metric files of the new
+    cell (``mla_attention_*``), whose ``what`` names the constant."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    names = (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV)
+    for name in names:
+        mine = _metric_files_reading(afmoe, name, "mla_attention_*.json")
+        assert f"mla_attention_{name.rsplit('_', 1)[1]}_kernel_ms_per_round.json" in mine
+        for other in names:  # told from the others as a whole word
+            hit = _whole_word(name).search(f"%{other}_residuals.105") is not None
+            assert hit == (other == name)
+    both = json.loads((REPO / "benchmarks" / "layer_metrics"
+                       / "mla_attention_kernel_roofline.json").read_text())
+    assert all(re.search(both["args"]["pattern"],
+                         f'%{n}_no_residuals.7 = x custom_call_target="tpu_custom_call"')
+               for n in names)
+    assert all(re.search(rf"\b{c}\b", both["what"])
+               for c in ("ATTN_FWD", "ATTN_DQ", "ATTN_DKV"))

@@ -164,6 +164,29 @@ def test_attention_kernels_compile_for_the_chip_under_the_site_fold(
         afmoe._splash.cache_clear()
 
 
+def test_attention_kernels_compile_at_latent_attentions_shape(
+        one_chip, no_compile_cache, monkeypatch):
+    """The same kernels as GLM-4.7-Flash's latent attention calls them (ISSUE
+    32): 20 key-value heads with ONE query head each at head width 256, full
+    causal attention over 8,192 positions in 512-row blocks, under the
+    trainer's vmap over two sites and its gradient."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    monkeypatch.setattr(afmoe, "_interpret", lambda: False)
+    afmoe._splash.cache_clear()
+    t, n, d = 8192, 20, 256
+    x = jax.ShapeDtypeStruct((2, 1, t, n, d), jnp.bfloat16, sharding=one_chip)
+    try:
+        text = jax.jit(jax.vmap(jax.grad(
+            lambda q, k, v: afmoe.kernel_attention(q, k, v, None).sum(),
+            argnums=(0, 1, 2)))).lower(x, x, x).compile().as_text()
+        for name in (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV):
+            assert re.search(r"%[\w.]*" + name + r"[\w.]* = .*tpu_custom_call",
+                             text), name
+    finally:
+        afmoe._splash.cache_clear()
+
+
 def test_grouped_products_lower_to_the_compilers_kernel(one_chip,
                                                          no_compile_cache):
     """``jax.lax.ragged_dot`` (rows by group) and its row-contracting form
