@@ -210,7 +210,7 @@ def blocked_attention(q, k, v, window, q_block: int, kv_chunk: int, cdt=None):
 ATTN_FWD = "splash_mqa_fwd"  # forward, keeps the log-sum-exp for the backward
 ATTN_DQ = "splash_mqa_dq"  # backward: the queries' cotangent
 ATTN_DKV = "splash_mqa_dkv"  # backward: the keys' and values' cotangents
-KERNEL_BLOCK = 512  # query and key rows a kernel block holds
+KERNEL_BLOCK = 512  # the granule: a sequence the kernels take is whole such blocks
 # the forward kernel's output and log-sum-exp, as a block's checkpoint knows
 # them (the XLA path has no such value: its blocks are recomputed)
 ATTN_OUT = "attention_kernel_out"
@@ -224,8 +224,127 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
+# The kernels' block geometry: what was measured on a v5e (PERF.md §6, PR 33;
+# ``scripts/kernel_tune.py --attention`` is the sweep). A kernel's time is
+# within 4 % of ``steps * 0.4..0.75 us + blocks_computed * c(kernel, d)``: a
+# grid step has a fixed cost, and a visited block is computed WHOLE however
+# much of it the mask leaves. So blocks grow while the mask's band is wide
+# beside them, and stay one granule where it is not.
+MAX_EDGE = 1024  # 2,048-row blocks never won, and seldom fit the scope
+BAND_EDGES = 4  # a block edge is at most this share of the band the mask leaves
+COMPUTE_BLOCK = 512  # key columns a kernel works on at a time inside its key block
+VMEM_BUDGET = 15 * 2 ** 20  # of the 16 MiB scope; no vmem_limit_bytes (PERF.md §6, PR 31)
+
+
+ATTN_KERNELS = ("fwd", "dq", "dkv")
+
+
+def block_sizes(fwd, dq, dkv, **layouts):
+    """A splash-attention ``BlockSizes`` from each kernel's ``(block_q,
+    block_kv, compute)``; the dq kernel has no compute block."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    return sk.BlockSizes(
+        block_q=fwd[0], block_kv=fwd[1], block_kv_compute=fwd[2],
+        block_q_dkv=dkv[0], block_kv_dkv=dkv[1], block_kv_dkv_compute=dkv[2],
+        block_q_dq=dq[0], block_kv_dq=dq[1], **layouts)
+
+
+def kernel_blocks(sizes) -> dict:
+    """``{kernel: (block_q, block_kv, compute)}`` of a ``BlockSizes``: the
+    inverse of :func:`block_sizes` (dq's compute block reads its key block)."""
+    return {"fwd": (sizes.block_q, sizes.block_kv, sizes.block_kv_compute),
+            "dq": (sizes.block_q_dq, sizes.block_kv_dq, sizes.block_kv_dq),
+            "dkv": (sizes.block_q_dkv, sizes.block_kv_dkv,
+                    sizes.block_kv_dkv_compute)}
+
+
+def attention_vmem_bytes(kernel: str, block_q: int, block_kv: int,
+                         compute: int, head_dim: int, dtype) -> int:
+    """VMEM one grid step of the ``"fwd"``, ``"dq"`` or ``"dkv"`` kernel
+    holds: the blocks the pipeline moves, twice (its double buffer); the
+    accumulators that stay for a whole row of steps, once; and the float32
+    score tiles of the body. An upper bound fitted to what the TPU compiler
+    allocated or refused over step 0's sweep (bfloat16, widths 128 and 256):
+    it refuses every geometry the compiler refused, and a few it took."""
+    item = jnp.dtype(dtype).itemsize
+    q_rows = block_q * head_dim  # a [block_q, d] tile, in elements
+    kv_rows = block_kv * head_dim
+    lanes = block_q * 128 * 4  # a per-row statistic, lane-broadcast
+    sublanes = 8 * block_q * 4  # the same, sublane-broadcast
+    if kernel == "fwd":  # q, k, v, positions -> o, log-sum-exp; m, l, o_acc
+        moved = (2 * q_rows + 2 * kv_rows) * item + 2 * lanes
+        kept = 2 * lanes + 4 * q_rows
+        body = 4 * block_q * block_kv + 8 * q_rows  # the slices are unrolled
+    elif kernel == "dq":  # q, k, v, do, lse, di, positions -> dq; dq_acc
+        moved = (3 * q_rows + 2 * kv_rows) * item + 2 * sublanes + lanes
+        kept = 4 * q_rows
+        body = 6 * block_q * block_kv  # no compute block: the whole tile
+    elif kernel == "dkv":  # q, k, v, do, lse, di, positions -> dk, dv; accs
+        moved = (2 * q_rows + 4 * kv_rows) * item + 3 * sublanes
+        kept = 8 * kv_rows
+        body = 12 * block_q * compute
+    else:
+        raise ValueError(kernel)
+    return 2 * moved + kept + body
+
+
+def attention_blocks(t: int, heads_per_kv: int, head_dim: int,
+                     window: int | None, dtype, override=None):
+    """The splash-attention ``BlockSizes`` of one call: query block, key block
+    and compute block of the forward, the dq and the dkv kernel.
+
+    A pure function of what the call can see. The rules, each from step 0's
+    sweep of the three shapes the language-model cells run (PERF.md §6, PR 33):
+
+    - The granule is ``min(KERNEL_BLOCK, t)``; every block is a whole number
+      of granules that divides ``t``, so whatever ``masked_attention`` takes
+      runs (1,536 or 2,560 positions at 512, 128 at 128).
+    - An edge is at most ``band / BAND_EDGES``, the band being the window, or
+      ``t`` under the plain causal mask: a block is computed whole, so on a
+      2,048-wide window 1,024-blocks compute half as many pairs again as the
+      band holds and measured within 2 % of 512 either way, where over a
+      causal 8,192 they compute an eighth more and took 7-19 % off every
+      kernel (the steps fall from 136 to 36 a head).
+    - Each kernel takes the first of (1,024 x 1,024), (512 x 1,024), (512 x
+      512) (query x key: the long KEY block was second in every kernel) that
+      these allow and whose :func:`attention_vmem_bytes` is within
+      VMEM_BUDGET; head width and operand dtype enter here (float32 operands
+      at width 256 leave the forward 512 x 1,024).
+    - The compute block is COMPUTE_BLOCK: whole key blocks cost the forward
+      5-10 %, 256 columns cost it 22 % at width 128 with 512-blocks.
+
+    ``heads_per_kv`` decides nothing today: with eight query heads a
+    key-value head and with one, the kernels' gains followed the head width.
+
+    ``override`` is for tests and ``scripts/kernel_tune.py``: one edge for
+    every block, or a ``BlockSizes`` used as given.
+    """
+    del heads_per_kv
+    gran = min(KERNEL_BLOCK, t)
+    if isinstance(override, int):
+        return block_sizes(*[(min(override, t),) * 3] * 3)
+    if override is not None:
+        return override
+    band = t if window is None else min(window, t)
+    compute = min(COMPUTE_BLOCK, gran)
+    long = max((e for e in range(gran, MAX_EDGE + 1, gran)
+                if t % e == 0 and e * BAND_EDGES <= band), default=gran)
+
+    def blocks(kernel):
+        for bq, bkv in ((long, long), (gran, long)):
+            if attention_vmem_bytes(kernel, bq, bkv, compute, head_dim,
+                                    dtype) <= VMEM_BUDGET:
+                return bq, bkv, compute
+        return gran, gran, compute
+
+    return block_sizes(*map(blocks, ATTN_KERNELS))
+
+
 @functools.lru_cache(maxsize=None)
-def _splash(t: int, heads_per_kv: int, window: int | None, block: int,
+def _splash(t: int, heads_per_kv: int, window: int | None, sizes,
             interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
@@ -234,10 +353,6 @@ def _splash(t: int, heads_per_kv: int, window: int | None, block: int,
 
     one = (sm.CausalMask((t, t)) if window is None
            else sm.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
-    sizes = sk.BlockSizes(
-        block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        block_q_dq=block, block_kv_dq=block)
     kernel = sk.make_splash_mqa_single_device(
         sm.MultiHeadMask([one] * heads_per_kv), block_sizes=sizes,
         residual_checkpoint_name=ATTN_OUT, interpret=interpret)
@@ -247,15 +362,18 @@ def _splash(t: int, heads_per_kv: int, window: int | None, block: int,
     return kernel
 
 
-def kernel_attention(q, k, v, window, block: int = KERNEL_BLOCK, cdt=None):
+def kernel_attention(q, k, v, window, blocks=None, cdt=None):
     """:func:`blocked_attention`'s result from the splash-attention kernels:
-    every key-value head's group of query heads is one multi-query call."""
+    every key-value head's group of query heads is one multi-query call, in
+    the blocks :func:`attention_blocks` chooses (``blocks`` is its
+    ``override``)."""
     B, T, N, D = q.shape
     G = k.shape[2]
     if cdt is not None:
         q, k, v = q.astype(cdt), k.astype(cdt), v.astype(cdt)
     with jax.ensure_compile_time_eval():
-        kernel = _splash(T, N // G, window, min(block, T), _interpret())
+        sizes = attention_blocks(T, N // G, D, window, q.dtype, override=blocks)
+        kernel = _splash(T, N // G, window, sizes, _interpret())
     qh = jnp.moveaxis(q.reshape(B, T, G, N // G, D), 1, 3) * (D ** -0.5)
     out = jax.vmap(jax.vmap(kernel))(  # over sequences and key-value heads
         qh, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2))

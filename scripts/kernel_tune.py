@@ -1,19 +1,34 @@
-"""Sweep the block a grid step of the LSTM kernels carries, on the real chip.
+"""Sweep the block geometry of the Pallas kernels on the real chip.
 
-Times the forward call and the backward call ALONE at the benchmark's folded
-shape (T=98, rows=32 sites x 16 batch = 512, D=256, H=174, bf16 streams) for
-a list of ``(Tb, R)`` blocks, handed to the kernels through
+**LSTM (default).** Times the forward call and the backward call ALONE at the
+benchmark's folded shape (T=98, rows=32 sites x 16 batch = 512, D=256, H=174,
+bf16 streams) for a list of ``(Tb, R)`` blocks, handed to the kernels through
 ``lstm_pallas.lstm_block``'s ``override`` argument (the ``block=`` of
 ``_fwd_fused_call`` / ``_bwd_call``). ``auto`` is what ``lstm_block`` chooses
 itself. Every block's outputs are compared with the first block's.
 
-A call is timed inside ONE jitted ``fori_loop`` that feeds the call's carry
-outputs (``hT, cT`` / ``dh0, dc0``) back as its carry inputs, so the device
-runs the iterations back to back with no host in between; the marginal
-between a long and a short loop cancels what starting and ending one costs.
+**Attention (``--attention``).** Times the splash-attention forward, dq and
+dkv kernels ALONE, as ``afmoe.kernel_attention`` calls them under the
+trainer's vmap over two sites, at one of the shapes the language-model cells
+run (``--shape a|b|c`` or ``T,kv_heads,heads_per_kv,head_dim,window|none``),
+for a list of ``block_q x block_kv x compute`` geometries (``--blocks``;
+``auto`` is what ``afmoe.attention_blocks`` chooses itself, each kernel its
+own). The three kernels' block sizes are independent fields, so one geometry
+is given to all three and each is timed in its own loop (the dq kernel has no
+compute block: it is timed once a ``block_q x block_kv``). ``--layouts qkv``
+with each letter ``h`` (head width minor, the default) or ``s`` (sequence
+minor) sets the operand layouts. A strided sample of every point's outputs is
+compared with the first point's.
+
+A call is timed inside ONE jitted loop that feeds the call's outputs back into
+its inputs, so the device runs the iterations back to back with no host in
+between; the marginal between a long and a short loop cancels what starting
+and ending one costs.
 
 Usage: python scripts/kernel_tune.py [--blocks 1x128,7x256,auto]
            [--shape T,rows,D,H] [--dtype bfloat16|float32]
+       python scripts/kernel_tune.py --attention [--shape a]
+           [--blocks 512x512x512,1024x1024x512,auto] [--layouts hhh]
 """
 
 import functools
@@ -27,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dinunet_implementations_tpu.models import afmoe
 from dinunet_implementations_tpu.ops import lstm_pallas
 
 SHAPE = (98, 512, 256, 174)
@@ -116,10 +132,158 @@ def parse_block(text):
     return None if text == "auto" else tuple(int(v) for v in text.split("x"))
 
 
+# -- the attention kernels ------------------------------------------------------
+
+# (T, key-value heads, query heads a key-value head, head width, window)
+ATTN_SHAPES = {
+    "a": (8192, 20, 1, 256, None),  # latent attention: full causal, width 256
+    "b": (8192, 4, 8, 128, None),  # grouped-query attention, a full layer
+    "c": (8192, 4, 8, 128, 2048),  # the same, a sliding layer
+}
+ATTN_SIDES = (512, 1024, 2048)
+SITES = 2  # the trainer's fold
+ATTN_ITERS = 12
+
+
+def attention_grid(sides=ATTN_SIDES):
+    """``(block_q, block_kv, compute)`` over ``sides`` squared, the compute
+    block 256, 512 or the whole key block; the 512 point first."""
+    grid = [(bq, bkv, c) for bq in sides for bkv in sides
+            for c in sorted({256, 512, bkv}) if c <= bkv]
+    grid.remove((512, 512, 512))
+    return [(512, 512, 512)] + grid
+
+
+def attention_sizes(geometry, layouts="hhh"):
+    """One ``(block_q, block_kv, compute)`` for all three kernels; ``layouts``
+    gives the query's, keys' and values': ``h`` head width minor, ``s``
+    sequence minor."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    q, k, v = (sk.QKVLayout.SEQ_MINOR if ch == "s" else sk.QKVLayout.HEAD_DIM_MINOR
+               for ch in layouts)
+    return afmoe.block_sizes(geometry, geometry, geometry,
+                             q_layout=q, k_layout=k, v_layout=v)
+
+
+@functools.lru_cache(maxsize=1)
+def attention_inputs(shape, cdt):
+    t, g, n, d, _ = shape
+    rng = np.random.default_rng(0)
+
+    def arr(*dims):
+        return jnp.asarray(rng.normal(size=dims).astype(np.float32), cdt)
+
+    # [sites, sequences, key-value heads, (query heads,) T, d], as
+    # kernel_attention hands them to the kernel under the trainer's vmap
+    q = arr(SITES, 1, g, n, t, d) * (d ** -0.5)
+    return q, arr(SITES, 1, g, t, d), arr(SITES, 1, g, t, d), arr(SITES, 1, g, n, t, d)
+
+
+def attention_loop(shape, sizes, kernel):
+    """``run(q, k, v, do, n)``: ``n`` dependent calls of ONE kernel in one
+    device program, returning a strided sample of the last call's outputs.
+    The forward runs as the backward needs it (it writes the log-sum-exp too);
+    the backward's two kernels are told apart by what is used of the
+    cotangents: the compiler drops the call nobody reads. Their one forward
+    outside the loop falls out of the marginal."""
+    t, g, n, d, window = shape
+    fold = jax.vmap(jax.vmap(jax.vmap(
+        afmoe._splash(t, n, window, sizes, afmoe._interpret()))))
+
+    def sample(a):
+        return a[..., ::67, :].astype(jnp.float32)
+
+    def chain(call, x, n):
+        """``x`` moves by one element of the outputs a call: a real
+        dependence that costs nothing to write."""
+        first = (0,) * (x.ndim - 1)
+
+        def body(_, carry):
+            x, _ = carry
+            outs = call(x)
+            nudge = sum(o[first] for o in outs) * 1e-3
+            return x.at[first].add(nudge.astype(x.dtype)), tuple(map(sample, outs))
+
+        shapes = jax.eval_shape(lambda x: tuple(map(sample, call(x))), x)
+        zeros = tuple(jnp.zeros(s.shape, s.dtype) for s in shapes)
+        return jax.lax.fori_loop(0, n, body, (x, zeros))[1]
+
+    def run(q, k, v, do, n):
+        if kernel == "fwd":
+            return chain(lambda q: (jax.vjp(fold, q, k, v)[0],), q, n)
+        vjp = jax.vjp(fold, q, k, v)[1]
+        used = slice(0, 1) if kernel == "dq" else slice(1, 3)
+        return chain(lambda do: vjp(do)[used], do, n)
+
+    return jax.jit(run)
+
+
+def time_attention(shape, sizes, kernel, iters=ATTN_ITERS, repeats=3):
+    """``(ms a call, sample)`` of one kernel; the first line of the refusal
+    where the compiler refuses the geometry (VMEM): that is a result."""
+    args = attention_inputs(shape, jnp.bfloat16)
+    run = attention_loop(shape, sizes, kernel)
+
+    def wall(n):
+        best = float("inf")
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            got = jax.block_until_ready(run(*args, n))
+            best = min(best, time.perf_counter() - t0)
+        return best, got
+
+    try:
+        jax.block_until_ready(run(*args, 1))  # compile, warm
+    except Exception as e:
+        return str(e).strip().splitlines()[0][:200]
+    short = iters // 3
+    (full, got), (part, _) = wall(iters), wall(short)
+    return (full - part) / (iters - short) * 1e3, [np.asarray(a) for a in got]
+
+
+def attention_main(option, argv):
+    shape = option("--shape", "a")
+    shape = ATTN_SHAPES.get(shape) or tuple(
+        None if v == "none" else int(v) for v in shape.split(","))
+    t, g, n, d, window = shape
+    layouts = option("--layouts", "hhh")
+    points = attention_grid()
+    if "--blocks" in argv:
+        points = [None if p == "auto" else tuple(int(v) for v in p.split("x"))
+                  for p in option("--blocks", "").split(",")]
+    dev = jax.devices()[0]
+    print(f"device={dev.platform} {dev.device_kind}  T,kv_heads,heads_per_kv,d,window="
+          f"{shape} bfloat16 sites={SITES} layouts={layouts}", flush=True)
+    auto = afmoe.attention_blocks(t, n, d, window, jnp.bfloat16)
+    seen, first = {}, {}
+    for point in points:
+        sizes = auto if point is None else attention_sizes(point, layouts)
+        row = [f"{'auto' if point is None else 'x'.join(map(str, point)):>14}:"]
+        for kernel, geometry in afmoe.kernel_blocks(sizes).items():
+            key = (kernel, geometry)
+            if key not in seen:
+                seen[key] = time_attention(shape, sizes, kernel)
+            got = seen[key]
+            label = kernel + " " + "x".join(map(str, geometry[:2 if kernel == "dq" else 3]))
+            if isinstance(got, str):
+                row.append(f"{label} REFUSED {got}")
+                continue
+            ms, sample = got
+            ref = first.setdefault(kernel, sample)
+            err = max(float(np.abs(a - r).max()) for a, r in zip(sample, ref))
+            row.append(f"{label} {ms:8.4f} ms max|diff| {err:.2g}")
+        print(" | ".join(row), flush=True)
+
+
 def main(argv):
     def option(name, default):
         return argv[argv.index(name) + 1] if name in argv else default
 
+    if "--attention" in argv:
+        return attention_main(option, argv)
     blocks = BLOCKS
     if "--blocks" in argv:
         blocks = [parse_block(t) for t in option("--blocks", "").split(",")]

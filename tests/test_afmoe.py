@@ -12,6 +12,7 @@ layer in the gradient's program) without moving a gradient.
 """
 
 import dataclasses
+import itertools
 import json
 import os
 import re
@@ -254,21 +255,90 @@ def test_blocked_attention_is_plain_attention():
         assert float(jnp.abs(got - want).max()) < 1e-5
 
 
-@pytest.mark.parametrize("window", [None, 100])
-def test_kernel_attention_is_blocked_attention(window):
+def unequal_blocks():
+    """A ``BlockSizes`` whose query, key and compute blocks all differ, each
+    kernel with its own."""
+    return afmoe.block_sizes(fwd=(256, 512, 128), dq=(512, 256, 256),
+                             dkv=(128, 512, 256))
+
+
+@pytest.mark.parametrize("window,t,blocks", [
+    (None, 256, 128), (100, 256, 128),
+    (None, 512, "unequal"), (300, 512, "unequal"),
+])
+def test_kernel_attention_is_blocked_attention(window, t, blocks):
     """The splash-attention kernels (interpret mode here) against the XLA
     blocks, forward and backward; the causal-window mask keeps ``j > i -
-    window``."""
-    t = 256
+    window``. Equal blocks, and a geometry in which query, key and compute
+    block of every kernel differ."""
+    if blocks == "unequal":
+        blocks = unequal_blocks()
     q = jax.random.normal(jax.random.PRNGKey(0), (1, t, 4, 128))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, t, 2, 128))
     v = jax.random.normal(jax.random.PRNGKey(2), (1, t, 2, 128))
     plain = lambda q, k, v: afmoe.blocked_attention(q, k, v, window, 64, 128)
-    kernel = lambda q, k, v: afmoe.kernel_attention(q, k, v, window, block=128)
+    kernel = lambda q, k, v: afmoe.kernel_attention(q, k, v, window, blocks=blocks)
     assert float(jnp.abs(plain(q, k, v) - kernel(q, k, v)).max()) < 1e-5
     grad = lambda f: jax.grad(lambda *a: (f(*a) ** 2).sum(), argnums=(0, 1, 2))
     for a, b in zip(grad(plain)(q, k, v), grad(kernel)(q, k, v)):
         assert float(jnp.abs(a - b).max()) < 1e-4
+
+
+@pytest.mark.parametrize("t", [128, 384, 512, 1536, 2048, 2560, 4096, 8192, 16384])
+@pytest.mark.parametrize("window", [None, 100, 2048, 4096])
+def test_attention_blocks_are_blocks_the_kernels_can_run(t, window):
+    """Over head widths, heads a key-value head and operand dtypes: every
+    block divides the sequence and none exceeds it, a compute block divides
+    its key block in whole lanes, and each kernel's VMEM estimate stays
+    within the budget (up to float32 at width 256 and bfloat16 at 512: past
+    that the granule itself, the floor, is estimated over it)."""
+    for heads_per_kv, (d, dtype) in itertools.product((1, 8), (
+            (64, jnp.float32), (128, jnp.bfloat16), (128, jnp.float32),
+            (256, jnp.bfloat16), (256, jnp.float32), (512, jnp.bfloat16))):
+        sizes = afmoe.attention_blocks(t, heads_per_kv, d, window, dtype)
+        assert not sizes.use_fused_bwd_kernel
+        for kernel, (bq, bkv, c) in afmoe.kernel_blocks(sizes).items():
+            where = (t, heads_per_kv, d, window, dtype, kernel, bq, bkv, c)
+            assert t % bq == 0 and t % bkv == 0, where
+            assert bq <= t and bkv <= t, where
+            assert bkv % c == 0 and c % 128 == 0, where
+            assert afmoe.attention_vmem_bytes(
+                kernel, bq, bkv, c, d, dtype) <= afmoe.VMEM_BUDGET, where
+
+
+@pytest.mark.parametrize("shape,edge", [
+    # the three shapes the language-model cells run (step 0, PERF.md §6, PR 33)
+    ((8192, 1, 256, None), 1024),  # latent attention: full causal, width 256
+    ((8192, 8, 128, None), 1024),  # grouped-query attention, a full layer
+    ((8192, 8, 128, 2048), 512),  # the same, a sliding layer: the control
+    # what masked_attention takes and larger blocks do not divide, or a band
+    # too narrow for them: the granule
+    ((1536, 8, 128, None), 512), ((2560, 8, 128, 2048), 512),
+    ((2048, 8, 128, None), 512), ((128, 1, 256, None), 128),
+])
+def test_attention_blocks_at_the_cells_shapes(shape, edge):
+    """The counter of a static chooser is its answer: a later PR that moves
+    the geometry of a cell's call moves this table knowingly."""
+    sizes = afmoe.attention_blocks(*shape, jnp.bfloat16)
+    compute = min(512, edge)
+    assert afmoe.kernel_blocks(sizes) == {"fwd": (edge, edge, compute),
+                                "dq": (edge, edge, edge),
+                                "dkv": (edge, edge, compute)}
+
+
+def test_attention_blocks_shrink_by_kernel_where_vmem_is_short():
+    """Head width and operand dtype enter through the VMEM estimate: float32
+    operands at width 256 leave the forward and dq a long KEY block and the
+    dkv kernel the granule; an override is used as given."""
+    sizes = afmoe.attention_blocks(8192, 1, 256, None, jnp.float32)
+    assert afmoe.kernel_blocks(sizes) == {"fwd": (512, 1024, 512), "dq": (512, 1024, 1024),
+                                "dkv": (512, 512, 512)}
+    assert afmoe.attention_vmem_bytes(
+        "fwd", 1024, 1024, 512, 256, jnp.float32) > afmoe.VMEM_BUDGET
+    given = unequal_blocks()
+    assert afmoe.attention_blocks(512, 2, 128, None, jnp.float32, given) is given
+    assert afmoe.kernel_blocks(afmoe.attention_blocks(
+        256, 2, 128, None, jnp.float32, 128))["dkv"] == (128, 128, 128)
 
 
 def test_kernel_names_are_the_librarys():

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from jax._src.ad_checkpoint import saved_residuals
-from test_afmoe import _kernel_calls
+from test_afmoe import _kernel_calls, unequal_blocks
 
 from benchmarks.lib.refcheck_lm import GROUPS, group_cosines, group_of
 from benchmarks.reference import federated as fed
@@ -304,14 +304,39 @@ def _latent_qkv(t=256, heads=4, nope=192, rope=64):
     return q, key, jax.random.normal(k[3], (1, t, heads, nope + rope))
 
 
-def test_kernel_attention_is_blocked_attention_at_latent_attentions_shape():
-    q, k, v = _latent_qkv()
+@pytest.mark.parametrize("t,blocks", [(256, 128), (512, "unequal")])
+def test_kernel_attention_is_blocked_attention_at_latent_attentions_shape(t, blocks):
+    """Equal blocks, and a geometry in which query, key and compute block of
+    every kernel differ."""
+    if blocks == "unequal":
+        blocks = unequal_blocks()
+    q, k, v = _latent_qkv(t=t)
     plain = lambda q, k, v: afmoe.blocked_attention(q, k, v, None, 64, 128)
-    kernel = lambda q, k, v: afmoe.kernel_attention(q, k, v, None, block=128)
+    kernel = lambda q, k, v: afmoe.kernel_attention(q, k, v, None, blocks=blocks)
     assert float(jnp.abs(plain(q, k, v) - kernel(q, k, v)).max()) < 1e-5
     grad = lambda f: jax.grad(lambda *a: (f(*a) ** 2).sum(), argnums=(0, 1, 2))
     for a, b in zip(grad(plain)(q, k, v), grad(kernel)(q, k, v)):
         assert float(jnp.abs(a - b).max()) < 2e-4
+
+
+def test_attention_blocks_at_latent_attentions_shape():
+    """One query head a key-value head at width 256 over a causal 8,192:
+    1,024-row query and key blocks in all three kernels, 512 columns at a
+    time (step 0, PERF.md §6, PR 33); within the VMEM budget where 2,048-row
+    blocks and whole-block compute are not (the compiler refused them)."""
+    sizes = afmoe.attention_blocks(8192, 1, 256, None, jnp.bfloat16)
+    assert (sizes.block_q, sizes.block_kv, sizes.block_kv_compute) == (1024, 1024, 512)
+    assert (sizes.block_q_dq, sizes.block_kv_dq) == (1024, 1024)
+    assert (sizes.block_q_dkv, sizes.block_kv_dkv,
+            sizes.block_kv_dkv_compute) == (1024, 1024, 512)
+    for refused in (("fwd", 2048, 512, 512), ("fwd", 1024, 2048, 512),
+                    ("dq", 2048, 512, 512), ("dq", 1024, 2048, 2048),
+                    ("dkv", 512, 2048, 512), ("dkv", 1024, 1024, 1024)):
+        assert afmoe.attention_vmem_bytes(
+            *refused, 256, jnp.bfloat16) > afmoe.VMEM_BUDGET, refused
+    # the toy the model tests run: one granule of 128 rows
+    toy = afmoe.attention_blocks(128, 1, 16, None, jnp.float32)
+    assert (toy.block_q, toy.block_kv_dkv, toy.block_kv_dq) == (128, 128, 128)
 
 
 def test_blocked_attention_at_latent_attentions_shape_is_plain_attention():

@@ -137,9 +137,11 @@ def test_epoch_program_gathers_rows_and_nothing_else(
 def test_attention_kernels_compile_for_the_chip_under_the_site_fold(
         one_chip, no_compile_cache, monkeypatch):
     """The splash-attention kernels at Trinity-Mini's widths (32 query / 4
-    key-value heads of 128, 8,192 positions, window 2,048 and full), under
-    the trainer's vmap over two sites and its gradient: three Mosaic calls a
-    mask, named after the model's constants."""
+    key-value heads of 128, 8,192 positions, window 2,048 and full), in the
+    blocks ``afmoe.attention_blocks`` chooses there (512 under the window,
+    1,024 x 1,024 under the causal mask), under the trainer's vmap over two
+    sites and its gradient: three Mosaic calls a mask, named after the
+    model's constants."""
     from dinunet_implementations_tpu.models import afmoe
 
     monkeypatch.setattr(afmoe, "_interpret", lambda: False)
@@ -150,7 +152,10 @@ def test_attention_kernels_compile_for_the_chip_under_the_site_fold(
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     try:
-        for window in (2048, None):
+        for window, edge in ((2048, 512), (None, 1024)):
+            sizes = afmoe.attention_blocks(t, n // g, d, window, jnp.bfloat16)
+            assert (sizes.block_q, sizes.block_kv_dq, sizes.block_q_dkv) == (edge,) * 3
+
             def loss(q, k, v):
                 return afmoe.kernel_attention(q, k, v, window).sum()
 
@@ -168,14 +173,18 @@ def test_attention_kernels_compile_at_latent_attentions_shape(
         one_chip, no_compile_cache, monkeypatch):
     """The same kernels as GLM-4.7-Flash's latent attention calls them (ISSUE
     32): 20 key-value heads with ONE query head each at head width 256, full
-    causal attention over 8,192 positions in 512-row blocks, under the
-    trainer's vmap over two sites and its gradient."""
+    causal attention over 8,192 positions in the blocks
+    ``afmoe.attention_blocks`` chooses there (1,024 x 1,024, the largest the
+    16 MiB scope takes at this width), under the trainer's vmap over two
+    sites and its gradient."""
     from dinunet_implementations_tpu.models import afmoe
 
     monkeypatch.setattr(afmoe, "_interpret", lambda: False)
     afmoe._splash.cache_clear()
     t, n, d = 8192, 20, 256
     x = jax.ShapeDtypeStruct((2, 1, t, n, d), jnp.bfloat16, sharding=one_chip)
+    sizes = afmoe.attention_blocks(t, 1, d, None, jnp.bfloat16)
+    assert (sizes.block_q, sizes.block_kv_dq, sizes.block_q_dkv) == (1024,) * 3
     try:
         text = jax.jit(jax.vmap(jax.grad(
             lambda q, k, v: afmoe.kernel_attention(q, k, v, None).sum(),
