@@ -177,8 +177,11 @@ class MultimodalArgs:
 class AFMoEArgs:
     """A mixture-of-experts decoder as a federated next-token task
     (models/afmoe.py), of the type ``model_type`` names: ``afmoe`` (the
-    Trinity family) or ``glm4_moe_lite`` (latent attention, two pre-norms a
-    block, no embedding scale, multi-token prediction). The
+    Trinity family), ``glm4_moe_lite`` (latent attention, two pre-norms a
+    block, no embedding scale, multi-token prediction) or ``smallthinker``
+    (the router reads the block's input before attention, a softmax over the
+    chosen experts' logits, ReGLU experts, no shared expert, no dense layer,
+    a full layer without positions first in each period of four). The
     widths default to the published Trinity-Mini ``config.json`` (source:
     huggingface.co/arcee-ai/Trinity-Mini) under its own key names; what a
     configuration CUTS is the depth (``num_hidden_layers``,
@@ -194,10 +197,19 @@ class AFMoEArgs:
     ``norm_topk_prob`` -> ``route_norm``, ``routed_scaling_factor`` ->
     ``route_scale``) and the latent widths under its own; it reads neither
     ``num_key_value_heads``, ``head_dim``, ``sliding_window`` nor
-    ``mup_enabled``, and every layer of it is ``full_attention``."""
+    ``mup_enabled``, and every layer of it is ``full_attention``.
+
+    A ``smallthinker`` configuration likewise (``moe_num_primary_experts`` ->
+    ``num_experts``, ``moe_num_active_primary_experts`` ->
+    ``num_experts_per_tok``, ``moe_ffn_hidden_size`` ->
+    ``moe_intermediate_size``, ``sliding_window_size`` -> ``sliding_window``)
+    with ``num_dense_layers`` 0 and ``num_shared_experts`` 0, and its two
+    per-layer lists under their own names; it reads neither
+    ``intermediate_size``, ``route_norm`` (``norm_topk_prob`` divides a softmax
+    by its sum, 1), ``route_scale`` nor ``mup_enabled``."""
 
     data_file: str = ""
-    model_type: str = "afmoe"  # or "glm4_moe_lite"
+    model_type: str = "afmoe"  # or "glm4_moe_lite", "smallthinker"
     seq_len: int = 8192  # a sample is seq_len + 1 token ids
     vocab_size: int = 200192
     vocab_rows: int = 0  # rows of the vocabulary held here; 0 = all
@@ -232,6 +244,11 @@ class AFMoEArgs:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # smallthinker's published per-layer lists, 1 = the layer has a window /
+    # a rotary term (() = the type has none; the two must agree: attention
+    # applies rotary positions iff it has a window)
+    sliding_window_layout: tuple = ()
+    rope_layout: tuple = ()
     # prediction depths beyond the next token (0 or 1), and the weight of
     # that depth's loss beside the next token's (the config gives none)
     num_nextn_predict_layers: int = 0
@@ -715,7 +732,7 @@ COMPSPEC_META: dict[str, dict] = {
                             compspec_key="Multimodal-Classification_args"),
     "lm_args": dict(type="object", source="owner", group="Computation", order=29,
                     conditional=dict(variable="task_id", value="LM-NextToken"),
-                    label="Next-token language-model parameters (model_type afmoe | glm4_moe_lite).",
+                    label="Next-token language-model parameters (model_type afmoe | glm4_moe_lite | smallthinker).",
                     compspec_key="LM-NextToken_args"),
 }
 

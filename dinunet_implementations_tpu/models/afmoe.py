@@ -1,8 +1,10 @@
 """Mixture-of-experts decoders as a federated next-token task: the Trinity
-family's ``model_type: afmoe`` and ``model_type: glm4_moe_lite`` (latent
-attention on every layer, one multi-token-prediction module). The two share
-the expert layer, the norms, the rotary term, the attention kernels, the
-blocked head and the loss; they differ in the attention's projections and in
+family's ``model_type: afmoe``, ``model_type: glm4_moe_lite`` (latent
+attention on every layer, one multi-token-prediction module) and
+``model_type: smallthinker`` (the router before attention, ReGLU experts).
+The three share the expert layer's grouped products, the norms, the rotary
+term, the attention kernels, the blocked head and the loss; they differ in
+the attention's projections, in the routing rule and what it reads, and in
 the block around them, which ``Dims.model_type`` selects.
 
 One chip's SHARE of the model: the expert layer is told which routed experts
@@ -57,6 +59,26 @@ layer's equations, the dense MLP, the head and the loss, and replaces the rest:
   training loss is ``L_main + mtp_loss_weight * L_mtp``, ``L_mtp`` the mean
   over the ``T - 1`` positions that have such a target.
 
+THE THIRD TYPE'S block, ``smallthinker`` (SmallThinker-21BA3B-Instruct; same
+precisions; every layer an expert layer, no dense layer, no shared expert, no
+bias). With ``h`` the block's input:
+
+- ``h0 = E[tok]`` (no scale); two pre-norms a block and no post-norm, as the
+  type above;
+- the router, BEFORE attention and on the un-normed input: ``r = h W_r``;
+  ``sel = top_k(r)``; ``p = softmax(r[sel])`` over the chosen alone (no
+  selection bias, no scale: no ``expert_bias`` in the tree). The experts a
+  token goes to are a function of the block's INPUT, and the router's
+  gradient reaches ``h`` past the attention;
+- attention: ``a = RMSNorm(h)``; ``q, k, v = a Wq, a Wk, a Wv`` (grouped
+  queries as Trinity's; no QK-norm, no output gate: neither ``q_norm``,
+  ``k_norm`` nor ``wg`` in the tree); rotary positions on sliding layers
+  only, a full layer has NO positional term (Trinity's rule; the full layer
+  is FIRST in the period of four); ``h' = h + o Wo``;
+- experts: ``m = RMSNorm(h')``; ``y = sum over sel that are held of p_e
+  (relu(m W1_e) * (m W3_e)) W2_e`` (ReGLU for SwiGLU; ``p`` is not
+  renormalised over the held ones); ``h_next = h' + y``.
+
 Attention never builds a ``[T, T]`` tensor. On a TPU, at sequences of whole
 kernel blocks, it is jax's splash-attention Pallas kernels (block-sparse flash
 attention: a masked block is never visited); elsewhere it is XLA query blocks:
@@ -65,7 +87,9 @@ window touches, a full layer the causal prefix in ``kv_chunk`` steps. The
 routed experts run as grouped matrix products (``jax.lax.ragged_dot``) over
 the token assignments sorted by expert, walked in row chunks: dropless (the
 index arrays hold the worst case, every token on held experts), memory and
-work follow the real counts.
+work follow the real counts; the rows go back to token order in one gather,
+or a token's ``k`` slots one at a time where ``[tokens, k, hidden]`` would be
+past COMBINE_BYTES.
 One ``jax.checkpoint`` a block, which keeps what ``BLOCK_KEEPS`` names and
 recomputes the rest: the routed experts' output (their backward pass runs
 their forward itself, chunk by chunk); where attention is the kernels, the
@@ -99,6 +123,8 @@ SLIDING = "sliding_attention"
 FULL = "full_attention"
 AFMOE = "afmoe"  # Dims.model_type: Trinity's block
 GLM4_MOE_LITE = "glm4_moe_lite"  # latent attention, two pre-norms, MTP
+SMALLTHINKER = "smallthinker"  # router before attention, ReGLU, two pre-norms
+MODEL_TYPES = (AFMOE, GLM4_MOE_LITE, SMALLTHINKER)
 
 
 def _init(std: float = 0.02):
@@ -224,7 +250,7 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-# The kernels' block geometry: what was measured on a v5e (PERF.md §6, PR 33;
+# The kernels' block geometry: what was measured on a v5e (PERF.md §6, PRs 33, 34;
 # ``scripts/kernel_tune.py --attention`` is the sweep). A kernel's time is
 # within 4 % of ``steps * 0.4..0.75 us + blocks_computed * c(kernel, d)``: a
 # grid step has a fixed cost, and a visited block is computed WHOLE however
@@ -297,17 +323,20 @@ def attention_blocks(t: int, heads_per_kv: int, head_dim: int,
     and compute block of the forward, the dq and the dkv kernel.
 
     A pure function of what the call can see. The rules, each from step 0's
-    sweep of the three shapes the language-model cells run (PERF.md §6, PR 33):
+    sweeps of the five shapes the language-model cells run (PERF.md §6, PRs 33
+    and 34):
 
     - The granule is ``min(KERNEL_BLOCK, t)``; every block is a whole number
       of granules that divides ``t``, so whatever ``masked_attention`` takes
       runs (1,536 or 2,560 positions at 512, 128 at 128).
     - An edge is at most ``band / BAND_EDGES``, the band being the window, or
       ``t`` under the plain causal mask: a block is computed whole, so on a
-      2,048-wide window 1,024-blocks compute half as many pairs again as the
-      band holds and measured within 2 % of 512 either way, where over a
-      causal 8,192 they compute an eighth more and took 7-19 % off every
-      kernel (the steps fall from 136 to 36 a head).
+      2,048-wide window 1,024-blocks compute a fifth more pairs than
+      512-blocks and measured within 2 % of them either way; on a 4,096-wide
+      window over 16,384 positions they compute a ninth more and took 6-8 %
+      off every kernel; over a causal 8,192 they compute a sixteenth more
+      and took 7-19 % off (the steps fall from 136 to 36 a head), over a
+      causal 16,384 18-22 %.
     - Each kernel takes the first of (1,024 x 1,024), (512 x 1,024), (512 x
       512) (query x key: the long KEY block was second in every kernel) that
       these allow and whose :func:`attention_vmem_bytes` is within
@@ -316,8 +345,11 @@ def attention_blocks(t: int, heads_per_kv: int, head_dim: int,
     - The compute block is COMPUTE_BLOCK: whole key blocks cost the forward
       5-10 %, 256 columns cost it 22 % at width 128 with 512-blocks.
 
-    ``heads_per_kv`` decides nothing today: with eight query heads a
-    key-value head and with one, the kernels' gains followed the head width.
+    ``heads_per_kv`` decides nothing: with eight, seven and one query heads a
+    key-value head the kernels' gains followed the head width and the mask,
+    and the compiler's VMEM verdicts were the same at seven as at eight
+    (2,048 x 1,024 refused in the dkv kernel under the causal mask, taken
+    under a window; 2,048 x 2,048 refused everywhere).
 
     ``override`` is for tests and ``scripts/kernel_tune.py``: one edge for
     every block, or a ``BlockSizes`` used as given.
@@ -416,6 +448,13 @@ def route(scores, bias, top_k: int, route_norm: bool, route_scale: float):
     return sel, w * route_scale
 
 
+def route_chosen(logits, top_k: int):
+    """The other rule (``smallthinker``): the ``top_k`` largest logits, and a
+    softmax over those alone; no bias, no scale."""
+    top, sel = jax.lax.top_k(logits, top_k)
+    return sel, jax.nn.softmax(top, axis=-1)
+
+
 def _plan(sel, first_expert: int, held: int):
     """Sort the assignments ``sel [S, T, k]`` of ``S`` folds by (held expert,
     fold): ``(order, inverse, bounds [held * S + 1], is_held [S, T, k])``;
@@ -436,6 +475,12 @@ def _plan(sel, first_expert: int, held: int):
 
 
 ROW_CHUNK = 8192  # sorted assignment rows the expert layer holds at a time
+# The sorted rows go back to token order through ``[tokens, k, hidden]``, which
+# the compiler holds in float32: 1 GiB at Trinity's cell, 0.5 at GLM's, and
+# 1.875 GiB (three times over in the backward pass) at 2 x 16,384 tokens of
+# top-6 and width 2,560, where the epoch program no longer fitted the chip.
+# Past this size the sum over a token's k slots walks the slots instead.
+COMBINE_BYTES = 2 ** 30
 ROUTED_OUT = "routed_experts_out"
 KV_PROJ = "attention_kv_proj"  # a layer's raw key and value projections
 MLA_LATENT = "attention_mla_latent"  # latent attention's raw [c_kv | k_r]
@@ -458,7 +503,8 @@ class _Experts:
     assignments is skipped (``lax.cond``), so that memory and work follow the
     real counts while the index arrays hold the worst case (dropless)."""
 
-    def __init__(self, m, sel, w, w1, w3, w2, first_expert, cdt):
+    def __init__(self, m, sel, w, w1, w3, w2, first_expert, cdt, relu=False):
+        self.relu = relu  # ReGLU experts (relu(a) * b) for SwiGLU's silu(a) * b
         self.folds, self.t, self.h = m.shape
         self.k, self.held = sel.shape[-1], w1.shape[0]
         self.rows = self.folds * self.t * self.k
@@ -501,7 +547,7 @@ class _Experts:
                                 preferred_element_type=jnp.float32)
         xs = jnp.take(self.tokens, tok, axis=0)
         a, b = dot(xs, self.w1), dot(xs, self.w3)
-        mid = self.cast(jax.nn.silu(a) * b)
+        mid = self.cast((jax.nn.relu(a) if self.relu else jax.nn.silu(a)) * b)
         # rows past the held assignments belong to no group: whatever the
         # grouped product leaves there is not a result
         return xs, a, b, mid, jnp.where(live, dot(mid, self.w2), 0.0)
@@ -512,11 +558,31 @@ class _Experts:
         out = jnp.take(buf, self.inverse, axis=0)
         return out.reshape((self.folds * self.t, self.k) + buf.shape[1:])
 
+    @property
+    def by_slot(self) -> bool:
+        """Whether ``[S * T, k, H]`` in float32 is past COMBINE_BYTES."""
+        return self.rows * self.h * 4 > COMBINE_BYTES
 
-def _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt):
+    def slot_sum(self, buf, weights=None):
+        """``sum_j weights[:, j] * buf[inverse[:, j]]`` (``weights [S * T, k]``,
+        ones if None) as ``[S * T, H]`` float32, one of a token's ``k`` slots
+        at a time: :meth:`per_token`'s gather in ``k`` parts, so that
+        ``[S * T, k, H]`` is never whole."""
+        inv = self.inverse.reshape(-1, self.k).T  # [k, S * T]
+        by_slot = None if weights is None else weights.T
+
+        def slot(j, acc):
+            rows = jnp.take(buf, inv[j], axis=0).astype(jnp.float32)
+            return acc + (rows if weights is None else rows * by_slot[j][:, None])
+
+        return jax.lax.fori_loop(
+            0, self.k, slot, jnp.zeros((inv.shape[1], self.h), jnp.float32))
+
+
+def _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt, relu=False):
     """``m [S, T, H]``, ``sel, w [S, T, k]`` -> the held experts' part ``[S,
     T, H]`` float32."""
-    ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt)
+    ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt, relu)
 
     def body(lo, buf):
         ys = ex.forward(*ex.rows_of(lo))[-1]
@@ -524,16 +590,20 @@ def _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt):
             buf, ys.astype(buf.dtype), lo, axis=0)
 
     buf = ex.walk(body, jnp.zeros((ex.rows, ex.h), ex.tokens.dtype))
-    y = jnp.einsum("nkh,nk->nh", ex.per_token(buf), ex.wk.reshape(-1, ex.k),
-                   preferred_element_type=jnp.float32)
+    if ex.by_slot:
+        y = ex.slot_sum(buf, ex.wk.reshape(-1, ex.k))
+    else:
+        y = jnp.einsum("nkh,nk->nh", ex.per_token(buf), ex.wk.reshape(-1, ex.k),
+                       preferred_element_type=jnp.float32)
     return y.reshape(m.shape)
 
 
-def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt):
+def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
+                      relu=False):
     """Cotangents ``(dm [S, T, H], dw [S, T, k], dw1, dw3, dw2 [S, E, ..])``
     of :func:`_experts_forward` for ``dy [S, T, H]``, the forward recomputed
     chunk by chunk."""
-    ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt)
+    ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt, relu)
     wdot = functools.partial(
         jax.lax.ragged_dot_general,
         ragged_dot_dimension_numbers=_CONTRACT_ROWS,
@@ -553,9 +623,15 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt):
         wk = jax.lax.dynamic_slice_in_dim(wk_sorted, lo, ex.chunk)
         dys = ex.cast(jnp.where(live, g * wk[:, None], 0.0))
         dmid = dot(dys, w2t)
-        sig = jax.nn.sigmoid(a)
-        da = ex.cast(jnp.where(live, dmid * b * sig * (1.0 + a * (1.0 - sig)), 0.0))
-        db = ex.cast(jnp.where(live, dmid * a * sig, 0.0))
+        if relu:  # d relu(a) b: the gate's cotangent is b where a > 0
+            on = live & (a > 0)
+            da = ex.cast(jnp.where(on, dmid * b, 0.0))
+            db = ex.cast(jnp.where(on, dmid * a, 0.0))
+        else:
+            sig = jax.nn.sigmoid(a)
+            da = ex.cast(jnp.where(
+                live, dmid * b * sig * (1.0 + a * (1.0 - sig)), 0.0))
+            db = ex.cast(jnp.where(live, dmid * a * sig, 0.0))
         dxs = jnp.where(live, dot(da, w1t) + dot(db, w3t), 0.0)
         put = jax.lax.dynamic_update_slice_in_dim
         return (put(dxs_buf, dxs.astype(dxs_buf.dtype), lo, axis=0),
@@ -571,7 +647,10 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt):
         jnp.zeros((groups, h, f), jnp.float32),
         jnp.zeros((groups, h, f), jnp.float32),
         jnp.zeros((groups, f, h), jnp.float32)))
-    dm = ex.per_token(dxs_buf).astype(jnp.float32).sum(axis=1).reshape(m.shape)
+    if ex.by_slot:
+        dm = ex.slot_sum(dxs_buf).reshape(m.shape)
+    else:
+        dm = ex.per_token(dxs_buf).astype(jnp.float32).sum(axis=1).reshape(m.shape)
     dwk = jnp.where(ex.is_held, ex.per_token(dwk_buf).reshape(w.shape), 0.0)
 
     def per_fold(x):  # [held * S, ...] expert-major -> [S, held, ...]
@@ -581,9 +660,10 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt):
 
 
 @functools.lru_cache(maxsize=None)
-def _expert_layer(first_expert: int, cdt):
+def _expert_layer(first_expert: int, cdt, relu: bool = False):
     """``experts(m [T, H], sel [T, k], w [T, k], w1, w3, w2) -> [T, H]`` for
-    one share of the experts, differentiable and mappable."""
+    one share of the experts (ReGLU ones if ``relu``), differentiable and
+    mappable."""
     from jax.custom_batching import custom_vmap
 
     def folded(fn, n_tok, n_out):
@@ -607,10 +687,11 @@ def _expert_layer(first_expert: int, cdt):
         return call
 
     def fwd_impl(m, sel, w, w1, w3, w2):
-        return _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt)
+        return _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt, relu)
 
     def bwd_impl(m, sel, w, dy, w1, w3, w2):
-        return _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt)
+        return _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
+                                 relu)
 
     fwd_call, bwd_call = folded(fwd_impl, 3, 1), folded(bwd_impl, 4, 5)
 
@@ -632,13 +713,15 @@ def _expert_layer(first_expert: int, cdt):
     return experts
 
 
-def routed_experts(m, sel, w, w1, w3, w2, first_expert: int, cdt=None):
+def routed_experts(m, sel, w, w1, w3, w2, first_expert: int, cdt=None,
+                   relu: bool = False):
     """The held experts' part of the layer for the tokens ``m [T, H]`` with
     assignments ``sel, w [T, k]`` over ALL experts; stacks ``w1, w3 [E, H,
     F]``, ``w2 [E, F, H]`` of the experts ``first_expert .. + E - 1`` ->
-    ``[T, H]`` float32. Dropless: the buffer holds all ``T * k`` assignments,
-    the grouped products cover ``sum(group_sizes)`` rows."""
-    return _expert_layer(first_expert, cdt)(m, sel, w, w1, w3, w2)
+    ``[T, H]`` float32; an expert is ``(silu(m w1) * (m w3)) w2``, with
+    ``relu`` for ``silu`` if asked. Dropless: the buffer holds all ``T * k``
+    assignments, the grouped products cover ``sum(group_sizes)`` rows."""
+    return _expert_layer(first_expert, cdt, relu)(m, sel, w, w1, w3, w2)
 
 
 # -- modules -----------------------------------------------------------------
@@ -678,6 +761,9 @@ class Attention(nn.Module):
     q_block: int
     kv_chunk: int
     compute_dtype: str | None = None
+    # Trinity's QK-norm and output gate; without them the tree has neither
+    # ``q_norm``, ``k_norm`` nor ``wg`` (smallthinker)
+    gated: bool = True
 
     @nn.compact
     def __call__(self, a):
@@ -687,22 +773,25 @@ class Attention(nn.Module):
         wq = self.param("wq", _init(), (H, N * D))
         wk = self.param("wk", _init(), (H, G * D))
         wv = self.param("wv", _init(), (H, G * D))
-        wg = self.param("wg", _init(), (H, N * D))
+        if self.gated:
+            wg = self.param("wg", _init(), (H, N * D))
         wo = self.param("wo", _init(), (N * D, H))
         q = _mm(a, wq, cdt).reshape(B, T, N, D)
         # the raw key and value projections are kept across the block's
         # recomputation (an eighth of the queries' bytes); q is recomputed
         k = checkpoint_name(_mm(a, wk, cdt), KV_PROJ).reshape(B, T, G, D)
         v = checkpoint_name(_mm(a, wv, cdt), KV_PROJ).reshape(B, T, G, D)
-        q = RMSNorm(self.eps, name="q_norm")(q)
-        k = RMSNorm(self.eps, name="k_norm")(k)
+        if self.gated:
+            q = RMSNorm(self.eps, name="q_norm")(q)
+            k = RMSNorm(self.eps, name="k_norm")(k)
         if self.window is not None:
             pos = jnp.arange(T)
             q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
         o = masked_attention(q, k, v, self.window, self.q_block,
                              self.kv_chunk, cdt)
         o = o.reshape(B, T, N * D)
-        o = o * jax.nn.sigmoid(_mm(a, wg, cdt))
+        if self.gated:
+            o = o * jax.nn.sigmoid(_mm(a, wg, cdt))
         return _mm(o, wo, cdt)
 
 
@@ -757,6 +846,12 @@ class LatentAttention(nn.Module):
 
 
 class MoE(nn.Module):
+    """The expert layer: the router and the share of the experts held here.
+    ``__call__(m)`` routes the tokens it is given; a block whose router reads
+    something else (``smallthinker``: the block's input, before attention)
+    calls :meth:`route` on that itself and hands the routing over."""
+
+    hidden_size: int
     num_experts: int
     top_k: int
     experts_held: int
@@ -766,36 +861,52 @@ class MoE(nn.Module):
     route_norm: bool
     route_scale: float
     compute_dtype: str | None = None
+    # smallthinker's layer: top-k over the router's logits and a softmax over
+    # the chosen (no ``expert_bias`` in the tree), ReGLU experts
+    model_type: str = AFMOE
 
-    @nn.compact
-    def __call__(self, m):
-        cdt = compute_dtype_of(self.compute_dtype)
-        B, T, H = m.shape
-        E, F = self.experts_held, self.width
-        router = self.param("router", _init(), (H, self.num_experts))
-        bias = self.param("expert_bias", nn.initializers.zeros,
-                          (self.num_experts,))
-        w1 = self.param("w1", _init(), (E, H, F))
-        w3 = self.param("w3", _init(), (E, H, F))
-        w2 = self.param("w2", _init(), (E, F, H))
+    def setup(self):
+        E, F, H = self.experts_held, self.width, self.hidden_size
+        self.chosen_softmax = self.relu = self.model_type == SMALLTHINKER
+        self.router = self.param("router", _init(), (H, self.num_experts))
+        if not self.chosen_softmax:
+            self.expert_bias = self.param(
+                "expert_bias", nn.initializers.zeros, (self.num_experts,))
+        self.w1 = self.param("w1", _init(), (E, H, F))
+        self.w3 = self.param("w3", _init(), (E, H, F))
+        self.w2 = self.param("w2", _init(), (E, F, H))
+        if self.shared_width:
+            self.shared = SwiGLU(self.shared_width, self.compute_dtype)
+
+    def route(self, x):
+        """``(sel, w) [B, T, k]`` over ALL experts from ``x [B, T, H]``."""
         with jax.named_scope(scopes.MOE_ROUTE):
             # the router reads float32 (as the family's code does): a top-k
             # over rounded scores picks other experts than the model's
-            scores = jax.nn.sigmoid(jnp.matmul(
-                m.astype(jnp.float32), router.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            sel, w = route(scores, bias, self.top_k, self.route_norm,
-                           self.route_scale)
+            logits = jnp.matmul(
+                x.astype(jnp.float32), self.router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            if self.chosen_softmax:
+                return route_chosen(logits, self.top_k)
+            return route(jax.nn.sigmoid(logits), self.expert_bias, self.top_k,
+                         self.route_norm, self.route_scale)
+
+    def __call__(self, m, routing=None):
+        cdt = compute_dtype_of(self.compute_dtype)
+        B, T, H = m.shape
+        sel, w = self.route(m) if routing is None else routing
         # a routing counter for whoever asks (apply(..., mutable=
         # ["intermediates"])): assignments on each held expert, [B, held]
         local = sel - self.first_expert
         self.sow("intermediates", "held_counts", jnp.sum(
-            local[..., None] == jnp.arange(E), axis=(1, 2), dtype=jnp.int32))
+            local[..., None] == jnp.arange(self.experts_held), axis=(1, 2),
+            dtype=jnp.int32))
         with jax.named_scope(scopes.MOE_EXPERTS):
             # a site's sequences are just tokens to the experts
             y = routed_experts(
                 m.reshape(B * T, H), sel.reshape(B * T, -1),
-                w.reshape(B * T, -1), w1, w3, w2, self.first_expert, cdt,
+                w.reshape(B * T, -1), self.w1, self.w3, self.w2,
+                self.first_expert, cdt, self.relu,
             ).reshape(B, T, H)
             # kept across the block's recomputation (BLOCK_KEEPS): the
             # backward pass runs the layer's forward in chunks itself and
@@ -803,8 +914,7 @@ class MoE(nn.Module):
             y = checkpoint_name(y, ROUTED_OUT)
         if self.shared_width:
             with jax.named_scope(scopes.MOE_SHARED):
-                y = y + SwiGLU(self.shared_width, self.compute_dtype,
-                               name="shared")(m)
+                y = y + self.shared(m)
         return y
 
 
@@ -853,15 +963,26 @@ class Block(nn.Module):
     def __call__(self, h):
         c = self.dims
         latent = c.model_type == GLM4_MOE_LITE
+        early = c.model_type == SMALLTHINKER  # the router reads the input
 
         def norm(name):
             return RMSNorm(c.rms_norm_eps, name=name)
 
         def branch(name, y):
             # Trinity's block norms a branch's output too, inside the
-            # residual; the other type has the two pre-norms only
-            return y if latent else norm(name)(y)
+            # residual; the other types have the two pre-norms only
+            return norm(name)(y) if c.model_type == AFMOE else y
 
+        dense = self.layer < c.num_dense_layers
+        if not dense:
+            moe = MoE(c.hidden_size, c.num_experts, c.num_experts_per_tok,
+                      c.experts_held, c.first_expert, c.moe_intermediate_size,
+                      c.moe_intermediate_size * c.num_shared_experts,
+                      c.route_norm, c.route_scale, c.compute_dtype,
+                      c.model_type, name="moe")
+        # smallthinker routes on the block's un-normed INPUT: the experts a
+        # token goes to do not depend on what attention adds to it
+        routing = moe.route(h) if early else None
         a = norm("input_norm")(h)
         if latent:
             with jax.named_scope(scopes.ATTENTION_MLA):
@@ -878,17 +999,13 @@ class Block(nn.Module):
                     c.num_attention_heads, c.num_key_value_heads, c.head_dim,
                     c.sliding_window if sliding else None, c.rope_theta,
                     c.rms_norm_eps, c.q_block, c.kv_chunk, c.compute_dtype,
-                    name="attn")(a)
+                    gated=not early, name="attn")(a)
         h = h + branch("post_attn_norm", o)
         m = norm("pre_mlp_norm")(h)
-        if self.layer < c.num_dense_layers:
+        if dense:
             y = SwiGLU(c.intermediate_size, c.compute_dtype, name="mlp")(m)
         else:
-            y = MoE(c.num_experts, c.num_experts_per_tok, c.experts_held,
-                    c.first_expert, c.moe_intermediate_size,
-                    c.moe_intermediate_size * c.num_shared_experts,
-                    c.route_norm, c.route_scale, c.compute_dtype,
-                    name="moe")(m)
+            y = moe(m, routing)
         return h + branch("post_mlp_norm", y)
 
 
@@ -937,8 +1054,16 @@ class AFMoE(nn.Module):
 
     def setup(self):
         d = self.dims
+        # smallthinker's router reads the UN-normed stream: the stream's scale
+        # decides the routing. With rows of norm 1 under branches of norm 10
+        # and more, every token's stream is the branches' common part, the
+        # router sends a layer's tokens to one or two experts, and Adam moves
+        # all their logits together (PERF.md §6, PR 34); rows at unit
+        # variance keep a token's own vector the larger part, as the scaled
+        # embedding does for Trinity
+        std = 1.0 if d.model_type == SMALLTHINKER else 0.02
         self.embed = self.param(
-            "embed", _init(), (self.vocab_rows, d.hidden_size))
+            "embed", _init(std), (self.vocab_rows, d.hidden_size))
         self.blocks = [
             nn.remat(Block, policy=BLOCK_KEEPS)(d, i, name=f"layer_{i}")
             for i in range(len(d.layer_types))

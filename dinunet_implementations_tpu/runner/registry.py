@@ -20,7 +20,16 @@ from ..data.ica import ICADataHandle, ICADataset
 from ..data.multimodal import MultimodalDataHandle, MultimodalDataset
 from ..data.smri import SMRIDataHandle, SMRIDataset
 from ..data.tokens import TokenDataHandle, TokenDataset
-from ..models.afmoe import AFMOE, FULL, GLM4_MOE_LITE, SLIDING, AFMoE, Dims
+from ..models.afmoe import (
+    AFMOE,
+    FULL,
+    GLM4_MOE_LITE,
+    MODEL_TYPES,
+    SLIDING,
+    SMALLTHINKER,
+    AFMoE,
+    Dims,
+)
 from ..models.cnn3d import SMRI3DNet
 from ..models.icalstm import ICALstm
 from ..models.msannet import MSANNet
@@ -136,14 +145,22 @@ def _build_multimodal(cfg: TrainConfig):
     )
 
 
+def _kinds(layout) -> tuple:
+    """A published per-layer list of flags as attention kinds: 1 = sliding."""
+    return tuple(SLIDING if on else FULL for on in layout)
+
+
 def afmoe_layer_types(a) -> tuple:
     """One attention kind a layer: ``layer_types`` as given, else the
     published period (a full layer every ``global_attn_every_n_layers``-th;
-    latent attention has no window: every layer full)."""
+    latent attention has no window: every layer full; ``smallthinker``
+    publishes its period as a list, ``sliding_window_layout``, full FIRST)."""
     if a.layer_types:
         return tuple(a.layer_types)
     if a.model_type == GLM4_MOE_LITE:
         return (FULL,) * a.num_hidden_layers
+    if a.model_type == SMALLTHINKER:
+        return _kinds(a.sliding_window_layout)
     n = a.global_attn_every_n_layers
     return tuple(FULL if (i + 1) % n == 0 else SLIDING
                  for i in range(a.num_hidden_layers))
@@ -161,11 +178,10 @@ def _build_afmoe(cfg: TrainConfig):
         raise ValueError(
             f"experts {a.first_expert}..{a.first_expert + held - 1} are not "
             f"among the model's {a.num_experts}")
-    latent = a.model_type == GLM4_MOE_LITE
-    if not latent and a.model_type != AFMOE:
+    if a.model_type not in MODEL_TYPES:
         raise ValueError(
-            f"model_type {a.model_type!r} is neither {AFMOE!r} nor "
-            f"{GLM4_MOE_LITE!r}")
+            f"model_type {a.model_type!r} is none of {', '.join(MODEL_TYPES)}")
+    latent = a.model_type == GLM4_MOE_LITE
     if latent:
         widths = (a.q_lora_rank, a.kv_lora_rank, a.qk_nope_head_dim,
                   a.qk_rope_head_dim, a.v_head_dim)
@@ -177,6 +193,19 @@ def _build_afmoe(cfg: TrainConfig):
             raise ValueError(
                 "the attention paths carry one head width: qk_nope_head_dim "
                 "+ qk_rope_head_dim must equal v_head_dim")
+    if a.model_type == SMALLTHINKER:
+        # attention applies rotary positions iff it has a window, so the
+        # type's two published lists have to say the same of every layer
+        if _kinds(a.rope_layout) != layer_types:
+            raise ValueError(
+                f"rope_layout {tuple(a.rope_layout)} disagrees with the "
+                f"layers' windows {layer_types}: a {SMALLTHINKER} layer has "
+                "a rotary term iff it has a window")
+        if a.num_dense_layers or a.num_shared_experts:
+            raise ValueError(
+                f"{SMALLTHINKER} has no dense layer and no shared expert "
+                f"(got num_dense_layers {a.num_dense_layers}, "
+                f"num_shared_experts {a.num_shared_experts})")
     if a.num_nextn_predict_layers not in ((0, 1) if latent else (0,)):
         raise ValueError(
             f"num_nextn_predict_layers {a.num_nextn_predict_layers}: one "
@@ -192,7 +221,7 @@ def _build_afmoe(cfg: TrainConfig):
             for f in dataclasses.fields(Dims)
         }),
         vocab_rows=a.vocab_rows or a.vocab_size,
-        mup_enabled=a.mup_enabled and not latent,
+        mup_enabled=a.mup_enabled and a.model_type == AFMOE,
         loss_block=a.loss_block,
     )
 

@@ -10,7 +10,7 @@ itself. Every block's outputs are compared with the first block's.
 **Attention (``--attention``).** Times the splash-attention forward, dq and
 dkv kernels ALONE, as ``afmoe.kernel_attention`` calls them under the
 trainer's vmap over two sites, at one of the shapes the language-model cells
-run (``--shape a|b|c`` or ``T,kv_heads,heads_per_kv,head_dim,window|none``),
+run (``--shape a|b|c|d|e`` or ``T,kv_heads,heads_per_kv,head_dim,window|none``),
 for a list of ``block_q x block_kv x compute`` geometries (``--blocks``;
 ``auto`` is what ``afmoe.attention_blocks`` chooses itself, each kernel its
 own). The three kernels' block sizes are independent fields, so one geometry
@@ -139,6 +139,9 @@ ATTN_SHAPES = {
     "a": (8192, 20, 1, 256, None),  # latent attention: full causal, width 256
     "b": (8192, 4, 8, 128, None),  # grouped-query attention, a full layer
     "c": (8192, 4, 8, 128, 2048),  # the same, a sliding layer
+    # smallthinker at its full context: seven query heads a key-value head
+    "d": (16384, 4, 7, 128, 4096),  # a sliding layer, 4 windows long
+    "e": (16384, 4, 7, 128, None),  # the full layer
 }
 ATTN_SIDES = (512, 1024, 2048)
 SITES = 2  # the trainer's fold

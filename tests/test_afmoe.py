@@ -177,8 +177,8 @@ def _moe_layer(seed: int = 0):
 def _share(p, first: int, shared: bool):
     """The system's MoE module holding experts ``first .. first + HELD - 1``
     of the layer ``p``, and its parameters."""
-    module = afmoe.MoE(EXPERTS, TOP_K, HELD, first, 32, 32 if shared else 0,
-                       True, 2.826)
+    module = afmoe.MoE(64, EXPERTS, TOP_K, HELD, first, 32,
+                       32 if shared else 0, True, 2.826)
     mine = {k: p[k] for k in ("router", "expert_bias")}
     mine.update({k: p[k][first: first + HELD] for k in ("w1", "w3", "w2")})
     if shared:
@@ -307,10 +307,13 @@ def test_attention_blocks_are_blocks_the_kernels_can_run(t, window):
 
 
 @pytest.mark.parametrize("shape,edge", [
-    # the three shapes the language-model cells run (step 0, PERF.md §6, PR 33)
+    # the shapes the language-model cells run (step 0, PERF.md §6, PR 33)
     ((8192, 1, 256, None), 1024),  # latent attention: full causal, width 256
     ((8192, 8, 128, None), 1024),  # grouped-query attention, a full layer
     ((8192, 8, 128, 2048), 512),  # the same, a sliding layer: the control
+    # smallthinker at 16,384 positions, seven query heads a key-value head
+    # (step 0, PERF.md §6, PR 34): the quarter of a 4,096 window is 1,024
+    ((16384, 7, 128, 4096), 1024), ((16384, 7, 128, None), 1024),
     # what masked_attention takes and larger blocks do not divide, or a band
     # too narrow for them: the granule
     ((1536, 8, 128, None), 512), ((2560, 8, 128, 2048), 512),

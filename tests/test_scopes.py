@@ -246,3 +246,63 @@ def test_every_kernel_latent_attention_launches_is_read_by_a_new_metric():
                for n in names)
     assert all(re.search(rf"\b{c}\b", both["what"])
                for c in ("ATTN_FWD", "ATTN_DQ", "ATTN_DKV"))
+
+
+# -- the next-token model whose router reads the block's input (ISSUE 34) -------
+
+
+def _block_scopes(model_type: str) -> list[str]:
+    """The name stacks of a block's equations, in the order it traces them."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    if model_type == afmoe.SMALLTHINKER:
+        from test_smallthinker import build
+    elif model_type == afmoe.GLM4_MOE_LITE:
+        from test_glm4_moe_lite import build
+    else:
+        from test_afmoe import build
+    _, model, _ = build()
+    layer = len(model.dims.layer_types) - 1  # an expert layer in every toy
+    block = afmoe.Block(model.dims, layer)
+    h = jnp.zeros((1, 32, model.dims.hidden_size))
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0), h)
+    jaxpr = jax.make_jaxpr(block.apply)(params, h).jaxpr
+    return [str(e.source_info.name_stack) for e in jaxpr.eqns]
+
+
+@pytest.mark.parametrize("model_type,route_first", [
+    ("smallthinker", True), ("afmoe", False), ("glm4_moe_lite", False)])
+def test_the_router_sits_before_attention_in_smallthinkers_block_only(
+        model_type, route_first):
+    """``model/moe_route`` holds the router's product and the choice: of the
+    type that routes on the block's input it comes before the layer's
+    attention scope, of the other two after it; the experts' products
+    (``model/moe_experts``) come after attention in all three."""
+    stacks = _block_scopes(model_type)
+
+    def first(*names):
+        return next(i for i, s in enumerate(stacks) if any(n in s for n in names))
+
+    attention = first(scopes.ATTENTION_FULL, scopes.ATTENTION_WINDOW,
+                      scopes.ATTENTION_MLA)
+    assert (first(scopes.MOE_ROUTE) < attention) == route_first
+    assert first(scopes.MOE_EXPERTS) > attention
+    shared = any(scopes.MOE_SHARED in s for s in stacks)
+    assert shared == (model_type != "smallthinker")  # it has no shared expert
+
+
+def test_every_kernel_smallthinker_launches_is_read_by_a_new_metric():
+    """No new kernel name: the block launches the three splash-attention
+    calls, and each is read, as a whole word, by a metric file of the new
+    cell (``smallthinker_attention_*``), whose ``what`` names the constant;
+    the grouped products are read by name (``ragged-dot``)."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    for name in (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV):
+        mine = _metric_files_reading(afmoe, name, "smallthinker_attention_*.json")
+        assert (f"smallthinker_attention_{name.rsplit('_', 1)[1]}"
+                "_kernel_ms_per_round.json") in mine
+    grouped = json.loads((REPO / "benchmarks" / "layer_metrics" /
+                          "smallthinker_moe_grouped_matmul_ms_per_round.json"
+                          ).read_text())
+    assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")

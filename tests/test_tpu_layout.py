@@ -196,6 +196,37 @@ def test_attention_kernels_compile_at_latent_attentions_shape(
         afmoe._splash.cache_clear()
 
 
+def test_attention_kernels_compile_at_smallthinkers_shapes(
+        one_chip, no_compile_cache, monkeypatch):
+    """The same kernels as the smallthinker block calls them (ISSUE 34): seven
+    query heads a key-value head at width 128 over 16,384 positions, a 4,096
+    window and full causal, in the blocks ``afmoe.attention_blocks`` chooses
+    there (1,024 x 1,024 under both masks: a quarter of the window), under
+    the trainer's vmap over two sites and its gradient."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    monkeypatch.setattr(afmoe, "_interpret", lambda: False)
+    afmoe._splash.cache_clear()
+    t, n, g, d = 16384, 28, 4, 128
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((2, 1, t, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    try:
+        for window in (4096, None):
+            sizes = afmoe.attention_blocks(t, n // g, d, window, jnp.bfloat16)
+            assert (sizes.block_q, sizes.block_kv_dq, sizes.block_q_dkv) == (1024,) * 3
+            text = jax.jit(jax.vmap(jax.grad(
+                lambda q, k, v: afmoe.kernel_attention(q, k, v, window).sum(),
+                argnums=(0, 1, 2)))).lower(sds(n), sds(g), sds(g)).compile().as_text()
+            for name in (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV):
+                assert re.search(r"%[\w.]*" + name + r"[\w.]* = .*tpu_custom_call",
+                                 text), name
+    finally:
+        afmoe._splash.cache_clear()
+
+
 def test_grouped_products_lower_to_the_compilers_kernel(one_chip,
                                                          no_compile_cache):
     """``jax.lax.ragged_dot`` (rows by group) and its row-contracting form
