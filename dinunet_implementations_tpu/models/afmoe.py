@@ -497,6 +497,55 @@ def _row_chunk(rows: int) -> int:
     return c
 
 
+# The stacks' cotangents are float32 ``[folds * held, ...]`` and the sorted rows
+# of one chunk lie in few of those (expert, fold) groups: a chunk whose rows all
+# lie within this many experts' groups adds its products into that window of
+# the stacks, any other chunk into the whole stacks (PERF.md section 6, PR 35).
+WINDOW_EXPERTS = 2
+
+
+def _window_groups(folds: int, held: int) -> int:
+    """Groups in the window; all of them where the stacks are no larger."""
+    return min(folds * held, WINDOW_EXPERTS * folds)
+
+
+def _chunk_sizes(bounds, lo, chunk: int):
+    """Sizes of the groups ``bounds`` delimits, clipped to the rows ``lo ..
+    lo + chunk - 1``."""
+    return jnp.diff(jnp.clip(bounds, lo, lo + chunk))
+
+
+def stack_window(sizes, window: int):
+    """Where a chunk's rows lie among the groups: ``(g0, inside [window],
+    narrow)``: the ``window`` consecutive groups from the chunk's first
+    nonempty one, their sizes, and whether every row of the chunk is in them.
+    ``sizes`` are clipped to the chunk, so the groups before its first
+    nonempty one are empty: a start clamped to the last window only prepends
+    empty groups, and the window's rows start at the chunk's first row."""
+    g0 = jnp.clip(jnp.argmax(sizes > 0), 0, sizes.shape[0] - window)
+    inside = jax.lax.dynamic_slice_in_dim(sizes, g0, window)
+    return g0, inside, inside.sum() == sizes.sum()
+
+
+def window_chunks(held_counts, rows: int):
+    """``(chunks that take the window, live chunks)`` of one backward pass
+    over ``rows`` worst-case assignment rows (``folds * tokens * k``) whose
+    routing put ``held_counts [folds, held]`` assignments on the held experts:
+    a function of the routing alone, on the predicate the chunk loop calls."""
+    counts = jnp.asarray(held_counts, jnp.int32)
+    folds, held = counts.shape
+    chunk, window = _row_chunk(rows), _window_groups(folds, held)
+    bounds = jnp.concatenate(  # expert-major, as _plan sorts
+        [jnp.zeros(1, jnp.int32), jnp.cumsum(counts.T.reshape(-1))])
+    los = jnp.arange(rows // chunk) * chunk
+    live = los < bounds[-1]
+    if window == folds * held:  # no window is built
+        return jnp.int32(0), live.sum()
+    narrow = jax.vmap(lambda lo: stack_window(
+        _chunk_sizes(bounds, lo, chunk), window)[2])(los)
+    return (narrow & live).sum(), live.sum()
+
+
 class _Experts:
     """The grouped products of one call: the sorted assignment rows are walked
     in chunks of ``chunk`` rows, and a chunk that starts past the held
@@ -533,24 +582,34 @@ class _Experts:
         """``(token of each row, sizes of the (expert, fold) groups inside the
         chunk, live rows)``."""
         rows = jax.lax.dynamic_slice_in_dim(self.order, lo, self.chunk)
-        inside = jnp.clip(self.bounds, lo, lo + self.chunk)
         live = (lo + jnp.arange(self.chunk) < self.bounds[-1])[:, None]
-        return rows // self.k, jnp.diff(inside), live
+        return rows // self.k, _chunk_sizes(self.bounds, lo, self.chunk), live
 
     def by_expert(self, sizes):
         """Group sizes by expert, the folds merged."""
         return sizes.reshape(self.held, self.folds).sum(axis=1)
 
-    def forward(self, tok, sizes, live):
-        dot = functools.partial(jax.lax.ragged_dot,
-                                group_sizes=self.by_expert(sizes),
-                                preferred_element_type=jnp.float32)
+    def dot(self, sizes):
+        """The grouped product by an expert-major stack, rows by expert."""
+        return functools.partial(jax.lax.ragged_dot,
+                                 group_sizes=self.by_expert(sizes),
+                                 preferred_element_type=jnp.float32)
+
+    def hidden(self, tok, sizes):
+        """``(xs, a, b, mid)`` of a chunk: all of the forward that the
+        backward pass needs again."""
+        dot = self.dot(sizes)
         xs = jnp.take(self.tokens, tok, axis=0)
         a, b = dot(xs, self.w1), dot(xs, self.w3)
         mid = self.cast((jax.nn.relu(a) if self.relu else jax.nn.silu(a)) * b)
+        return xs, a, b, mid
+
+    def forward(self, tok, sizes, live):
+        """The experts' outputs ``[chunk, H]`` float32 of a chunk's rows."""
+        mid = self.hidden(tok, sizes)[-1]
         # rows past the held assignments belong to no group: whatever the
         # grouped product leaves there is not a result
-        return xs, a, b, mid, jnp.where(live, dot(mid, self.w2), 0.0)
+        return jnp.where(live, self.dot(sizes)(mid, self.w2), 0.0)
 
     def per_token(self, buf):
         """Sorted rows ``[rows, ...]`` back in token-major order ``[S * T, k,
@@ -585,7 +644,7 @@ def _experts_forward(m, sel, w, w1, w3, w2, first_expert, cdt, relu=False):
     ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt, relu)
 
     def body(lo, buf):
-        ys = ex.forward(*ex.rows_of(lo))[-1]
+        ys = ex.forward(*ex.rows_of(lo))
         return jax.lax.dynamic_update_slice_in_dim(
             buf, ys.astype(buf.dtype), lo, axis=0)
 
@@ -602,7 +661,10 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
                       relu=False):
     """Cotangents ``(dm [S, T, H], dw [S, T, k], dw1, dw3, dw2 [S, E, ..])``
     of :func:`_experts_forward` for ``dy [S, T, H]``, the forward recomputed
-    chunk by chunk."""
+    chunk by chunk (all but its product by ``w2``). A chunk whose rows lie
+    within ``WINDOW_EXPERTS`` experts' groups adds its stack gradients into
+    that window of the stacks, any other into the whole stacks: the same
+    products into the same accumulators in the same order."""
     ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt, relu)
     wdot = functools.partial(
         jax.lax.ragged_dot_general,
@@ -612,17 +674,22 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
     wk_sorted = jnp.take(ex.wk.reshape(-1), ex.order)
     w1t, w3t, w2t = (jnp.swapaxes(a, 1, 2) for a in (ex.w1, ex.w3, ex.w2))
 
+    groups, window = ex.folds * ex.held, _window_groups(ex.folds, ex.held)
+
     def body(lo, carry):
         dxs_buf, dwk_buf, dw1, dw3, dw2 = carry
         tok, sizes, live = ex.rows_of(lo)
-        xs, a, b, mid, ys = ex.forward(tok, sizes, live)
-        dot = functools.partial(jax.lax.ragged_dot,
-                                group_sizes=ex.by_expert(sizes),
-                                preferred_element_type=jnp.float32)
-        g = jnp.take(dys_tok, tok, axis=0).astype(jnp.float32)
-        wk = jax.lax.dynamic_slice_in_dim(wk_sorted, lo, ex.chunk)
-        dys = ex.cast(jnp.where(live, g * wk[:, None], 0.0))
-        dmid = dot(dys, w2t)
+        xs, a, b, mid = ex.hidden(tok, sizes)
+        dot = ex.dot(sizes)
+        g = jnp.take(dys_tok, tok, axis=0)
+        wk = jax.lax.dynamic_slice_in_dim(wk_sorted, lo, ex.chunk)[:, None]
+        # a row's weight commutes with the product by w2: u serves dmid and,
+        # since sum_h (mid w2)_h g_h = sum_f mid_f (g w2^T)_f, the weight's
+        # own cotangent, without the forward's product by w2
+        u = dot(g, w2t)
+        dmid = u * wk
+        dwk = jnp.where(live[:, 0], (mid.astype(jnp.float32) * u).sum(-1), 0.0)
+        dys = ex.cast(jnp.where(live, g.astype(jnp.float32) * wk, 0.0))
         if relu:  # d relu(a) b: the gate's cotangent is b where a > 0
             on = live & (a > 0)
             da = ex.cast(jnp.where(on, dmid * b, 0.0))
@@ -633,13 +700,28 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
                 live, dmid * b * sig * (1.0 + a * (1.0 - sig)), 0.0))
             db = ex.cast(jnp.where(live, dmid * a * sig, 0.0))
         dxs = jnp.where(live, dot(da, w1t) + dot(db, w3t), 0.0)
+        pairs = ((xs, da), (xs, db), (mid, dys))
+
+        def whole(stacks):
+            return tuple(dw + wdot(x, d, sizes)
+                         for dw, (x, d) in zip(stacks, pairs))
+
+        def windowed(stacks):
+            cut = jax.lax.dynamic_slice_in_dim
+            return tuple(jax.lax.dynamic_update_slice_in_dim(
+                dw, cut(dw, g0, window) + wdot(x, d, inside), g0, axis=0)
+                for dw, (x, d) in zip(stacks, pairs))
+
+        stacks = (dw1, dw3, dw2)
+        if window < groups:
+            g0, inside, narrow = stack_window(sizes, window)
+            stacks = jax.lax.cond(narrow, windowed, whole, stacks)
+        else:
+            stacks = whole(stacks)
         put = jax.lax.dynamic_update_slice_in_dim
         return (put(dxs_buf, dxs.astype(dxs_buf.dtype), lo, axis=0),
-                put(dwk_buf, (ys * g).sum(-1), lo, axis=0),
-                dw1 + wdot(xs, da, sizes), dw3 + wdot(xs, db, sizes),
-                dw2 + wdot(mid, dys, sizes))
+                put(dwk_buf, dwk, lo, axis=0)) + stacks
 
-    groups = ex.folds * ex.held
     f, h = w1.shape[2], ex.h
     dxs_buf, dwk_buf, dw1, dw3, dw2 = ex.walk(body, (
         jnp.zeros((ex.rows, h), ex.tokens.dtype),

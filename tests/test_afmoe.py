@@ -222,6 +222,139 @@ def test_no_token_is_dropped_when_all_pick_the_same_held_expert(first):
         assert float(jnp.abs(want).max()) > 0.1  # the share did the work
 
 
+# -- the backward pass's chunk: the stacks' window, the weights' cotangent -------
+
+FOLDS, K2, WIN_CHUNK = 2, 2, 16  # 2 folds x 4 held = 8 groups, a window of 4
+
+# assignments on each held expert, [fold][expert], of T * K2 = 64 a fold; the
+# rest go to experts held elsewhere
+ROUTINGS = {
+    # 16 rows a group: a chunk of 16 lies in one or two groups
+    "narrow": ([[16, 16, 16, 16], [16, 16, 16, 16]], (8, 8)),
+    # 2 rows a group: the one live chunk spans all 8
+    "wide": ([[2, 2, 2, 2], [2, 2, 2, 2]], (0, 1)),
+    # expert 0 fills two chunks and a half; the third spans five groups
+    "mix": ([[21, 2, 2, 2], [20, 2, 3, 2]], (3, 4)),
+    # everything on the last expert: the window's start is clamped
+    "last": ([[0, 0, 0, 24], [0, 0, 0, 24]], (3, 3)),
+    # the last chunk starts in the last group but one, past the last window
+    "clamped": ([[16, 16, 16, 9], [16, 16, 16, 7]], (7, 7)),
+}
+
+
+def _routed(counts, seed: int = 0):
+    """``(m, sel, w, w1, w3, w2, dy)`` of two folds whose tokens' slots go to
+    the held experts ``counts`` times, in a seeded order."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 8)
+    sel = []
+    for s, row in enumerate(counts):
+        slots = np.concatenate(
+            [np.full(n, e) for e, n in enumerate(row)]
+            + [HELD + np.arange(T * K2 - sum(row)) % (EXPERTS - HELD)])
+        sel.append(np.asarray(jax.random.permutation(k[s], slots)).reshape(T, K2))
+    h, f = 64, 32
+    return (jax.random.normal(k[2], (FOLDS, T, h)),
+            jnp.asarray(np.stack(sel), jnp.int32),
+            jax.random.uniform(k[3], (FOLDS, T, K2), minval=0.2),
+            0.2 * jax.random.normal(k[4], (HELD, h, f)),
+            0.2 * jax.random.normal(k[5], (HELD, h, f)),
+            0.2 * jax.random.normal(k[6], (HELD, f, h)),
+            jax.random.normal(k[7], (FOLDS, T, h)))
+
+
+def _plain_experts(m, sel, w, w1, w3, w2, relu):
+    """One fold's held part, every assignment's expert gathered whole."""
+    held = sel < HELD
+    e = jnp.where(held, sel, 0)
+    a = jnp.einsum("th,tkhf->tkf", m, w1[e])
+    b = jnp.einsum("th,tkhf->tkf", m, w3[e])
+    mid = (jax.nn.relu(a) if relu else jax.nn.silu(a)) * b
+    return jnp.einsum("tkf,tkfh,tk->th", mid, w2[e], jnp.where(held, w, 0.0))
+
+
+@pytest.mark.parametrize("relu", [False, True], ids=["swiglu", "reglu"])
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_stacks_window_changes_no_cotangent(monkeypatch, routing, relu):
+    """Chunks that add into a window of the stacks, chunks that add into the
+    whole stacks, and both in one walk: the five cotangents are the whole
+    path's, and the plain layer's."""
+    counts, (narrow, live) = ROUTINGS[routing]
+    m, sel, w, w1, w3, w2, dy = _routed(counts)
+    monkeypatch.setattr(afmoe, "ROW_CHUNK", WIN_CHUNK)
+    taken = afmoe.window_chunks(np.asarray(counts), FOLDS * T * K2)
+    assert (int(taken[0]), int(taken[1])) == (narrow, live)
+    with jax.default_matmul_precision("highest"):
+        got = afmoe._experts_backward(m, sel, w, w1, w3, w2, dy, 0, None, relu)
+        monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", HELD)  # no window built
+        whole = afmoe._experts_backward(m, sel, w, w1, w3, w2, dy, 0, None, relu)
+        want = jax.vmap(lambda m, sel, w, dy: jax.vjp(
+            lambda m, w, w1, w3, w2: _plain_experts(m, sel, w, w1, w3, w2, relu),
+            m, w, w1, w3, w2)[1](dy))(m, sel, w, dy)
+    for a, b in zip(got, whole):
+        assert float(jnp.abs(a - b).max()) <= 1e-6 * float(jnp.abs(b).max())
+    for name, a, b in zip(("dm", "dw", "dw1", "dw3", "dw2"), got, want):
+        assert float(jnp.abs(a - b).max()) < 2e-4 * max(
+            float(jnp.abs(b).max()), 1e-3), name
+    assert float(jnp.abs(got[1]).max()) > 0.1  # the weights' cotangent is there
+
+
+def test_a_window_past_the_last_groups_is_clamped():
+    """The chunk's first nonempty group is the last: the window starts
+    ``window`` groups before the end and only empty groups come before."""
+    g0, inside, narrow = afmoe.stack_window(jnp.asarray([0, 0, 0, 0, 0, 0, 0, 5]), 4)
+    assert (int(g0), inside.tolist(), bool(narrow)) == (4, [0, 0, 0, 5], True)
+    g0, inside, narrow = afmoe.stack_window(jnp.asarray([0, 3, 0, 0, 2, 1, 0, 0]), 4)
+    assert (int(g0), inside.tolist(), bool(narrow)) == (1, [3, 0, 0, 2], False)
+    g0, inside, narrow = afmoe.stack_window(jnp.asarray([0, 0, 3, 0, 0, 2, 0, 0]), 4)
+    assert (int(g0), inside.tolist(), bool(narrow)) == (2, [3, 0, 0, 2], True)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_window_chunks_counts_what_a_walk_over_the_rows_finds(monkeypatch, seed):
+    """The counter against a brute-force walk: a chunk takes the window where
+    its rows' groups all lie within ``window`` groups of the first (or of the
+    last window's start)."""
+    monkeypatch.setattr(afmoe, "ROW_CHUNK", 64)
+    rng = np.random.default_rng(seed)
+    folds, held, rows = 2, 8, 2048
+    counts = rng.integers(0, (8, 40, 150, 300)[seed], (folds, held))
+    counts[:, rng.integers(held)] = 0  # an empty expert
+    for experts in (2, 4):
+        monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", experts)
+        window = experts * folds
+        group_of = np.repeat(np.arange(folds * held), counts.T.reshape(-1))
+        narrow = live = 0
+        for lo in range(0, rows, 64):
+            inside = group_of[lo: lo + 64]
+            if not len(inside):
+                continue
+            live += 1
+            start = min(inside[0], folds * held - window)
+            narrow += inside[-1] < start + window
+        got = afmoe.window_chunks(counts, rows)
+        assert (int(got[0]), int(got[1])) == (narrow, live)
+    monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", held)  # no window is built
+    none = afmoe.window_chunks(counts, rows)
+    assert (int(none[0]), int(none[1])) == (0, live)
+
+
+@pytest.mark.parametrize("relu", [False, True], ids=["swiglu", "reglu"])
+def test_the_weights_cotangent_needs_no_product_by_w2(relu):
+    """``sum_h (mid w2)_h g_h = sum_f mid_f (g w2^T)_f`` row by row under the
+    grouped products, in float32: what the chunk reads for ``dwk``."""
+    k = jax.random.split(jax.random.PRNGKey(5), 4)
+    sizes = jnp.asarray([5, 0, 9, 2], jnp.int32)
+    a, b = jax.random.normal(k[0], (2, 16, 32))
+    mid = (jax.nn.relu(a) if relu else jax.nn.silu(a)) * b
+    w2, g = jax.random.normal(k[1], (4, 32, 64)), jax.random.normal(k[2], (16, 64))
+    with jax.default_matmul_precision("highest"):
+        ys = jax.lax.ragged_dot(mid, w2, sizes)
+        u = jax.lax.ragged_dot(g, jnp.swapaxes(w2, 1, 2), sizes)
+    old, new = (ys * g).sum(-1), (mid * u).sum(-1)
+    assert float(jnp.abs(old - new).max()) < 1e-5 * float(jnp.abs(old).max())
+    assert float(jnp.abs(old[:16]).max()) > 1.0
+
+
 # -- attention ----------------------------------------------------------------
 
 

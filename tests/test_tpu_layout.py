@@ -256,6 +256,38 @@ def test_grouped_products_lower_to_the_compilers_kernel(one_chip,
         ).compile()
 
 
+def test_experts_backward_adds_into_a_window_of_the_stacks(one_chip,
+                                                          no_compile_cache):
+    """The expert layer's backward pass at ``smallthinker-21b-ep8.dsgd-fold2-
+    long``'s shapes (2 folds x 16,384 tokens, top-6, 8 held ReGLU experts of
+    2,560 x 768): the chunk's stack gradients have a grouped product of the
+    WINDOW's shape (two experts' four groups) beside the whole stacks' sixteen,
+    no whole stack is ever copied (the window's update is in place in the
+    carried stack), and the program's temporaries are no more than they were
+    before the window (1.759 GiB, ISSUE 35)."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    folds, t, h, k, held, f = 2, 16384, 2560, 6, 8, 768
+    groups, window = folds * held, afmoe.WINDOW_EXPERTS * folds
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda m, sel, w, w1, w3, w2, dy: afmoe._experts_backward(
+        m, sel, w, w1, w3, w2, dy, 0, jnp.bfloat16, True)).lower(
+        sds((folds, t, h)), sds((folds, t, k), jnp.int32), sds((folds, t, k)),
+        sds((held, h, f)), sds((held, h, f)), sds((held, f, h)),
+        sds((folds, t, h))).compile()
+    text = compiled.as_text()
+    for rows, cols in ((h, f), (f, h)):
+        for n in (window, groups):
+            assert re.search(r"%%ragged-dot[\w.-]* = f32\[%d,%d,%d\]"
+                             % (n, rows, cols), text), (n, rows, cols)
+        assert not re.search(r"= f32\[%d,%d,%d\]\S* copy\(" % (groups, rows, cols),
+                             text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.759 * 2 ** 30
+
+
 # -- the LSTM kernels' blocks against the chip's own limits (ISSUE 31) ---------
 
 
