@@ -9,7 +9,11 @@ scripts/profile_epoch.py's one-off attribution. Now:
 - :mod:`.tracer` — thread-safe host-side **span tracer**: monotonic nested
   spans (safe across the trainer/prefetch.py planner thread), emitted as
   JSONL and as Chrome trace-event JSON (load in Perfetto / chrome://tracing).
-  Also the home of the ONE ``duration`` bookkeeping helper (formerly
+  A span has two sinks: the tracer's own event buffer (its own clock; on when
+  ``TrainConfig.telemetry="on"``) and, for an annotating tracer, the host
+  plane of a running ``jax.profiler`` session (the device trace's clock;
+  the fit loop's tracer always annotates: ``PROFILER_TRACER`` when telemetry
+  is off). Also the home of the ONE ``duration`` bookkeeping helper (formerly
   trainer/logs.py) and of bench.py's feed timing.
 - :mod:`.metrics` — **on-device round metrics** riding the epoch's rounds
   scan (trainer/steps.py): per-site grad/update norms, engine aggregation
@@ -21,7 +25,15 @@ scripts/profile_epoch.py's one-off attribution. Now:
   configurable epoch range (``TrainConfig.xprof_dir`` / ``xprof_window``,
   CLI ``--xprof-dir``) plus the device-op trace summarizer
   scripts/profile_epoch.py consumes.
-- :mod:`.scopes` — the **device scopes**: the names of the five
+- :mod:`.scopes` — the names the program gives its own work. The **host
+  half**: the seven spans of the fit loop (``plan-wait``, ``plan-build``,
+  ``epoch-inputs``, ``epoch-dispatch``, ``loss-fetch``, ``epoch-account``,
+  ``inventory-upload``; trainer/loop.py ``run_epoch``, trainer/prefetch.py),
+  each opened with ``epoch=`` and written as ``dinunet/<name>`` into any
+  profiler session, whatever ``cfg.telemetry`` says: under ``--xprof-dir`` they
+  lie above the device's operations on one clock, and the benchmark's
+  ``loop_*_idle_ms_*`` metrics put the device's idle gaps down to them. The
+  **device half**, the device scopes: the names of the five
   ``jax.named_scope``s the epoch program always wears (``data/gather``,
   ``model/fwd_bwd``, ``engine/aggregate`` with ``poweriter`` nested in it,
   ``optimizer/update``; trainer/steps.py, engines/lowrank.py) beside the two
@@ -82,10 +94,17 @@ one of the counters telemetry exports.
 
 from .bus import NULL_BUS, MetricsBus, global_bus
 from .hist import LogHistogram
-from .tracer import NULL_TRACER, SpanTracer, duration, new_trace_id
+from .tracer import (
+    NULL_TRACER,
+    PROFILER_TRACER,
+    SpanTracer,
+    duration,
+    new_trace_id,
+)
 
 __all__ = [
     "NULL_TRACER",
+    "PROFILER_TRACER",
     "SpanTracer",
     "duration",
     "new_trace_id",

@@ -1,14 +1,29 @@
 """Thread-safe host-side span tracer.
 
 One tracer instance serves a whole fit: the training loop opens phase spans
-(``fit`` / ``epoch`` / ``eval`` / ``checkpoint``), the prefetch planner
-thread opens ``plan-build`` spans concurrently, and bench.py times its
-per-epoch feed path — all into one event buffer. Spans nest per thread
-(each thread keeps its own stack), timestamps come from ONE monotonic clock
-(``time.perf_counter`` relative to the tracer's birth), so cross-thread
-ordering in the emitted trace is real.
+(``fit`` / ``epoch`` / ``eval`` / ``checkpoint``) and the spans of its own
+host work (telemetry/scopes.py, host half), the prefetch planner thread opens
+``plan-build`` spans concurrently, and bench.py times its per-epoch feed path.
 
-Output formats:
+A span has two sinks, each on a clock of its own:
+
+- the tracer's **event buffer** (``enabled=True``): spans nest per thread (each
+  thread keeps its own stack), timestamps come from ONE monotonic clock
+  (``time.perf_counter`` relative to the tracer's birth), so cross-thread
+  ordering in the emitted trace is real. That clock is the tracer's alone:
+  nothing here can be laid over a device trace;
+- the **profiler's host plane** (``annotate=True``): the span's body runs under
+  a ``jax.profiler.TraceAnnotation`` named ``scopes.HOST_PREFIX + name``, its
+  keyword attributes the event's stats. Whenever a ``jax.profiler`` session
+  is running (the benchmark's ``--trace 1`` stretch, an operator's
+  ``--xprof-dir`` window) the span lies in the same ``.xplane.pb`` as the
+  device's ``XLA Ops`` events, on THEIR clock; with no session an annotation
+  costs under a microsecond and leaves nothing behind.
+
+The sinks are independent: :data:`PROFILER_TRACER` records nothing and
+annotates, :data:`NULL_TRACER` does neither.
+
+Output formats of the event buffer:
 
 - ``write_jsonl(path)`` — one JSON object per event (machine-diffable; the
   report CLI's input);
@@ -21,7 +36,8 @@ call site — jaxlint R007 enforces it — so traces stay greppable and stable
 across runs.
 
 Deliberately stdlib-only: the report CLI and bench's host-side timing must
-not pull jax in.
+not pull jax in. The annotation class is taken from ``sys.modules`` when a
+span opens: a process that never imported jax annotates nothing.
 """
 
 from __future__ import annotations
@@ -31,7 +47,9 @@ import os
 import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
+
+from .scopes import HOST_PREFIX
 
 
 def duration(cache: dict, start: float, key: str):
@@ -61,13 +79,17 @@ def new_trace_id() -> str:
 class SpanTracer:
     """Collect nested spans + instant events + counters across threads.
 
-    ``enabled=False`` builds a no-op tracer (every call returns immediately)
-    so call sites can thread one tracer object unconditionally —
-    :data:`NULL_TRACER` is the shared disabled instance.
+    ``enabled=False`` records nothing (every call but ``span`` returns
+    immediately) so call sites can thread one tracer object unconditionally —
+    :data:`NULL_TRACER` is the shared disabled instance. ``annotate=True``
+    also puts every span into the profiler's host plane (module docstring),
+    whether or not it is recorded: :data:`PROFILER_TRACER` is the shared
+    instance that only annotates.
     """
 
-    def __init__(self, enabled: bool = True):
+    def __init__(self, enabled: bool = True, annotate: bool = False):
         self.enabled = enabled
+        self.annotate = annotate
         self._lock = threading.Lock()
         self._events: list[dict] = []
         self._listeners: list = []
@@ -112,37 +134,50 @@ class SpanTracer:
         with self._lock:
             self._listeners.append(fn)
 
-    @contextmanager
+    def _annotation(self, name: str, attrs: dict):
+        """The profiler annotation around one span's body, or a null context
+        where this tracer does not annotate or jax was never imported."""
+        jax = sys.modules.get("jax") if self.annotate else None
+        if jax is None:
+            return nullcontext()
+        return jax.profiler.TraceAnnotation(HOST_PREFIX + name, **attrs)
+
     def span(self, name: str, **attrs):
         """Context manager for one named span. Nests per thread; closes (and
         records) on ANY exit — normal return, early ``break``, or an
         exception unwinding through (``Preempted`` included), with
-        ``ok: false`` marking the exceptional exits."""
+        ``ok: false`` marking the exceptional exits. A tracer that records
+        nothing hands out the bare annotation (or a null context): no
+        generator frame on the fit loop's path."""
         if not self.enabled:
-            yield self
-            return
-        stack = self._stack()
-        depth = len(stack)
-        stack.append(name)
-        start = time.perf_counter()
-        try:
-            yield self
-        finally:
-            stack.pop()
-            end = time.perf_counter()
-            self._record({
-                "ph": "X",
-                "name": name,
-                "ts": (start - self._t0) * 1e6,  # trace-event µs
-                "dur": (end - start) * 1e6,
-                "tid": threading.get_ident(),
-                "thread": threading.current_thread().name,
-                "depth": depth,
-                # sys.exc_info survives into finally only while an exception
-                # is actually unwinding through the with-body
-                "ok": sys.exc_info()[0] is None,
-                **attrs,
-            })
+            return self._annotation(name, attrs)
+        return self._recorded(name, attrs)
+
+    @contextmanager
+    def _recorded(self, name: str, attrs: dict):
+        with self._annotation(name, attrs):
+            stack = self._stack()
+            depth = len(stack)
+            stack.append(name)
+            start = time.perf_counter()
+            try:
+                yield self
+            finally:
+                stack.pop()
+                end = time.perf_counter()
+                self._record({
+                    "ph": "X",
+                    "name": name,
+                    "ts": (start - self._t0) * 1e6,  # trace-event µs
+                    "dur": (end - start) * 1e6,
+                    "tid": threading.get_ident(),
+                    "thread": threading.current_thread().name,
+                    "depth": depth,
+                    # sys.exc_info survives into finally only while an exception
+                    # is actually unwinding through the with-body
+                    "ok": sys.exc_info()[0] is None,
+                    **attrs,
+                })
 
     def event(self, name: str, **attrs) -> None:
         """Instant event (checkpoint written, site quarantined, retry...)."""
@@ -250,3 +285,6 @@ class SpanTracer:
 
 #: shared no-op tracer — thread it where telemetry is off instead of None
 NULL_TRACER = SpanTracer(enabled=False)
+#: shared tracer that records nothing and annotates: the fit loop's when
+#: ``cfg.telemetry`` is off, so that its host spans are in every profile
+PROFILER_TRACER = SpanTracer(enabled=False, annotate=True)

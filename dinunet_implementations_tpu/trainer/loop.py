@@ -44,7 +44,8 @@ from .checkpoint import (
 from ..robustness.faults import poison_inputs
 from ..robustness.health import health_summary
 from ..robustness.preemption import Preempted, PreemptionGuard
-from ..telemetry.tracer import NULL_TRACER, SpanTracer, duration
+from ..telemetry import scopes
+from ..telemetry.tracer import PROFILER_TRACER, SpanTracer, duration
 from .logs import (
     fold_dir,
     health_log_fields,
@@ -151,9 +152,11 @@ class FederatedTrainer:
 
         enable_compile_cache(cfg.compile_cache_dir)
         # unified telemetry (telemetry/): span tracer + on-device round
-        # metrics + manifest/metrics artifacts. Off = a disabled (no-op)
-        # tracer and a telemetry-free epoch program (bitwise-equal to the
-        # pre-telemetry one).
+        # metrics + manifest/metrics artifacts. Off = a tracer that records
+        # nothing and a telemetry-free epoch program (bitwise-equal to the
+        # pre-telemetry one). The loop's host spans (telemetry/scopes.py,
+        # host half) do not hang on the switch: either tracer writes them
+        # into a running jax.profiler session.
         if cfg.telemetry not in ("on", "off"):
             raise ValueError(
                 f"cfg.telemetry must be 'on' or 'off', got {cfg.telemetry!r}"
@@ -165,7 +168,9 @@ class FederatedTrainer:
                 "capture) are mutually exclusive — jax.profiler supports one "
                 "active trace"
             )
-        self.tracer = SpanTracer() if self._telemetry_on else NULL_TRACER
+        self.tracer = (
+            SpanTracer(annotate=True) if self._telemetry_on else PROFILER_TRACER
+        )
         # live metrics (telemetry/bus.py): published into the process-wide
         # bus when telemetry is on (the /statusz exporter's read side), the
         # NULL bus otherwise. Publishing is host-side bookkeeping over
@@ -331,7 +336,7 @@ class FederatedTrainer:
             return state
         return jax.tree.map(jnp.copy, state)
 
-    def _ensure_inventory(self, train_sites):
+    def _ensure_inventory(self, train_sites, epoch=None):
         """Device-resident inventory: uploaded once per fit in its RESIDENT
         FORM (data/api.py SiteInventory: ``[S, rows + 1, *stored_sample_shape]``
         — every site's subjects, then one all-zero row that the plan's ``-1``
@@ -352,7 +357,7 @@ class FederatedTrainer:
         if self._inventory is None or self._inventory_src != key:
             from ..parallel.distributed import put_site_inventory
 
-            with self.tracer.span("inventory-upload"):
+            with self.tracer.span(scopes.INVENTORY_UPLOAD, epoch=epoch):
                 self._inventory = put_site_inventory(
                     self.mesh,
                     stack_site_inventory(
@@ -374,7 +379,7 @@ class FederatedTrainer:
         prefetch thread in steady state)."""
         from ..robustness.faults import fault_window
 
-        with self.tracer.span("plan-build", epoch=epoch):
+        with self.tracer.span(scopes.PLAN_BUILD, epoch=epoch):
             plan = plan_epoch_positions(
                 train_sites, batch_size,
                 seed=self.cfg.seed * 100003 + epoch, pad_mode="wrap",
@@ -478,25 +483,52 @@ class FederatedTrainer:
         ``_build_epoch_payload`` result; built inline when None). Host
         pipeline: materializes and ships the dense epoch tensor."""
         if self._pipeline == "device":
-            if plan is None:
-                plan = self._build_epoch_payload(
-                    train_sites, epoch, batch_size or self.cfg.batch_size,
-                    round0=int(state.round),
+            with self.tracer.span(scopes.EPOCH_INPUTS, epoch=epoch):
+                if plan is None:
+                    plan = self._build_epoch_payload(
+                        train_sites, epoch, batch_size or self.cfg.batch_size,
+                        round0=int(state.round),
+                    )
+                idx, live, poison, attack, slice_live = plan
+                inv_x, inv_y = self._ensure_inventory(train_sites, epoch)
+                # the device pipeline's ENTIRE per-epoch host→device traffic
+                self._last_transfer_bytes = int(sum(
+                    a.nbytes for a in (idx, live, poison, attack, slice_live)
+                    if a is not None
+                ))
+                self._publish_slice_liveness(slice_live)
+            with self.tracer.span(scopes.EPOCH_DISPATCH, epoch=epoch):
+                state, losses = self.epoch_fn(
+                    state, inv_x, inv_y, idx, live, poison, attack, slice_live
                 )
-            idx, live, poison, attack, slice_live = plan
-            inv_x, inv_y = self._ensure_inventory(train_sites)
-            # the device pipeline's ENTIRE per-epoch host→device traffic
-            self._last_transfer_bytes = int(sum(
-                a.nbytes for a in (idx, live, poison, attack, slice_live)
-                if a is not None
-            ))
-            self._publish_slice_liveness(slice_live)
+            return state, self._fetch_and_account(
+                train_sites, losses, epoch, batch_size
+            )
+        with self.tracer.span(scopes.EPOCH_INPUTS, epoch=epoch):
+            batch, live_dev, attack_dev, slice_dev = self._host_epoch_inputs(
+                state, train_sites, epoch, batch_size
+            )
+        with self.tracer.span(scopes.EPOCH_DISPATCH, epoch=epoch):
             state, losses = self.epoch_fn(
-                state, inv_x, inv_y, idx, live, poison, attack, slice_live
+                state, *batch, live_dev, attack_dev, slice_dev
             )
-            return state, self._account_epoch(
-                train_sites, np.asarray(losses), batch_size
-            )
+        return state, self._fetch_and_account(
+            train_sites, losses, epoch, batch_size
+        )
+
+    def _fetch_and_account(self, train_sites, losses, epoch: int, batch_size):
+        """The end of an epoch on the host: the loss fetch (the wait for the
+        device, then the copy), then the epoch's accounting, each under its
+        span."""
+        with self.tracer.span(scopes.LOSS_FETCH, epoch=epoch):
+            losses = np.asarray(losses)
+        with self.tracer.span(scopes.EPOCH_ACCOUNT, epoch=epoch):
+            return self._account_epoch(train_sites, losses, batch_size)
+
+    def _host_epoch_inputs(self, state, train_sites, epoch: int, batch_size):
+        """The host pipeline's epoch inputs, placed: ``(batch, live, attack,
+        slice_live)`` — the dense epoch tensor and the fault, attack and
+        slice masks of its round window."""
         fb = plan_epoch(
             train_sites,
             batch_size or self.cfg.batch_size,
@@ -561,12 +593,7 @@ class FederatedTrainer:
                   if a is not None)
         )
         self._publish_slice_liveness(slice_live)
-        state, losses = self.epoch_fn(
-            state, *batch, live_dev, attack_dev, slice_dev
-        )
-        return state, self._account_epoch(
-            train_sites, np.asarray(losses), batch_size
-        )
+        return batch, live_dev, attack_dev, slice_dev
 
     def _account_epoch(self, train_sites, losses, batch_size=None):
         """Step the RDP ledger by this epoch's executed rounds and publish
@@ -932,7 +959,7 @@ class FederatedTrainer:
                 lambda e: self._build_epoch_payload(
                     train_sites, e, cfg.batch_size, round0 + (e - first) * rpe
                 ),
-                start_epoch, cfg.epochs,
+                start_epoch, cfg.epochs, tracer=self.tracer,
             )
         guard = PreemptionGuard()
         try:

@@ -36,6 +36,8 @@ import queue
 import threading
 import time
 
+from ..telemetry import scopes
+from ..telemetry.tracer import PROFILER_TRACER
 from .logs import log_warning
 
 
@@ -51,11 +53,18 @@ class EpochPlanPrefetcher:
     spent BLOCKED waiting on the builder (``stall_s``: the double-buffering
     failure signal), gets served, inline-build fallbacks, and the summed
     queue depth at get time — surfaced via :meth:`stats` into the fit's
-    ``metrics.jsonl`` summary row (telemetry/sink.py).
+    ``metrics.jsonl`` summary row (telemetry/sink.py). ``stall_s`` is a sum
+    over the fit on the host's clock; beside it every :meth:`get` runs under
+    the ``plan-wait`` span (``scopes.PLAN_WAIT``) of ``tracer``, which by
+    default only annotates: in a ``jax.profiler`` session each wait lies, with
+    its ``epoch``, on the clock of the device's operations, so a device idle
+    gap can be laid over it.
     """
 
-    def __init__(self, build, first_epoch: int, last_epoch: int):
+    def __init__(self, build, first_epoch: int, last_epoch: int, *,
+                 tracer=PROFILER_TRACER):
         self._build = build
+        self._tracer = tracer
         self._queue: queue.Queue = queue.Queue(maxsize=1)
         self._stop = threading.Event()
         self._error: BaseException | None = None
@@ -95,6 +104,10 @@ class EpochPlanPrefetcher:
     def get(self, epoch: int):
         """The prefetched payload for ``epoch`` (blocking briefly if the
         builder is still working on it). Re-raises a builder crash."""
+        with self._tracer.span(scopes.PLAN_WAIT, epoch=epoch):
+            return self._get(epoch)
+
+    def _get(self, epoch: int):
         t0 = time.perf_counter()
         self._gets += 1
         self._depth_sum += self._queue.qsize()

@@ -306,3 +306,105 @@ def test_every_kernel_smallthinker_launches_is_read_by_a_new_metric():
                           "smallthinker_moe_grouped_matmul_ms_per_round.json"
                           ).read_text())
     assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")
+
+
+# -- the host half: the fit loop's own spans (ISSUE 36) ---------------------------
+
+HOST_CONSTANTS = ("PLAN_WAIT", "PLAN_BUILD", "EPOCH_INPUTS", "EPOCH_DISPATCH",
+                  "LOSS_FETCH", "EPOCH_ACCOUNT", "INVENTORY_UPLOAD")
+SPAN_METRICS = sorted(
+    p.name for p in (REPO / "benchmarks" / "layer_metrics").glob("*.json")
+    if json.loads(p.read_text()).get("reader") == "program_span_ms")
+
+
+def test_host_span_constants_are_distinct_plain_names():
+    names = [getattr(scopes, c) for c in HOST_CONSTANTS]
+    assert tuple(names) == scopes.HOST_SPANS and len(set(names)) == len(names)
+    for name in names:
+        assert re.fullmatch(r"[a-z]+(-[a-z]+)*", name), name
+    assert scopes.HOST_PREFIX.endswith("/")
+    assert not "bench/".startswith(scopes.HOST_PREFIX)  # the harness's own
+
+
+def test_the_benchmark_reads_the_loops_spans_through_eight_metric_files():
+    assert len(SPAN_METRICS) == 8
+
+
+@pytest.mark.parametrize("metric_file", SPAN_METRICS)
+def test_every_host_span_a_metric_reads_exists(metric_file):
+    """The rule ``KERNEL_NAMES`` is held to, for the host half: a metric file
+    that reads a program span names one that ``scopes.HOST_SPANS`` lists, as
+    it stands, and its ``what`` names the constant — a renamed span fails
+    here instead of falling silent on the chip."""
+    metric = json.loads(
+        (REPO / "benchmarks" / "layer_metrics" / metric_file).read_text())
+    spans = metric["args"]["span"]
+    for name in [spans] if isinstance(spans, str) else spans:
+        assert name in scopes.HOST_SPANS, (metric_file, name)
+        constant = next(c for c in HOST_CONSTANTS if getattr(scopes, c) == name)
+        assert re.search(rf"\b{constant}\b", metric["what"]), (metric_file,
+                                                               constant)
+
+
+def test_every_span_of_the_fit_loop_is_named_from_the_constants():
+    """Every ``tracer.span(...)`` of trainer/loop.py's per-epoch path and of
+    trainer/prefetch.py takes its name from ``scopes``; each host constant is
+    opened somewhere, with ``epoch=``."""
+    opened = {}
+    for rel in ("trainer/loop.py", "trainer/prefetch.py"):
+        tree = ast.parse((PACKAGE / rel).read_text())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "span" and node.args
+                    and isinstance(node.args[0], ast.Attribute)
+                    and getattr(node.args[0].value, "id", "") == "scopes"):
+                opened.setdefault(node.args[0].attr, []).append(
+                    {k.arg for k in node.keywords})
+    assert set(opened) == set(HOST_CONSTANTS)
+    assert all("epoch" in kw for kws in opened.values() for kw in kws)
+
+
+def _toy_trainer_lowered(monkeypatch, tracer=None) -> tuple:
+    """``(lowered epoch program text, trainer)`` of a small dSGD trainer with
+    ``cfg.telemetry`` off, after one epoch through ``run_epoch``; ``tracer``
+    replaces the loop's (None: the one the trainer builds)."""
+    from test_telemetry import _toy_sites
+
+    from dinunet_implementations_tpu.core.config import TrainConfig
+    from dinunet_implementations_tpu.models import MSANNet
+    from dinunet_implementations_tpu.trainer.loop import FederatedTrainer
+
+    sites = _toy_sites(2)
+    trainer = FederatedTrainer(
+        TrainConfig(epochs=1, batch_size=8),
+        MSANNet(in_size=6, hidden_sizes=(8,), out_size=2), mesh=None)
+    if tracer is not None:
+        trainer.tracer = tracer
+    state = trainer.init_state(jnp.ones((8, 6)), num_sites=2)
+    seen = {}
+    real = trainer.epoch_fn
+
+    def spy(*args):
+        seen["text"] = real.lower(*args).as_text()
+        return real(*args)
+
+    monkeypatch.setattr(trainer, "epoch_fn", spy)
+    trainer.run_epoch(state, sites, 1, batch_size=8)
+    return seen["text"], trainer
+
+
+def test_host_spans_leave_the_lowered_epoch_program_as_it_is(monkeypatch):
+    """The spans are host work around the call: the epoch program lowered
+    from ``run_epoch``'s own arguments is the same text under the annotating
+    tracer (``cfg.telemetry`` off) as under one that does nothing."""
+    from dinunet_implementations_tpu.telemetry import (
+        NULL_TRACER,
+        PROFILER_TRACER,
+    )
+
+    with_spans, trainer = _toy_trainer_lowered(monkeypatch)
+    assert trainer.tracer is PROFILER_TRACER
+    without, _ = _toy_trainer_lowered(monkeypatch, tracer=NULL_TRACER)
+    assert diff_report(without, with_spans, "no-spans", "spans") is None
+    assert PROFILER_TRACER.events() == []

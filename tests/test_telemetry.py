@@ -20,7 +20,7 @@ from dinunet_implementations_tpu.engines import make_engine
 from dinunet_implementations_tpu.models import MSANNet
 from dinunet_implementations_tpu.parallel.mesh import SITE_AXIS
 from dinunet_implementations_tpu.robustness import FaultPlan, Preempted
-from dinunet_implementations_tpu.telemetry import SpanTracer, duration
+from dinunet_implementations_tpu.telemetry import SpanTracer, duration, scopes
 from dinunet_implementations_tpu.telemetry.metrics import (
     TELEMETRY_KEYS,
     default_round_telemetry,
@@ -631,3 +631,171 @@ def test_telemetry_summary_rollup_shapes():
     assert np.isnan(s["site_grad_norm_last"][2])
     assert s["rounds"] == 2
     assert telemetry_summary(None) is None
+
+
+# ---------------------------------------------------------------------------
+# the tracer's second sink: the profiler's host plane (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` inside a CPU ``jax.profiler`` session (python tracer
+    off, as the benchmark's traced stretch) and return the host-plane events
+    named ``dinunet/...`` as ``(name, thread line, stats)``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    events = []
+    for path in glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            for i, line in enumerate(plane.lines):
+                events += [
+                    (e.name, f"{plane.name}#{i}", dict(e.stats))
+                    for e in line.events
+                    if e.name.startswith(scopes.HOST_PREFIX)
+                ]
+    return events
+
+
+def _loop_epochs(trainer, sites, epochs):
+    """``epochs`` epochs the way ``_fit_impl`` runs them: plans from the
+    prefetch thread under the trainer's tracer, then ``run_epoch``."""
+    from dinunet_implementations_tpu.trainer.prefetch import EpochPlanPrefetcher
+
+    state = trainer.init_state(jnp.ones((8, 6)), num_sites=len(sites))
+    prefetch = EpochPlanPrefetcher(
+        lambda e: trainer._build_epoch_payload(sites, e, 8, (e - 1) * 3),
+        1, epochs, tracer=trainer.tracer,
+    )
+    try:
+        for epoch in range(1, epochs + 1):
+            state, _ = trainer.run_epoch(
+                state, sites, epoch, batch_size=8, plan=prefetch.get(epoch)
+            )
+    finally:
+        prefetch.close()
+
+
+@pytest.mark.parametrize("telemetry", ["off", "on"])
+def test_fit_loop_spans_reach_the_profiler_whatever_telemetry_says(
+        tmp_path, telemetry):
+    """Every host name of scopes.py is in the profile once an epoch
+    (``inventory-upload`` once a fit), with ``epoch=`` in its stats and
+    ``plan-build`` on another thread line than the loop's — with
+    ``cfg.telemetry`` off (a tracer that records no event) and on (the same
+    names in the event buffer)."""
+    cfg = TrainConfig(epochs=3, batch_size=8, telemetry=telemetry)
+    trainer = FederatedTrainer(
+        cfg, MSANNet(in_size=6, hidden_sizes=(8,), out_size=2), mesh=None)
+    assert trainer.tracer.annotate
+    assert trainer.tracer.enabled == (telemetry == "on")
+    sites = _toy_sites(2)
+    events = _profiled(tmp_path, lambda: _loop_epochs(trainer, sites, 3))
+    by_name: dict = {}
+    for name, line, stats in events:
+        by_name.setdefault(name[len(scopes.HOST_PREFIX):], []).append(
+            (line, stats))
+    assert set(by_name) == set(scopes.HOST_SPANS)
+    for name, hits in by_name.items():
+        once = name == scopes.INVENTORY_UPLOAD
+        assert sorted(s["epoch"] for _, s in hits) == ([1] if once
+                                                       else [1, 2, 3]), name
+    loop_line = {line for line, _ in by_name[scopes.EPOCH_DISPATCH]}
+    assert len(loop_line) == 1
+    for name in (scopes.PLAN_WAIT, scopes.EPOCH_INPUTS, scopes.LOSS_FETCH,
+                 scopes.EPOCH_ACCOUNT, scopes.INVENTORY_UPLOAD):
+        assert {line for line, _ in by_name[name]} == loop_line, name
+    assert not {line for line, _ in by_name[scopes.PLAN_BUILD]} & loop_line
+    recorded = {e["name"] for e in trainer.tracer.events() if e["ph"] == "X"}
+    assert recorded == (set(scopes.HOST_SPANS) if telemetry == "on" else set())
+
+
+def test_null_tracer_annotates_nothing_and_profiler_tracer_records_nothing(
+        tmp_path):
+    from dinunet_implementations_tpu.telemetry import (
+        NULL_TRACER,
+        PROFILER_TRACER,
+    )
+
+    assert not NULL_TRACER.enabled and not NULL_TRACER.annotate
+    assert not PROFILER_TRACER.enabled and PROFILER_TRACER.annotate
+
+    def body():
+        with NULL_TRACER.span("from-null", epoch=1):
+            pass
+        with PROFILER_TRACER.span("from-profiler", epoch=7):
+            PROFILER_TRACER.event("an-event")
+            PROFILER_TRACER.counter("a-counter", 1)
+        with SpanTracer().span("from-plain", epoch=1):
+            pass
+
+    events = _profiled(tmp_path, body)
+    assert [(n, s) for n, _, s in events] == [
+        (scopes.HOST_PREFIX + "from-profiler", {"epoch": 7})]
+    assert PROFILER_TRACER.events() == [] and NULL_TRACER.events() == []
+
+
+def test_tracer_imports_and_spans_without_jax():
+    """tracer.py and scopes.py are stdlib-only: in a process where jax is not
+    imported (the package's ``__init__`` would import it, so the two parents
+    are bare namespaces here) an annotating tracer's spans still work, record
+    when enabled, and pull nothing in."""
+    import subprocess
+    import sys
+
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(
+        sys.modules[SpanTracer.__module__].__file__)))
+    code = f"""
+import importlib, sys, types
+for name, path in (("dinunet_implementations_tpu", {pkg!r}),
+                   ("dinunet_implementations_tpu.telemetry",
+                    {os.path.join(pkg, "telemetry")!r})):
+    mod = types.ModuleType(name)
+    mod.__path__ = [path]
+    sys.modules[name] = mod
+tracer = importlib.import_module("dinunet_implementations_tpu.telemetry.tracer")
+with tracer.PROFILER_TRACER.span("plan-wait", epoch=1):
+    pass
+both = tracer.SpanTracer(annotate=True)
+with both.span("epoch-dispatch", epoch=2):
+    pass
+assert [e["name"] for e in both.events()] == ["epoch-dispatch"]
+assert both.events()[0]["epoch"] == 2
+assert tracer.PROFILER_TRACER.events() == []
+assert "jax" not in sys.modules, "tracer.py pulled jax in"
+print("ok")
+"""
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_prefetcher_keeps_its_positional_signature_and_default_tracer():
+    """The harness builds ``EpochPlanPrefetcher(build, first, last)``: three
+    positional arguments and no tracer; the default one only annotates."""
+    from dinunet_implementations_tpu.telemetry import PROFILER_TRACER
+    from dinunet_implementations_tpu.trainer.prefetch import EpochPlanPrefetcher
+
+    pf = EpochPlanPrefetcher(lambda e: e * 10, 1, 2)
+    try:
+        assert pf._tracer is PROFILER_TRACER
+        assert [pf.get(1), pf.get(2)] == [10, 20]
+        assert pf.stats()["gets"] == 2
+    finally:
+        pf.close()
+    recording = SpanTracer()
+    pf = EpochPlanPrefetcher(lambda e: e, 1, 1, tracer=recording)
+    try:
+        pf.get(1)
+    finally:
+        pf.close()
+    (ev,) = recording.events()
+    assert ev["name"] == scopes.PLAN_WAIT and ev["epoch"] == 1
