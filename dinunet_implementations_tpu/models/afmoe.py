@@ -116,6 +116,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import rope_pallas
 from ..telemetry import scopes
 from .layers import compute_dtype_of
 
@@ -231,7 +232,11 @@ def blocked_attention(q, k, v, window, q_block: int, kv_chunk: int, cdt=None):
 # attention: the causal and the causal-window mask are block maps, a masked
 # block is never visited, scores live in VMEM only). Their names, as the
 # compiler's instructions carry them (a transform wraps them), are constants
-# here so that a metric's pattern has something to hold on to.
+# here so that a metric's pattern has something to hold on to. They take
+# their operands head-major (``splash_heads``); a layer with rotary positions
+# whose heads are whole lane tiles writes them so from its raw projections in
+# one pass (``ops/rope_pallas.py``), every other caller through XLA's
+# cast, ``moveaxis`` and scale (``kernel_attention``).
 
 ATTN_FWD = "splash_mqa_fwd"  # forward, keeps the log-sum-exp for the backward
 ATTN_DQ = "splash_mqa_dq"  # backward: the queries' cotangent
@@ -403,20 +408,33 @@ def kernel_attention(q, k, v, window, blocks=None, cdt=None):
     G = k.shape[2]
     if cdt is not None:
         q, k, v = q.astype(cdt), k.astype(cdt), v.astype(cdt)
-    with jax.ensure_compile_time_eval():
-        sizes = attention_blocks(T, N // G, D, window, q.dtype, override=blocks)
-        kernel = _splash(T, N // G, window, sizes, _interpret())
     qh = jnp.moveaxis(q.reshape(B, T, G, N // G, D), 1, 3) * (D ** -0.5)
-    out = jax.vmap(jax.vmap(kernel))(  # over sequences and key-value heads
-        qh, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2))
-    return jnp.moveaxis(out, 3, 1).reshape(B, T, N, D).astype(jnp.float32)
+    return splash_heads(qh, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2),
+                        window, blocks)
+
+
+def splash_heads(qh, kh, vh, window, blocks=None):
+    """The kernels on operands in their own layout: ``qh [B, G, R, T, D]``
+    (scaled by ``D ** -0.5``), ``kh, vh [B, G, T, D]`` -> ``[B, T, G * R, D]``
+    float32."""
+    B, G, R, T, D = qh.shape
+    with jax.ensure_compile_time_eval():
+        sizes = attention_blocks(T, R, D, window, qh.dtype, override=blocks)
+        kernel = _splash(T, R, window, sizes, _interpret())
+    out = jax.vmap(jax.vmap(kernel))(qh, kh, vh)  # sequences, key-value heads
+    return jnp.moveaxis(out, 3, 1).reshape(B, T, G * R, D).astype(jnp.float32)
+
+
+def takes_kernels(t: int) -> bool:
+    """Whether attention over ``t`` positions runs as the kernels here: on a
+    TPU, at sequences of whole kernel blocks."""
+    return _auto_pallas() and t % min(KERNEL_BLOCK, t) == 0 and t >= 128
 
 
 def masked_attention(q, k, v, window, q_block: int, kv_chunk: int, cdt):
     """The kernels on a TPU at sequences of whole kernel blocks, the XLA
     blocks elsewhere."""
-    T = q.shape[1]
-    if _auto_pallas() and T % min(KERNEL_BLOCK, T) == 0 and T >= 128:
+    if takes_kernels(q.shape[1]):
         return kernel_attention(q, k, v, window, cdt=cdt)
     return blocked_attention(q, k, v, window, q_block, kv_chunk, cdt)
 
@@ -813,9 +831,9 @@ class RMSNorm(nn.Module):
     eps: float = 1e-5
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, weight_only: bool = False):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return rms_norm(x, scale, self.eps)
+        return scale if weight_only else rms_norm(x, scale, self.eps)
 
 
 class SwiGLU(nn.Module):
@@ -863,14 +881,28 @@ class Attention(nn.Module):
         # recomputation (an eighth of the queries' bytes); q is recomputed
         k = checkpoint_name(_mm(a, wk, cdt), KV_PROJ).reshape(B, T, G, D)
         v = checkpoint_name(_mm(a, wv, cdt), KV_PROJ).reshape(B, T, G, D)
-        if self.gated:
-            q = RMSNorm(self.eps, name="q_norm")(q)
-            k = RMSNorm(self.eps, name="k_norm")(k)
-        if self.window is not None:
-            pos = jnp.arange(T)
-            q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
-        o = masked_attention(q, k, v, self.window, self.q_block,
-                             self.kv_chunk, cdt)
+        norms = ((RMSNorm(self.eps, name="q_norm"), RMSNorm(self.eps, name="k_norm"))
+                 if self.gated else ())
+        out = q.dtype if cdt is None else cdt
+        if (self.window is not None and takes_kernels(T)
+                and rope_pallas.rope_block(T, N // G, D, out, self.gated)):
+            # QK-norm, rotary, the rounding, the scale and the kernels' layout
+            # in ONE pass each way (ROPE_FWD / ROPE_BWD): the heads are whole
+            # lane tiles and the rotation covers them whole
+            qh, kh = rope_pallas.rope_heads(
+                q.reshape(B, T, N * D), k.reshape(B, T, G * D),
+                tuple(n(x, weight_only=True) for n, x in zip(norms, (q, k))),
+                D, self.rope_theta, self.eps if self.gated else None, out)
+            o = splash_heads(qh, kh, jnp.moveaxis(v.astype(out), 1, 2),
+                             self.window)
+        else:
+            if self.gated:
+                q, k = norms[0](q), norms[1](k)
+            if self.window is not None:  # a full layer has no positional term
+                pos = jnp.arange(T)
+                q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
+            o = masked_attention(q, k, v, self.window, self.q_block,
+                                 self.kv_chunk, cdt)
         o = o.reshape(B, T, N * D)
         if self.gated:
             o = o * jax.nn.sigmoid(_mm(a, wg, cdt))

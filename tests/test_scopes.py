@@ -408,3 +408,50 @@ def test_host_spans_leave_the_lowered_epoch_program_as_it_is(monkeypatch):
     without, _ = _toy_trainer_lowered(monkeypatch, tracer=NULL_TRACER)
     assert diff_report(without, with_spans, "no-spans", "spans") is None
     assert PROFILER_TRACER.events() == []
+
+
+# -- rotary's hand-over kernels (ISSUE 37) ----------------------------------------
+
+
+def test_every_rope_pallas_call_is_named_from_the_constants():
+    from dinunet_implementations_tpu.ops import rope_pallas
+
+    tree = ast.parse(inspect.getsource(rope_pallas))
+    names = [{k.arg: k.value for k in n.keywords}["name"].id
+             for n in ast.walk(tree)
+             if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+             and n.func.attr == "pallas_call"]
+    assert sorted(getattr(rope_pallas, n) for n in names) == sorted(
+        rope_pallas.KERNEL_NAMES)
+
+
+@pytest.mark.parametrize("name", ["rope_fwd", "rope_bwd"])
+def test_every_rope_kernel_name_is_read_by_a_metric_of_the_benchmark(name):
+    """``KERNEL_NAMES``' rule for the two new names: each is read, as a whole
+    word, by ``rotary_kernel_ms_per_round.json``, whose ``what`` names the
+    constant; the instruction names the compiler gives the calls match, a
+    longer word does not."""
+    from dinunet_implementations_tpu.ops import rope_pallas
+
+    assert name in rope_pallas.KERNEL_NAMES
+    assert _metric_files_reading(rope_pallas, name) == [
+        "rotary_kernel_ms_per_round.json"]
+    metric = json.loads((REPO / "benchmarks" / "layer_metrics"
+                         / "rotary_kernel_ms_per_round.json").read_text())
+    call = ' = (bf16[2,4,7,16384,128]{4,3,2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call"'
+    for instruction in (f"%{name}.3", f"%vmap_{name}_.2", f"%vmap_jvp_{name}__.6",
+                        f"%checkpoint_vmap_{name}_.11"):
+        assert re.search(metric["args"]["pattern"], instruction + call)
+    for other in (f"%vmap_x{name}_.2", "%splash_mqa_fwd_residuals.1", "%lstm_fwd.2"):
+        assert not re.search(metric["args"]["pattern"], other + call)
+    assert not re.search(metric["args"]["pattern"], f"%{name}.3 = f32[8] fusion(%x)")
+
+
+def test_the_old_rotarys_witness_reads_slice_negate_fusions_by_name():
+    metric = json.loads((REPO / "benchmarks" / "layer_metrics"
+                         / "rotary_slice_negate_ms_per_round.json").read_text())
+    assert metric["args"]["field"] == "name"
+    hit = re.compile(metric["args"]["pattern"])
+    assert hit.search("slice_negate_fusion") and hit.search("slice_negate_fusion.12")
+    assert not hit.search("slice_negate_fusion_2") and not hit.search("negate_fusion")
+    assert not hit.search("pad_slice_negate_fusion.1")

@@ -227,6 +227,101 @@ def test_attention_kernels_compile_at_smallthinkers_shapes(
         afmoe._splash.cache_clear()
 
 
+def _attention_layer_text(one_chip, monkeypatch, t, hidden, heads, window,
+                          gated, rope=True) -> str:
+    """Compiled text of one ``afmoe.Attention`` layer, forward and backward,
+    under a block's checkpoint and the trainer's fold of two sites, with the
+    kernel paths steered on as they are on the chip."""
+    from dinunet_implementations_tpu.models import afmoe
+    from dinunet_implementations_tpu.ops import rope_pallas
+
+    monkeypatch.setattr(afmoe, "_auto_pallas", lambda: True)
+    monkeypatch.setattr(afmoe, "_interpret", lambda: False)
+    monkeypatch.setattr(rope_pallas, "_interpret", lambda: False)
+    if not rope:
+        monkeypatch.setattr(rope_pallas, "rope_block", lambda *a: None)
+    afmoe._splash.cache_clear()
+    n, g, d = heads
+    layer = afmoe.Attention(n, g, d, window, 1e4, 1e-5, 512, 2048,
+                            compute_dtype="bfloat16", gated=gated)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 128, hidden)))
+
+    def put(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def loss(p, a):
+        run = jax.checkpoint(layer.apply, policy=afmoe.BLOCK_KEEPS)
+        return jnp.sum(jax.vmap(run, in_axes=(None, 0))(p, a))
+
+    try:
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree.map(put, params),
+            put(jnp.zeros((2, 1, t, hidden), jnp.float32))).compile().as_text()
+    finally:
+        afmoe._splash.cache_clear()
+
+
+def _plain_copy_bytes(text: str) -> int:
+    """Bytes the program's plain ``copy`` instructions write (the metric
+    ``layout_copy_ms_per_round`` sums their time)."""
+    sizes = {"f32": 4, "bf16": 2, "s32": 4}
+    total = 0
+    for dtype, dims in re.findall(
+            r"^ +%copy(?:\.\d+)? = (\w+)\[([\d,]*)\]", text, re.M):
+        count = 1
+        for n in dims.split(","):
+            count *= int(n)
+        total += count * sizes[dtype]
+    return total
+
+
+@pytest.mark.parametrize("cell,t,hidden,heads,window,gated", [
+    ("smallthinker-21b-ep8", 16384, 2560, (28, 4, 128), 4096, False),
+    ("trinity-mini-ep16", 8192, 2048, (32, 4, 128), 2048, True),
+], ids=["smallthinker", "trinity"])
+def test_a_rotary_layer_hands_over_through_one_kernel_each_way(
+        one_chip, no_compile_cache, monkeypatch, cell, t, hidden, heads,
+        window, gated):
+    """A sliding layer at the two cells' shapes (ISSUE 37): between the
+    projections and ``splash_mqa_*`` stand the new kernels' calls and nothing
+    else — the forward call's operands ARE the projections' own outputs,
+    float32 and row-major; no fusion named ``slice_negate*`` (XLA's rotary);
+    one forward call in the forward pass, one in the recomputation, one
+    transposed call, whose bfloat16 cotangents the projections' backward
+    matmuls read as they are; and the plain copies write no more bytes than
+    the full layer's beside it (which has no positions and never took the
+    new path: its text holds neither kernel, with or without it)."""
+    from dinunet_implementations_tpu.ops import rope_pallas
+
+    n, g, d = heads
+    text = _attention_layer_text(one_chip, monkeypatch, t, hidden, heads,
+                                 window, gated)
+    assert "slice_negate" not in text
+    fwd = re.findall(r"^ +%[\w.]*rope_fwd[\w.]* = .*custom-call\(%([\w.\-]+), "
+                     r"%([\w.\-]+),.*tpu_custom_call", text, re.M)
+    bwd = re.findall(r"^ +%%([\w.]*rope_bwd[\w.]*) = \(bf16\[2,%d,%d\]\S*, "
+                     r"bf16\[2,%d,%d\].*tpu_custom_call"
+                     % (t, n * d, t, g * d), text, re.M)
+    assert len(fwd) == 2 and len(bwd) == 1, (fwd, bwd)
+    for q_operand, _ in fwd:  # written by the projection's own fusion
+        assert re.search(r"^ +%%%s = f32\[2,%d,%d\]\{2,1,0[:}].* fusion\("
+                         % (re.escape(q_operand), t, n * d), text, re.M), q_operand
+    for name in ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"):
+        assert len(re.findall(r"^ +%%[\w.]*%s[\w.]* = .*tpu_custom_call" % name,
+                              text, re.M)) == 1, name
+    assert not re.search(r"= f32\[2,(1,)?%d,(%d|%d,%d)\]\S* copy\("
+                         % (t, n * d, n, d), text)  # the queries, whole, float32
+    full = _attention_layer_text(one_chip, monkeypatch, t, hidden, heads, None,
+                                 gated)
+    assert not any(k in full for k in rope_pallas.KERNEL_NAMES + ("slice_negate",))
+    assert _plain_copy_bytes(text) <= _plain_copy_bytes(full)
+    old = _attention_layer_text(one_chip, monkeypatch, t, hidden, heads, window,
+                                gated, rope=False)
+    assert "slice_negate" in old  # the witness, where rotary() stays
+    assert _plain_copy_bytes(old) > 3 * _plain_copy_bytes(text)
+
+
 def test_grouped_products_lower_to_the_compilers_kernel(one_chip,
                                                          no_compile_cache):
     """``jax.lax.ragged_dot`` (rows by group) and its row-contracting form
