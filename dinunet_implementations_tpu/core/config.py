@@ -181,7 +181,9 @@ class AFMoEArgs:
     block, no embedding scale, multi-token prediction) or ``smallthinker``
     (the router reads the block's input before attention, a softmax over the
     chosen experts' logits, ReGLU experts, no shared expert, no dense layer,
-    a full layer without positions first in each period of four). The
+    a full layer without positions first in each period of four) or
+    ``lfm2_moe`` (a gated short convolution for attention on its ``conv``
+    layers, QK-norm then rotary on its full ones, a tied head). The
     widths default to the published Trinity-Mini ``config.json`` (source:
     huggingface.co/arcee-ai/Trinity-Mini) under its own key names; what a
     configuration CUTS is the depth (``num_hidden_layers``,
@@ -206,10 +208,18 @@ class AFMoEArgs:
     with ``num_dense_layers`` 0 and ``num_shared_experts`` 0, and its two
     per-layer lists under their own names; it reads neither
     ``intermediate_size``, ``route_norm`` (``norm_topk_prob`` divides a softmax
-    by its sum, 1), ``route_scale`` nor ``mup_enabled``."""
+    by its sum, 1), ``route_scale`` nor ``mup_enabled``.
+
+    An ``lfm2_moe`` configuration likewise (``norm_eps`` -> ``rms_norm_eps``,
+    ``norm_topk_prob`` -> ``route_norm``, ``routed_scaling_factor`` ->
+    ``route_scale``) with ``num_shared_experts`` 0 and ``head_dim`` =
+    ``hidden_size / num_attention_heads``; ``layer_types`` is REQUIRED, one
+    of ``conv`` and ``full_attention`` a kept layer (the published list has
+    no rule to derive it from), and ``num_dense_layers`` counts layers of that
+    list; it reads neither ``sliding_window`` nor ``mup_enabled``."""
 
     data_file: str = ""
-    model_type: str = "afmoe"  # or "glm4_moe_lite", "smallthinker"
+    model_type: str = "afmoe"  # or "glm4_moe_lite", "smallthinker", "lfm2_moe"
     seq_len: int = 8192  # a sample is seq_len + 1 token ids
     vocab_size: int = 200192
     vocab_rows: int = 0  # rows of the vocabulary held here; 0 = all
@@ -253,6 +263,10 @@ class AFMoEArgs:
     # that depth's loss beside the next token's (the config gives none)
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # lfm2_moe's published keys: positions a ``conv`` layer's filter covers;
+    # the head is the embedding's own matrix (no ``lm_head`` in the tree)
+    conv_L_cache: int = 3
+    tie_word_embeddings: bool = False
     # "bfloat16" = bf16 matmuls, f32 accumulation/norms/softmax/router/loss
     compute_dtype: str = ""
     q_block: int = 512  # query rows an attention block holds

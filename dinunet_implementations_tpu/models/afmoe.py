@@ -1,11 +1,13 @@
 """Mixture-of-experts decoders as a federated next-token task: the Trinity
 family's ``model_type: afmoe``, ``model_type: glm4_moe_lite`` (latent
-attention on every layer, one multi-token-prediction module) and
-``model_type: smallthinker`` (the router before attention, ReGLU experts).
-The three share the expert layer's grouped products, the norms, the rotary
-term, the attention kernels, the blocked head and the loss; they differ in
-the attention's projections, in the routing rule and what it reads, and in
-the block around them, which ``Dims.model_type`` selects.
+attention on every layer, one multi-token-prediction module),
+``model_type: smallthinker`` (the router before attention, ReGLU experts) and
+``model_type: lfm2_moe`` (a gated short convolution for attention in three
+layers of four, a tied head). The four share the expert layer's grouped
+products, the norms, the rotary term, the attention kernels, the blocked head
+and the loss; they differ in the token mixer and its projections, in the
+routing rule and what it reads, and in the block around them, which
+``Dims.model_type`` selects.
 
 One chip's SHARE of the model: the expert layer is told which routed experts
 it holds (``experts_held`` of ``num_experts``, from ``first_expert``), routes
@@ -79,6 +81,29 @@ bias). With ``h`` the block's input:
   (relu(m W1_e) * (m W3_e)) W2_e`` (ReGLU for SwiGLU; ``p`` is not
   renormalised over the held ones); ``h_next = h' + y``.
 
+THE FOURTH TYPE'S block, ``lfm2_moe`` (LFM2-8B-A1B; same precisions; two
+pre-norms a block and no post-norm, no embedding scale, no bias, no shared
+expert). ``layer_types`` names each layer's token mixer, which sits where
+attention sits (under the module name ``attn``, whichever it is):
+
+- ``conv``, the gated short convolution (:class:`ShortConv`), ``a =
+  RMSNorm(h)``: ``[B | C | x] = a W_in`` (hidden -> 3 x hidden); ``u = B *
+  x``; ``c_t = sum_{j < L} f_j * u_{t - (L - 1) + j}`` with ``u`` zero before
+  position 0: a causal depth-wise convolution over ``conv_L_cache`` = ``L``
+  positions, one ``L``-tap filter a channel; ``o = (C * c) W_out``. No
+  positions, no softmax, no state beyond ``L - 1`` rows;
+- ``full_attention``: grouped queries; RMSNorm over each head on ``q`` and
+  ``k``, THEN rotary over the whole head; causal, no window, no gate: a
+  full layer WITH a positional term, at head width 64 (half a lane tile: the
+  kernels take it natively, the rotary hand-over kernel does not and XLA's
+  ``rotary`` stays);
+- experts: Trinity's rule (``sigmoid``, top-k of ``s + expert_bias``, the
+  chosen over their sum, ``route_scale``) with SwiGLU experts; the leading
+  ``num_dense_layers`` layers a dense SwiGLU MLP;
+- the head is the embedding's own matrix (``tie_word_embeddings``): the tree
+  has no ``lm_head``, ``logits = RMSNorm(h) E^T``, and the embedding's
+  gradient is the sum of its two uses.
+
 Attention never builds a ``[T, T]`` tensor. On a TPU, at sequences of whole
 kernel blocks, it is jax's splash-attention Pallas kernels (block-sparse flash
 attention: a masked block is never visited); elsewhere it is XLA query blocks:
@@ -122,10 +147,12 @@ from .layers import compute_dtype_of
 
 SLIDING = "sliding_attention"
 FULL = "full_attention"
+CONV = "conv"  # a layer whose token mixer is the gated short convolution
 AFMOE = "afmoe"  # Dims.model_type: Trinity's block
 GLM4_MOE_LITE = "glm4_moe_lite"  # latent attention, two pre-norms, MTP
 SMALLTHINKER = "smallthinker"  # router before attention, ReGLU, two pre-norms
-MODEL_TYPES = (AFMOE, GLM4_MOE_LITE, SMALLTHINKER)
+LFM2_MOE = "lfm2_moe"  # short convolutions, QK-norm then rotary, a tied head
+MODEL_TYPES = (AFMOE, GLM4_MOE_LITE, SMALLTHINKER, LFM2_MOE)
 
 
 def _init(std: float = 0.02):
@@ -299,8 +326,11 @@ def attention_vmem_bytes(kernel: str, block_q: int, block_kv: int,
     accumulators that stay for a whole row of steps, once; and the float32
     score tiles of the body. An upper bound fitted to what the TPU compiler
     allocated or refused over step 0's sweep (bfloat16, widths 128 and 256):
-    it refuses every geometry the compiler refused, and a few it took."""
+    it refuses every geometry the compiler refused, and a few it took. A
+    head narrower than a lane tile (64) is held in whole 128-lane tiles: the
+    compiler's verdicts at width 64 were those at 128 (PERF.md §6, PR 38)."""
     item = jnp.dtype(dtype).itemsize
+    head_dim = -(-head_dim // 128) * 128
     q_rows = block_q * head_dim  # a [block_q, d] tile, in elements
     kv_rows = block_kv * head_dim
     lanes = block_q * 128 * 4  # a per-row statistic, lane-broadcast
@@ -349,6 +379,11 @@ def attention_blocks(t: int, heads_per_kv: int, head_dim: int,
       at width 256 leave the forward 512 x 1,024).
     - The compute block is COMPUTE_BLOCK: whole key blocks cost the forward
       5-10 %, 256 columns cost it 22 % at width 128 with 512-blocks.
+    - A head HALF a lane tile wide (64, four query heads a key-value head,
+      causal over 8,192: PR 38's sweep) is taken natively by all three
+      kernels, costs what width 128 costs to the percent (the time follows
+      the pairs, not the width) and ranks the blocks as width 128 does:
+      1,024 x 1,024 took 18, 15 and 16 % off 512 x 512.
 
     ``heads_per_kv`` decides nothing: with eight, seven and one query heads a
     key-value head the kernels' gains followed the head width and the mask,
@@ -855,15 +890,20 @@ class Attention(nn.Module):
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    window: int | None  # None = full attention, no positional term
+    window: int | None  # None = full attention
     rope_theta: float
     eps: float
     q_block: int
     kv_chunk: int
     compute_dtype: str | None = None
-    # Trinity's QK-norm and output gate; without them the tree has neither
-    # ``q_norm``, ``k_norm`` nor ``wg`` (smallthinker)
-    gated: bool = True
+    # three facts of a layer, each its own: RMSNorm over every head of q and k
+    # (``q_norm``, ``k_norm`` in the tree), the sigmoid output gate (``wg``),
+    # rotary positions. Trinity has the first two, smallthinker neither, and
+    # in both a layer has positions iff it has a window (``rope`` None);
+    # lfm2_moe norms, does not gate, and rotates on its full layers
+    qk_norm: bool = True
+    gate: bool = True
+    rope: bool | None = None
 
     @nn.compact
     def __call__(self, a):
@@ -873,7 +913,7 @@ class Attention(nn.Module):
         wq = self.param("wq", _init(), (H, N * D))
         wk = self.param("wk", _init(), (H, G * D))
         wv = self.param("wv", _init(), (H, G * D))
-        if self.gated:
+        if self.gate:
             wg = self.param("wg", _init(), (H, N * D))
         wo = self.param("wo", _init(), (N * D, H))
         q = _mm(a, wq, cdt).reshape(B, T, N, D)
@@ -882,31 +922,65 @@ class Attention(nn.Module):
         k = checkpoint_name(_mm(a, wk, cdt), KV_PROJ).reshape(B, T, G, D)
         v = checkpoint_name(_mm(a, wv, cdt), KV_PROJ).reshape(B, T, G, D)
         norms = ((RMSNorm(self.eps, name="q_norm"), RMSNorm(self.eps, name="k_norm"))
-                 if self.gated else ())
+                 if self.qk_norm else ())
+        rope = self.window is not None if self.rope is None else self.rope
         out = q.dtype if cdt is None else cdt
-        if (self.window is not None and takes_kernels(T)
-                and rope_pallas.rope_block(T, N // G, D, out, self.gated)):
+        if (rope and takes_kernels(T)
+                and rope_pallas.rope_block(T, N // G, D, out, self.qk_norm)):
             # QK-norm, rotary, the rounding, the scale and the kernels' layout
             # in ONE pass each way (ROPE_FWD / ROPE_BWD): the heads are whole
             # lane tiles and the rotation covers them whole
             qh, kh = rope_pallas.rope_heads(
                 q.reshape(B, T, N * D), k.reshape(B, T, G * D),
                 tuple(n(x, weight_only=True) for n, x in zip(norms, (q, k))),
-                D, self.rope_theta, self.eps if self.gated else None, out)
+                D, self.rope_theta, self.eps if self.qk_norm else None, out)
             o = splash_heads(qh, kh, jnp.moveaxis(v.astype(out), 1, 2),
                              self.window)
         else:
-            if self.gated:
+            if self.qk_norm:
                 q, k = norms[0](q), norms[1](k)
-            if self.window is not None:  # a full layer has no positional term
+            if rope:
                 pos = jnp.arange(T)
                 q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
             o = masked_attention(q, k, v, self.window, self.q_block,
                                  self.kv_chunk, cdt)
         o = o.reshape(B, T, N * D)
-        if self.gated:
+        if self.gate:
             o = o * jax.nn.sigmoid(_mm(a, wg, cdt))
         return _mm(o, wo, cdt)
+
+
+def short_conv(bcx, filt):
+    """The gated short convolution's element-wise half: ``C * conv(B * x)``
+    of ``bcx = [B | C | x] [..., T, 3 * hidden]`` float32 under the filter
+    ``filt [hidden, taps]``: tap ``j`` reads the position ``taps - 1 - j``
+    back, zeros before position 0. The shift is a pad and ``taps`` static
+    slices along the sequence."""
+    b, c, x = jnp.split(bcx, 3, axis=-1)
+    t, taps = bcx.shape[-2], filt.shape[1]
+    u = jnp.pad(b * x, ((0, 0),) * (bcx.ndim - 2) + ((taps - 1, 0), (0, 0)))
+    return c * sum(filt[:, j] * jax.lax.slice_in_dim(u, j, j + t, axis=-2)
+                   for j in range(taps))
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution, ``lfm2_moe``'s token mixer on a ``conv``
+    layer: ``[B | C | x] = a W_in``; ``o = (C * conv(B * x)) W_out``
+    (:func:`short_conv`). The projections in ``compute_dtype`` with float32
+    accumulation, the gates and the taps in float32."""
+
+    hidden: int
+    taps: int = 3
+    compute_dtype: str | None = None
+
+    @nn.compact
+    def __call__(self, a):
+        cdt = compute_dtype_of(self.compute_dtype)
+        w_in = self.param("w_in", _init(), (a.shape[-1], 3 * self.hidden))
+        filt = self.param("filter", _init(), (self.hidden, self.taps))
+        w_out = self.param("w_out", _init(), (self.hidden, a.shape[-1]))
+        mixed = short_conv(_mm(a, w_in, cdt), filt.astype(jnp.float32))
+        return _mm(mixed, w_out, cdt)
 
 
 class LatentAttention(nn.Module):
@@ -1067,6 +1141,8 @@ class Dims:
     v_head_dim: int = 0
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # positions a ``conv`` layer's filter covers (model_type lfm2_moe)
+    conv_L_cache: int = 3
 
 
 class Block(nn.Module):
@@ -1078,6 +1154,7 @@ class Block(nn.Module):
         c = self.dims
         latent = c.model_type == GLM4_MOE_LITE
         early = c.model_type == SMALLTHINKER  # the router reads the input
+        lfm = c.model_type == LFM2_MOE
 
         def norm(name):
             return RMSNorm(c.rms_norm_eps, name=name)
@@ -1105,6 +1182,11 @@ class Block(nn.Module):
                     c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
                     c.rope_theta, c.rms_norm_eps, c.q_block, c.kv_chunk,
                     c.compute_dtype, name="attn")(a)
+        elif c.layer_types[self.layer] == CONV:
+            # the token mixer sits where attention sits, under its name
+            with jax.named_scope(scopes.SHORT_CONV):
+                o = ShortConv(c.hidden_size, c.conv_L_cache, c.compute_dtype,
+                              name="attn")(a)
         else:
             sliding = c.layer_types[self.layer] == SLIDING
             with jax.named_scope(scopes.ATTENTION_WINDOW if sliding
@@ -1113,7 +1195,8 @@ class Block(nn.Module):
                     c.num_attention_heads, c.num_key_value_heads, c.head_dim,
                     c.sliding_window if sliding else None, c.rope_theta,
                     c.rms_norm_eps, c.q_block, c.kv_chunk, c.compute_dtype,
-                    gated=not early, name="attn")(a)
+                    qk_norm=not early, gate=not (early or lfm),
+                    rope=True if lfm else None, name="attn")(a)
         h = h + branch("post_attn_norm", o)
         m = norm("pre_mlp_norm")(h)
         if dense:
@@ -1150,7 +1233,7 @@ def _head_logits(h, norm_scale, head, eps, cdt):
 
 
 class AFMoE(nn.Module):
-    """The decoder, of either type (``dims.model_type``). A sample is
+    """The decoder, of any type (``dims.model_type``). A sample is
     ``seq_len + 1`` token ids: the model reads the first ``seq_len``, the loss
     the last ``seq_len``. ``__call__`` returns whole logits of the next-token
     depth (inference, tests); training goes through :meth:`task_loss`, which
@@ -1159,6 +1242,8 @@ class AFMoE(nn.Module):
     dims: Dims = Dims()
     vocab_rows: int = 200192
     mup_enabled: bool = True
+    # the head is the embedding's own matrix: no ``lm_head`` in the tree
+    tie_word_embeddings: bool = False
     loss_block: int = 1024  # positions the head and the loss hold at a time
     init_tokens: int = 8  # positions the forward traces under init()
 
@@ -1184,8 +1269,9 @@ class AFMoE(nn.Module):
         ]
         self.final_norm = self.param(
             "final_norm", nn.initializers.ones, (d.hidden_size,))
-        self.lm_head = self.param(
-            "lm_head", _init(), (d.hidden_size, self.vocab_rows))
+        if not self.tie_word_embeddings:
+            self.lm_head = self.param(
+                "lm_head", _init(), (d.hidden_size, self.vocab_rows))
         if d.num_nextn_predict_layers:
             self.mtp = NextDepth(d, name="mtp")
 
@@ -1216,8 +1302,11 @@ class AFMoE(nn.Module):
         return h
 
     def _logits_fn(self, norm_scale=None):
+        # tied, the head's gradient and the embedding's scatter-add land on
+        # the one matrix
+        head = self.embed.T if self.tie_word_embeddings else self.lm_head
         return functools.partial(
-            _head_logits, head=self.lm_head, eps=self.dims.rms_norm_eps,
+            _head_logits, head=head, eps=self.dims.rms_norm_eps,
             norm_scale=self.final_norm if norm_scale is None else norm_scale,
             cdt=compute_dtype_of(self.dims.compute_dtype))
 

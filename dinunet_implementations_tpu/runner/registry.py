@@ -22,8 +22,10 @@ from ..data.smri import SMRIDataHandle, SMRIDataset
 from ..data.tokens import TokenDataHandle, TokenDataset
 from ..models.afmoe import (
     AFMOE,
+    CONV,
     FULL,
     GLM4_MOE_LITE,
+    LFM2_MOE,
     MODEL_TYPES,
     SLIDING,
     SMALLTHINKER,
@@ -151,10 +153,23 @@ def _kinds(layout) -> tuple:
 
 
 def afmoe_layer_types(a) -> tuple:
-    """One attention kind a layer: ``layer_types`` as given, else the
+    """One kind of token mixer a layer: ``layer_types`` as given, else the
     published period (a full layer every ``global_attn_every_n_layers``-th;
     latent attention has no window: every layer full; ``smallthinker``
-    publishes its period as a list, ``sliding_window_layout``, full FIRST)."""
+    publishes its period as a list, ``sliding_window_layout``, full FIRST).
+    ``lfm2_moe`` publishes the list itself, with no rule behind it, and is
+    the one type whose layers may be ``conv``."""
+    if a.model_type == LFM2_MOE:
+        kinds = tuple(a.layer_types)
+        if not kinds or set(kinds) - {CONV, FULL}:
+            raise ValueError(
+                f"{LFM2_MOE} names every layer in layer_types, each {CONV!r} "
+                f"or {FULL!r} (got {kinds})")
+        return kinds
+    if CONV in a.layer_types:
+        raise ValueError(
+            f"a {CONV!r} layer is {LFM2_MOE}'s: model_type {a.model_type!r} "
+            "has attention on every layer")
     if a.layer_types:
         return tuple(a.layer_types)
     if a.model_type == GLM4_MOE_LITE:
@@ -206,6 +221,16 @@ def _build_afmoe(cfg: TrainConfig):
                 f"{SMALLTHINKER} has no dense layer and no shared expert "
                 f"(got num_dense_layers {a.num_dense_layers}, "
                 f"num_shared_experts {a.num_shared_experts})")
+    if a.model_type == LFM2_MOE:
+        if a.num_shared_experts:
+            raise ValueError(
+                f"{LFM2_MOE} has no shared expert (got num_shared_experts "
+                f"{a.num_shared_experts})")
+        if a.head_dim * a.num_attention_heads != a.hidden_size:
+            raise ValueError(
+                f"{LFM2_MOE} publishes no head width: its heads split the "
+                f"hidden size, {a.hidden_size} / {a.num_attention_heads}, "
+                f"and head_dim says {a.head_dim}")
     if a.num_nextn_predict_layers not in ((0, 1) if latent else (0,)):
         raise ValueError(
             f"num_nextn_predict_layers {a.num_nextn_predict_layers}: one "
@@ -222,6 +247,7 @@ def _build_afmoe(cfg: TrainConfig):
         }),
         vocab_rows=a.vocab_rows or a.vocab_size,
         mup_enabled=a.mup_enabled and a.model_type == AFMOE,
+        tie_word_embeddings=a.tie_word_embeddings,
         loss_block=a.loss_block,
     )
 

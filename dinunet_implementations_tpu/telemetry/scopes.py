@@ -34,6 +34,8 @@ ATTENTION_MLA = "model/attention_mla"  # a latent-attention layer's attention
 # nests in it: the low-rank down- and up-projections and the latent norms
 MLA_LATENT = "model/mla_latent"
 MTP = "model/mtp"  # the second prediction depth: projection, block, head
+# a ``conv`` layer's token mixer (lfm2_moe), its two projections included
+SHORT_CONV = "model/short_conv"
 
 # -- host half ---------------------------------------------------------------
 

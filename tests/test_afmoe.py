@@ -447,6 +447,9 @@ def test_attention_blocks_are_blocks_the_kernels_can_run(t, window):
     # smallthinker at 16,384 positions, seven query heads a key-value head
     # (step 0, PERF.md §6, PR 34): the quarter of a 4,096 window is 1,024
     ((16384, 7, 128, 4096), 1024), ((16384, 7, 128, None), 1024),
+    # lfm2_moe's one attention layer: heads HALF a lane tile wide, four query
+    # heads a key-value head (PERF.md §6, PR 38: the kernels take 64 natively)
+    ((8192, 4, 64, None), 1024),
     # what masked_attention takes and larger blocks do not divide, or a band
     # too narrow for them: the granule
     ((1536, 8, 128, None), 512), ((2560, 8, 128, 2048), 512),

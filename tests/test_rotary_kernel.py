@@ -234,7 +234,7 @@ def test_under_the_trainers_site_fold():
 def attention(gated, window=64, heads=(4, 2, 128)):
     n, g, d = heads
     return afmoe.Attention(n, g, d, window, THETA, 1e-5, 64, 128,
-                           compute_dtype="bfloat16", gated=gated)
+                           compute_dtype="bfloat16", qk_norm=gated, gate=gated)
 
 
 def _rope_calls(fn, *args) -> list[str]:

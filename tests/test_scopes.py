@@ -308,6 +308,66 @@ def test_every_kernel_smallthinker_launches_is_read_by_a_new_metric():
     assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")
 
 
+# -- the next-token model whose token mixer is a short convolution (ISSUE 38) ----
+
+
+def test_the_short_convolution_wears_its_scope_where_attention_sits():
+    """``model/short_conv`` holds a ``conv`` layer's operator, its two
+    projections included, inside ``model/fwd_bwd``; no attention scope is on
+    such a layer, the attention layer of the same model wears
+    ``model/attention_full``, and the expert layer's scopes come after the
+    token mixer in both."""
+    from test_lfm2_moe import KINDS, build
+
+    from dinunet_implementations_tpu.models import afmoe
+    from dinunet_implementations_tpu.trainer.loop import FederatedTrainer
+
+    assert scopes.SHORT_CONV == "model/short_conv"
+    cfg, model, _ = build(num_sites=2, batch_size=1)
+    for layer, kind in enumerate(KINDS):
+        block = afmoe.Block(model.dims, layer)
+        h = jnp.zeros((1, 32, model.dims.hidden_size))
+        params = jax.eval_shape(block.init, jax.random.PRNGKey(0), h)
+        eqns = jax.make_jaxpr(block.apply)(params, h).jaxpr.eqns
+        stacks = [str(e.source_info.name_stack) for e in eqns]
+        mixer = scopes.SHORT_CONV if kind == afmoe.CONV else scopes.ATTENTION_FULL
+        other = scopes.ATTENTION_FULL if kind == afmoe.CONV else scopes.SHORT_CONV
+        assert any(mixer in s for s in stacks) and not any(other in s for s in stacks)
+        # the operator's projections are inside: two products on a conv layer
+        dots = [s for e, s in zip(eqns, stacks)
+                if e.primitive.name == "dot_general" and mixer in s]
+        assert len(dots) == (2 if kind == afmoe.CONV else 4)
+        if layer:  # an expert layer: the router and the experts after the mixer
+            last = max(i for i, s in enumerate(stacks) if mixer in s)
+            assert min(i for i, s in enumerate(stacks)
+                       if scopes.MOE_ROUTE in s) > last
+    trainer = FederatedTrainer(cfg, model, None)
+    state = trainer.init_state(jnp.ones((1, 33), jnp.int32), num_sites=2)
+    text = trainer.epoch_fn.lower(
+        state, jnp.zeros((2, 3, 33), jnp.int32), jnp.zeros((2, 3), jnp.int32),
+        jnp.zeros((2, 2, 1), jnp.int32), None, None, None, None,
+    ).as_text(debug_info=True)
+    assert re.search(re.escape(scopes.MODEL) + r"\)*/.*"
+                     + re.escape(scopes.SHORT_CONV) + r"(?![A-Za-z0-9_])", text)
+
+
+def test_every_kernel_lfm2_launches_is_read_by_a_new_metric():
+    """No new kernel name: the one attention layer launches the three
+    splash-attention calls, and each is read, as a whole word, by a metric
+    file of the new cell (``lfm2_attention_*``), whose ``what`` names the
+    constant; the grouped products are read by name (``ragged-dot``)."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    for name in (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV):
+        mine = _metric_files_reading(afmoe, name, "lfm2_attention_*.json")
+        assert (f"lfm2_attention_{name.rsplit('_', 1)[1]}"
+                "_kernel_ms_per_round.json") in mine
+    grouped = json.loads((REPO / "benchmarks" / "layer_metrics" /
+                          "lfm2_moe_grouped_matmul_ms_per_round.json"
+                          ).read_text())
+    assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")
+
+
 # -- the host half: the fit loop's own spans (ISSUE 36) ---------------------------
 
 HOST_CONSTANTS = ("PLAN_WAIT", "PLAN_BUILD", "EPOCH_INPUTS", "EPOCH_DISPATCH",

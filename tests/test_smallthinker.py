@@ -367,7 +367,8 @@ def test_the_published_period_puts_the_full_layer_first():
     other = TrainConfig(task_id=NNComputation.TASK_LM).with_overrides(
         {"lm_args": {"num_hidden_layers": 4}}).lm_args
     assert afmoe_layer_types(other) == (SLIDING, SLIDING, SLIDING, FULL)
-    assert set(afmoe.MODEL_TYPES) == {"afmoe", "glm4_moe_lite", "smallthinker"}
+    assert set(afmoe.MODEL_TYPES) == {"afmoe", "glm4_moe_lite", "smallthinker",
+                                      "lfm2_moe"}
 
 
 # -- the task through the trainer ------------------------------------------------

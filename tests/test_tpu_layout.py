@@ -227,6 +227,39 @@ def test_attention_kernels_compile_at_smallthinkers_shapes(
         afmoe._splash.cache_clear()
 
 
+def test_attention_kernels_compile_at_lfm2s_half_tile_heads(
+        one_chip, no_compile_cache, monkeypatch):
+    """The same kernels as the lfm2_moe block's one attention layer calls them
+    (ISSUE 38): four query heads a key-value head at head width 64, HALF a
+    lane tile, causal over 8,192 positions in the blocks
+    ``afmoe.attention_blocks`` chooses there (1,024 x 1,024), under the
+    trainer's vmap over two sites and its gradient. All three are Mosaic
+    calls on 64-wide operands: nothing is padded on the way in."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    monkeypatch.setattr(afmoe, "_interpret", lambda: False)
+    afmoe._splash.cache_clear()
+    t, n, g, d = 8192, 32, 8, 64
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((2, 1, t, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    sizes = afmoe.attention_blocks(t, n // g, d, None, jnp.bfloat16)
+    assert (sizes.block_q, sizes.block_kv_dq, sizes.block_q_dkv) == (1024,) * 3
+    try:
+        text = jax.jit(jax.vmap(jax.grad(
+            lambda q, k, v: afmoe.kernel_attention(q, k, v, None).sum(),
+            argnums=(0, 1, 2)))).lower(sds(n), sds(g), sds(g)).compile().as_text()
+        for name in (afmoe.ATTN_FWD, afmoe.ATTN_DQ, afmoe.ATTN_DKV):
+            call = re.search(r"%[\w.]*" + name + r"[\w.]* = .*tpu_custom_call",
+                             text)
+            assert call, name
+            assert re.search(r"bf16\[2,8,(4,)?8192,64\]", call.group(0)), name
+    finally:
+        afmoe._splash.cache_clear()
+
+
 def _attention_layer_text(one_chip, monkeypatch, t, hidden, heads, window,
                           gated, rope=True) -> str:
     """Compiled text of one ``afmoe.Attention`` layer, forward and backward,
@@ -243,7 +276,7 @@ def _attention_layer_text(one_chip, monkeypatch, t, hidden, heads, window,
     afmoe._splash.cache_clear()
     n, g, d = heads
     layer = afmoe.Attention(n, g, d, window, 1e4, 1e-5, 512, 2048,
-                            compute_dtype="bfloat16", gated=gated)
+                            compute_dtype="bfloat16", qk_norm=gated, gate=gated)
     params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 128, hidden)))
 
