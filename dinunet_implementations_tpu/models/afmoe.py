@@ -551,15 +551,17 @@ def _row_chunk(rows: int) -> int:
 
 
 # The stacks' cotangents are float32 ``[folds * held, ...]`` and the sorted rows
-# of one chunk lie in few of those (expert, fold) groups: a chunk whose rows all
-# lie within this many experts' groups adds its products into that window of
-# the stacks, any other chunk into the whole stacks (PERF.md section 6, PR 35).
+# of one chunk lie in few of those (expert, fold) groups: a chunk adds its
+# products into the narrowest window of the stacks that holds all its rows, a
+# chunk that no window holds into the whole stacks (PERF.md section 6, PRs 35
+# and 39). The narrowest window is this many experts' groups.
 WINDOW_EXPERTS = 2
 
 
-def _window_groups(folds: int, held: int) -> int:
-    """Groups in the window; all of them where the stacks are no larger."""
-    return min(folds * held, WINDOW_EXPERTS * folds)
+def window_rungs(folds: int, held: int) -> tuple:
+    """The windows' widths in groups, narrowest first: the groups of each
+    whole number of experts from ``WINDOW_EXPERTS`` to all held but one."""
+    return tuple(e * folds for e in range(WINDOW_EXPERTS, held))
 
 
 def _chunk_sizes(bounds, lo, chunk: int):
@@ -580,23 +582,34 @@ def stack_window(sizes, window: int):
     return g0, inside, inside.sum() == sizes.sum()
 
 
+def rung_of(sizes, rungs: tuple):
+    """Which of ``rungs`` a chunk takes: the index of the narrowest whose
+    window holds all its rows, ``len(rungs)`` where none does (the whole
+    stacks). A wider window holds what a narrower one does, so it is the
+    count of those that do not."""
+    return sum(((~stack_window(sizes, w)[2]).astype(jnp.int32) for w in rungs),
+               jnp.int32(0))
+
+
 def window_chunks(held_counts, rows: int):
-    """``(chunks that take the window, live chunks)`` of one backward pass
-    over ``rows`` worst-case assignment rows (``folds * tokens * k``) whose
-    routing put ``held_counts [folds, held]`` assignments on the held experts:
-    a function of the routing alone, on the predicate the chunk loop calls."""
+    """``(taken, whole, live)`` of one backward pass over ``rows`` worst-case
+    assignment rows (``folds * tokens * k``) whose routing put ``held_counts
+    [folds, held]`` assignments on the held experts: ``{a rung's width in
+    groups: chunks that take it}``, the chunks that take the whole stacks,
+    and the live chunks. A function of the routing alone, on the predicate
+    the chunk loop calls."""
     counts = jnp.asarray(held_counts, jnp.int32)
     folds, held = counts.shape
-    chunk, window = _row_chunk(rows), _window_groups(folds, held)
+    chunk = _row_chunk(rows)
+    rungs = window_rungs(folds, held)
     bounds = jnp.concatenate(  # expert-major, as _plan sorts
         [jnp.zeros(1, jnp.int32), jnp.cumsum(counts.T.reshape(-1))])
     los = jnp.arange(rows // chunk) * chunk
     live = los < bounds[-1]
-    if window == folds * held:  # no window is built
-        return jnp.int32(0), live.sum()
-    narrow = jax.vmap(lambda lo: stack_window(
-        _chunk_sizes(bounds, lo, chunk), window)[2])(los)
-    return (narrow & live).sum(), live.sum()
+    rung = jax.vmap(lambda lo: rung_of(
+        _chunk_sizes(bounds, lo, chunk), rungs))(los)
+    taken = {w: ((rung == i) & live).sum() for i, w in enumerate(rungs)}
+    return taken, ((rung == len(rungs)) & live).sum(), live.sum()
 
 
 class _Experts:
@@ -714,10 +727,10 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
                       relu=False):
     """Cotangents ``(dm [S, T, H], dw [S, T, k], dw1, dw3, dw2 [S, E, ..])``
     of :func:`_experts_forward` for ``dy [S, T, H]``, the forward recomputed
-    chunk by chunk (all but its product by ``w2``). A chunk whose rows lie
-    within ``WINDOW_EXPERTS`` experts' groups adds its stack gradients into
-    that window of the stacks, any other into the whole stacks: the same
-    products into the same accumulators in the same order."""
+    chunk by chunk (all but its product by ``w2``). A chunk adds its stack
+    gradients into the narrowest window of :func:`window_rungs` that holds
+    its rows, or into the whole stacks: the same products into the same
+    accumulators in the same order."""
     ex = _Experts(m, sel, w, w1, w3, w2, first_expert, cdt, relu)
     wdot = functools.partial(
         jax.lax.ragged_dot_general,
@@ -727,7 +740,7 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
     wk_sorted = jnp.take(ex.wk.reshape(-1), ex.order)
     w1t, w3t, w2t = (jnp.swapaxes(a, 1, 2) for a in (ex.w1, ex.w3, ex.w2))
 
-    groups, window = ex.folds * ex.held, _window_groups(ex.folds, ex.held)
+    groups, rungs = ex.folds * ex.held, window_rungs(ex.folds, ex.held)
 
     def body(lo, carry):
         dxs_buf, dwk_buf, dw1, dw3, dw2 = carry
@@ -759,18 +772,16 @@ def _experts_backward(m, sel, w, w1, w3, w2, dy, first_expert, cdt,
             return tuple(dw + wdot(x, d, sizes)
                          for dw, (x, d) in zip(stacks, pairs))
 
-        def windowed(stacks):
+        def windowed(window, stacks):
+            g0, inside, _ = stack_window(sizes, window)
             cut = jax.lax.dynamic_slice_in_dim
             return tuple(jax.lax.dynamic_update_slice_in_dim(
                 dw, cut(dw, g0, window) + wdot(x, d, inside), g0, axis=0)
                 for dw, (x, d) in zip(stacks, pairs))
 
-        stacks = (dw1, dw3, dw2)
-        if window < groups:
-            g0, inside, narrow = stack_window(sizes, window)
-            stacks = jax.lax.cond(narrow, windowed, whole, stacks)
-        else:
-            stacks = whole(stacks)
+        stacks = jax.lax.switch(rung_of(sizes, rungs), [
+            functools.partial(windowed, w) for w in rungs] + [whole],
+            (dw1, dw3, dw2))
         put = jax.lax.dynamic_update_slice_in_dim
         return (put(dxs_buf, dxs.astype(dxs_buf.dtype), lo, axis=0),
                 put(dwk_buf, dwk, lo, axis=0)) + stacks

@@ -224,21 +224,42 @@ def test_no_token_is_dropped_when_all_pick_the_same_held_expert(first):
 
 # -- the backward pass's chunk: the stacks' window, the weights' cotangent -------
 
-FOLDS, K2, WIN_CHUNK = 2, 2, 16  # 2 folds x 4 held = 8 groups, a window of 4
+FOLDS, K2, WIN_CHUNK = 2, 2, 16  # 2 folds x 4 held = 8 groups, rungs of 4, 6
+
+
+def _by_group(sizes):
+    """``[fold][expert]`` counts of expert-major group sizes."""
+    return [list(sizes[f::FOLDS]) for f in range(FOLDS)]
+
 
 # assignments on each held expert, [fold][expert], of T * K2 = 64 a fold; the
-# rest go to experts held elsewhere
+# rest go to experts held elsewhere. Then what window_chunks counts:
+# ((chunks that take each rung, narrowest first), chunks that take the whole
+# stacks, live chunks)
 ROUTINGS = {
     # 16 rows a group: a chunk of 16 lies in one or two groups
-    "narrow": ([[16, 16, 16, 16], [16, 16, 16, 16]], (8, 8)),
+    "narrow": ([[16, 16, 16, 16], [16, 16, 16, 16]], ((8, 0), 0, 8)),
     # 2 rows a group: the one live chunk spans all 8
-    "wide": ([[2, 2, 2, 2], [2, 2, 2, 2]], (0, 1)),
+    "wide": ([[2, 2, 2, 2], [2, 2, 2, 2]], ((0, 0), 1, 1)),
     # expert 0 fills two chunks and a half; the third spans five groups
-    "mix": ([[21, 2, 2, 2], [20, 2, 3, 2]], (3, 4)),
+    "mix": ([[21, 2, 2, 2], [20, 2, 3, 2]], ((3, 1), 0, 4)),
     # everything on the last expert: the window's start is clamped
-    "last": ([[0, 0, 0, 24], [0, 0, 0, 24]], (3, 3)),
+    "last": ([[0, 0, 0, 24], [0, 0, 0, 24]], ((3, 0), 0, 3)),
     # the last chunk starts in the last group but one, past the last window
-    "clamped": ([[16, 16, 16, 9], [16, 16, 16, 7]], (7, 7)),
+    "clamped": ([[16, 16, 16, 9], [16, 16, 16, 7]], ((7, 0), 0, 7)),
+    # 8 held experts, 16 groups, rungs of 4, 6, ..., 14; between them every
+    # rung and the whole stacks, three paths or more in a walk. A chunk in
+    # group 0, one over 0-5 (a rung of 6 exactly), one over 5-15 (11: the
+    # rung of 12), one in group 15 (a window clamped to the last groups)
+    "ladder-6-12": (_by_group([17, 3, 3, 3, 3, 4] + [1] * 9 + [20]),
+                    ((2, 1, 0, 0, 1, 0), 0, 4)),
+    # over groups 0-7 (8) and 7-15 (9: the rung of 10)
+    "ladder-8-10": (_by_group([2] * 7 + [3] + [2] * 7 + [1]),
+                    ((0, 0, 1, 1, 0, 0), 0, 2)),
+    # over groups 0-13 (14), then 13-15 (clamped)
+    "ladder-14": (_by_group([1] * 13 + [4, 5, 10]), ((1, 0, 0, 0, 0, 1), 0, 2)),
+    # over all 16 groups (the whole stacks), then two in group 15
+    "ladder-whole": (_by_group([1] * 15 + [20]), ((2, 0, 0, 0, 0, 0), 1, 3)),
 }
 
 
@@ -246,25 +267,26 @@ def _routed(counts, seed: int = 0):
     """``(m, sel, w, w1, w3, w2, dy)`` of two folds whose tokens' slots go to
     the held experts ``counts`` times, in a seeded order."""
     k = jax.random.split(jax.random.PRNGKey(seed), 8)
+    held = len(counts[0])
     sel = []
     for s, row in enumerate(counts):
         slots = np.concatenate(
             [np.full(n, e) for e, n in enumerate(row)]
-            + [HELD + np.arange(T * K2 - sum(row)) % (EXPERTS - HELD)])
+            + [held + np.arange(T * K2 - sum(row)) % (EXPERTS - held)])
         sel.append(np.asarray(jax.random.permutation(k[s], slots)).reshape(T, K2))
     h, f = 64, 32
     return (jax.random.normal(k[2], (FOLDS, T, h)),
             jnp.asarray(np.stack(sel), jnp.int32),
             jax.random.uniform(k[3], (FOLDS, T, K2), minval=0.2),
-            0.2 * jax.random.normal(k[4], (HELD, h, f)),
-            0.2 * jax.random.normal(k[5], (HELD, h, f)),
-            0.2 * jax.random.normal(k[6], (HELD, f, h)),
+            0.2 * jax.random.normal(k[4], (held, h, f)),
+            0.2 * jax.random.normal(k[5], (held, h, f)),
+            0.2 * jax.random.normal(k[6], (held, f, h)),
             jax.random.normal(k[7], (FOLDS, T, h)))
 
 
 def _plain_experts(m, sel, w, w1, w3, w2, relu):
     """One fold's held part, every assignment's expert gathered whole."""
-    held = sel < HELD
+    held = sel < w1.shape[0]
     e = jnp.where(held, sel, 0)
     a = jnp.einsum("th,tkhf->tkf", m, w1[e])
     b = jnp.einsum("th,tkhf->tkf", m, w3[e])
@@ -272,20 +294,26 @@ def _plain_experts(m, sel, w, w1, w3, w2, relu):
     return jnp.einsum("tkf,tkfh,tk->th", mid, w2[e], jnp.where(held, w, 0.0))
 
 
+def _counted(taken, whole, live):
+    return tuple(int(v) for v in taken.values()), int(whole), int(live)
+
+
 @pytest.mark.parametrize("relu", [False, True], ids=["swiglu", "reglu"])
 @pytest.mark.parametrize("routing", list(ROUTINGS))
 def test_the_stacks_window_changes_no_cotangent(monkeypatch, routing, relu):
-    """Chunks that add into a window of the stacks, chunks that add into the
-    whole stacks, and both in one walk: the five cotangents are the whole
-    path's, and the plain layer's."""
-    counts, (narrow, live) = ROUTINGS[routing]
+    """Chunks that add into a window of the stacks (each rung), chunks that
+    add into the whole stacks, and several of them in one walk: the five
+    cotangents are the whole path's, and the plain layer's."""
+    counts, expect = ROUTINGS[routing]
     m, sel, w, w1, w3, w2, dy = _routed(counts)
+    held = len(counts[0])
     monkeypatch.setattr(afmoe, "ROW_CHUNK", WIN_CHUNK)
     taken = afmoe.window_chunks(np.asarray(counts), FOLDS * T * K2)
-    assert (int(taken[0]), int(taken[1])) == (narrow, live)
+    assert tuple(taken[0]) == tuple(range(4, 2 * held, 2))
+    assert _counted(*taken) == expect
     with jax.default_matmul_precision("highest"):
         got = afmoe._experts_backward(m, sel, w, w1, w3, w2, dy, 0, None, relu)
-        monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", HELD)  # no window built
+        monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", held)  # no window built
         whole = afmoe._experts_backward(m, sel, w, w1, w3, w2, dy, 0, None, relu)
         want = jax.vmap(lambda m, sel, w, dy: jax.vjp(
             lambda m, w, w1, w3, w2: _plain_experts(m, sel, w, w1, w3, w2, relu),
@@ -309,33 +337,71 @@ def test_a_window_past_the_last_groups_is_clamped():
     assert (int(g0), inside.tolist(), bool(narrow)) == (2, [3, 0, 0, 2], True)
 
 
+@pytest.mark.parametrize("sizes,rung,g0", [
+    ([2, 0, 0, 3, 0, 0, 0, 0], 0, 0),  # a span of exactly the first rung
+    ([2, 0, 0, 0, 3, 0, 0, 0], 1, 0),  # one past it: the second
+    ([0, 1, 0, 0, 0, 0, 1, 0], 1, 1),  # exactly the second
+    ([0, 1, 0, 0, 0, 0, 0, 1], 2, None),  # one past the last: the whole stacks
+    ([0, 0, 0, 0, 0, 1, 0, 4], 0, 4),  # clamped at the last groups, each rung
+    ([0, 0, 0, 1, 0, 0, 0, 4], 1, 2),
+], ids=["at-first", "past-first", "at-second", "past-last", "clamped-first",
+        "clamped-second"])
+def test_a_chunk_takes_the_narrowest_rung_that_holds_it(sizes, rung, g0):
+    """Rungs of 4 and 6 of 8 groups: the rung's index (``len(rungs)``: the
+    whole stacks), and where the window it takes starts."""
+    sizes, rungs = jnp.asarray(sizes), (4, 6)
+    assert int(afmoe.rung_of(sizes, rungs)) == rung
+    if g0 is not None:
+        start, inside, narrow = afmoe.stack_window(sizes, rungs[rung])
+        assert (int(start), int(inside.sum()), bool(narrow)) == (
+            g0, int(sizes.sum()), True)
+
+
+@pytest.mark.parametrize("folds,held,rungs", [
+    (2, 8, (4, 6, 8, 10, 12, 14)),  # the four language-model cells
+    (2, 3, (4,)),
+    (1, 4, (2, 3)),
+    (2, 2, ()),  # no window narrower than the stacks
+])
+def test_window_rungs_follow_the_stacks_shape(folds, held, rungs):
+    """A rung for each whole number of experts from ``WINDOW_EXPERTS`` to all
+    held but one, in groups of ``folds``."""
+    assert afmoe.window_rungs(folds, held) == rungs
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_window_chunks_counts_what_a_walk_over_the_rows_finds(monkeypatch, seed):
-    """The counter against a brute-force walk: a chunk takes the window where
-    its rows' groups all lie within ``window`` groups of the first (or of the
-    last window's start)."""
+    """The counter against a brute-force walk: a chunk takes the narrowest
+    rung whose window holds its rows' groups, from the first (or from the
+    last window's start), and the whole stacks where none does."""
     monkeypatch.setattr(afmoe, "ROW_CHUNK", 64)
     rng = np.random.default_rng(seed)
     folds, held, rows = 2, 8, 2048
     counts = rng.integers(0, (8, 40, 150, 300)[seed], (folds, held))
     counts[:, rng.integers(held)] = 0  # an empty expert
-    for experts in (2, 4):
-        monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", experts)
-        window = experts * folds
-        group_of = np.repeat(np.arange(folds * held), counts.T.reshape(-1))
-        narrow = live = 0
+    group_of = np.repeat(np.arange(folds * held), counts.T.reshape(-1))
+
+    def walk(rungs):
+        taken, whole, live = dict.fromkeys(rungs, 0), 0, 0
         for lo in range(0, rows, 64):
             inside = group_of[lo: lo + 64]
             if not len(inside):
                 continue
             live += 1
-            start = min(inside[0], folds * held - window)
-            narrow += inside[-1] < start + window
+            for w in rungs:
+                if inside[-1] < min(inside[0], folds * held - w) + w:
+                    taken[w] += 1
+                    break
+            else:
+                whole += 1
+        return tuple(taken.values()), whole, live
+
+    for experts, rungs in ((2, (4, 6, 8, 10, 12, 14)), (4, (8, 10, 12, 14)),
+                           (held, ())):  # the last: no window is built
+        monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", experts)
         got = afmoe.window_chunks(counts, rows)
-        assert (int(got[0]), int(got[1])) == (narrow, live)
-    monkeypatch.setattr(afmoe, "WINDOW_EXPERTS", held)  # no window is built
-    none = afmoe.window_chunks(counts, rows)
-    assert (int(none[0]), int(none[1])) == (0, live)
+        assert tuple(got[0]) == rungs
+        assert _counted(*got) == walk(rungs), experts
 
 
 @pytest.mark.parametrize("relu", [False, True], ids=["swiglu", "reglu"])

@@ -416,6 +416,37 @@ def test_experts_backward_adds_into_a_window_of_the_stacks(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.759 * 2 ** 30
 
 
+def test_experts_backward_has_a_window_of_each_rung(one_chip, no_compile_cache):
+    """The same at ``lfm2-8b-a1b-ep4.dsgd-fold2``'s shapes (2 folds x 8,192
+    tokens, top-4, 8 held SwiGLU experts of 2,048 x 1,792): a grouped
+    product of every rung's shape (two to seven experts' groups) beside the
+    whole stacks' sixteen, no whole stack copied, and temporaries no more
+    than the parent's single window left (1,454,147,072 bytes, ISSUE 39)."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    folds, t, h, k, held, f = 2, 8192, 2048, 4, 8, 1792
+    groups = folds * held
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda m, sel, w, w1, w3, w2, dy: afmoe._experts_backward(
+        m, sel, w, w1, w3, w2, dy, 0, jnp.bfloat16)).lower(
+        sds((folds, t, h)), sds((folds, t, k), jnp.int32), sds((folds, t, k)),
+        sds((held, h, f)), sds((held, h, f)), sds((held, f, h)),
+        sds((folds, t, h))).compile()
+    text = compiled.as_text()
+    rungs = afmoe.window_rungs(folds, held)
+    assert rungs == (4, 6, 8, 10, 12, 14)
+    for rows, cols in ((h, f), (f, h)):
+        for n in rungs + (groups,):
+            assert re.search(r"%%ragged-dot[\w.-]* = f32\[%d,%d,%d\]"
+                             % (n, rows, cols), text), (n, rows, cols)
+        assert not re.search(r"= f32\[%d,%d,%d\]\S* copy\(" % (groups, rows, cols),
+                             text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_454_147_072
+
+
 # -- the LSTM kernels' blocks against the chip's own limits (ISSUE 31) ---------
 
 
