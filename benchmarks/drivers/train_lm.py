@@ -14,8 +14,11 @@ from the model's size (half a billion parameters, 5.6 GB of state):
   and losses after the FIRST warm-up epoch: a 2 GB fetch inside ``setup_s``),
   reads the memory peak when the window ends, RELEASES the trained state and
   the inventory, and only then runs the check;
-- it hands the readers ``moe_expert_load_max_over_mean`` and prints the held
-  assignments a token, both from the model's own routing of round 1's batch.
+- where the model routes (the check's ``routing``, from the ``held_counts``
+  its expert layers sow), it hands the readers
+  ``moe_expert_load_max_over_mean`` and prints the held assignments a token,
+  both from the model's own routing of round 1's batch; a model without
+  expert layers hands them None, which a reader reports as nothing to read.
 
 Names of the program this file depends on beyond ``drivers/train.py``'s:
 ``data.batching.plan_epoch_positions`` (with the arguments
@@ -136,6 +139,7 @@ def run(cell, args, t0: float, device: dict, out_dir: str) -> dict:
     t_check = time.perf_counter()
     say(reference_check=check)
 
+    routing = check["routing"] or {}
     flops = importlib.import_module("benchmarks.flops." + cell.config["flops"])
     facts = {  # what the per-layer readers are handed (ctx.facts)
         "chips": len(used),
@@ -145,7 +149,7 @@ def run(cell, args, t0: float, device: dict, out_dir: str) -> dict:
         "train_flops_per_sample": flops.train_flops_per_sample(cfg),
         "kernel_model": flops.kernel_model(cfg, num_sites * batch // len(used)),
         "engine_payload_bytes_per_round": payload,
-        "moe_expert_load_max_over_mean": check["routing"]["load_max_over_mean"],
+        "moe_expert_load_max_over_mean": routing.get("load_max_over_mean"),
     }
 
     epochs = window["epochs"]  # [(start, end, losses, compiled)]
@@ -169,7 +173,8 @@ def run(cell, args, t0: float, device: dict, out_dir: str) -> dict:
             "of_which_first_epoch_fetch": first_fetch_s,
         },
         reference_check_s=t_check - t_window,
-        held_assignments_per_token=check["routing"]["held_per_token"],
+        **({"held_assignments_per_token": routing["held_per_token"]}
+           if routing else {}),
         warmup_epoch_ms=warm_ms, epochs_in_window=len(epochs),
         epoch_ms_median=float(np.median(epoch_ms)),
         epoch_ms_max=float(np.max(epoch_ms)),

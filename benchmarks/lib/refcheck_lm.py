@@ -12,8 +12,9 @@ loop's first epoch, in the loop's own order (``first["positions"]``).
     over that of the reference's logits, all positions and rows);
 (b) every site's round-1 gradient of the task's own loss against the
     reference's stage-by-stage chain: cosine per group of parameters
-    (attention, dense_mlp, experts, shared, router, embedding, head, norms),
-    the worst site's;
+    (``GROUPS`` by ``group_of``'s rule: attention, dense_mlp, experts,
+    shared, router, embedding, head, norms; before the rule, the
+    configuration's own ``check.groups``), the worst site's;
 (c) what the TIMED PATH produced in its first epoch (the round losses the
     trainer returned and the parameters it held afterwards) against the same
     rounds of the reference (``reference/federated.py``: mean of the sites'
@@ -27,9 +28,11 @@ by the reference, and differs in that row by an expert's whole output. The
 limits are therefore on means, root mean squares and cosines, never on a
 maximum over positions (the maximum is printed).
 
-Also returned, from the model's own routing of round 1's sequences:
-``routing.load_max_over_mean`` (the fullest held expert's assignments over
-the held experts' mean, worst layer and site) and ``routing.held_per_token``.
+Also returned, from the model's own routing of round 1's sequences (the
+``held_counts`` its expert layers sow): ``routing.load_max_over_mean`` (the
+fullest held expert's assignments over the held experts' mean, worst layer
+and site) and ``routing.held_per_token``; ``routing`` is None where no layer
+routes.
 """
 
 from __future__ import annotations
@@ -46,14 +49,21 @@ import numpy as np
 from benchmarks.lib import cells
 from benchmarks.reference import federated as fed
 
+#: the groups of ``group_of``'s rule; a configuration may add its own
 GROUPS = ("attention", "dense_mlp", "experts", "shared", "router", "embedding",
           "head", "norms")
 
 
-def group_of(path: tuple) -> str | None:
-    """The check's group of a parameter, by its path in the tree."""
+def group_of(path: tuple, named=()) -> str | None:
+    """The check's group of a parameter, by its path in the tree: the group
+    of the first ``[path element, group]`` pair of ``named`` (the
+    configuration's ``check.groups``) whose element is in the path, else the
+    rule below."""
     if path[-1] == "expert_bias":
         return None  # a buffer: selection only, no gradient on either side
+    for element, group in named:
+        if element in path:
+            return group
     if "attn" in path and path[-1] != "scale":
         return "attention"
     if "mlp" in path:
@@ -84,11 +94,12 @@ def _dots(a, b):
             jnp.dot(b, b, precision=hi))
 
 
-def group_cosines(a, b) -> dict:
-    """Cosine of two parameter-shaped trees group by group, and ``all``."""
-    sums = {g: np.zeros(3) for g in GROUPS + ("all",)}
+def group_cosines(a, b, named=()) -> dict:
+    """Cosine of two parameter-shaped trees group by group (``group_of``
+    with ``named``), and ``all``."""
+    sums = {g: np.zeros(3) for g in (*GROUPS, *(g for _, g in named), "all")}
     for (path, x), (_, y) in zip(_paths(a), _paths(b)):
-        g = group_of(path)
+        g = group_of(path, named)
         if g is None:
             continue
         d = np.asarray([float(v) for v in _dots(x, y)], np.float64)
@@ -143,6 +154,7 @@ def routing_counters(counts, tokens: int) -> dict:
 def run(cell, cfg, model, sites, params0, first, rehearse=None) -> dict:
     # a rehearsal's toy widths round differently: its size may bring limits
     limits = cells.merged(cell.config["check"], *cell._rehearse("check", rehearse))
+    named = [tuple(pair) for pair in limits.get("groups", ())]
     ref, dims = reference_dims(cell, cfg)
     positions = np.asarray(first["positions"])  # [S, rounds, B]
     n_sites, rounds, batch = positions.shape
@@ -162,12 +174,14 @@ def run(cell, cfg, model, sites, params0, first, rehearse=None) -> dict:
 
     @jax.jit
     def sys_logits(p, x):
-        """The model's logits and its routing counters, one forward pass."""
+        """The model's logits and the routing counters of the layers that
+        sowed one (None where none did), one forward pass."""
         logits, inter = model.apply({"params": p}, x[None],
                                     mutable=["intermediates"])
-        counts = jnp.stack([v["moe"]["held_counts"][0][0] for _, v in sorted(
-            inter["intermediates"].items())])
-        return logits[0], counts
+        held = [v["moe"]["held_counts"][0][0] for _, v in sorted(
+            inter.get("intermediates", {}).items())
+            if "held_counts" in v.get("moe", {})]
+        return logits[0], jnp.stack(held) if held else None
 
     sys_grad = jax.jit(jax.value_and_grad(lambda p, x: model.task_loss(
         {"params": p}, x[None], jnp.ones((1,), jnp.float32))))
@@ -185,7 +199,8 @@ def run(cell, cfg, model, sites, params0, first, rehearse=None) -> dict:
     for s in range(n_sites):
         x = jnp.asarray(rows[0, s])
         got, held = sys_logits(p0, x)
-        counts.append(np.asarray(held))
+        if held is not None:
+            counts.append(np.asarray(held))
         loss_sys, g_sys = sys_grad(p0, x)
         with jax.default_matmul_precision("highest"):
             want = ref.logits(p0, x[:-1], dims)
@@ -194,11 +209,12 @@ def run(cell, cfg, model, sites, params0, first, rehearse=None) -> dict:
             loss_ref, g_ref = ref.grads(p0, x, dims)
         rel_rms.append(e[0]), max_abs.append(e[1]), rows_off.append(e[2])
         loss_err.append(abs(float(loss_sys) - float(loss_ref)))
-        cosines.append(group_cosines(g_sys, g_ref))
+        cosines.append(group_cosines(g_sys, g_ref, named))
         ref_grads.append((float(loss_ref), g_ref))
         del g_sys
     seconds = {"logits_and_gradients": lap()}
-    out["routing"] = routing_counters(np.stack(counts, axis=1), out["tokens"])
+    out["routing"] = (routing_counters(np.stack(counts, axis=1), out["tokens"])
+                      if counts else None)
     out["logit_rel_rms"] = max(rel_rms)
     out["logit_max_abs_err"] = max(max_abs)
     out["logit_rows_off_share"] = max(rows_off)

@@ -9,6 +9,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from benchmarks.lib import cells
@@ -48,6 +49,14 @@ def test_rehearsal_of_the_new_cell_prints_the_contracts_last_line(trace):
     check = next(l["reference_check"] for l in lines if "reference_check" in l)
     assert check["ok"] and check["rounds"] == 4 and check["sites"] == 2
     assert any("held_assignments_per_token" in l for l in lines)
+    routing = check["routing"]
+    assert set(routing) == {"load_max_over_mean", "held_per_token",
+                            "held_per_expert"}
+    assert routing["load_max_over_mean"] >= 1.0
+    # the tiny size's four expert layers (layer 0 is dense), 4 held experts
+    assert np.shape(routing["held_per_expert"]) == (4, 4)
+    facts = next(l["facts"] for l in lines if "facts" in l)
+    assert facts["moe_expert_load_max_over_mean"] == routing["load_max_over_mean"]
     if trace == "1":
         last = lines[-1]["metrics"]
         assert last["moe_expert_load_max_over_mean"]["value"] >= 1.0

@@ -5,6 +5,7 @@ a rehearsed cell (all eight metrics read something)."""
 
 import json
 import os
+import shutil
 import time
 
 import pytest
@@ -223,9 +224,16 @@ def test_a_program_without_the_prefix_reads_nothing(checkout, monkeypatch):
     assert program_span_ms.read(ctx, span="loss-fetch", measure="idle") is None
 
 
-def test_a_rehearsed_traced_cell_lists_all_eight_metrics_with_a_value():
+def test_a_rehearsed_traced_cell_lists_all_eight_metrics_with_a_value(tmp_path):
+    """In a checkout of its own: ``find`` takes the checkout's NEWEST trace,
+    and other test files rehearse traced cells into the repository's
+    ``bench_out`` at the same time."""
+    shutil.copy(os.path.join(cells.ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(cells.HERE, tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     out = run_cell("--workload", "icalstm-hcp32.dsgd", "--seed", "5",
-                   "--seconds", "1", "--trace", "1", "--rehearse", "tiny")
+                   "--seconds", "1", "--trace", "1", "--rehearse", "tiny",
+                   cwd=str(tmp_path), env={"PYTHONPATH": cells.ROOT})
     assert out.returncode == 0, out.stderr[-2000:]
     left_out = next(json.loads(l)["per_layer_left_out"]
                     for l in out.stdout.splitlines()
@@ -233,5 +241,4 @@ def test_a_rehearsed_traced_cell_lists_all_eight_metrics_with_a_value():
     for name in METRICS:
         assert left_out[name].startswith("cpu rehearsal"), (name, left_out[name])
         float(left_out[name].rsplit(": ", 1)[1])
-    assert os.path.isdir(os.path.join(cells.ROOT, "bench_out",
-                                      "icalstm-hcp32.dsgd", "trace"))
+    assert os.path.isdir(tmp_path / "bench_out" / "icalstm-hcp32.dsgd" / "trace")

@@ -57,6 +57,17 @@ CHIP_LINES = {
            'custom_call_target="tpu_custom_call"',
     "ragged": 'ragged-dot-none.1',
 }
+#: the metrics with a ``workloads`` list when this cell was added; later PRs
+#: may list the cell in metrics of their own
+LISTS_BEFORE = (
+    "lstm_kernel_ms_per_round", "lstm_kernel_roofline",
+    "lstm_fwd_kernel_ms_per_round", "lstm_bwd_kernel_ms_per_round",
+    "moe_expert_load_max_over_mean", "moe_grouped_matmul_ms_per_round",
+    "attention_kernel_ms_per_round", "attention_kernel_roofline",
+    "attention_fwd_kernel_ms_per_round", "attention_dq_kernel_ms_per_round",
+    "attention_dkv_kernel_ms_per_round", "mla_attention_kernel_ms_per_round",
+    "mla_attention_fwd_kernel_ms_per_round", "mla_attention_dq_kernel_ms_per_round",
+    "mla_attention_dkv_kernel_ms_per_round", "mla_attention_kernel_roofline")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
@@ -76,7 +87,6 @@ def test_benchmark_json_lists_the_configuration_and_its_one_cell():
     assert [(w["name"], w["traffic"], w["chips"]) for w in mine] == [
         (CELL, "dsgd-fold2-long", 1)]
     assert len(mine[0]["why"]) <= 200
-    assert bench["workloads"][-1]["name"] == CELL  # appended, nothing moved
     cell = cells.load_cell(CELL)
     assert cell.traffic["kind"] == "train_lm" and cell.facts["loss_band"]["rounds"] == 8
     # the traffic file is dsgd-fold2 as it is, but for the sequence
@@ -116,7 +126,7 @@ def test_the_cell_reports_every_metric_without_a_workloads_list():
     for m in cells.benchmark_json()["per_layer"]:
         if "workloads" not in m:
             assert m["name"] in mine
-        elif m["name"] not in NEW_METRICS:
+        elif m["name"] in LISTS_BEFORE:
             assert CELL not in m["workloads"]  # the accepted lists are as they were
     assert mine >= set(NEW_METRICS)
     assert not [n for n in mine if n.startswith(("lstm_", "attention_", "moe_", "mla_"))]
