@@ -216,10 +216,21 @@ class AFMoEArgs:
     ``hidden_size / num_attention_heads``; ``layer_types`` is REQUIRED, one
     of ``conv`` and ``full_attention`` a kept layer (the published list has
     no rule to derive it from), and ``num_dense_layers`` counts layers of that
-    list; it reads neither ``sliding_window`` nor ``mup_enabled``."""
+    list; it reads neither ``sliding_window`` nor ``mup_enabled``.
+
+    A ``qwen3_next`` configuration likewise (``full_attention_interval`` ->
+    ``global_attn_every_n_layers``, ``norm_topk_prob`` -> ``route_norm``,
+    ``shared_expert_intermediate_size`` = ``moe_intermediate_size`` x
+    ``num_shared_experts`` 1) with its Gated DeltaNet widths and
+    ``partial_rotary_factor`` under their own names; every layer routes
+    (``num_dense_layers`` 0), ``layer_types`` defaults to the period, a
+    ``full_attention`` layer every ``global_attn_every_n_layers``-th and
+    ``linear_attention`` between; it reads neither ``sliding_window``,
+    ``route_scale`` nor ``mup_enabled``."""
 
     data_file: str = ""
-    model_type: str = "afmoe"  # or "glm4_moe_lite", "smallthinker", "lfm2_moe"
+    # or "glm4_moe_lite", "smallthinker", "lfm2_moe", "qwen3_next"
+    model_type: str = "afmoe"
     seq_len: int = 8192  # a sample is seq_len + 1 token ids
     vocab_size: int = 200192
     vocab_rows: int = 0  # rows of the vocabulary held here; 0 = all
@@ -267,6 +278,16 @@ class AFMoEArgs:
     # the head is the embedding's own matrix (no ``lm_head`` in the tree)
     conv_L_cache: int = 3
     tie_word_embeddings: bool = False
+    # qwen3_next's published keys (0 = the type has none): a
+    # ``linear_attention`` layer's key and value heads and their widths, the
+    # positions its convolution covers; the share of a full layer's head
+    # dimensions that rotary positions cover
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    partial_rotary_factor: float = 1.0
     # "bfloat16" = bf16 matmuls, f32 accumulation/norms/softmax/router/loss
     compute_dtype: str = ""
     q_block: int = 512  # query rows an attention block holds

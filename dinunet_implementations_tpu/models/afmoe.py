@@ -1,13 +1,14 @@
 """Mixture-of-experts decoders as a federated next-token task: the Trinity
 family's ``model_type: afmoe``, ``model_type: glm4_moe_lite`` (latent
 attention on every layer, one multi-token-prediction module),
-``model_type: smallthinker`` (the router before attention, ReGLU experts) and
+``model_type: smallthinker`` (the router before attention, ReGLU experts),
 ``model_type: lfm2_moe`` (a gated short convolution for attention in three
-layers of four, a tied head). The four share the expert layer's grouped
-products, the norms, the rotary term, the attention kernels, the blocked head
-and the loss; they differ in the token mixer and its projections, in the
-routing rule and what it reads, and in the block around them, which
-``Dims.model_type`` selects.
+layers of four, a tied head) and ``model_type: qwen3_next`` (Gated DeltaNet
+linear attention in three layers of four). The five share the expert layer's
+grouped products, the norms, the rotary term, the attention kernels, the
+blocked head and the loss; they differ in the token mixer and its
+projections, in the routing rule and what it reads, and in the block around
+them, which ``Dims.model_type`` selects.
 
 One chip's SHARE of the model: the expert layer is told which routed experts
 it holds (``experts_held`` of ``num_experts``, from ``first_expert``), routes
@@ -104,6 +105,31 @@ attention sits (under the module name ``attn``, whichever it is):
   has no ``lm_head``, ``logits = RMSNorm(h) E^T``, and the embedding's
   gradient is the sum of its two uses.
 
+THE FIFTH TYPE'S block, ``qwen3_next`` (Qwen3-Next; same precisions; two
+pre-norms a block and no post-norm, no embedding scale, no bias, an untied
+head). ``layer_types`` names each layer's token mixer, a full layer every
+``global_attn_every_n_layers``-th (the published ``full_attention_interval``):
+
+- ``linear_attention``, Gated DeltaNet (:class:`GatedDeltaNet`, under the
+  module name ``linear_attn``), ``a = RMSNorm(h)``: ``[q | k | v | z] = a
+  W_qkvz``, ``[b | a'] = a W_ba``; ``q, k, v = silu(conv(q | k | v))``, the
+  causal depth-wise convolution of :func:`causal_conv` over
+  ``linear_conv_kernel_dim`` positions; value head ``h`` reads key head ``h //
+  (value heads / key heads)``; ``q <- l2norm(q) / sqrt(d_k)``, ``k <-
+  l2norm(k)``; ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a' +
+  dt_bias)``; the gated delta rule (:func:`chunked_delta_rule`), from ``S = 0``
+  a value head: ``S <- exp(g_t) S``; ``S <- S + k_t beta_t (v_t - S^T k_t)^T``;
+  ``o_t = S^T q_t``; ``o <- RMSNorm(o) * silu(z)`` over each value head;
+  ``out = o W_out``;
+- ``full_attention``: ``q``, a gate and ``k``, ``v`` from four projections;
+  RMSNorm over each head on ``q`` and ``k``, THEN rotary over the first
+  ``head_dim * partial_rotary_factor`` dimensions of each head only; causal,
+  no window; ``o <- o * sigmoid(gate)``; ``out = o W_o``;
+- experts: a softmax over all ``num_experts`` router logits, top-k,
+  renormalised over the chosen (the same as a softmax over the chosen logits
+  alone: :func:`route_chosen`), SwiGLU experts, and a shared expert whose
+  output is scaled by ``sigmoid(m w_sg)`` (``shared_expert_gate``).
+
 Attention never builds a ``[T, T]`` tensor. On a TPU, at sequences of whole
 kernel blocks, it is jax's splash-attention Pallas kernels (block-sparse flash
 attention: a masked block is never visited); elsewhere it is XLA query blocks:
@@ -148,11 +174,13 @@ from .layers import compute_dtype_of
 SLIDING = "sliding_attention"
 FULL = "full_attention"
 CONV = "conv"  # a layer whose token mixer is the gated short convolution
+LINEAR = "linear_attention"  # a layer whose token mixer is Gated DeltaNet
 AFMOE = "afmoe"  # Dims.model_type: Trinity's block
 GLM4_MOE_LITE = "glm4_moe_lite"  # latent attention, two pre-norms, MTP
 SMALLTHINKER = "smallthinker"  # router before attention, ReGLU, two pre-norms
 LFM2_MOE = "lfm2_moe"  # short convolutions, QK-norm then rotary, a tied head
-MODEL_TYPES = (AFMOE, GLM4_MOE_LITE, SMALLTHINKER, LFM2_MOE)
+QWEN3_NEXT = "qwen3_next"  # Gated DeltaNet, partial rotary, a gated shared expert
+MODEL_TYPES = (AFMOE, GLM4_MOE_LITE, SMALLTHINKER, LFM2_MOE, QWEN3_NEXT)
 
 
 def _init(std: float = 0.02):
@@ -181,6 +209,15 @@ def rotary(x, positions, theta: float):
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, None, :]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def partial_rotary(x, positions, theta: float, dims: int):
+    """:func:`rotary` over the first ``dims`` of each head's ``d``
+    dimensions, the rest passed through (the whole head where ``dims == d``)."""
+    if dims == x.shape[-1]:
+        return rotary(x, positions, theta)
+    return jnp.concatenate(
+        [rotary(x[..., :dims], positions, theta), x[..., dims:]], -1)
 
 
 def _attend(q, k, v, qpos, kpos, window):
@@ -502,8 +539,9 @@ def route(scores, bias, top_k: int, route_norm: bool, route_scale: float):
 
 
 def route_chosen(logits, top_k: int):
-    """The other rule (``smallthinker``): the ``top_k`` largest logits, and a
-    softmax over those alone; no bias, no scale."""
+    """The other rule (``smallthinker``, ``qwen3_next``): the ``top_k``
+    largest logits, and a softmax over those alone; no bias, no scale. The
+    same weights as a softmax over ALL logits renormalised over the chosen."""
     top, sel = jax.lax.top_k(logits, top_k)
     return sel, jax.nn.softmax(top, axis=-1)
 
@@ -915,6 +953,9 @@ class Attention(nn.Module):
     qk_norm: bool = True
     gate: bool = True
     rope: bool | None = None
+    # positions on the first ``rotary_dim`` dimensions of each head only
+    # (qwen3_next: 64 of 256); None = the whole head
+    rotary_dim: int | None = None
 
     @nn.compact
     def __call__(self, a):
@@ -936,7 +977,8 @@ class Attention(nn.Module):
                  if self.qk_norm else ())
         rope = self.window is not None if self.rope is None else self.rope
         out = q.dtype if cdt is None else cdt
-        if (rope and takes_kernels(T)
+        rotated = D if self.rotary_dim is None else self.rotary_dim
+        if (rope and rotated == D and takes_kernels(T)
                 and rope_pallas.rope_block(T, N // G, D, out, self.qk_norm)):
             # QK-norm, rotary, the rounding, the scale and the kernels' layout
             # in ONE pass each way (ROPE_FWD / ROPE_BWD): the heads are whole
@@ -952,7 +994,8 @@ class Attention(nn.Module):
                 q, k = norms[0](q), norms[1](k)
             if rope:
                 pos = jnp.arange(T)
-                q, k = rotary(q, pos, self.rope_theta), rotary(k, pos, self.rope_theta)
+                q, k = (partial_rotary(x, pos, self.rope_theta, rotated)
+                        for x in (q, k))
             o = masked_attention(q, k, v, self.window, self.q_block,
                                  self.kv_chunk, cdt)
         o = o.reshape(B, T, N * D)
@@ -961,17 +1004,23 @@ class Attention(nn.Module):
         return _mm(o, wo, cdt)
 
 
+def causal_conv(u, filt):
+    """The causal depth-wise convolution of ``u [..., T, channels]`` under the
+    filter ``filt [channels, taps]``: tap ``j`` reads the position ``taps - 1
+    - j`` back, zeros before position 0. The shift is a pad and ``taps``
+    static slices along the sequence."""
+    t, taps = u.shape[-2], filt.shape[1]
+    u = jnp.pad(u, ((0, 0),) * (u.ndim - 2) + ((taps - 1, 0), (0, 0)))
+    return sum(filt[:, j] * jax.lax.slice_in_dim(u, j, j + t, axis=-2)
+               for j in range(taps))
+
+
 def short_conv(bcx, filt):
     """The gated short convolution's element-wise half: ``C * conv(B * x)``
     of ``bcx = [B | C | x] [..., T, 3 * hidden]`` float32 under the filter
-    ``filt [hidden, taps]``: tap ``j`` reads the position ``taps - 1 - j``
-    back, zeros before position 0. The shift is a pad and ``taps`` static
-    slices along the sequence."""
+    ``filt [hidden, taps]`` (:func:`causal_conv`)."""
     b, c, x = jnp.split(bcx, 3, axis=-1)
-    t, taps = bcx.shape[-2], filt.shape[1]
-    u = jnp.pad(b * x, ((0, 0),) * (bcx.ndim - 2) + ((taps - 1, 0), (0, 0)))
-    return c * sum(filt[:, j] * jax.lax.slice_in_dim(u, j, j + t, axis=-2)
-                   for j in range(taps))
+    return c * causal_conv(b * x, filt)
 
 
 class ShortConv(nn.Module):
@@ -992,6 +1041,231 @@ class ShortConv(nn.Module):
         w_out = self.param("w_out", _init(), (self.hidden, a.shape[-1]))
         mixed = short_conv(_mm(a, w_in, cdt), filt.astype(jnp.float32))
         return _mm(mixed, w_out, cdt)
+
+
+# -- the gated delta rule ------------------------------------------------------
+#
+# Gated DeltaNet's recurrence in chunks of DELTA_CHUNK positions: everything
+# that does not depend on the state carried in from earlier chunks is computed
+# for a segment's chunks at once, as batched products over (sequence, head,
+# chunk); a ``lax.scan`` over the chunks carries the float32 state ``[d_k,
+# d_v]`` a head and does only the two products the state needs. JAX
+# differentiates it, under the block's checkpoint like the rest of the layer.
+
+DELTA_CHUNK = 64  # positions a chunk of the delta rule holds
+DELTA_SEGMENT = 16  # chunks a checkpointed segment of the delta rule holds
+SUBSTITUTION_ROWS = 16  # the diagonal blocks the triangular inverse solves row by row
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def l2_normalize(x, eps: float = 1e-6):
+    """``x / sqrt(sum x^2 + eps)`` over the last axis, float32."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _substitute(a):
+    """``(I + a)^-1`` of strictly lower triangular ``a [..., m, m]`` by forward
+    substitution: row ``i`` of the inverse is ``e_i - a[i] (I + a)^-1``, whose
+    rows at and below ``i`` are still the identity's."""
+    m = a.shape[-1]
+    eye = jnp.eye(m, dtype=a.dtype)
+
+    def row(i, inv):
+        a_i = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)
+        new = jax.lax.dynamic_index_in_dim(eye, i, keepdims=False) - jnp.einsum(
+            "...j,...jk->...k", a_i, inv, precision=_HIGHEST)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, i, axis=-2)
+
+    return jax.lax.fori_loop(1, m, row, jnp.broadcast_to(eye, a.shape))
+
+
+def unit_lower_inverse(a):
+    """``(I + a)^-1`` of strictly lower triangular ``a [..., n, n]`` float32:
+    forward substitution on the diagonal blocks of SUBSTITUTION_ROWS rows,
+    then pairs of blocks merged, ``[[A, 0], [C, D]]^-1 = [[A^-1, 0], [-D^-1 C
+    A^-1, D^-1]]``, until one is left. No power series of ``a``: its terms
+    grow where the keys of a chunk are alike, and cancel only in exact
+    arithmetic."""
+    n = a.shape[-1]
+    m = min(SUBSTITUTION_ROWS, n)
+    if n % m or (n // m) & (n // m - 1):
+        raise ValueError(f"{n} rows are not a power of two of {m}-row blocks")
+
+    def blocks(size, row_off):  # the blocks of ``size`` on a block diagonal
+        return jnp.stack([a[..., i + row_off: i + row_off + size, i: i + size]
+                          for i in range(0, n - row_off, 2 * size if row_off
+                                         else size)], axis=-3)
+
+    inv = _substitute(blocks(m, 0))  # [..., n / m, m, m]
+    size = m
+    while inv.shape[-3] > 1:
+        upper, lower = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        corner = -jnp.einsum(
+            "...ij,...jk,...kl->...il", lower, blocks(size, size), upper,
+            precision=_HIGHEST)
+        inv = jnp.concatenate([
+            jnp.concatenate([upper, jnp.zeros_like(upper)], -1),
+            jnp.concatenate([corner, lower], -1)], -2)
+        size *= 2
+    return inv[..., 0, :, :]
+
+
+def _delta_segment(s, xs, cdt):
+    """One segment of chunks of :func:`chunked_delta_rule` from the state ``s
+    [B, H, d_k, d_v]`` carried in: ``xs = (q, k, v, beta, g)``, each ``[B,
+    heads, chunks, chunk, ...]`` (``q``, ``k`` of the key heads) -> ``(state
+    carried out, o [B, H, chunks, chunk, d_v])``. Everything that does not
+    need the state is computed for all the segment's chunks at once; one
+    ``lax.scan`` over them does the two products the state needs."""
+    q, k, v, beta, g = xs
+    chunk, dv = k.shape[-2], v.shape[-1]
+    # value head h reads key head h // (value heads / key heads)
+    q, k = (jnp.repeat(x, v.shape[1] // x.shape[1], axis=1) for x in (q, k))
+    gam = jnp.cumsum(g, axis=-1)  # [B, H, chunks, chunk]
+    row, col = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    diff = gam[..., :, None] - gam[..., None, :]
+    strict = jnp.exp(jnp.where(row > col, diff, -jnp.inf))
+    causal = jnp.exp(jnp.where(row >= col, diff, -jnp.inf))
+    kk = jnp.einsum("...id,...jd->...ij", k, k, precision=_HIGHEST)
+    inv = unit_lower_inverse(beta[..., :, None] * kk * strict)
+    decayed = jnp.exp(gam)
+    rhs = jnp.concatenate(
+        [v * beta[..., None], k * (beta * decayed)[..., None]], axis=-1)
+    uw = jnp.einsum("...ij,...jd->...id", inv, rhs, precision=_HIGHEST)
+    last = gam[..., -1:]
+    k_out = k * jnp.exp(last - gam)[..., None]  # what a position leaves in S
+
+    def step(s, xs):
+        uw_c, k_c, decay_c = xs
+        vn = uw_c[..., :dv] - jnp.einsum("...ik,...kv->...iv", uw_c[..., dv:], s,
+                                         precision=_HIGHEST)
+        s_next = decay_c[..., None, None] * s + jnp.einsum(
+            "...ik,...iv->...kv", k_c, vn, precision=_HIGHEST)
+        return s_next, (s, vn)
+
+    along = lambda x: jnp.moveaxis(x, 2, 0)  # noqa: E731  chunks first
+    s, (states, vn) = jax.lax.scan(
+        step, s, (along(uw), along(k_out), along(jnp.exp(last[..., 0]))))
+    states, vn = jnp.moveaxis(states, 0, 2), jnp.moveaxis(vn, 0, 2)
+    cast = (lambda x: x) if cdt is None else (lambda x: x.astype(cdt))
+    scores = jnp.einsum("...id,...jd->...ij", cast(q), cast(k),
+                        preferred_element_type=jnp.float32) * causal
+    o = jnp.einsum("...id,...dv->...iv", cast(q * decayed[..., None]),
+                   cast(states), preferred_element_type=jnp.float32)
+    return s, o + jnp.einsum("...ij,...jv->...iv", cast(scores), cast(vn),
+                             preferred_element_type=jnp.float32)
+
+
+def chunked_delta_rule(q, k, v, beta, g, chunk: int = DELTA_CHUNK, cdt=None):
+    """The gated delta rule over ``T`` positions, float32: ``q, k [B, T, K,
+    d_k]`` (``k`` l2-normed, ``q`` l2-normed and scaled), ``v [B, T, H, d_v]``,
+    ``beta, g [B, T, H]`` (``g <= 0``, the log of a position's decay) -> ``o
+    [B, T, H, d_v]``; value head ``h`` reads key head ``h // (H / K)``, the
+    state of every value head starts at zero. A sequence that is not whole
+    segments is padded with positions that change nothing before them
+    (``beta = g = 0``).
+
+    In a chunk, with ``G`` the cumulative sum of ``g`` and ``S`` the state
+    carried in: ``T = (I + strictly_lower(beta_i k_i.k_j exp(G_i - G_j)))^-1``,
+    ``U = T (beta v)``, ``W = T (beta k exp(G))``; ``V' = U - W S``; ``o = (q
+    exp(G)) S + (q k^T exp(G_i - G_j))_{j <= i} V'``; ``S <- exp(G_C) S + (k
+    exp(G_C - G))^T V'``. Every exponent is a difference ``G_i - G_j`` with ``j
+    <= i`` (or ``G`` itself), so ``<= 0``: no ratio of two decays, which
+    underflow within a few positions at the published initialisation; the
+    exponents above the diagonal are masked before ``exp``, not after.
+
+    The chunks run in segments of DELTA_SEGMENT (:func:`_delta_segment`), one
+    ``lax.scan`` over the segments carrying the state, each segment under
+    ``jax.checkpoint``: the backward pass keeps the state at the segments'
+    edges and recomputes one segment at a time, so the chunks' ``[chunk,
+    chunk]`` matrices and states are never whole for the sequence (at 2 x
+    8,192 positions and 32 heads they are 0.13-0.54 GB apiece).
+
+    Precision: what feeds the state (``k k^T``, the triangular inverse, ``U``,
+    ``W`` and the two products of the scan) is float32 at HIGHEST; the products
+    that only make the output (``q k^T``, and ``o``'s two) take ``cdt``
+    operands with float32 accumulation, as attention's do."""
+    B, T, H, dv = v.shape
+    dk = k.shape[-1]
+    chunks = -(-T // chunk)
+    per = min(DELTA_SEGMENT, chunks)
+    chunks = -(-chunks // per) * per
+
+    def segments(x):  # [B, T, H, ...] -> [segments, B, H, per, chunk, ...]
+        x = jnp.pad(x, ((0, 0), (0, chunks * chunk - T))
+                    + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((B, chunks // per, per, chunk) + x.shape[2:])
+        return jnp.moveaxis(x, (1, 4), (0, 2))
+
+    body = jax.checkpoint(functools.partial(_delta_segment, cdt=cdt))
+    _, o = jax.lax.scan(body, jnp.zeros((B, H, dk, dv), jnp.float32),
+                        tuple(map(segments, (q, k, v, beta, g))))
+    o = jnp.moveaxis(o, (0, 2), (1, 4)).reshape(B, chunks * chunk, H, dv)
+    return o[:, :T]
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log A`` with ``A ~ U(0, 16)``, the family's initialisation."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, maxval=16.0))
+
+
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet, ``qwen3_next``'s token mixer on a ``linear_attention``
+    layer (the equations are the module docstring's). Columns: ``in_proj_qkvz``
+    is ``[q | k | v | z]`` and ``in_proj_ba`` ``[b | a']``, each part head-major;
+    the convolution runs over the ``q | k | v`` channels. The projections in
+    ``compute_dtype`` with float32 accumulation; the convolution, the gates,
+    the norms and the recurrence in float32."""
+
+    num_k_heads: int
+    num_v_heads: int
+    k_dim: int
+    v_dim: int
+    taps: int
+    eps: float
+    compute_dtype: str | None = None
+
+    @nn.compact
+    def __call__(self, a):
+        cdt = compute_dtype_of(self.compute_dtype)
+        B, T, H = a.shape
+        nk, nv, dk, dv = self.num_k_heads, self.num_v_heads, self.k_dim, self.v_dim
+        keys, values = nk * dk, nv * dv
+        w_qkvz = self.param("in_proj_qkvz", _init(), (H, 2 * keys + 2 * values))
+        w_ba = self.param("in_proj_ba", _init(), (H, 2 * nv))
+        filt = self.param("conv1d", _init(), (2 * keys + values, self.taps))
+        a_log = self.param("A_log", _a_log_init, (nv,))
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (nv,))
+        w_out = self.param("out_proj", _init(), (values, H))
+        qkvz = _mm(a, w_qkvz, cdt)
+        ba = _mm(a, w_ba, cdt)
+
+        # the convolution, the norms and the gates are recomputed where the
+        # backward pass needs them: kept, their float32 [T, 8192] passes
+        # would be live beside the rule's own
+        @jax.checkpoint
+        def rule_inputs(qkv, ba, filt, a_log, dt_bias):
+            mixed = jax.nn.silu(causal_conv(qkv, filt.astype(jnp.float32)))
+            beta = jax.nn.sigmoid(ba[..., :nv])
+            g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+                ba[..., nv:] + dt_bias.astype(jnp.float32))
+            q, k = (l2_normalize(mixed[..., i * keys: (i + 1) * keys]
+                                 .reshape(B, T, nk, dk)) for i in (0, 1))
+            v = mixed[..., 2 * keys:].reshape(B, T, nv, dv)
+            return q * dk ** -0.5, k, v, beta, g
+
+        q, k, v, beta, g = rule_inputs(qkvz[..., : 2 * keys + values], ba,
+                                       filt, a_log, dt_bias)
+        with jax.named_scope(scopes.DELTA_CHUNKS):
+            o = chunked_delta_rule(q, k, v, beta, g, chunk=DELTA_CHUNK, cdt=cdt)
+        norm = RMSNorm(self.eps, name="norm")(o, weight_only=True)
+
+        @jax.checkpoint  # the same for the gated norm's float32 passes
+        def gated(o, z, norm):
+            return rms_norm(o, norm, self.eps) * jax.nn.silu(z)
+
+        o = gated(o, qkvz[..., 2 * keys + values:].reshape(B, T, nv, dv), norm)
+        return _mm(o.reshape(B, T, values), w_out, cdt)
 
 
 class LatentAttention(nn.Module):
@@ -1048,7 +1322,12 @@ class MoE(nn.Module):
     """The expert layer: the router and the share of the experts held here.
     ``__call__(m)`` routes the tokens it is given; a block whose router reads
     something else (``smallthinker``: the block's input, before attention)
-    calls :meth:`route` on that itself and hands the routing over."""
+    calls :meth:`route` on that itself and hands the routing over. Three
+    facts of a type, each its own: the routing rule (``softmax_routing``:
+    top-k of the logits and a softmax over the chosen, no ``expert_bias`` in
+    the tree; else sigmoid scores and Trinity's rule), the experts'
+    activation (``relu``: ReGLU for SwiGLU) and a sigmoid gate on the shared
+    expert (``shared_gate``: ``shared_expert_gate`` in the tree)."""
 
     hidden_size: int
     num_experts: int
@@ -1060,15 +1339,14 @@ class MoE(nn.Module):
     route_norm: bool
     route_scale: float
     compute_dtype: str | None = None
-    # smallthinker's layer: top-k over the router's logits and a softmax over
-    # the chosen (no ``expert_bias`` in the tree), ReGLU experts
-    model_type: str = AFMOE
+    softmax_routing: bool = False
+    relu: bool = False
+    shared_gate: bool = False
 
     def setup(self):
         E, F, H = self.experts_held, self.width, self.hidden_size
-        self.chosen_softmax = self.relu = self.model_type == SMALLTHINKER
         self.router = self.param("router", _init(), (H, self.num_experts))
-        if not self.chosen_softmax:
+        if not self.softmax_routing:
             self.expert_bias = self.param(
                 "expert_bias", nn.initializers.zeros, (self.num_experts,))
         self.w1 = self.param("w1", _init(), (E, H, F))
@@ -1076,6 +1354,9 @@ class MoE(nn.Module):
         self.w2 = self.param("w2", _init(), (E, F, H))
         if self.shared_width:
             self.shared = SwiGLU(self.shared_width, self.compute_dtype)
+        if self.shared_gate:
+            self.shared_expert_gate = self.param(
+                "shared_expert_gate", _init(), (H, 1))
 
     def route(self, x):
         """``(sel, w) [B, T, k]`` over ALL experts from ``x [B, T, H]``."""
@@ -1085,7 +1366,7 @@ class MoE(nn.Module):
             logits = jnp.matmul(
                 x.astype(jnp.float32), self.router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST)
-            if self.chosen_softmax:
+            if self.softmax_routing:
                 return route_chosen(logits, self.top_k)
             return route(jax.nn.sigmoid(logits), self.expert_bias, self.top_k,
                          self.route_norm, self.route_scale)
@@ -1113,7 +1394,11 @@ class MoE(nn.Module):
             y = checkpoint_name(y, ROUTED_OUT)
         if self.shared_width:
             with jax.named_scope(scopes.MOE_SHARED):
-                y = y + self.shared(m)
+                if self.shared_gate:
+                    y = y + self.shared(m) * jax.nn.sigmoid(
+                        _mm(m, self.shared_expert_gate, cdt))
+                else:
+                    y = y + self.shared(m)
         return y
 
 
@@ -1154,6 +1439,14 @@ class Dims:
     mtp_loss_weight: float = 0.3
     # positions a ``conv`` layer's filter covers (model_type lfm2_moe)
     conv_L_cache: int = 3
+    # a ``linear_attention`` layer's Gated DeltaNet and the share of a full
+    # layer's heads that rotary positions cover (model_type qwen3_next)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    partial_rotary_factor: float = 1.0
 
 
 class Block(nn.Module):
@@ -1166,6 +1459,7 @@ class Block(nn.Module):
         latent = c.model_type == GLM4_MOE_LITE
         early = c.model_type == SMALLTHINKER  # the router reads the input
         lfm = c.model_type == LFM2_MOE
+        qwen = c.model_type == QWEN3_NEXT
 
         def norm(name):
             return RMSNorm(c.rms_norm_eps, name=name)
@@ -1181,7 +1475,8 @@ class Block(nn.Module):
                       c.experts_held, c.first_expert, c.moe_intermediate_size,
                       c.moe_intermediate_size * c.num_shared_experts,
                       c.route_norm, c.route_scale, c.compute_dtype,
-                      c.model_type, name="moe")
+                      softmax_routing=early or qwen, relu=early,
+                      shared_gate=qwen, name="moe")
         # smallthinker routes on the block's un-normed INPUT: the experts a
         # token goes to do not depend on what attention adds to it
         routing = moe.route(h) if early else None
@@ -1198,6 +1493,13 @@ class Block(nn.Module):
             with jax.named_scope(scopes.SHORT_CONV):
                 o = ShortConv(c.hidden_size, c.conv_L_cache, c.compute_dtype,
                               name="attn")(a)
+        elif c.layer_types[self.layer] == LINEAR:
+            with jax.named_scope(scopes.DELTA_RULE):
+                o = GatedDeltaNet(
+                    c.linear_num_key_heads, c.linear_num_value_heads,
+                    c.linear_key_head_dim, c.linear_value_head_dim,
+                    c.linear_conv_kernel_dim, c.rms_norm_eps, c.compute_dtype,
+                    name="linear_attn")(a)
         else:
             sliding = c.layer_types[self.layer] == SLIDING
             with jax.named_scope(scopes.ATTENTION_WINDOW if sliding
@@ -1207,7 +1509,11 @@ class Block(nn.Module):
                     c.sliding_window if sliding else None, c.rope_theta,
                     c.rms_norm_eps, c.q_block, c.kv_chunk, c.compute_dtype,
                     qk_norm=not early, gate=not (early or lfm),
-                    rope=True if lfm else None, name="attn")(a)
+                    rope=True if lfm or qwen else None,
+                    # static: two numbers of the configuration
+                    rotary_dim=(None if c.partial_rotary_factor == 1 else int(  # jaxlint: disable=R005
+                        c.head_dim * c.partial_rotary_factor)),
+                    name="attn")(a)
         h = h + branch("post_attn_norm", o)
         m = norm("pre_mlp_norm")(h)
         if dense:

@@ -26,7 +26,9 @@ from ..models.afmoe import (
     FULL,
     GLM4_MOE_LITE,
     LFM2_MOE,
+    LINEAR,
     MODEL_TYPES,
+    QWEN3_NEXT,
     SLIDING,
     SMALLTHINKER,
     AFMoE,
@@ -158,7 +160,9 @@ def afmoe_layer_types(a) -> tuple:
     latent attention has no window: every layer full; ``smallthinker``
     publishes its period as a list, ``sliding_window_layout``, full FIRST).
     ``lfm2_moe`` publishes the list itself, with no rule behind it, and is
-    the one type whose layers may be ``conv``."""
+    the one type whose layers may be ``conv``; ``qwen3_next`` the one whose
+    layers may be ``linear_attention``, and its period puts them between the
+    full layers."""
     if a.model_type == LFM2_MOE:
         kinds = tuple(a.layer_types)
         if not kinds or set(kinds) - {CONV, FULL}:
@@ -166,10 +170,20 @@ def afmoe_layer_types(a) -> tuple:
                 f"{LFM2_MOE} names every layer in layer_types, each {CONV!r} "
                 f"or {FULL!r} (got {kinds})")
         return kinds
-    if CONV in a.layer_types:
-        raise ValueError(
-            f"a {CONV!r} layer is {LFM2_MOE}'s: model_type {a.model_type!r} "
-            "has attention on every layer")
+    if a.model_type == QWEN3_NEXT:
+        n = a.global_attn_every_n_layers
+        kinds = tuple(a.layer_types) or tuple(
+            FULL if (i + 1) % n == 0 else LINEAR
+            for i in range(a.num_hidden_layers))
+        if set(kinds) - {LINEAR, FULL}:
+            raise ValueError(
+                f"a {QWEN3_NEXT} layer is {LINEAR!r} or {FULL!r} (got {kinds})")
+        return kinds
+    for kind, owner in ((CONV, LFM2_MOE), (LINEAR, QWEN3_NEXT)):
+        if kind in a.layer_types:
+            raise ValueError(
+                f"a {kind!r} layer is {owner}'s: model_type {a.model_type!r} "
+                "has attention on every layer")
     if a.layer_types:
         return tuple(a.layer_types)
     if a.model_type == GLM4_MOE_LITE:
@@ -231,6 +245,25 @@ def _build_afmoe(cfg: TrainConfig):
                 f"{LFM2_MOE} publishes no head width: its heads split the "
                 f"hidden size, {a.hidden_size} / {a.num_attention_heads}, "
                 f"and head_dim says {a.head_dim}")
+    if a.model_type == QWEN3_NEXT:
+        heads = (a.linear_num_key_heads, a.linear_num_value_heads,
+                 a.linear_key_head_dim, a.linear_value_head_dim)
+        if min(heads) <= 0 or a.linear_num_value_heads % a.linear_num_key_heads:
+            raise ValueError(
+                f"{QWEN3_NEXT} needs its Gated DeltaNet heads and widths, the "
+                f"value heads a whole number of key heads' (got {heads})")
+        if a.num_shared_experts != 1 or a.num_dense_layers:
+            raise ValueError(
+                f"{QWEN3_NEXT} has one gated shared expert and no dense layer "
+                f"(got num_shared_experts {a.num_shared_experts}, "
+                f"num_dense_layers {a.num_dense_layers})")
+    rotated = a.head_dim * a.partial_rotary_factor
+    if a.partial_rotary_factor != 1 and (
+            a.model_type != QWEN3_NEXT or not 0 < rotated < a.head_dim
+            or rotated != int(rotated) or int(rotated) % 2):
+        raise ValueError(
+            f"partial_rotary_factor {a.partial_rotary_factor} is {QWEN3_NEXT}'s "
+            f"and must leave an even number of a head's {a.head_dim} dimensions")
     if a.num_nextn_predict_layers not in ((0, 1) if latent else (0,)):
         raise ValueError(
             f"num_nextn_predict_layers {a.num_nextn_predict_layers}: one "

@@ -36,6 +36,11 @@ MLA_LATENT = "model/mla_latent"
 MTP = "model/mtp"  # the second prediction depth: projection, block, head
 # a ``conv`` layer's token mixer (lfm2_moe), its two projections included
 SHORT_CONV = "model/short_conv"
+# a ``linear_attention`` layer's token mixer (qwen3_next: Gated DeltaNet),
+# its projections, convolution, gates and norm included
+DELTA_RULE = "model/delta_rule"
+# nests in it: the chunked recurrence alone (models/afmoe.py chunked_delta_rule)
+DELTA_CHUNKS = "model/delta_chunks"
 
 # -- host half ---------------------------------------------------------------
 

@@ -516,6 +516,8 @@ def test_attention_blocks_are_blocks_the_kernels_can_run(t, window):
     # lfm2_moe's one attention layer: heads HALF a lane tile wide, four query
     # heads a key-value head (PERF.md §6, PR 38: the kernels take 64 natively)
     ((8192, 4, 64, None), 1024),
+    # qwen3_next's full layer: eight query heads of 256 a key-value head
+    ((8192, 8, 256, None), 1024),
     # what masked_attention takes and larger blocks do not divide, or a band
     # too narrow for them: the granule
     ((1536, 8, 128, None), 512), ((2560, 8, 128, 2048), 512),

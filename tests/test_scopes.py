@@ -368,6 +368,69 @@ def test_every_kernel_lfm2_launches_is_read_by_a_new_metric():
     assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")
 
 
+# -- the next-token model with Gated DeltaNet ------------------------------------
+
+
+def test_the_delta_rule_wears_its_scopes_where_attention_sits():
+    """``model/delta_rule`` holds a ``linear_attention`` layer's whole mixer
+    (three projections: q | k | v | z, b | a', out), ``model/delta_chunks``
+    nests in it and holds the chunked recurrence alone (no projection of the
+    layer's); the full layer wears ``model/attention_full`` and neither of the
+    two; the expert layer's scopes come after the token mixer."""
+    from test_qwen3_next import KINDS, build
+
+    from dinunet_implementations_tpu.models import afmoe
+
+    assert (scopes.DELTA_RULE, scopes.DELTA_CHUNKS) == (
+        "model/delta_rule", "model/delta_chunks")
+    _, model, _ = build()
+    for layer, kind in enumerate(KINDS):
+        block = afmoe.Block(model.dims, layer)
+        h = jnp.zeros((1, 32, model.dims.hidden_size))
+        params = jax.eval_shape(block.init, jax.random.PRNGKey(0), h)
+        eqns = jax.make_jaxpr(block.apply)(params, h).jaxpr.eqns
+        stacks = [str(e.source_info.name_stack) for e in eqns]
+        linear = kind == afmoe.LINEAR
+        mixer = scopes.DELTA_RULE if linear else scopes.ATTENTION_FULL
+        other = scopes.ATTENTION_FULL if linear else scopes.DELTA_RULE
+        assert any(mixer in s for s in stacks) and not any(other in s for s in stacks)
+        assert any(scopes.DELTA_CHUNKS in s for s in stacks) == linear
+        assert all(scopes.DELTA_RULE in s for s in stacks if scopes.DELTA_CHUNKS in s)
+        dots = [s for e, s in zip(eqns, stacks)
+                if e.primitive.name == "dot_general" and mixer in s]
+        if linear:  # the projections are in the mixer and out of the chunks
+            assert sum(scopes.DELTA_CHUNKS not in s for s in dots) == 3
+        last = max(i for i, s in enumerate(stacks) if mixer in s)
+        assert min(i for i, s in enumerate(stacks) if scopes.MOE_ROUTE in s) > last
+
+
+def test_every_kernel_qwen3_next_launches_is_read_by_a_new_metric():
+    """No new kernel name: the one full layer launches the three
+    splash-attention calls, which the new cell's two attention metrics
+    (``qwen3_next_attention_kernel_ms_per_round`` and its roofline, over the
+    model's three calls) read together, their ``what`` naming each constant;
+    the grouped products are read by name (``ragged-dot``)."""
+    from dinunet_implementations_tpu.models import afmoe
+
+    metrics = REPO / "benchmarks" / "layer_metrics"
+    for file in ("qwen3_next_attention_kernel_ms_per_round.json",
+                 "qwen3_next_attention_kernel_roofline.json"):
+        metric = json.loads((metrics / file).read_text())
+        for constant in ("ATTN_FWD", "ATTN_DQ", "ATTN_DKV"):
+            name = getattr(afmoe, constant)
+            line = (f'%{name}_residuals.5 = (f32[2,2,8,1024,128]) custom-call('
+                    's8[1,8,8]), custom_call_target="tpu_custom_call"')
+            assert re.search(metric["args"]["pattern"], line), (file, name)
+            assert re.search(rf"\b{constant}\b", metric["what"]), (file, constant)
+        assert not re.search(metric["args"]["pattern"], "ragged-dot-none.3")
+    roofline = json.loads(
+        (metrics / "qwen3_next_attention_kernel_roofline.json").read_text())
+    assert roofline["args"]["calls"] == ["forward", "backward_dq", "backward_dkv"]
+    grouped = json.loads(
+        (metrics / "qwen3_next_moe_grouped_matmul_ms_per_round.json").read_text())
+    assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")
+
+
 # -- the host half: the fit loop's own spans (ISSUE 36) ---------------------------
 
 HOST_CONSTANTS = ("PLAN_WAIT", "PLAN_BUILD", "EPOCH_INPUTS", "EPOCH_DISPATCH",

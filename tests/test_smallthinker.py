@@ -368,7 +368,7 @@ def test_the_published_period_puts_the_full_layer_first():
         {"lm_args": {"num_hidden_layers": 4}}).lm_args
     assert afmoe_layer_types(other) == (SLIDING, SLIDING, SLIDING, FULL)
     assert set(afmoe.MODEL_TYPES) == {"afmoe", "glm4_moe_lite", "smallthinker",
-                                      "lfm2_moe"}
+                                      "lfm2_moe", "qwen3_next"}
 
 
 # -- the task through the trainer ------------------------------------------------
