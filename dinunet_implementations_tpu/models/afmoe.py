@@ -167,7 +167,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import rope_pallas
+from ..ops import delta_pallas, rope_pallas
 from ..telemetry import scopes
 from .layers import compute_dtype_of
 
@@ -309,6 +309,9 @@ KERNEL_BLOCK = 512  # the granule: a sequence the kernels take is whole such blo
 # the forward kernel's output and log-sum-exp, as a block's checkpoint knows
 # them (the XLA path has no such value: its blocks are recomputed)
 ATTN_OUT = "attention_kernel_out"
+# the delta rule kernels' output and edge states, as a block's checkpoint knows
+# them (the XLA path has no such value: its segments are recomputed)
+DELTA_OUT = "delta_rule_kernel_out"
 
 
 def _auto_pallas() -> bool:
@@ -577,7 +580,7 @@ KV_PROJ = "attention_kv_proj"  # a layer's raw key and value projections
 MLA_LATENT = "attention_mla_latent"  # latent attention's raw [c_kv | k_r]
 # what a block's checkpoint keeps; everything else is recomputed
 BLOCK_KEEPS = jax.checkpoint_policies.save_only_these_names(
-    ROUTED_OUT, ATTN_OUT, KV_PROJ, MLA_LATENT)
+    ROUTED_OUT, ATTN_OUT, KV_PROJ, MLA_LATENT, DELTA_OUT)
 
 
 def _row_chunk(rows: int) -> int:
@@ -1051,6 +1054,8 @@ class ShortConv(nn.Module):
 # chunk); a ``lax.scan`` over the chunks carries the float32 state ``[d_k,
 # d_v]`` a head and does only the two products the state needs. JAX
 # differentiates it, under the block's checkpoint like the rest of the layer.
+# On a TPU, where ``delta_pallas.delta_block`` takes the call's shape, the
+# chunk loop runs as that module's two kernels instead, the state in VMEM.
 
 DELTA_CHUNK = 64  # positions a chunk of the delta rule holds
 DELTA_SEGMENT = 16  # chunks a checkpointed segment of the delta rule holds
@@ -1184,9 +1189,20 @@ def chunked_delta_rule(q, k, v, beta, g, chunk: int = DELTA_CHUNK, cdt=None):
     Precision: what feeds the state (``k k^T``, the triangular inverse, ``U``,
     ``W`` and the two products of the scan) is float32 at HIGHEST; the products
     that only make the output (``q k^T``, and ``o``'s two) take ``cdt``
-    operands with float32 accumulation, as attention's do."""
+    operands with float32 accumulation, as attention's do.
+
+    On a TPU, in chunks of ``delta_pallas.CHUNK`` and where
+    ``delta_pallas.delta_block`` takes the shape (heads of whole lane tiles),
+    the same arithmetic runs as ``ops/delta_pallas.py``'s two kernels, the
+    state in VMEM across the chunks and its own backward kernel; everywhere
+    else as below."""
     B, T, H, dv = v.shape
-    dk = k.shape[-1]
+    K, dk = k.shape[-2:]
+    if (chunk == delta_pallas.CHUNK and _auto_pallas() and delta_pallas.delta_block(
+            T, H, K, dk, dv, DELTA_SEGMENT) is not None):
+        # the chunk loop as one kernel each way, the state in VMEM
+        return delta_pallas.delta_rule(q, k, v, beta, g, DELTA_SEGMENT, cdt,
+                                       DELTA_OUT)
     chunks = -(-T // chunk)
     per = min(DELTA_SEGMENT, chunks)
     chunks = -(-chunks // per) * per

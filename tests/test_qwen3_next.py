@@ -37,6 +37,7 @@ from dinunet_implementations_tpu.models.afmoe import (
     QWEN3_NEXT,
     SLIDING,
 )
+from dinunet_implementations_tpu.ops import delta_pallas
 from dinunet_implementations_tpu.runner.registry import (
     afmoe_layer_types,
     get_task,
@@ -210,6 +211,112 @@ def test_the_block_inverse_is_the_inverse(n, alike):
     got = np.asarray(afmoe.unit_lower_inverse(a), np.float64)
     want = np.linalg.inv(np.eye(n) + np.asarray(a, np.float64))
     assert np.abs(got - want).max() < 1e-5 * max(np.abs(want).max(), 1.0)
+
+
+# -- the delta rule's kernels (ops/delta_pallas.py), in interpret mode -----------
+
+
+def _wide_inputs(t: int, rows: int = 1, gates=None, alike: bool = False):
+    """Heads of 128 (whole lane tiles), two value heads a key head (the
+    cell's grouping); ``alike``: the second chunk holds one key repeated at
+    beta 1, where a power series of the chunk's matrix sums terms of 1e17."""
+    keys = jax.random.split(jax.random.PRNGKey(4), 6)
+    d, heads = 128, 4
+    q = afmoe.l2_normalize(jax.random.normal(keys[0], (rows, t, 2, d))) * d ** -0.5
+    k = afmoe.l2_normalize(jax.random.normal(keys[1], (rows, t, 2, d)))
+    v = jax.random.normal(keys[2], (rows, t, heads, d))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[3], (rows, t, heads)))
+    g = (-jnp.exp(jax.random.uniform(keys[4], (rows, t, heads), minval=-4, maxval=3))
+         if gates is None else jnp.broadcast_to(gates, (rows, t, heads)))
+    if alike:
+        k = k.at[:, 64:128].set(k[:, 64:65])
+        beta = beta.at[:, 64:128].set(1.0)
+    return q, k, v, beta, g
+
+
+def _rule_and_grads(kernels: bool, args, w):
+    """The rule's output and the cotangents of ``sum(o * w)``, the kernels
+    taken or not (in interpret mode on this backend)."""
+    def rule(*a):
+        return afmoe.chunked_delta_rule(*a, chunk=64)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(afmoe, "_auto_pallas", lambda: kernels)
+        assert (delta_pallas.DELTA_FWD in str(jax.make_jaxpr(rule)(*args))) == kernels
+        return jax.jit(lambda *a: (rule(*a), jax.grad(
+            lambda *b: jnp.sum(rule(*b) * w), argnums=range(5))(*a)))(*args)
+
+
+@pytest.mark.parametrize("case", ["ragged-segments", "gates", "alike"])
+def test_the_delta_kernels_are_the_chunked_rule_and_the_recurrence(
+        monkeypatch, case):
+    """Both kernels against XLA's chunked rule and the position-by-position
+    reference: the output and the five cotangents. ``ragged-segments``: two
+    sequences of three chunks and a tail of 17 positions, two segments of
+    two chunks, two head blocks of the grid; ``gates``: log-gates of -21
+    mixed with 0 (a head that keeps everything); ``alike``: a chunk of one
+    repeated key at beta 1."""
+    monkeypatch.setattr(afmoe, "DELTA_SEGMENT", 2)
+    t, rows, gates = 3 * 64 + 17, 1, None
+    if case == "ragged-segments":
+        rows = 2
+        monkeypatch.setattr(delta_pallas, "HEAD_BLOCKS", (2,))
+    elif case == "gates":
+        gates = jnp.where(jnp.arange(4) % 2 == 1, -21.0, 0.0)
+    args = _wide_inputs(t, rows, gates, alike=case == "alike")
+    w = jax.random.normal(jax.random.PRNGKey(8), args[2].shape)
+    out, grads = _rule_and_grads(True, args, w)
+    xla_out, xla_grads = _rule_and_grads(False, args, w)
+    rep = tuple(jnp.repeat(x, 2, axis=2) for x in args[:2])
+    want = _recurrence(*rep, *args[2:])
+    with jax.default_matmul_precision("highest"):
+        ref_grads = jax.grad(lambda *a: jnp.sum(_recurrence(
+            *(jnp.repeat(x, 2, axis=2) for x in a[:2]), *a[2:]) * w),
+            argnums=range(5))(*args)
+    assert bool(jnp.isfinite(out).all())
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(out - xla_out).max()) < 1e-5 * scale
+    assert float(jnp.abs(out - want).max()) < 5e-5 * scale
+    for name, a, x, r in zip("q k v beta g".split(), grads, xla_grads, ref_grads):
+        assert bool(jnp.isfinite(a).all()), name
+        top = max(float(jnp.abs(r).max()), 1e-6)
+        assert float(jnp.abs(a - x).max()) < 1e-5 * top, name
+        assert float(jnp.abs(a - r).max()) < 1e-4 * top, name
+
+
+def test_delta_blocks_at_the_cells_shapes():
+    """``delta_block`` is the chooser and the dispatch predicate: four value
+    heads a grid step at the Qwen3-Next cell (eight do not fit beside
+    sixteen chunk states and inverses a head), eight where a sequence is one
+    segment of four chunks; None at 64-wide heads, where a block cannot hold
+    whole key groups, or where value heads are no whole groups of the key
+    heads."""
+    cell = (32, 16, 128, 128, 16)
+    assert delta_pallas.delta_block(8192, *cell) == 4
+    assert (delta_pallas.block_vmem_bytes(8, *cell) > delta_pallas.VMEM_BUDGET
+            >= delta_pallas.block_vmem_bytes(4, *cell))
+    assert delta_pallas.delta_block(256, *cell) == 8
+    assert delta_pallas.delta_block(8192, 32, 16, 64, 64, 16) is None
+    assert delta_pallas.delta_block(8192, 32, 2, 128, 128, 16) is None
+    assert delta_pallas.delta_block(8192, 3, 2, 128, 128, 16) is None
+
+
+def test_the_kernels_are_taken_by_shape_alone():
+    """On a TPU backend the rule runs as the kernels where ``delta_block``
+    takes the shape and chunks are 64; the toy model's 16-wide heads and
+    chunks of 8 stay XLA's."""
+    wide = _wide_inputs(64)
+    narrow = _rule_inputs(64)
+
+    def calls(args, chunk):
+        return str(jax.make_jaxpr(lambda *a: afmoe.chunked_delta_rule(
+            *a, chunk=chunk))(*args)).count("pallas_call")
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(afmoe, "_auto_pallas", lambda: True)
+        assert calls(wide, 64) == 1
+        assert calls(wide, 32) == calls(narrow, 64) == 0
+    assert calls(wide, 64) == 0  # not a TPU
 
 
 def test_the_linear_layer_against_the_reference():

@@ -26,7 +26,7 @@ from dinunet_implementations_tpu.checks.semantic import (
     TraceCell,
     build_cell_inputs,
 )
-from dinunet_implementations_tpu.ops import lstm_pallas
+from dinunet_implementations_tpu.ops import delta_pallas, lstm_pallas
 from dinunet_implementations_tpu.telemetry import scopes
 from dinunet_implementations_tpu.trainer.steps import (
     epoch_program_artifacts,
@@ -151,12 +151,13 @@ def _metric_files_reading(module, name: str, glob: str = "*.json") -> list[str]:
     return readers
 
 
-@pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES)
+@pytest.mark.parametrize("name", lstm_pallas.KERNEL_NAMES + delta_pallas.KERNEL_NAMES)
 def test_every_kernel_name_is_read_by_a_metric_of_the_benchmark(name):
     """A kernel no metric file reads is a kernel no cell can judge: its
     metric's pattern holds the name as it stands and its ``what`` names the
     constant, so a new kernel comes with its metric or not at all."""
-    assert _metric_files_reading(lstm_pallas, name), name
+    module = lstm_pallas if name in lstm_pallas.KERNEL_NAMES else delta_pallas
+    assert _metric_files_reading(module, name), name
 
 
 def test_every_ops_module_is_imported_by_a_model_or_an_engine():
@@ -405,11 +406,15 @@ def test_the_delta_rule_wears_its_scopes_where_attention_sits():
 
 
 def test_every_kernel_qwen3_next_launches_is_read_by_a_new_metric():
-    """No new kernel name: the one full layer launches the three
-    splash-attention calls, which the new cell's two attention metrics
-    (``qwen3_next_attention_kernel_ms_per_round`` and its roofline, over the
-    model's three calls) read together, their ``what`` naming each constant;
-    the grouped products are read by name (``ragged-dot``)."""
+    """The one full layer launches the three splash-attention calls, which
+    the cell's two attention metrics (``qwen3_next_attention_kernel_ms_per_round``
+    and its roofline, over the model's three calls) read together, their
+    ``what`` naming each constant; the grouped products are read by name
+    (``ragged-dot``); the Gated DeltaNet layers launch the delta rule's two
+    kernels (``delta_pallas.KERNEL_NAMES``), each read, as a whole word, by
+    ``qwen3_next_delta_kernel_ms_per_round`` alone, whose ``what`` names the
+    constant: the instruction names the compiler gives the calls match, a
+    longer word does not."""
     from dinunet_implementations_tpu.models import afmoe
 
     metrics = REPO / "benchmarks" / "layer_metrics"
@@ -429,6 +434,31 @@ def test_every_kernel_qwen3_next_launches_is_read_by_a_new_metric():
     grouped = json.loads(
         (metrics / "qwen3_next_moe_grouped_matmul_ms_per_round.json").read_text())
     assert re.search(grouped["args"]["pattern"], "ragged-dot-none.3")
+    delta = json.loads(
+        (metrics / "qwen3_next_delta_kernel_ms_per_round.json").read_text())
+    call = (' = (f32[2,1,8192,4096]{3,2,1,0}) custom-call(%x), '
+            'custom_call_target="tpu_custom_call"')
+    for name in delta_pallas.KERNEL_NAMES:
+        assert _metric_files_reading(delta_pallas, name) == [
+            "qwen3_next_delta_kernel_ms_per_round.json"]
+        for instruction in (f"%{name}.3", f"%vmap_{name}_.2",
+                            f"%transpose_jvp_vmap_{name}___.1",
+                            f"%checkpoint_vmap_{name}_.11"):
+            assert re.search(delta["args"]["pattern"], instruction + call)
+        assert not re.search(delta["args"]["pattern"], f"%vmap_x{name}_.2" + call)
+        assert not re.search(delta["args"]["pattern"], f"%{name}.3 = f32[8] fusion(%x)")
+    for other in ("%splash_mqa_fwd_residuals.1", "%rope_fwd.2", "%lstm_fwd.2"):
+        assert not re.search(delta["args"]["pattern"], other + call)
+
+
+def test_every_delta_pallas_call_is_named_from_the_constants():
+    tree = ast.parse(inspect.getsource(delta_pallas))
+    names = [{k.arg: k.value for k in n.keywords}["name"].id
+             for n in ast.walk(tree)
+             if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+             and n.func.attr == "pallas_call"]
+    assert sorted(getattr(delta_pallas, n) for n in names) == sorted(
+        delta_pallas.KERNEL_NAMES)
 
 
 # -- the host half: the fit loop's own spans (ISSUE 36) ---------------------------

@@ -487,3 +487,44 @@ def test_lstm_kernels_compile_with_the_block_lstm_block_chooses(
             (i, f, o, g), cs, w, c0, dhs, dhT, dcT, cdt)
     ).lower(s, s, s, s, s, sds((4, H, H)), c, s, c, c).compile().as_text()
     assert re.search(r"%[\w.]*lstm_bwd[\w.]* = .*tpu_custom_call", bwd)
+
+
+# -- the gated delta rule's kernels at the Qwen3-Next cell's shapes ------------
+
+
+def test_the_delta_rule_runs_as_one_kernel_each_way(one_chip, no_compile_cache,
+                                                    monkeypatch):
+    """One Gated DeltaNet layer of ``qwen3-next-80b-a3b-ep32.dsgd-fold2``
+    (2 sites under the site ``vmap``, T 8,192, 16 key heads and 32 value
+    heads of 128), forward and backward under the block's checkpoint, with
+    the kernel path steered on as it is on the chip: both kernels compile
+    (their blocks fit the 16 MiB the compiler scopes a kernel to), the rule
+    is Mosaic calls named ``delta_fwd`` / ``delta_bwd`` and no ``while``
+    over chunks, and the compiler's plan for the layer is below the XLA
+    scan's (4,141,142,016 bytes, the same layer before the kernels)."""
+    from dinunet_implementations_tpu.models import afmoe
+    from dinunet_implementations_tpu.ops import delta_pallas
+
+    monkeypatch.setattr(afmoe, "_auto_pallas", lambda: True)
+    monkeypatch.setattr(delta_pallas, "_interpret", lambda: False)
+    layer = afmoe.GatedDeltaNet(16, 32, 128, 128, 4, 1e-6,
+                                compute_dtype="bfloat16")
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 128, 2048)))
+
+    def put(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def loss(p, a):
+        run = jax.checkpoint(layer.apply, policy=afmoe.BLOCK_KEEPS)
+        return jnp.sum(jax.vmap(run, in_axes=(None, 0))(p, a))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree.map(put, params),
+        put(jnp.zeros((2, 1, 8192, 2048), jnp.float32))).compile()
+    text = compiled.as_text()
+    for name in delta_pallas.KERNEL_NAMES:
+        assert re.search(r"^ +%%[\w.]*%s[\w.]* = .*tpu_custom_call" % name,
+                         text, re.M), name
+    assert not re.search(r"^ +%[\w.\-]+ = .* while\(", text, re.M)
+    assert compiled.memory_analysis().peak_memory_in_bytes < 4_141_142_016
