@@ -1055,7 +1055,8 @@ class ShortConv(nn.Module):
 # d_v]`` a head and does only the two products the state needs. JAX
 # differentiates it, under the block's checkpoint like the rest of the layer.
 # On a TPU, where ``delta_pallas.delta_block`` takes the call's shape, the
-# chunk loop runs as that module's two kernels instead, the state in VMEM.
+# chunk loop runs as that module's two kernels instead, the state in VMEM,
+# and they take ``q`` and ``k`` raw and norm them (:func:`gated_delta_rule`).
 
 DELTA_CHUNK = 64  # positions a chunk of the delta rule holds
 DELTA_SEGMENT = 16  # chunks a checkpointed segment of the delta rule holds
@@ -1191,18 +1192,10 @@ def chunked_delta_rule(q, k, v, beta, g, chunk: int = DELTA_CHUNK, cdt=None):
     that only make the output (``q k^T``, and ``o``'s two) take ``cdt``
     operands with float32 accumulation, as attention's do.
 
-    On a TPU, in chunks of ``delta_pallas.CHUNK`` and where
-    ``delta_pallas.delta_block`` takes the shape (heads of whole lane tiles),
-    the same arithmetic runs as ``ops/delta_pallas.py``'s two kernels, the
-    state in VMEM across the chunks and its own backward kernel; everywhere
-    else as below."""
+    :func:`gated_delta_rule` is Gated DeltaNet's way in: it norms ``q`` and
+    ``k`` and runs this, or on a TPU the kernels of ``ops/delta_pallas.py``."""
     B, T, H, dv = v.shape
-    K, dk = k.shape[-2:]
-    if (chunk == delta_pallas.CHUNK and _auto_pallas() and delta_pallas.delta_block(
-            T, H, K, dk, dv, DELTA_SEGMENT) is not None):
-        # the chunk loop as one kernel each way, the state in VMEM
-        return delta_pallas.delta_rule(q, k, v, beta, g, DELTA_SEGMENT, cdt,
-                                       DELTA_OUT)
+    dk = k.shape[-1]
     chunks = -(-T // chunk)
     per = min(DELTA_SEGMENT, chunks)
     chunks = -(-chunks // per) * per
@@ -1218,6 +1211,34 @@ def chunked_delta_rule(q, k, v, beta, g, chunk: int = DELTA_CHUNK, cdt=None):
                         tuple(map(segments, (q, k, v, beta, g))))
     o = jnp.moveaxis(o, (0, 2), (1, 4)).reshape(B, chunks * chunk, H, dv)
     return o[:, :T]
+
+
+def gated_delta_rule(qkv, beta, g, key_heads: int, d_k: int,
+                     chunk: int = DELTA_CHUNK, cdt=None):
+    """The gated delta rule on Gated DeltaNet's raw columns: ``qkv [B, T, 2
+    K d_k + H d_v]`` = ``[q | k | v]`` float32 as the convolution leaves
+    them (``K = key_heads``), ``beta, g [B, T, H]`` -> ``o [B, T, H, d_v]``,
+    ``q`` and ``k`` l2-normed (:func:`l2_normalize`) and ``q`` scaled by
+    ``d_k^-1/2`` first. The one dispatch point, by shape: on a TPU, in
+    chunks of ``delta_pallas.CHUNK`` and where ``delta_pallas.delta_block``
+    takes the shape (heads of whole lane tiles), the chunk loop runs as
+    ``ops/delta_pallas.py``'s two kernels, which read the raw columns as they
+    are and norm ``q`` and ``k`` themselves, the state in VMEM across the
+    chunks; everywhere else XLA norms them and runs
+    :func:`chunked_delta_rule`."""
+    B, T, H = beta.shape
+    keys = key_heads * d_k
+    dv = (qkv.shape[-1] - 2 * keys) // H
+    if (chunk == delta_pallas.CHUNK and _auto_pallas() and delta_pallas.delta_block(
+            T, H, key_heads, d_k, dv, DELTA_SEGMENT) is not None):
+        with jax.named_scope(scopes.DELTA_CHUNKS):
+            return delta_pallas.delta_rule(qkv, beta, g, key_heads, d_k,
+                                           DELTA_SEGMENT, cdt, DELTA_OUT)
+    q, k = (l2_normalize(qkv[..., i * keys: (i + 1) * keys]
+                         .reshape(B, T, key_heads, d_k)) for i in (0, 1))
+    q, v = q * d_k ** -0.5, qkv[..., 2 * keys:].reshape(B, T, H, dv)
+    with jax.named_scope(scopes.DELTA_CHUNKS):
+        return chunked_delta_rule(q, k, v, beta, g, chunk=chunk, cdt=cdt)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -1256,24 +1277,21 @@ class GatedDeltaNet(nn.Module):
         qkvz = _mm(a, w_qkvz, cdt)
         ba = _mm(a, w_ba, cdt)
 
-        # the convolution, the norms and the gates are recomputed where the
-        # backward pass needs them: kept, their float32 [T, 8192] passes
-        # would be live beside the rule's own
+        # the convolution and the gates are recomputed where the backward
+        # pass needs them: kept, their float32 [T, 8192] passes would be live
+        # beside the rule's own
         @jax.checkpoint
         def rule_inputs(qkv, ba, filt, a_log, dt_bias):
             mixed = jax.nn.silu(causal_conv(qkv, filt.astype(jnp.float32)))
             beta = jax.nn.sigmoid(ba[..., :nv])
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
                 ba[..., nv:] + dt_bias.astype(jnp.float32))
-            q, k = (l2_normalize(mixed[..., i * keys: (i + 1) * keys]
-                                 .reshape(B, T, nk, dk)) for i in (0, 1))
-            v = mixed[..., 2 * keys:].reshape(B, T, nv, dv)
-            return q * dk ** -0.5, k, v, beta, g
+            return mixed, beta, g
 
-        q, k, v, beta, g = rule_inputs(qkvz[..., : 2 * keys + values], ba,
-                                       filt, a_log, dt_bias)
-        with jax.named_scope(scopes.DELTA_CHUNKS):
-            o = chunked_delta_rule(q, k, v, beta, g, chunk=DELTA_CHUNK, cdt=cdt)
+        # the raw columns [q | k | v], flat: the rule norms q and k
+        mixed, beta, g = rule_inputs(qkvz[..., : 2 * keys + values], ba, filt,
+                                     a_log, dt_bias)
+        o = gated_delta_rule(mixed, beta, g, nk, dk, chunk=DELTA_CHUNK, cdt=cdt)
         norm = RMSNorm(self.eps, name="norm")(o, weight_only=True)
 
         @jax.checkpoint  # the same for the gated norm's float32 passes

@@ -10,11 +10,21 @@ loop is the grid's last axis, walked in order, and the state lives in VMEM
 scratch across a sequence's chunks; the arithmetic of a chunk is
 ``chunked_delta_rule``'s, written out for one head.
 
+**Inputs**: the raw columns ``[q | k | v]`` of Gated DeltaNet's convolution,
+one float32 array ``[B, T, 2 K d_k + H d_v]``, flat as the convolution writes
+them. Each kernel reads ``q``, ``k`` and ``v`` as column blocks of that one
+array (three block specs offset by the columns' positions: nothing is sliced
+or laid out head-major in HBM) and l2-normalises a chunk of each key head in
+VMEM with ``models/afmoe.py l2_normalize``'s arithmetic, ``x rsqrt(sum x^2 +
+NORM_EPS)`` in float32, the query's ``d_k^-1/2`` folded into its factor. A
+head is one 128-lane column block of the tile: its squared norm is a lane
+reduction, its factor a lane broadcast.
+
 **Forward** (``DELTA_FWD``): grid ``(B, H / hb, chunks)``; a step holds one
 sequence's chunk of ``hb`` value heads, reads ``q`` and ``k`` as the 128-lane
-column blocks of ``[B, T, K * d_k]`` that those heads read (value head ``h``
-reads key head ``h // (H / K)``: no head is repeated in HBM), ``v`` and writes
-``o`` as column blocks of ``[B, T, H * d_v]``, and takes the gates and their
+column blocks of the key heads those heads read (value head ``h`` reads key
+head ``h // (H / K)``: no head is repeated in HBM), ``v`` and writes ``o`` as
+column blocks of ``[B, T, H * d_v]``, and takes the gates and their
 chunk-wise cumulative sums as ``[B, chunks, C, H]`` (all heads: a step picks
 its own by a one-hot product). At every segment's first chunk it writes the
 state it starts from, the backward pass's residual (the states the XLA path's
@@ -24,9 +34,12 @@ checkpoint keeps).
 the segments in reverse; a segment's first ``segment`` steps rebuild its chunk
 states from the edge state into VMEM scratch, and keep each chunk's triangular
 inverse there too; the next ``segment`` walk its chunks backwards carrying
-``dS`` in VMEM and write the cotangents of ``q``, ``k`` (summed over the value
-heads that read a key head), ``v``, ``beta`` and the cumulative gates; XLA
-turns the last into the gates' cotangent (a reverse cumulative sum). The
+``dS`` in VMEM and write the cotangents of the raw ``q`` and ``k`` (summed
+over the value heads that read a key head, then through the norm: ``dx = r
+(dx' - x' <x', dx'>)`` for ``x' = x r``), ``v``, ``beta`` and the cumulative
+gates; both phases norm ``q`` and ``k`` from the raw chunk again. XLA joins
+the three column cotangents into that of the convolution's output and turns
+the gates' into the cotangent of ``g`` (a reverse cumulative sum). The
 forward's output and edge states carry a checkpoint name, so that a block's
 checkpoint that keeps them never runs the forward kernel twice. The blocked algorithm is the WY form of flash-linear-attention's
 ``chunk_gated_delta_rule`` (a state pass forward, a ``dS`` pass backward); the
@@ -74,6 +87,7 @@ VMEM_BUDGET = 14 << 20
 # the body's own temporaries: at eight heads a step the compiler's scoped
 # stack for the backward kernel held 5 MB more than the blocks and scratch
 WORK_BYTES = 5 << 20
+NORM_EPS = 1e-6  # ``models/afmoe.py l2_normalize``'s
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -116,16 +130,19 @@ def delta_block(t: int, heads: int, key_heads: int, d_k: int, d_v: int,
     """Value heads a grid step holds, or None where the kernels do not take
     the call: a head is no whole number of lane tiles, the value heads are no
     whole number of groups of the key heads, or no block of HEAD_BLOCKS holds
-    whole groups and fits. A pure function of the call's shape, the chooser
-    and the dispatch predicate at once: 4 at the Qwen3-Next cell (32 value
-    heads, 16 key heads of 128, segments of 16 chunks), 8 where a sequence
-    is one segment of 4 chunks. ``segment`` is the chunks of one segment,
-    as many as ``t`` has up to that."""
+    whole groups, starts ``v``'s columns at a whole block of ``[q | k | v]``
+    (the kernels read all three from that one array) and fits. A pure
+    function of the call's shape, the chooser and the dispatch predicate at
+    once: 4 at the Qwen3-Next cell (32 value heads, 16 key heads of 128,
+    segments of 16 chunks), 8 where a sequence is one segment of 4 chunks.
+    ``segment`` is the chunks of one segment, as many as ``t`` has up to
+    that."""
     if d_k % LANES or d_v % LANES or heads % key_heads or t < 1:
         return None
     per, segment = heads // key_heads, _segment(t, segment)
     return next((hb for hb in HEAD_BLOCKS
                  if heads % hb == 0 and hb % per == 0
+                 and 2 * key_heads * d_k % (hb * d_v) == 0
                  and block_vmem_bytes(hb, heads, key_heads, d_k, d_v, segment)
                  <= VMEM_BUDGET), None)
 
@@ -243,12 +260,33 @@ def _iotas(n):
             jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
 
 
+def _unit(x, scale=1.0):
+    """``x rsqrt(sum x^2 + NORM_EPS)`` over the lanes of a raw head ``x [C,
+    d]``, times ``scale`` folded into the row's factor, and the factor ``r =
+    rsqrt(...)`` itself ``[C, 1]``."""
+    r = jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + NORM_EPS)
+    return x * (r * scale), r
+
+
+def _raw_cotangent(x, r, dy, scale=1.0):
+    """The cotangent of the raw head ``x`` from that of ``y = x r scale``
+    (:func:`_unit`): ``r (d - u <u, d>)`` with ``u = x r``, ``d = dy
+    scale``."""
+    u, d = x * r, dy * scale
+    return r * (d - u * jnp.sum(u * d, axis=1, keepdims=True))
+
+
 def _groups(q_ref, k_ref, dk, per, with_q=True):
-    """``(j, q, k, k^T, k k^T, value heads)`` of each key head in the block."""
+    """``(q, k, k^T, k k^T, value heads, raw)`` of each key head in the
+    block: ``q`` and ``k`` l2-normed from the raw columns, ``q`` scaled by
+    ``d_k^-1/2``; ``raw = (q, q's factor, k, k's factor)`` as read."""
     for j in range(k_ref.shape[1] // dk):
-        k = k_ref[:, j * dk:(j + 1) * dk]
-        q = q_ref[:, j * dk:(j + 1) * dk] if with_q else None
-        yield j, q, k, k.T, _dot_nt(k, k), range(j * per, (j + 1) * per)
+        k_raw = k_ref[:, j * dk:(j + 1) * dk]
+        k, rk = _unit(k_raw)
+        q_raw = q_ref[:, j * dk:(j + 1) * dk] if with_q else None
+        q, rq = _unit(q_raw, dk ** -0.5) if with_q else (None, None)
+        yield (q, k, k.T, _dot_nt(k, k), range(j * per, (j + 1) * per),
+               (q_raw, rq, k_raw, rk))
 
 
 # -- the kernels ---------------------------------------------------------------
@@ -278,7 +316,7 @@ def _fwd_kernel(cdt, segment, q_ref, k_ref, v_ref, b_ref, g_ref, o_ref,
     rows, cols = _iotas(n)
     beta, gam, gam_t = _gates(b_ref, g_ref, hb, pl.program_id(1) * hb)
     states, outs = [s_ref[h] for h in range(hb)], []
-    for _, q, k, k_t, kk, heads in _groups(q_ref, k_ref, dk, per):
+    for q, k, k_t, kk, heads, _ in _groups(q_ref, k_ref, dk, per):
         qk = _dot_nt(cast(q), cast(k), None)
         for h in heads:
             ch = _Chunk(kk, k, v_ref[:, h * dv:(h + 1) * dv], beta[:, h:h + 1],
@@ -318,7 +356,7 @@ def _bwd_kernel(cdt, segment, q_ref, k_ref, v_ref, b_ref, g_ref, edge_ref,
     def _():  # rebuild the segment's chunk states, keep the chunks' inverses
         beta, gam, gam_t = _gates(b_ref, g_ref, hb, first)
         states, inverses, after = states_ref[w], [], []
-        for _, _, k, k_t, kk, heads in _groups(q_ref, k_ref, dk, per, False):
+        for _, k, k_t, kk, heads, _ in _groups(q_ref, k_ref, dk, per, False):
             for h in heads:
                 ch = _Chunk(kk, k, v_ref[:, h * dv:(h + 1) * dv],
                             beta[:, h:h + 1], gam[:, h:h + 1],
@@ -342,7 +380,7 @@ def _bwd_kernel(cdt, segment, q_ref, k_ref, v_ref, b_ref, g_ref, edge_ref,
         dg_row = jnp.zeros((hb, n), jnp.float32)
         states, ds_in, inverses = states_ref[ci], ds_ref[...], t_ref[ci]
         ds_out, dvs, dqs, dks = [], [], [], []
-        for _, q, k, k_t, kk, heads in _groups(q_ref, k_ref, dk, per):
+        for q, k, k_t, kk, heads, raw in _groups(q_ref, k_ref, dk, per):
             qk = _dot_nt(cast(q), cast(k), None)
             dq = dk_acc = jnp.zeros_like(k)
             dkk = dqk = jnp.zeros_like(kk)
@@ -394,9 +432,14 @@ def _bwd_kernel(cdt, segment, q_ref, k_ref, v_ref, b_ref, g_ref, edge_ref,
                 dq = dq + dqe * ch.e
                 dk_acc = dk_acc + dbk * (b_h * ch.e) + dkf * ch.f
                 dvs.append(dbv * b_h)
-            dqs.append(dq + _dot(cast(dqk), cast(k), None))
-            dks.append(dk_acc + _dot_tn(cast(dqk), cast(q), None)
-                       + _dot(dkk, k) + _dot(dkk.T, k))
+            # the cotangents of the normed q and k, complete for the key
+            # head, go through the norm to the raw columns
+            dq = dq + _dot(cast(dqk), cast(k), None)
+            dk_acc = (dk_acc + _dot_tn(cast(dqk), cast(q), None) + _dot(dkk, k)
+                      + _dot(dkk.T, k))
+            q_raw, rq, k_raw, rk = raw
+            dqs.append(_raw_cotangent(q_raw, rq, dq, dk ** -0.5))
+            dks.append(_raw_cotangent(k_raw, rk, dk_acc))
         ds_ref[...] = jnp.stack(ds_out)
         dv_ref[...] = jnp.concatenate(dvs, axis=1)
         dq_ref[...] = jnp.concatenate(dqs, axis=1)
@@ -413,12 +456,12 @@ def _vmem(shape, index):
     return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
 
-def _layout(q, k, v, beta, g, per):
+def _layout(qkv, beta, g, per):
     """Pad ``T`` to whole segments of chunks (positions that change nothing
-    before them: ``beta = g = 0``) and lay the operands out as the kernels
-    read them: ``q, k [B, T', K d_k]``, ``v [B, T', H d_v]``, ``beta`` and
-    the chunk-wise cumulative gates ``[B, chunks, C, H]``."""
-    B, T, H, dv = v.shape
+    before them: ``beta = g = 0``; raw columns of zeros norm to zeros) and
+    lay the gates out as the kernels read them: ``beta`` and the chunk-wise
+    cumulative gates ``[B, chunks, C, H]``."""
+    B, T, H = beta.shape
     tp = -(-T // (CHUNK * per)) * CHUNK * per
 
     def pad(x):
@@ -426,23 +469,25 @@ def _layout(q, k, v, beta, g, per):
 
     chunks = (B, tp // CHUNK, CHUNK, H)
     gam = jnp.cumsum(pad(g).reshape(chunks), axis=2)
-    return (pad(q).reshape(B, tp, -1), pad(k).reshape(B, tp, -1),
-            pad(v).reshape(B, tp, -1), pad(beta).reshape(chunks), gam)
+    return pad(qkv), pad(beta).reshape(chunks), gam
 
 
-def _fwd_call(q, k, v, beta, gam, heads, dk, dv, segment, cdt):
-    B, tp, keys = q.shape
+def _fwd_call(qkv, beta, gam, heads, keys, dk, dv, segment, cdt):
+    B, tp, _ = qkv.shape
     hb = delta_block(tp, heads, keys // dk, dk, dv, segment)
-    kb, chunks = hb * keys // (heads * dk), tp // CHUNK
-    col = _vmem((None, CHUNK, kb * dk), lambda b, j, c: (b, c, j))
-    val = _vmem((None, CHUNK, hb * dv), lambda b, j, c: (b, c, j))
+    kw, vw, chunks = hb * keys // heads, hb * dv, tp // CHUNK
+
+    def col(width, first=0):  # blocks of ``width`` columns from block ``first``
+        return _vmem((None, CHUNK, width), lambda b, j, c: (b, c, first + j))
+
     gates = _vmem((None, None, CHUNK, heads), lambda b, j, c: (b, c, 0, 0))
     edge = _vmem((None, hb, None, dk, dv), lambda b, j, c: (b, j, c // segment, 0, 0))
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, cdt, segment),
         grid=(B, heads // hb, chunks),
-        in_specs=[col, col, val, gates, gates],
-        out_specs=[val, edge],
+        in_specs=[col(kw), col(kw, keys // kw), col(vw, 2 * keys // vw), gates,
+                  gates],
+        out_specs=[col(vw), edge],
         out_shape=[jax.ShapeDtypeStruct((B, tp, heads * dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, heads, chunks // segment, dk, dv),
                                         jnp.float32)],
@@ -452,14 +497,14 @@ def _fwd_call(q, k, v, beta, gam, heads, dk, dv, segment, cdt):
         interpret=_interpret(),
         name=DELTA_FWD,
     )
-    return call(q, k, v, beta, gam)
+    return call(qkv, qkv, qkv, beta, gam)
 
 
-def _bwd_call(q, k, v, beta, gam, edges, do, heads, segment, cdt):
-    B, tp, keys = q.shape
+def _bwd_call(qkv, beta, gam, edges, do, heads, keys, segment, cdt):
+    B, tp, _ = qkv.shape
     dk, dv = edges.shape[-2:]
     hb = delta_block(tp, heads, keys // dk, dk, dv, segment)
-    kb, segments = hb * keys // (heads * dk), tp // (CHUNK * segment)
+    kw, vw, segments = hb * keys // heads, hb * dv, tp // (CHUNK * segment)
     span = 2 * segment
 
     def chunk(s, back_only=False):  # the chunk step s reads
@@ -469,10 +514,10 @@ def _bwd_call(q, k, v, beta, gam, edges, do, heads, segment, cdt):
             return first + segment - 1 - jnp.maximum(within - segment, 0)
         return first + jnp.where(within < segment, within, span - 1 - within)
 
-    col = _vmem((None, CHUNK, kb * dk), lambda b, j, s: (b, chunk(s), j))
-    q_col = _vmem((None, CHUNK, kb * dk), lambda b, j, s: (b, chunk(s, True), j))
-    val = _vmem((None, CHUNK, hb * dv), lambda b, j, s: (b, chunk(s), j))
-    back_val = _vmem((None, CHUNK, hb * dv), lambda b, j, s: (b, chunk(s, True), j))
+    def col(width, first=0, back_only=False):
+        return _vmem((None, CHUNK, width),
+                     lambda b, j, s: (b, chunk(s, back_only), first + j))
+
     gates = _vmem((None, None, CHUNK, heads), lambda b, j, s: (b, chunk(s), 0, 0))
     edge = _vmem((None, hb, None, dk, dv),
                  lambda b, j, s: (b, j, segments - 1 - s // span, 0, 0))
@@ -483,9 +528,11 @@ def _bwd_call(q, k, v, beta, gam, edges, do, heads, segment, cdt):
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, cdt, segment),
         grid=(B, heads // hb, segments * span),
-        in_specs=[q_col, col, val, gates, gates, edge, back_val],
-        out_specs=[q_col, q_col, back_val, rows, rows],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32)] * 2
+        in_specs=[col(kw, 0, True), col(kw, keys // kw), col(vw, 2 * keys // vw),
+                  gates, gates, edge, col(vw, 0, True)],
+        out_specs=[col(kw, 0, True), col(kw, 0, True), col(vw, 0, True), rows,
+                   rows],
+        out_shape=[jax.ShapeDtypeStruct((B, tp, keys), jnp.float32)] * 2
         + [jax.ShapeDtypeStruct((B, tp, heads * dv), jnp.float32),
            grads_of_gates, grads_of_gates],
         scratch_shapes=[pltpu.VMEM((segment, hb, dk, dv), jnp.float32),
@@ -496,56 +543,63 @@ def _bwd_call(q, k, v, beta, gam, edges, do, heads, segment, cdt):
         interpret=_interpret(),
         name=DELTA_BWD,
     )
-    return call(q, k, v, beta, gam, edges, do)
+    return call(qkv, qkv, qkv, beta, gam, edges, do)
 
 
-def _forward(q, k, v, beta, g, segment, cdt, name):
-    B, T, H, dv = v.shape
+def _forward(qkv, beta, g, key_heads, dk, segment, cdt, name):
+    B, T, H = beta.shape
+    keys = key_heads * dk
+    dv = (qkv.shape[-1] - 2 * keys) // H
     per = _segment(T, segment)
-    ops = _layout(q, k, v, beta, g, per)
-    o, edges = _fwd_call(*ops, H, k.shape[-1], dv, per, cdt)
-    o = o[:, :T].reshape(v.shape)
+    ops = _layout(qkv, beta, g, per)
+    o, edges = _fwd_call(*ops, H, keys, dk, dv, per, cdt)
+    o = o[:, :T].reshape(B, T, H, dv)
     if name is not None:
         o, edges = checkpoint_name(o, name), checkpoint_name(edges, name)
     return o, (ops, edges)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def delta_rule(q, k, v, beta, g, segment: int, cdt=None,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def delta_rule(qkv, beta, g, key_heads: int, d_k: int, segment: int, cdt=None,
                residual_checkpoint_name: str | None = None):
     """``models/afmoe.py chunked_delta_rule`` in chunks of CHUNK as the two
-    kernels: ``q, k [B, T, K, d_k]``, ``v [B, T, H, d_v]``, ``beta, g [B, T,
-    H]`` float32 -> ``o [B, T, H, d_v]`` float32, the state starting at
-    zero; the backward pass keeps the state at the edges of segments of
+    kernels, on Gated DeltaNet's RAW columns: ``qkv [B, T, 2 K d_k + H
+    d_v]`` = ``[q | k | v]`` as the convolution leaves them (``q`` and ``k``
+    not normed: the kernels norm them, ``q`` scaled by ``d_k^-1/2``), ``K =
+    key_heads``, ``beta, g [B, T, H]``, all float32 -> ``o [B, T, H, d_v]``
+    float32, the state starting at zero; its cotangent is that of the raw
+    columns. The backward pass keeps the state at the edges of segments of
     ``segment`` chunks. The output and those states carry
     ``residual_checkpoint_name``, as the splash kernels' residuals do: a
     checkpoint whose policy saves the name keeps them and does not run the
     forward kernel again. Leading axes are ``vmap``s. The call must satisfy
     :func:`delta_block`."""
-    return _forward(q, k, v, beta, g, segment, cdt, residual_checkpoint_name)[0]
+    return _forward(qkv, beta, g, key_heads, d_k, segment, cdt,
+                    residual_checkpoint_name)[0]
 
 
-def _delta_rule_fwd(q, k, v, beta, g, segment, cdt, residual_checkpoint_name):
-    return _forward(q, k, v, beta, g, segment, cdt, residual_checkpoint_name)
+def _delta_rule_fwd(qkv, beta, g, key_heads, d_k, segment, cdt,
+                    residual_checkpoint_name):
+    return _forward(qkv, beta, g, key_heads, d_k, segment, cdt,
+                    residual_checkpoint_name)
 
 
-def _delta_rule_bwd(segment, cdt, residual_checkpoint_name, res, do):
-    (q, k, v, beta, gam), edges = res  # as the kernels read them, padded
+def _delta_rule_bwd(key_heads, d_k, segment, cdt, residual_checkpoint_name,
+                    res, do):
+    (qkv, beta, gam), edges = res  # as the kernels read them, padded
     B, T, H, dv = do.shape
-    tp, dk = q.shape[1], edges.shape[-2]
+    tp = qkv.shape[1]
     do = jnp.pad(do, ((0, 0), (0, tp - T), (0, 0), (0, 0)))
-    dq, dk_, dv_, db, dgam = _bwd_call(q, k, v, beta, gam, edges,
-                                       do.reshape(B, tp, H * dv), H,
-                                       _segment(T, segment), cdt)
+    dq, dk, dv_, db, dgam = _bwd_call(qkv, beta, gam, edges,
+                                      do.reshape(B, tp, H * dv), H,
+                                      key_heads * d_k, _segment(T, segment), cdt)
 
     def heads_last(x):  # [B, H / hb, chunks, hb, C] -> [B, chunks, C, H]
         return jnp.transpose(x, (0, 2, 4, 1, 3)).reshape(beta.shape)
 
     # G is the cumulative sum of g within a chunk
     dg = jax.lax.cumsum(heads_last(dgam), axis=2, reverse=True)
-    heads = (B, tp, q.shape[-1] // dk, dk)
-    return (dq.reshape(heads)[:, :T], dk_.reshape(heads)[:, :T],
-            dv_.reshape(B, tp, H, dv)[:, :T],
+    return (jnp.concatenate([dq, dk, dv_], axis=-1)[:, :T],
             heads_last(db).reshape(B, tp, H)[:, :T], dg.reshape(B, tp, H)[:, :T])
 
 

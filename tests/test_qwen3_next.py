@@ -216,46 +216,90 @@ def test_the_block_inverse_is_the_inverse(n, alike):
 # -- the delta rule's kernels (ops/delta_pallas.py), in interpret mode -----------
 
 
-def _wide_inputs(t: int, rows: int = 1, gates=None, alike: bool = False):
-    """Heads of 128 (whole lane tiles), two value heads a key head (the
-    cell's grouping); ``alike``: the second chunk holds one key repeated at
-    beta 1, where a power series of the chunk's matrix sums terms of 1e17."""
+def _wide_inputs(t: int, rows: int = 1, gates=None, alike: bool = False,
+                 norm: float = 1.0):
+    """The raw columns ``[q | k | v] [rows, t, 2 * 256 + 4 * 128]`` as the
+    convolution leaves them (``q`` and ``k`` not normed, entries of about
+    ``norm``), ``beta`` and ``g``: heads of 128 (whole lane tiles), two value
+    heads a key head (the cell's grouping); ``alike``: the second chunk holds
+    one key repeated at beta 1, where a power series of the chunk's matrix
+    sums terms of 1e17."""
     keys = jax.random.split(jax.random.PRNGKey(4), 6)
     d, heads = 128, 4
-    q = afmoe.l2_normalize(jax.random.normal(keys[0], (rows, t, 2, d))) * d ** -0.5
-    k = afmoe.l2_normalize(jax.random.normal(keys[1], (rows, t, 2, d)))
-    v = jax.random.normal(keys[2], (rows, t, heads, d))
+    q = norm * jax.random.normal(keys[0], (rows, t, 2 * d))
+    k = norm * jax.random.normal(keys[1], (rows, t, 2 * d))
+    v = jax.random.normal(keys[2], (rows, t, heads * d))
     beta = jax.nn.sigmoid(jax.random.normal(keys[3], (rows, t, heads)))
     g = (-jnp.exp(jax.random.uniform(keys[4], (rows, t, heads), minval=-4, maxval=3))
          if gates is None else jnp.broadcast_to(gates, (rows, t, heads)))
     if alike:
         k = k.at[:, 64:128].set(k[:, 64:65])
         beta = beta.at[:, 64:128].set(1.0)
-    return q, k, v, beta, g
+    return jnp.concatenate([q, k, v], axis=-1), beta, g
 
 
 def _rule_and_grads(kernels: bool, args, w):
-    """The rule's output and the cotangents of ``sum(o * w)``, the kernels
-    taken or not (in interpret mode on this backend)."""
+    """``afmoe.gated_delta_rule`` on raw columns, the kernels taken or not
+    (in interpret mode on this backend): the output and the cotangents of
+    ``sum(o * w)`` for the raw columns, ``beta`` and ``g``."""
     def rule(*a):
-        return afmoe.chunked_delta_rule(*a, chunk=64)
+        return afmoe.gated_delta_rule(*a, 2, 128, chunk=64)
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(afmoe, "_auto_pallas", lambda: kernels)
         assert (delta_pallas.DELTA_FWD in str(jax.make_jaxpr(rule)(*args))) == kernels
         return jax.jit(lambda *a: (rule(*a), jax.grad(
-            lambda *b: jnp.sum(rule(*b) * w), argnums=range(5))(*a)))(*args)
+            lambda *b: jnp.sum(rule(*b) * w), argnums=range(3))(*a)))(*args)
+
+
+def _normed_recurrence(qkv, beta, g):
+    """The reference on raw columns: ``q`` and ``k`` l2-normed (``q`` scaled
+    by ``128^-1/2``), each key head read by its two value heads, the rule
+    position by position."""
+    B, T = beta.shape[:2]
+    q, k = (afmoe.l2_normalize(qkv[..., i * 256:(i + 1) * 256].reshape(B, T, 2, 128))
+            for i in (0, 1))
+    return _recurrence(jnp.repeat(q * 128 ** -0.5, 2, axis=2),
+                       jnp.repeat(k, 2, axis=2),
+                       qkv[..., 512:].reshape(B, T, 4, 128), beta, g)
+
+
+def _the_kernels_against_xla_and_the_recurrence(args, w):
+    """The output and the five cotangents (raw ``q``, ``k``, ``v``, ``beta``
+    and ``g``) of the kernels against XLA's norm and chunked rule and
+    against the position-by-position reference."""
+    out, grads = _rule_and_grads(True, args, w)
+    xla_out, xla_grads = _rule_and_grads(False, args, w)
+    want = _normed_recurrence(*args)
+    with jax.default_matmul_precision("highest"):
+        ref_grads = jax.grad(lambda *a: jnp.sum(_normed_recurrence(*a) * w),
+                             argnums=range(3))(*args)
+    assert bool(jnp.isfinite(out).all())
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(out - xla_out).max()) < 1e-5 * scale
+    assert float(jnp.abs(out - want).max()) < 5e-5 * scale
+
+    def five(cotangents):  # the raw columns' cotangent split into q | k | v
+        qkv, beta, g = cotangents
+        return (qkv[..., :256], qkv[..., 256:512], qkv[..., 512:], beta, g)
+
+    for name, a, x, r in zip("q k v beta g".split(), five(grads), five(xla_grads),
+                             five(ref_grads)):
+        assert bool(jnp.isfinite(a).all()), name
+        top = max(float(jnp.abs(r).max()), 1e-6)
+        assert float(jnp.abs(a - x).max()) < 1e-5 * top, name
+        assert float(jnp.abs(a - r).max()) < 1e-4 * top, name
 
 
 @pytest.mark.parametrize("case", ["ragged-segments", "gates", "alike"])
 def test_the_delta_kernels_are_the_chunked_rule_and_the_recurrence(
         monkeypatch, case):
-    """Both kernels against XLA's chunked rule and the position-by-position
-    reference: the output and the five cotangents. ``ragged-segments``: two
-    sequences of three chunks and a tail of 17 positions, two segments of
-    two chunks, two head blocks of the grid; ``gates``: log-gates of -21
-    mixed with 0 (a head that keeps everything); ``alike``: a chunk of one
-    repeated key at beta 1."""
+    """Both kernels, fed the raw columns, against XLA's norm and chunked
+    rule and the position-by-position reference: the output and the five
+    cotangents. ``ragged-segments``: two sequences of three chunks and a tail
+    of 17 positions, two segments of two chunks, two head blocks of the
+    grid; ``gates``: log-gates of -21 mixed with 0 (a head that keeps
+    everything); ``alike``: a chunk of one repeated key at beta 1."""
     monkeypatch.setattr(afmoe, "DELTA_SEGMENT", 2)
     t, rows, gates = 3 * 64 + 17, 1, None
     if case == "ragged-segments":
@@ -264,24 +308,23 @@ def test_the_delta_kernels_are_the_chunked_rule_and_the_recurrence(
     elif case == "gates":
         gates = jnp.where(jnp.arange(4) % 2 == 1, -21.0, 0.0)
     args = _wide_inputs(t, rows, gates, alike=case == "alike")
-    w = jax.random.normal(jax.random.PRNGKey(8), args[2].shape)
-    out, grads = _rule_and_grads(True, args, w)
-    xla_out, xla_grads = _rule_and_grads(False, args, w)
-    rep = tuple(jnp.repeat(x, 2, axis=2) for x in args[:2])
-    want = _recurrence(*rep, *args[2:])
-    with jax.default_matmul_precision("highest"):
-        ref_grads = jax.grad(lambda *a: jnp.sum(_recurrence(
-            *(jnp.repeat(x, 2, axis=2) for x in a[:2]), *a[2:]) * w),
-            argnums=range(5))(*args)
-    assert bool(jnp.isfinite(out).all())
-    scale = float(jnp.abs(want).max())
-    assert float(jnp.abs(out - xla_out).max()) < 1e-5 * scale
-    assert float(jnp.abs(out - want).max()) < 5e-5 * scale
-    for name, a, x, r in zip("q k v beta g".split(), grads, xla_grads, ref_grads):
-        assert bool(jnp.isfinite(a).all()), name
-        top = max(float(jnp.abs(r).max()), 1e-6)
-        assert float(jnp.abs(a - x).max()) < 1e-5 * top, name
-        assert float(jnp.abs(a - r).max()) < 1e-4 * top, name
+    w = jax.random.normal(jax.random.PRNGKey(8), (rows, t, 4, 128))
+    _the_kernels_against_xla_and_the_recurrence(args, w)
+
+
+@pytest.mark.parametrize("case", ["large-norm", "tiny-norm", "alike"])
+def test_the_kernels_norm_the_raw_columns_as_l2_normalize_does(monkeypatch, case):
+    """The norm inside the kernels (``x rsqrt(sum x^2 + 1e-6)``, the query's
+    scale in its factor, and its transpose to the raw columns' cotangents)
+    against ``l2_normalize`` in XLA and the reference, over a chunk and a
+    tail: ``large-norm``, rows of norm about 11,000; ``tiny-norm``, rows
+    whose squared norm, about 1.3e-6, is of the order of ``eps``;
+    ``alike``, a chunk of one repeated raw key at beta 1."""
+    monkeypatch.setattr(afmoe, "DELTA_SEGMENT", 2)
+    norm = {"large-norm": 1e3, "tiny-norm": 1e-4}.get(case, 1.0)
+    args = _wide_inputs(64 + 9, norm=norm, alike=case == "alike")
+    w = jax.random.normal(jax.random.PRNGKey(9), (1, 64 + 9, 4, 128))
+    _the_kernels_against_xla_and_the_recurrence(args, w)
 
 
 def test_delta_blocks_at_the_cells_shapes():
@@ -289,8 +332,11 @@ def test_delta_blocks_at_the_cells_shapes():
     heads a grid step at the Qwen3-Next cell (eight do not fit beside
     sixteen chunk states and inverses a head), eight where a sequence is one
     segment of four chunks; None at 64-wide heads, where a block cannot hold
-    whole key groups, or where value heads are no whole groups of the key
-    heads."""
+    whole key groups, where value heads are no whole groups of the key
+    heads, or where no block that holds whole groups starts ``v``'s columns
+    at a whole block of the raw ``[q | k | v]`` array (one key head of 128
+    and four value heads: ``v`` starts at column 256, a block of four value
+    heads is 512 wide)."""
     cell = (32, 16, 128, 128, 16)
     assert delta_pallas.delta_block(8192, *cell) == 4
     assert (delta_pallas.block_vmem_bytes(8, *cell) > delta_pallas.VMEM_BUDGET
@@ -299,24 +345,33 @@ def test_delta_blocks_at_the_cells_shapes():
     assert delta_pallas.delta_block(8192, 32, 16, 64, 64, 16) is None
     assert delta_pallas.delta_block(8192, 32, 2, 128, 128, 16) is None
     assert delta_pallas.delta_block(8192, 3, 2, 128, 128, 16) is None
+    assert delta_pallas.delta_block(8192, 4, 1, 128, 128, 16) is None
 
 
 def test_the_kernels_are_taken_by_shape_alone():
-    """On a TPU backend the rule runs as the kernels where ``delta_block``
-    takes the shape and chunks are 64; the toy model's 16-wide heads and
-    chunks of 8 stay XLA's."""
+    """On a TPU backend ``gated_delta_rule`` hands the raw columns to the
+    kernels where ``delta_block`` takes the shape and chunks are 64; the toy
+    model's 16-wide heads and chunks of 8 stay XLA's (``l2_normalize``, then
+    ``chunked_delta_rule``, which never calls a kernel)."""
     wide = _wide_inputs(64)
-    narrow = _rule_inputs(64)
+    q, k, v, beta, g = _rule_inputs(64)
+    narrow = (jnp.concatenate([x.reshape(2, 64, -1) for x in (q, k, v)], -1),
+              beta, g)
 
-    def calls(args, chunk):
-        return str(jax.make_jaxpr(lambda *a: afmoe.chunked_delta_rule(
-            *a, chunk=chunk))(*args)).count("pallas_call")
+    def calls(args, heads, width, chunk):
+        return str(jax.make_jaxpr(lambda *a: afmoe.gated_delta_rule(
+            *a, heads, width, chunk=chunk))(*args)).count("pallas_call")
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(afmoe, "_auto_pallas", lambda: True)
-        assert calls(wide, 64) == 1
-        assert calls(wide, 32) == calls(narrow, 64) == 0
-    assert calls(wide, 64) == 0  # not a TPU
+        assert calls(wide, 2, 128, 64) == 1
+        assert calls(wide, 2, 128, 32) == calls(narrow, 3, 8, 64) == 0
+        qkv, beta, g = wide
+        heads = [qkv[..., i:i + n].reshape(1, 64, n // 128, 128)
+                 for i, n in ((0, 256), (256, 256), (512, 512))]
+        assert "pallas_call" not in str(jax.make_jaxpr(
+            lambda *a: afmoe.chunked_delta_rule(*a, chunk=64))(*heads, beta, g))
+    assert calls(wide, 2, 128, 64) == 0  # not a TPU
 
 
 def test_the_linear_layer_against_the_reference():
@@ -344,6 +399,57 @@ def test_the_linear_layer_against_the_reference():
     with jax.default_matmul_precision("highest"):
         want = jax.jit(jax.vmap(lambda row: ref.linear_attention(params, row, dims)))(a)
     assert float(jnp.abs(got - want).max()) < 5e-5 * float(jnp.abs(want).max())
+
+
+def _layer_before(params, a, cdt, nk=2, nv=4, dk=16, dv=16, eps=1e-6):
+    """``GatedDeltaNet`` as it was before the kernels took the raw columns:
+    ``q`` and ``k`` l2-normed in XLA beside the convolution and the gates,
+    under their checkpoint, then ``chunked_delta_rule`` and the gated norm."""
+    B, T, _ = a.shape
+    keys, values = nk * dk, nv * dv
+    qkvz = afmoe._mm(a, params["in_proj_qkvz"], cdt)
+    ba = afmoe._mm(a, params["in_proj_ba"], cdt)
+
+    @jax.checkpoint
+    def rule_inputs(qkv, ba, filt, a_log, dt_bias):
+        mixed = jax.nn.silu(afmoe.causal_conv(qkv, filt.astype(jnp.float32)))
+        beta = jax.nn.sigmoid(ba[..., :nv])
+        g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+            ba[..., nv:] + dt_bias.astype(jnp.float32))
+        q, k = (afmoe.l2_normalize(mixed[..., i * keys: (i + 1) * keys]
+                                   .reshape(B, T, nk, dk)) for i in (0, 1))
+        v = mixed[..., 2 * keys:].reshape(B, T, nv, dv)
+        return q * dk ** -0.5, k, v, beta, g
+
+    q, k, v, beta, g = rule_inputs(qkvz[..., : 2 * keys + values], ba,
+                                   params["conv1d"], params["A_log"],
+                                   params["dt_bias"])
+    o = afmoe.chunked_delta_rule(q, k, v, beta, g, chunk=afmoe.DELTA_CHUNK,
+                                 cdt=cdt)
+
+    @jax.checkpoint
+    def gated(o, z, norm):
+        return afmoe.rms_norm(o, norm, eps) * jax.nn.silu(z)
+
+    o = gated(o, qkvz[..., 2 * keys + values:].reshape(B, T, nv, dv),
+              params["norm"]["scale"])
+    return afmoe._mm(o.reshape(B, T, values), params["out_proj"], cdt)
+
+
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+def test_off_the_kernels_the_layer_is_what_it_was_to_the_digit(compute_dtype):
+    """Where the kernels do not take the shape (here: 16-wide heads on the
+    CPU) the layer hands its raw columns to ``gated_delta_rule``, which norms
+    ``q`` and ``k`` in XLA: the output is the one the layer gave when it
+    normed them itself, to the digit."""
+    layer = afmoe.GatedDeltaNet(2, 4, 16, 16, 4, 1e-6, compute_dtype=compute_dtype)
+    a = jax.random.normal(jax.random.PRNGKey(4), (2, 40, HIDDEN))
+    params = jax.tree.map(lambda w: w * 10.0 if w.ndim == 2 else w,
+                          layer.init(jax.random.PRNGKey(5), a)["params"])
+    cdt = afmoe.compute_dtype_of(compute_dtype)
+    got = jax.jit(layer.apply)({"params": params}, a)
+    want = jax.jit(lambda p, x: _layer_before(p, x, cdt))(params, a)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- gated attention over partial rotary -----------------------------------------
@@ -633,16 +739,20 @@ ACCEPTED = {
 }
 
 
-@pytest.mark.parametrize("model_type", sorted(ACCEPTED))
-def test_the_accepted_types_keep_their_expert_layers(model_type):
+def _accepted_model(model_type):
     cfg = TrainConfig(task_id=NNComputation.TASK_LM).with_overrides({"lm_args": {
         **{k: v for k, v in TOY.items() if not k.startswith(("linear_", "partial_"))
            and k != "global_attn_every_n_layers"},
         "model_type": model_type, "num_hidden_layers": 2, "sliding_window": 8,
         "head_dim": 16, "layer_types": (), **ACCEPTED[model_type]}})
     model = get_task(cfg.task_id).build_model(cfg)
-    params = jax.eval_shape(lambda: model.init(
+    return model, jax.eval_shape(lambda: model.init(
         {"params": jax.random.PRNGKey(0)}, tokens(9), train=True))["params"]
+
+
+@pytest.mark.parametrize("model_type", sorted(ACCEPTED))
+def test_the_accepted_types_keep_their_expert_layers(model_type):
+    model, params = _accepted_model(model_type)
     moe = sorted(params["layer_1"]["moe"])
     want = ["router", "w1", "w2", "w3"]
     if model_type != "smallthinker":
@@ -650,6 +760,26 @@ def test_the_accepted_types_keep_their_expert_layers(model_type):
     if model_type in ("afmoe", "glm4_moe_lite"):
         want.append("shared")
     assert moe == sorted(want)
+
+
+@pytest.mark.parametrize("model_type", sorted(ACCEPTED))
+def test_the_accepted_types_never_reach_the_delta_rule(monkeypatch, model_type):
+    """The four accepted types' loss and its gradient trace with Gated
+    DeltaNet, the delta rule, its norm and its kernels taken away: nothing of
+    them is in those programs, so a change to them leaves the programs as
+    they were."""
+    def refuse(*args, **kwargs):
+        raise AssertionError("reached the delta rule")
+
+    for name in ("GatedDeltaNet", "gated_delta_rule", "chunked_delta_rule",
+                 "l2_normalize"):
+        monkeypatch.setattr(afmoe, name, refuse)
+    monkeypatch.setattr(delta_pallas, "delta_rule", refuse)
+    model, params = _accepted_model(model_type)
+    x = tokens(9)
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: model.task_loss(
+        {"params": p}, x, jnp.ones(x.shape[0]))))(params))
+    assert delta_pallas.DELTA_FWD not in jaxpr and delta_pallas.DELTA_BWD not in jaxpr
 
 
 # -- the comparison notices a missing term ---------------------------------------

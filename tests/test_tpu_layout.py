@@ -492,16 +492,11 @@ def test_lstm_kernels_compile_with_the_block_lstm_block_chooses(
 # -- the gated delta rule's kernels at the Qwen3-Next cell's shapes ------------
 
 
-def test_the_delta_rule_runs_as_one_kernel_each_way(one_chip, no_compile_cache,
-                                                    monkeypatch):
-    """One Gated DeltaNet layer of ``qwen3-next-80b-a3b-ep32.dsgd-fold2``
-    (2 sites under the site ``vmap``, T 8,192, 16 key heads and 32 value
-    heads of 128), forward and backward under the block's checkpoint, with
-    the kernel path steered on as it is on the chip: both kernels compile
-    (their blocks fit the 16 MiB the compiler scopes a kernel to), the rule
-    is Mosaic calls named ``delta_fwd`` / ``delta_bwd`` and no ``while``
-    over chunks, and the compiler's plan for the layer is below the XLA
-    scan's (4,141,142,016 bytes, the same layer before the kernels)."""
+def _delta_layer(one_chip, monkeypatch):
+    """One Gated DeltaNet layer of ``qwen3-next-80b-a3b-ep32.dsgd-fold2`` (2
+    sites under the site ``vmap``, T 8,192, 16 key heads and 32 value heads
+    of 128), forward and backward under the block's checkpoint, with the
+    kernel path steered on as it is on the chip, compiled."""
     from dinunet_implementations_tpu.models import afmoe
     from dinunet_implementations_tpu.ops import delta_pallas
 
@@ -519,12 +514,65 @@ def test_the_delta_rule_runs_as_one_kernel_each_way(one_chip, no_compile_cache,
         run = jax.checkpoint(layer.apply, policy=afmoe.BLOCK_KEEPS)
         return jnp.sum(jax.vmap(run, in_axes=(None, 0))(p, a))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         jax.tree.map(put, params),
         put(jnp.zeros((2, 1, 8192, 2048), jnp.float32))).compile()
+
+
+def test_the_delta_rule_runs_as_one_kernel_each_way(one_chip, no_compile_cache,
+                                                    monkeypatch):
+    """One Gated DeltaNet layer of ``qwen3-next-80b-a3b-ep32.dsgd-fold2``
+    (2 sites under the site ``vmap``, T 8,192, 16 key heads and 32 value
+    heads of 128), forward and backward under the block's checkpoint, with
+    the kernel path steered on as it is on the chip: both kernels compile
+    (their blocks fit the 16 MiB the compiler scopes a kernel to), the rule
+    is Mosaic calls named ``delta_fwd`` / ``delta_bwd`` and no ``while``
+    over chunks, and the compiler's plan for the layer is below the XLA
+    scan's (4,141,142,016 bytes, the same layer before the kernels)."""
+    from dinunet_implementations_tpu.ops import delta_pallas
+
+    compiled = _delta_layer(one_chip, monkeypatch)
     text = compiled.as_text()
     for name in delta_pallas.KERNEL_NAMES:
         assert re.search(r"^ +%%[\w.]*%s[\w.]* = .*tpu_custom_call" % name,
                          text, re.M), name
     assert not re.search(r"^ +%[\w.\-]+ = .* while\(", text, re.M)
     assert compiled.memory_analysis().peak_memory_in_bytes < 4_141_142_016
+
+
+def test_the_delta_kernels_read_the_convolutions_columns_raw(
+        one_chip, no_compile_cache, monkeypatch):
+    """The same layer: both kernels read ``q``, ``k`` and ``v`` as column
+    blocks of the convolution's own output, float32 ``[.., 8192]`` flat as
+    its fusion writes it (the three operands are that one instruction); the
+    backward kernel's three column cotangents go straight into a fusion (no
+    relayout takes them); no ``reshape`` or ``copy`` writes the key heads
+    head-major (float32 ``[.., 16, 128]``), where l2-norming ``q`` and ``k``
+    in XLA laid them out; and the layer's plan is not above what it was with
+    the norm in XLA (4,032,106,496 bytes)."""
+    from dinunet_implementations_tpu.ops import delta_pallas
+
+    compiled = _delta_layer(one_chip, monkeypatch)
+    text = compiled.as_text()
+    calls = {}
+    for name in delta_pallas.KERNEL_NAMES:
+        found = re.findall(r"^ +%%([\w.]*%s[\w.]*) = .*? custom-call\(%%([\w.\-]+), "
+                           r"%%([\w.\-]+), %%([\w.\-]+),.*tpu_custom_call"
+                           % name, text, re.M)
+        assert len(found) == 1, (name, found)
+        calls[name], q, k, v = found[0]
+        assert q == k == v, (name, q, k, v)
+        assert re.search(r"^ +%%%s = f32\[2,1,8192,8192\]\S* fusion\("
+                         % re.escape(q), text, re.M), (name, q)
+    outs = re.findall(r"^ +%%([\w.\-]+) = \S+ get-tuple-element\(%%%s\), "
+                      r"index=[012]\b" % re.escape(calls[delta_pallas.DELTA_BWD]),
+                      text, re.M)
+    assert len(outs) == 3, outs
+    for out in outs:
+        users = [line for line in text.splitlines()
+                 if re.search(r"%%%s[,)]" % re.escape(out), line)
+                 and not line.lstrip().startswith("%%%s " % out)]
+        assert users and all(re.search(r"\sfusion\(%", u) for u in users), users
+    assert not re.search(r"^ +%(reshape|copy)[\w.\-]* = f32\[[\d,]*16,128\]",
+                         text, re.M)
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 4_032_106_496
